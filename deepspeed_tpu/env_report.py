@@ -42,7 +42,7 @@ def op_report():
         print(name, dots, installed, "..", compatible)
     print("-" * 64)
     print("trace-time kernels (no prebuild needed):")
-    print("  flash_attention ......... Pallas (TPU) / interpret (CPU)")
+    print("  flash_attention ......... Pallas (TPU)")
     print("  block_sparse_attention .. Pallas masked-flash")
     print("  fused train step ........ XLA fusion of loss/grad/update")
     print("-" * 64)
@@ -119,7 +119,9 @@ def feature_report():
         on_tpu = jax.devices()[0].platform == "tpu"
         rows.append(("Pallas flash attention",
                      SUCCESS if on_tpu else
-                     f"{SUCCESS} interpret mode (no TPU attached)"))
+                     f"{WARNING} no TPU attached (the Pallas "
+                     "interpreter checks kernel logic in tests; it "
+                     "is not a way to run the model)"))
     except Exception as e:  # ds-lint: allow[BROADEXC] environment probe: the failure text IS the report row
         rows.append(("Pallas flash attention", f"{FAIL} {e}"))
     try:

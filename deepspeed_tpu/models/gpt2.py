@@ -27,6 +27,9 @@ import numpy as np
 from jax.sharding import PartitionSpec
 
 from deepspeed_tpu.ops import overlap as _overlap
+from deepspeed_tpu.ops.transformer.flash_attention import (
+    dense_attention, flash_attention, flash_attention_rematerializable,
+    flash_attention_usable)
 from deepspeed_tpu.runtime.mesh import EXPERT_AXIS, MODEL_AXIS
 
 
@@ -166,7 +169,6 @@ def _dense(features, config, name, init_scale=1.0):
 def causal_attention_xla(q, k, v, dropout_rng=None, dropout_rate=0.0,
                          deterministic=True):
     """Plain XLA causal attention (shared dense_attention under the hood)."""
-    from deepspeed_tpu.ops.transformer.flash_attention import dense_attention
     return dense_attention(q, k, v, causal=True, dropout_rate=dropout_rate,
                            dropout_rng=dropout_rng,
                            deterministic=deterministic)
@@ -192,23 +194,17 @@ def _attention(config, q, k, v, dropout_rng, deterministic):
                   axis_name=config.sp_axis, causal=True,
                   head_packing=config.attention_head_packing)
     if config.attention_impl in ("pallas", "auto"):
-        try:
-            from deepspeed_tpu.ops.transformer.flash_attention import (
-                flash_attention_usable, flash_attention,
-                flash_attention_rematerializable)
-            if flash_attention_usable(q, deterministic or config.dropout == 0.0):
-                if config.remat:
-                    # (out, lse) carry checkpoint_names: with a
-                    # save_only_these_names:attn_out,attn_lse policy the
-                    # backward never re-runs the flash fwd kernel
-                    return flash_attention_rematerializable(
-                        q, k, v, causal=True,
-                        head_packing=config.attention_head_packing)
-                return flash_attention(
+        if flash_attention_usable(q, deterministic or config.dropout == 0.0):
+            if config.remat:
+                # (out, lse) carry checkpoint_names: with a
+                # save_only_these_names:attn_out,attn_lse policy the
+                # backward never re-runs the flash fwd kernel
+                return flash_attention_rematerializable(
                     q, k, v, causal=True,
                     head_packing=config.attention_head_packing)
-        except ImportError:
-            pass
+            return flash_attention(
+                q, k, v, causal=True,
+                head_packing=config.attention_head_packing)
         if config.attention_impl == "pallas":
             raise RuntimeError("pallas attention requested but unusable "
                                "for these shapes/settings")

@@ -40,8 +40,10 @@ Selection: `MoEConfig.fused_dispatch` ("auto"|"on"|"off") —
 see moe/layer.py `resolve_fused_dispatch`. The fused path is local
 gather/scatter math; expert-parallel meshes keep the GSPMD-declarative
 einsum pair (its sharding constraints ARE the all-to-all), so "on" +
-an expert-axis mesh is a config error, and "auto" only fuses where no
-expert axis shards the buffers.
+an expert-axis mesh is a config error, and "auto" only fuses on a
+single TPU device: the kernels gather over the whole batch's slot
+table, and GSPMD cannot partition a Mosaic call, so any sharded
+program keeps the einsum pair.
 """
 
 import functools
@@ -52,16 +54,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# CompilerParams was TPUCompilerParams before jax 0.6 (same fields)
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 
 def _on_tpu():
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # ds-lint: allow[BROADEXC] backend probe; no devices -> not a TPU
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _zeros_ct(x):
@@ -117,23 +112,30 @@ def _dispatch_kernel(src_ref, x_ref, o_ref):
     o_ref[...] = x_ref[...]
 
 
+def _row_spec(h, index_map):
+    """One [1, h] row of a [rows, 1, h] array per grid step. The
+    leading dim is squeezed out of the block so its last two dims equal
+    the array's: Mosaic refuses a (1, h) block over a [rows, h] array
+    (second-minor block dim neither a multiple of 8 nor the whole
+    dim)."""
+    return pl.BlockSpec((None, 1, h), index_map)
+
+
 def _dispatch_pallas(xp, src, interpret):
     s = src.shape[0]
     h = xp.shape[1]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(s,),
-        in_specs=[pl.BlockSpec((1, h), lambda i, src_ref:
-                               (src_ref[i], 0))],
-        out_specs=pl.BlockSpec((1, h), lambda i, src_ref: (i, 0)),
+        in_specs=[_row_spec(h, lambda i, src_ref: (src_ref[i], 0, 0))],
+        out_specs=_row_spec(h, lambda i, src_ref: (i, 0, 0)),
     )
-    kwargs = {}
-    if _CompilerParams is not None and not interpret:
-        kwargs["compiler_params"] = _CompilerParams()
-    return pl.pallas_call(
-        _dispatch_kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, h), xp.dtype),
-        interpret=interpret, **kwargs)(src, xp)
+    out = pl.pallas_call(
+        _dispatch_kernel, name="moe_fused_dispatch",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((s, 1, h), xp.dtype),
+        interpret=interpret)(src, xp[:, None, :])
+    return out[:, 0, :]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -184,10 +186,11 @@ def _make_combine_kernel(k, out_dtype):
     def kernel(dest_ref, cw_ref, *refs):
         del dest_ref  # consumed by the index_maps
         o_ref = refs[-1]
-        i = pl.program_id(0)
-        acc = refs[0][...].astype(jnp.float32) * cw_ref[i, 0]
+        base = pl.program_id(0) * k
+        acc = refs[0][...].astype(jnp.float32) * cw_ref[base]
         for j in range(1, k):
-            acc = acc + refs[j][...].astype(jnp.float32) * cw_ref[i, j]
+            acc = acc + refs[j][...].astype(jnp.float32) * \
+                cw_ref[base + j]
         o_ref[...] = acc.astype(out_dtype)
     return kernel
 
@@ -197,23 +200,24 @@ def _combine_pallas(ye_flat, dest, cw, interpret):
     h = ye_flat.shape[1]
 
     def _ye_map(j):
-        return lambda i, dest_ref, cw_ref: (dest_ref[i, j], 0)
+        return lambda i, dest_ref, cw_ref: (dest_ref[i * k + j], 0, 0)
 
+    # the slot and weight tables prefetch FLAT, like the block-sparse
+    # index tables (SMEM is small; a 1-D table takes what it holds)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n,),
-        in_specs=[pl.BlockSpec((1, h), _ye_map(j)) for j in range(k)],
-        out_specs=pl.BlockSpec(
-            (1, h), lambda i, dest_ref, cw_ref: (i, 0)),
+        in_specs=[_row_spec(h, _ye_map(j)) for j in range(k)],
+        out_specs=_row_spec(h, lambda i, dest_ref, cw_ref: (i, 0, 0)),
     )
-    kwargs = {}
-    if _CompilerParams is not None and not interpret:
-        kwargs["compiler_params"] = _CompilerParams()
-    return pl.pallas_call(
-        _make_combine_kernel(k, ye_flat.dtype), grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, h), ye_flat.dtype),
-        interpret=interpret, **kwargs)(
-            dest, cw, *([ye_flat] * k))
+    out = pl.pallas_call(
+        _make_combine_kernel(k, ye_flat.dtype), name="moe_fused_combine",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n, 1, h), ye_flat.dtype),
+        interpret=interpret)(
+            dest.reshape(-1), cw.reshape(-1),
+            *([ye_flat[:, None, :]] * k))
+    return out[:, 0, :]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))

@@ -61,7 +61,8 @@ class MoEConfig:
     kernels (moe/fused_dispatch.py); the fused path is local
     gather/scatter math, so "on" refuses expert-parallel meshes
     (their all-to-all IS the einsum pair's sharding constraint) and
-    "auto" fuses only on real TPU without an expert axis."""
+    "auto" fuses only on a single TPU device (GSPMD cannot partition
+    a Mosaic call)."""
     num_experts: int = 8
     top_k: int = 2
     capacity_factor: float = 1.25
@@ -129,8 +130,8 @@ def resolve_fused_dispatch(mode, mesh=None):
     """`fused_dispatch` -> bool at trace time. "on"/True force the
     fused gather-scatter path (refused on expert-parallel meshes —
     validate() catches that earlier; re-checked here for direct
-    callers); "auto" fuses on real TPU when no expert axis shards the
-    dispatch buffers (the GSPMD einsum pair owns those meshes)."""
+    callers); "auto" fuses on a single TPU device (any sharded program
+    keeps the GSPMD einsum pair)."""
     if mode in (False, "off"):
         return False
     if mode in (True, "on"):
@@ -140,8 +141,12 @@ def resolve_fused_dispatch(mode, mesh=None):
                 "expert-parallel mesh (see MoEConfig.validate)")
         return True
     if mode == "auto":
-        return jax.devices()[0].platform == "tpu" and \
-            not _mesh_active(mesh)
+        # one device only: the kernels gather over the whole batch's
+        # slot table, and GSPMD cannot partition a Mosaic call
+        # ("Mosaic kernels cannot be automatically partitioned"); a
+        # sharded program keeps the einsum pair
+        devices = mesh.size if mesh is not None else jax.device_count()
+        return jax.devices()[0].platform == "tpu" and devices == 1
     raise ValueError(
         f"fused_dispatch must be 'on', 'off' or 'auto', got {mode!r}")
 

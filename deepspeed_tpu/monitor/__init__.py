@@ -369,8 +369,8 @@ class Monitor:
 
     def _throughput_derived(self):
         """tokens/s/chip + MFU once the throughput timer has a warmed
-        measurement window (None before that, and MFU None off-TPU
-        where no nominal peak applies).  Same convention as bench.py's
+        measurement window (None before that, and MFU None on a device
+        whose nominal peak is not in the profiler's table).  Same convention as bench.py's
         headline: conservative 6·N·tokens/s against the chip's nominal
         bf16 peak — MFU becomes observable IN-LOOP instead of
         bench-only."""
@@ -394,9 +394,9 @@ class Monitor:
         elif n and jax.devices()[0].platform == "tpu":
             from deepspeed_tpu.profiling.flops_profiler.profiler import \
                 device_peak_specs
-            peak, _ = device_peak_specs()
-            if peak:
-                mfu = round(6.0 * n * tps_chip / peak, 4)
+            specs = device_peak_specs()
+            if specs is not None:
+                mfu = round(6.0 * n * tps_chip / specs[0], 4)
         return {"tokens_per_sec_per_chip": round(tps_chip, 1),
                 "mfu": mfu}
 

@@ -117,30 +117,20 @@ def reset(drop_monitor=True):
 
 def table_path():
     """Resolution order: configure()/autotune.table_path config key >
-    DS_TPU_AUTOTUNE_TABLE env > next to the jax compile cache >
-    ~/.cache/deepspeed_tpu."""
+    DS_TPU_AUTOTUNE_TABLE env > beside the jax compile cache
+    (utils/compile_cache.py)."""
     if _state["path"]:
         return _state["path"]
     env = os.environ.get("DS_TPU_AUTOTUNE_TABLE")
     if env:
         return env
-    cache_dir = None
-    try:
-        import jax
-        cache_dir = jax.config.jax_compilation_cache_dir
-    except Exception:  # ds-lint: allow[BROADEXC] no jax / unreadable config -> fall through to the home cache dir
-        cache_dir = None
-    if not cache_dir:
-        cache_dir = os.path.expanduser("~/.cache/deepspeed_tpu")
-    return os.path.join(cache_dir, TABLE_BASENAME)
+    from deepspeed_tpu.utils.compile_cache import compile_cache_dir
+    return os.path.join(compile_cache_dir(), TABLE_BASENAME)
 
 
 def _backend():
-    try:
-        import jax
-        return jax.default_backend()
-    except Exception:  # ds-lint: allow[BROADEXC] backend probe for a cache key; "cpu" is the safe default
-        return "cpu"
+    import jax
+    return jax.default_backend()
 
 
 def kernel_source_hash(kernel):

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
-from deepspeed_tpu.runtime.compat import shard_map
+from jax import shard_map
 
 from deepspeed_tpu.ops import overlap as _overlap
 from deepspeed_tpu.ops.transformer.flash_attention import (NEG_INF,

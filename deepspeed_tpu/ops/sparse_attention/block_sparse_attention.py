@@ -28,6 +28,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops.per_device import ROWS, per_device
 from deepspeed_tpu.ops.transformer.flash_attention import (NEG_INF, _on_tpu,
                                                            dense_attention)
 
@@ -40,15 +41,9 @@ _FWD_MIN_OUTER = 8
 
 
 def _element_spec(shape, index_map):
-    """All-Element BlockSpec (every index_map coordinate is an ELEMENT
-    offset). Spelled `pl.Element` per dim on modern pallas; older
-    releases (jax 0.4.x) express the same thing as a whole-spec
-    Unblocked indexing mode."""
-    if hasattr(pl, "Element"):
-        return pl.BlockSpec(tuple(pl.Element(s) for s in shape),
-                            index_map)
-    return pl.BlockSpec(tuple(shape), index_map,
-                        indexing_mode=pl.Unblocked())
+    """All-Element BlockSpec: every index_map coordinate is an ELEMENT
+    offset."""
+    return pl.BlockSpec(tuple(pl.Element(s) for s in shape), index_map)
 
 
 def _compiler_params(kind):
@@ -57,10 +52,8 @@ def _compiler_params(kind):
     # the forward's online-softmax carry pipelines better with Mosaic's
     # own scheduling (declared semantics cost it ~25%).
     sem = ("parallel", "parallel", "arbitrary") if kind == "bwd" else None
-    # CompilerParams was TPUCompilerParams before jax 0.6 (same fields)
-    cls = getattr(pltpu, "CompilerParams",
-                  getattr(pltpu, "TPUCompilerParams", None))
-    return cls(dimension_semantics=sem, vmem_limit_bytes=_VMEM_LIMIT)
+    return pltpu.CompilerParams(dimension_semantics=sem,
+                                vmem_limit_bytes=_VMEM_LIMIT)
 
 
 # ----------------------------------------------------------------------
@@ -376,6 +369,7 @@ def _bs_fwd(q, k, v, head_map, kidx, kcnt, kmask, sm_scale, causal,
     )
     out, lse = pl.pallas_call(
         kernel,
+        name="block_sparse_fwd",
         grid_spec=grid_spec,
         compiler_params=_compiler_params("fwd"),
         out_shape=[
@@ -441,6 +435,7 @@ def _bs_bwd(sm_scale, causal, block, interpret, kmax, qmax, g_grp, qt,
     )
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="block_sparse_bwd_dkv",
         grid_spec=dkv_spec,
         compiler_params=_compiler_params("bwd"),
         out_shape=[
@@ -474,6 +469,7 @@ def _bs_bwd(sm_scale, causal, block, interpret, kmax, qmax, g_grp, qt,
     )
     dq = pl.pallas_call(
         dq_kernel,
+        name="block_sparse_bwd_dq",
         grid_spec=dq_spec,
         compiler_params=_compiler_params("bwd"),
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
@@ -763,6 +759,7 @@ def _band_fwd(q, k, v, band, sm_scale, causal, block, interpret, qt,
 
     out, lse = pl.pallas_call(
         kernel,
+        name="block_sparse_band_fwd",
         grid=(bh // g, nqs, n_steps),
         in_specs=[
             pl.BlockSpec((g, qtb, d), lambda grp, R, st: (grp, R, 0)),
@@ -934,9 +931,19 @@ def block_sparse_attention(q, k, v, layout, block, causal=False,
     g_fwd = g
     while g_fwd > 1 and (b * h) // g_fwd < _FWD_MIN_OUTER:
         g_fwd //= 2
-    return _bs_flash(q, k, v, head_map, kidx, kcnt, kmask, qidx, qcnt,
-                     qmask, float(sm_scale), bool(causal), int(block),
-                     bool(interpret), kmax, qmax, (g_fwd, g), qt, band)
+    def local(q, k, v, *tables):
+        return (_bs_flash(q, k, v, *tables, float(sm_scale), bool(causal),
+                          int(block), bool(interpret), kmax, qmax,
+                          (g_fwd, g), qt, band),)
+
+    # on a mesh each device attends over its own batch rows (forward,
+    # backward and the lse between them all stay on the device); heads
+    # are held whole because the tables index them
+    bthd = (ROWS, None, None, None)
+    out, = per_device(local, in_dims=(bthd,) * 3 + ((None,),) * 7,
+                      out_dims=(bthd,))(
+        q, k, v, head_map, kidx, kcnt, kmask, qidx, qcnt, qmask)
+    return out
 
 
 def block_sparse_attention_dense_fallback(q, k, v, layout, block,

@@ -16,10 +16,11 @@ reference instead checkpoints 17 intermediate activations
 
 Head packing (d = 64).  The MXU contracts 128 elements per pass, so a
 d=64 attention runs its QK^T at K=64 (half the systolic rows idle) and
-its PV at N=64 (half the lanes idle) — measured ~5 TF on a 197 TF chip
-(VERDICT r5).  With `head_packing` the kernel processes TWO heads per
-grid step in a feature-packed layout [rows, T, 128] (adjacent B·H rows
-pair up; an odd B·H count pads one zero row that is sliced off):
+its PV at N=64 (half the lanes idle; what that costs is not measured
+on the current installation).  With `head_packing` the kernel
+processes TWO heads per grid step in a feature-packed layout
+[rows, T, 128] (adjacent B·H rows pair up; an odd B·H count pads one
+zero row that is sliced off):
 
     Qp  = [q0 | q1]                          [bq, 128]   (dense)
     Kbd = [[k0 | 0], [0 | k1]]               [2·bk, 128] (block diagonal)
@@ -28,8 +29,8 @@ pair up; an odd B·H count pads one zero row that is sliced off):
 
 The zero blocks double the MAC count per useful flop, but every matmul
 now runs at full MXU occupancy — a win whenever K=64 throughput is
-below half of K=128 throughput (it is far below on v5e).  The zero
-lanes contribute exact +0 to every fp32 partial sum, so packed and
+below half of K=128 throughput.  The zero lanes contribute exact +0
+to every fp32 partial sum, so packed and
 unpacked results agree bit-for-bit under a deterministic backend.  The
 backward's dV/dK contractions come out row-stacked ([2·bk, 128] with
 the useful blocks on the diagonal) and are folded back with a lane
@@ -54,6 +55,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops.per_device import COLS, ROWS, per_device
+
 NEG_INF = -1e30
 # The online softmax runs in log2 space: exp2 is the TPU VPU's native
 # transcendental (jnp.exp lowers to exp2(x·log2e) anyway), so folding
@@ -76,10 +79,7 @@ _DEFAULT_BLOCK = 1024
 # scoped-vmem ceiling to make the fatter tiles legal).
 _DEFAULT_HEAD_GROUP = 8
 _VMEM_LIMIT = 100 * 1024 * 1024
-# CompilerParams was TPUCompilerParams before jax 0.6 (same fields)
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-_COMPILER_PARAMS = _CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT)
 
 
 def _on_tpu():
@@ -428,6 +428,12 @@ def _head_group(bh, block_q, block_k, d, tile_budget=8 * 1024 * 1024):
     return max(g, 1)
 
 
+# operand layouts, as per_device dims: the batch divides with the rows
+# of the mesh, the heads with its columns
+_BTHD = (ROWS, None, COLS, None)
+_BHTX = (ROWS, COLS, None, None)
+
+
 def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, pack,
          prev=None):
     """Forward launcher.  Returns (out [bh, t, d], lse [bh, t, 1]); with
@@ -435,8 +441,25 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, pack,
     the prior softmax partial in its epilogue and additionally returns
     the CURRENT partial's lse_n [bh, t, 1] (the backward residual)."""
     b, t, h, d = q.shape
-    bh = b * h
     merge = prev is not None
+    local = functools.partial(
+        _fwd_local, sm_scale=sm_scale, causal=causal, block_q=block_q,
+        block_k=block_k, interpret=interpret, pack=pack)
+    outs = per_device(
+        local,
+        in_dims=(_BTHD,) * 3 + ((_BTHD, _BHTX) if merge else ()),
+        out_dims=(_BHTX,) * (3 if merge else 2))(
+            q, k, v, *(prev if merge else ()))
+    return tuple(o.reshape(b * h, t, o.shape[-1]) for o in outs)
+
+
+def _fwd_local(q, k, v, *prev, sm_scale, causal, block_q, block_k,
+               interpret, pack):
+    """One device's launch: [B, T, H, D] blocks in, (out [B, H, T, D],
+    lse [B, H, T, 1][, lse_n]) out."""
+    b, t, h, d = q.shape
+    bh = b * h
+    merge = bool(prev)
 
     # [B, T, H, D] -> [B*H, T, D]
     def to_bht(x):
@@ -490,6 +513,7 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, pack,
             jax.ShapeDtypeStruct((rows, t, lanes), jnp.float32))
     outs = pl.pallas_call(
         kernel,
+        name="flash_fwd" + ("_packed" if pack else ""),
         grid=grid,
         compiler_params=_COMPILER_PARAMS,
         in_specs=in_specs,
@@ -504,7 +528,7 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, pack,
     )(*operands)
     if pack:
         outs = [_unpack_pairs(o, bh) for o in outs]
-    return tuple(outs) if merge else (outs[0], outs[1])
+    return tuple(o.reshape(b, h, t, o.shape[-1]) for o in outs)
 
 
 # ----------------------------------------------------------------------
@@ -775,6 +799,30 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, res, g,
     materializing the per-step partial out (res[3] may then be None)."""
     q, k, v, out, lse = res
     b, t, h, d = q.shape
+    if delta is None:
+        # δ = rowsum(dO ⊙ O) — computed by XLA (one fused
+        # elementwise+reduce)
+        delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1, keepdims=True) \
+            .transpose(0, 2, 1, 3)                  # [b, h, t, 1]
+    delta = delta.reshape(b, h, t, 1)
+    if dlse is not None:
+        delta = delta - LOG2E * dlse.astype(jnp.float32) \
+            .reshape(b, h, t, 1)
+    local = functools.partial(
+        _bwd_local, sm_scale=sm_scale, causal=causal, block_q=block_q,
+        block_k=block_k, interpret=interpret, pack=pack)
+    return per_device(
+        local, in_dims=(_BTHD,) * 4 + (_BHTX,) * 2,
+        out_dims=(_BTHD,) * 3)(
+            q, k, v, g, lse.reshape(b, h, t, 1), delta)
+
+
+def _bwd_local(q, k, v, g, lse, delta, *, sm_scale, causal, block_q,
+               block_k, interpret, pack):
+    """One device's backward launch: [B, T, H, D] blocks of q, k, v, dO
+    and [B, H, T, 1] row statistics in, (dq, dk, dv) [B, T, H, D] out."""
+    b, t, h, d = q.shape
     bh = b * h
 
     def to_bht(x):
@@ -784,14 +832,8 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, res, g,
         return x.reshape(b, h, t, d).transpose(0, 2, 1, 3)
 
     qt, kt, vt, dot_ = to_bht(q), to_bht(k), to_bht(v), to_bht(g)
-    if delta is None:
-        ot = to_bht(out)
-        # δ = rowsum(dO ⊙ O) — computed by XLA (one fused
-        # elementwise+reduce)
-        delta = jnp.sum(dot_.astype(jnp.float32) * ot.astype(jnp.float32),
-                        axis=-1, keepdims=True)    # [bh, t, 1]
-    if dlse is not None:
-        delta = delta - LOG2E * dlse.astype(jnp.float32)
+    lse = lse.reshape(bh, t, 1)
+    delta = delta.reshape(bh, t, 1)
 
     if pack:
         qt, kt, vt, dot_ = map(_pack_pairs, (qt, kt, vt, dot_))
@@ -824,6 +866,7 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, res, g,
         row_spec = pl.BlockSpec((gf, t, lanes), lambda i: (i, 0, 0))
         dq, dk, dv = pl.pallas_call(
             fused,
+            name="flash_bwd_fused" + ("_packed" if pack else ""),
             grid=(rows // gf,),
             compiler_params=_COMPILER_PARAMS,
             in_specs=[specs, specs, specs, specs, row_spec, row_spec],
@@ -844,6 +887,7 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, res, g,
         block_q=block_q, block_k=block_k)
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_bwd_dkv" + ("_packed" if pack else ""),
         grid=(rows // gg, nk, nq),
         compiler_params=_COMPILER_PARAMS,
         in_specs=[
@@ -883,6 +927,7 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, res, g,
         block_q=block_q, block_k=block_k)
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_bwd_dq" + ("_packed" if pack else ""),
         grid=(rows // gg, nq, nk),
         compiler_params=_COMPILER_PARAMS,
         in_specs=[

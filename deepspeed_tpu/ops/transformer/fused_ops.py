@@ -58,6 +58,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops.per_device import COLS, ROWS, per_device
+
 # names the save_fused_epilogues remat policy saves (fused_gelu_out is
 # named but EXCLUDED from the policy: 4·H bytes/token vs a one-erf
 # recompute from the saved sum)
@@ -72,10 +74,8 @@ _SQRT_2_OVER_PI = 0.7978845608028654   # sqrt(2/pi), the tanh-gelu const
 _INV_SQRT_2PI = 0.3989422804014327     # 1/sqrt(2*pi)
 _GELU_C = 0.044715
 
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-_COMPILER_PARAMS = None if _CompilerParams is None else \
-    _CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    vmem_limit_bytes=64 * 1024 * 1024)
 
 
 def _on_tpu():
@@ -310,115 +310,136 @@ def _pad_lanes(x, h_padded):
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, h_padded - h)])
 
 
-def _pallas_call(kernel, *, grid, in_specs, out_specs, out_shape,
-                 scratch_shapes, interpret, name):
-    kwargs = dict(grid=grid, in_specs=in_specs, out_specs=out_specs,
-                  out_shape=out_shape, scratch_shapes=scratch_shapes,
-                  interpret=interpret)
-    if _COMPILER_PARAMS is not None:
-        kwargs["compiler_params"] = _COMPILER_PARAMS
-    try:
-        return pl.pallas_call(kernel, name=name, **kwargs)
-    except TypeError:   # older pallas without the name kwarg
-        return pl.pallas_call(kernel, **kwargs)
+def _pallas_call(kernel, **kwargs):
+    return pl.pallas_call(kernel, compiler_params=_COMPILER_PARAMS,
+                          **kwargs)
+
+
+# The launchers below take [N, H] row-flattened operands. On a mesh
+# each device launches on its own rows (per_device): the LayerNorm
+# width is held whole, the GeLU width may be a tensor-parallel column
+# block. Parameter gradients leave the kernels in fp32 — they are sums
+# over rows, added over the devices that divided the rows before the
+# caller rounds them to the parameter dtype.
+_ROW_BLOCK = (ROWS, None)
+_VEC = (None,)
 
 
 def _ln_fwd_launch(y2, bias, res2, gamma, beta, eps, h, out_dtype,
                    sum_dtype, interpret):
-    """[N, H] row-flattened launcher.  Pads H to a lane multiple (the
-    kernel masks pad lanes out of the statistics) and tiles rows."""
-    n = y2.shape[0]
-    hp = -(-h // 128) * 128
-    blk = _tuned_row_block("fused_ln", n, hp, out_dtype)
-    args = [_pad_lanes(y2, hp), _pad_lanes(bias[None], hp),
-            _pad_lanes(res2, hp), _pad_lanes(gamma[None], hp),
-            _pad_lanes(beta[None], hp)]
-    row_spec = pl.BlockSpec((blk, hp), lambda i: (i, 0))
-    vec_spec = pl.BlockSpec((1, hp), lambda i: (0, 0))
-    out, s = _pallas_call(
-        functools.partial(_ln_fwd_kernel, eps=eps, h_valid=h),
-        grid=(n // blk,),
-        in_specs=[row_spec, vec_spec, row_spec, vec_spec, vec_spec],
-        out_specs=[row_spec, row_spec],
-        out_shape=[jax.ShapeDtypeStruct((n, hp), out_dtype),
-                   jax.ShapeDtypeStruct((n, hp), sum_dtype)],
-        scratch_shapes=[], interpret=interpret,
-        name="fused_bias_residual_layernorm_fwd")(*args)
-    return out[:, :h], s[:, :h]
+    """Pads H to a lane multiple (the kernel masks pad lanes out of the
+    statistics) and tiles rows."""
+    def launch(y2, bias, res2, gamma, beta):
+        n = y2.shape[0]
+        hp = -(-h // 128) * 128
+        blk = _tuned_row_block("fused_ln", n, hp, out_dtype)
+        args = [_pad_lanes(y2, hp), _pad_lanes(bias[None], hp),
+                _pad_lanes(res2, hp), _pad_lanes(gamma[None], hp),
+                _pad_lanes(beta[None], hp)]
+        row_spec = pl.BlockSpec((blk, hp), lambda i: (i, 0))
+        vec_spec = pl.BlockSpec((1, hp), lambda i: (0, 0))
+        out, s = _pallas_call(
+            functools.partial(_ln_fwd_kernel, eps=eps, h_valid=h),
+            grid=(n // blk,),
+            in_specs=[row_spec, vec_spec, row_spec, vec_spec, vec_spec],
+            out_specs=[row_spec, row_spec],
+            out_shape=[jax.ShapeDtypeStruct((n, hp), out_dtype),
+                       jax.ShapeDtypeStruct((n, hp), sum_dtype)],
+            scratch_shapes=[], interpret=interpret,
+            name="fused_bias_residual_layernorm_fwd")(*args)
+        return out[:, :h], s[:, :h]
+    return per_device(
+        launch, in_dims=(_ROW_BLOCK, _VEC, _ROW_BLOCK, _VEC, _VEC),
+        out_dims=(_ROW_BLOCK, _ROW_BLOCK))(y2, bias, res2, gamma, beta)
 
 
-def _ln_bwd_launch(s2, gamma, dout2, dsum2, eps, h, in_dtype,
-                   param_dtype, interpret):
-    n = s2.shape[0]
-    hp = -(-h // 128) * 128
-    blk = _tuned_row_block("fused_ln", n, hp, in_dtype)
+def _ln_bwd_launch(s2, gamma, dout2, dsum2, eps, h, in_dtype, interpret):
     has_dsum = dsum2 is not None
-    args = [_pad_lanes(s2, hp), _pad_lanes(gamma[None], hp),
-            _pad_lanes(dout2, hp)]
-    row_spec = pl.BlockSpec((blk, hp), lambda i: (i, 0))
-    vec_spec = pl.BlockSpec((1, hp), lambda i: (0, 0))
-    in_specs = [row_spec, vec_spec, row_spec]
-    if has_dsum:
-        args.append(_pad_lanes(dsum2, hp))
-        in_specs.append(row_spec)
-    else:
-        args.append(jnp.zeros((1, hp), jnp.float32))
-        in_specs.append(vec_spec)
-    dx, dbias, dgamma, dbeta = _pallas_call(
-        functools.partial(_ln_bwd_kernel, eps=eps, h_valid=h,
-                          has_dsum=has_dsum),
-        grid=(n // blk,),
-        in_specs=in_specs,
-        out_specs=[row_spec, vec_spec, vec_spec, vec_spec],
-        out_shape=[jax.ShapeDtypeStruct((n, hp), in_dtype),
-                   jax.ShapeDtypeStruct((1, hp), param_dtype),
-                   jax.ShapeDtypeStruct((1, hp), param_dtype),
-                   jax.ShapeDtypeStruct((1, hp), param_dtype)],
-        scratch_shapes=[pltpu.VMEM((1, hp), jnp.float32)] * 3,
-        interpret=interpret,
-        name="fused_bias_residual_layernorm_bwd")(*args)
-    return dx[:, :h], dbias[0, :h], dgamma[0, :h], dbeta[0, :h]
+
+    def launch(s2, gamma, dout2, *dsum2):
+        n = s2.shape[0]
+        hp = -(-h // 128) * 128
+        blk = _tuned_row_block("fused_ln", n, hp, in_dtype)
+        args = [_pad_lanes(s2, hp), _pad_lanes(gamma[None], hp),
+                _pad_lanes(dout2, hp)]
+        row_spec = pl.BlockSpec((blk, hp), lambda i: (i, 0))
+        vec_spec = pl.BlockSpec((1, hp), lambda i: (0, 0))
+        in_specs = [row_spec, vec_spec, row_spec]
+        if has_dsum:
+            args.append(_pad_lanes(dsum2[0], hp))
+            in_specs.append(row_spec)
+        else:
+            args.append(jnp.zeros((1, hp), jnp.float32))
+            in_specs.append(vec_spec)
+        dx, dbias, dgamma, dbeta = _pallas_call(
+            functools.partial(_ln_bwd_kernel, eps=eps, h_valid=h,
+                              has_dsum=has_dsum),
+            grid=(n // blk,),
+            in_specs=in_specs,
+            out_specs=[row_spec, vec_spec, vec_spec, vec_spec],
+            out_shape=[jax.ShapeDtypeStruct((n, hp), in_dtype)] +
+            [jax.ShapeDtypeStruct((1, hp), jnp.float32)] * 3,
+            scratch_shapes=[pltpu.VMEM((1, hp), jnp.float32)] * 3,
+            interpret=interpret,
+            name="fused_bias_residual_layernorm_bwd")(*args)
+        return dx[:, :h], dbias[0, :h], dgamma[0, :h], dbeta[0, :h]
+    return per_device(
+        launch,
+        in_dims=(_ROW_BLOCK, _VEC, _ROW_BLOCK) +
+        ((_ROW_BLOCK,) if has_dsum else ()),
+        out_dims=(_ROW_BLOCK, _VEC, _VEC, _VEC), row_summed=(1, 2, 3))(
+            s2, gamma, dout2, *((dsum2,) if has_dsum else ()))
 
 
-def _gelu_fwd_launch(x2, bias, approximate, h, out_dtype, sum_dtype,
+_COL_BLOCK = (ROWS, COLS)
+_COL_VEC = (COLS,)
+
+
+def _gelu_fwd_launch(x2, bias, approximate, out_dtype, sum_dtype,
                      interpret):
-    n = x2.shape[0]
-    hp = -(-h // 128) * 128
-    blk = _tuned_row_block("fused_gelu", n, hp, out_dtype)
-    row_spec = pl.BlockSpec((blk, hp), lambda i: (i, 0))
-    vec_spec = pl.BlockSpec((1, hp), lambda i: (0, 0))
-    out, s = _pallas_call(
-        functools.partial(_gelu_fwd_kernel, approximate=approximate),
-        grid=(n // blk,),
-        in_specs=[row_spec, vec_spec],
-        out_specs=[row_spec, row_spec],
-        out_shape=[jax.ShapeDtypeStruct((n, hp), out_dtype),
-                   jax.ShapeDtypeStruct((n, hp), sum_dtype)],
-        scratch_shapes=[], interpret=interpret,
-        name="fused_bias_gelu_fwd")(
-            _pad_lanes(x2, hp), _pad_lanes(bias[None], hp))
-    return out[:, :h], s[:, :h]
+    def launch(x2, bias):
+        n, h = x2.shape
+        hp = -(-h // 128) * 128
+        blk = _tuned_row_block("fused_gelu", n, hp, out_dtype)
+        row_spec = pl.BlockSpec((blk, hp), lambda i: (i, 0))
+        vec_spec = pl.BlockSpec((1, hp), lambda i: (0, 0))
+        out, s = _pallas_call(
+            functools.partial(_gelu_fwd_kernel, approximate=approximate),
+            grid=(n // blk,),
+            in_specs=[row_spec, vec_spec],
+            out_specs=[row_spec, row_spec],
+            out_shape=[jax.ShapeDtypeStruct((n, hp), out_dtype),
+                       jax.ShapeDtypeStruct((n, hp), sum_dtype)],
+            scratch_shapes=[], interpret=interpret,
+            name="fused_bias_gelu_fwd")(
+                _pad_lanes(x2, hp), _pad_lanes(bias[None], hp))
+        return out[:, :h], s[:, :h]
+    return per_device(launch, in_dims=(_COL_BLOCK, _COL_VEC),
+                      out_dims=(_COL_BLOCK, _COL_BLOCK))(x2, bias)
 
 
-def _gelu_bwd_launch(s2, dout2, approximate, h, in_dtype, param_dtype,
-                     interpret):
-    n = s2.shape[0]
-    hp = -(-h // 128) * 128
-    blk = _tuned_row_block("fused_gelu", n, hp, in_dtype)
-    row_spec = pl.BlockSpec((blk, hp), lambda i: (i, 0))
-    vec_spec = pl.BlockSpec((1, hp), lambda i: (0, 0))
-    dx, dbias = _pallas_call(
-        functools.partial(_gelu_bwd_kernel, approximate=approximate),
-        grid=(n // blk,),
-        in_specs=[row_spec, row_spec],
-        out_specs=[row_spec, vec_spec],
-        out_shape=[jax.ShapeDtypeStruct((n, hp), in_dtype),
-                   jax.ShapeDtypeStruct((1, hp), param_dtype)],
-        scratch_shapes=[pltpu.VMEM((1, hp), jnp.float32)],
-        interpret=interpret,
-        name="fused_bias_gelu_bwd")(_pad_lanes(s2, hp),
-                                    _pad_lanes(dout2, hp))
-    return dx[:, :h], dbias[0, :h]
+def _gelu_bwd_launch(s2, dout2, approximate, in_dtype, interpret):
+    def launch(s2, dout2):
+        n, h = s2.shape
+        hp = -(-h // 128) * 128
+        blk = _tuned_row_block("fused_gelu", n, hp, in_dtype)
+        row_spec = pl.BlockSpec((blk, hp), lambda i: (i, 0))
+        vec_spec = pl.BlockSpec((1, hp), lambda i: (0, 0))
+        dx, dbias = _pallas_call(
+            functools.partial(_gelu_bwd_kernel, approximate=approximate),
+            grid=(n // blk,),
+            in_specs=[row_spec, row_spec],
+            out_specs=[row_spec, vec_spec],
+            out_shape=[jax.ShapeDtypeStruct((n, hp), in_dtype),
+                       jax.ShapeDtypeStruct((1, hp), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((1, hp), jnp.float32)],
+            interpret=interpret,
+            name="fused_bias_gelu_bwd")(_pad_lanes(s2, hp),
+                                        _pad_lanes(dout2, hp))
+        return dx[:, :h], dbias[0, :h]
+    return per_device(launch, in_dims=(_COL_BLOCK, _COL_BLOCK),
+                      out_dims=(_COL_BLOCK, _COL_VEC), row_summed=(1,))(
+                          s2, dout2)
 
 
 # ----------------------------------------------------------------------
@@ -455,8 +476,7 @@ def _ln_apply_bwd(eps, use_pallas, interpret, sum_dtype, res, g):
     dsum2 = None if d_sum is None else _flat_rows(d_sum)
     if use_pallas:
         dx2, dbias, dgamma, dbeta = _ln_bwd_launch(
-            s2, gamma, dout2, dsum2, eps, h, in_dtype, param_dtype,
-            interpret)
+            s2, gamma, dout2, dsum2, eps, h, in_dtype, interpret)
     else:
         ds, dg_rows, dbeta_rows = _ln_bwd_math(
             s2, gamma, dout2, dsum2, eps, h)
@@ -520,8 +540,8 @@ def _gelu_apply_bwd(approximate, use_pallas, interpret, res, g):
     s2 = _flat_rows(s)
     dout2 = _flat_rows(g)
     if use_pallas:
-        dx2, dbias = _gelu_bwd_launch(s2, dout2, approximate, h,
-                                      in_dtype, param_dtype, interpret)
+        dx2, dbias = _gelu_bwd_launch(s2, dout2, approximate,
+                                      in_dtype, interpret)
     else:
         dx2 = _gelu_bwd_math(s2, dout2, approximate)
         dbias = jnp.sum(dx2, axis=0)
@@ -599,12 +619,11 @@ def fused_bias_gelu(x, bias, *, approximate=False, out_dtype=None,
     out_dtype = np.dtype(out_dtype) if out_dtype is not None else x.dtype
     use_pallas, interpret = _resolve_impl(impl)
     approximate = bool(approximate)
-    h = x.shape[-1]
     with jax.named_scope("fused_bias_gelu"):
         sg = jax.lax.stop_gradient
         if use_pallas:
             out2, s2 = _gelu_fwd_launch(
-                _flat_rows(sg(x)), sg(bias), approximate, h, out_dtype,
+                _flat_rows(sg(x)), sg(bias), approximate, out_dtype,
                 x.dtype, interpret)
             out = out2.reshape(x.shape)
             s = s2.reshape(x.shape)

@@ -60,16 +60,16 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops.per_device import ROWS, per_device
+
 # default quantization block along the contraction dim for the
 # quantized-compute (training) family. 128 = one MXU/lane tile, the
 # Pallas kernel's minimum legal int8 K-tile. (Serving keeps its own
 # 64 default — finer blocks, XLA epilogue only.)
 DEFAULT_QUANT_BLOCK = 128
 
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-_COMPILER_PARAMS = None if _CompilerParams is None else \
-    _CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    vmem_limit_bytes=64 * 1024 * 1024)
 
 
 def _on_tpu():
@@ -229,7 +229,20 @@ def _qmm_kernel(xq_ref, wq_ref, sx_ref, sw_ref, out_ref, acc_scr, *,
 def _qmm_pallas(xq, wq, sx, sw, block, out_dtype, block_m, block_n,
                 interpret):
     """[M, Kp] int8 @ [Kp, N] int8 via the Pallas epilogue kernel.
-    Kp = nb*block (pre-padded by the quantizers); M/N pad here."""
+    Kp = nb*block (pre-padded by the quantizers); M/N pad here. On a
+    mesh each device multiplies its own rows by the whole weight."""
+    launch = functools.partial(
+        _qmm_launch, block=block, out_dtype=out_dtype, block_m=block_m,
+        block_n=block_n, interpret=interpret)
+    out, = per_device(
+        launch, in_dims=((ROWS, None), (None, None), (ROWS, None),
+                         (None, None)),
+        out_dims=((ROWS, None),))(xq, wq, sx, sw)
+    return out
+
+
+def _qmm_launch(xq, wq, sx, sw, *, block, out_dtype, block_m, block_n,
+                interpret):
     m, kp = xq.shape
     n = wq.shape[-1]
     nb = kp // block
@@ -241,28 +254,27 @@ def _qmm_pallas(xq, wq, sx, sw, block, out_dtype, block_m, block_n,
     if np_ != n:
         wq = jnp.pad(wq, ((0, 0), (0, np_ - n)))
         sw = jnp.pad(sw, ((0, 0), (0, np_ - n)), constant_values=1.0)
-    kwargs = dict(
+    # sw rides as [nb, 1, N] with the K-block dim squeezed out of the
+    # block, so the block's last two dims are (1, block_n) over an
+    # array whose matching dims are (1, N): Mosaic requires the
+    # second-minor block dim to be a multiple of 8 or the whole dim
+    out = pl.pallas_call(
+        functools.partial(_qmm_kernel, nb=nb, out_dtype=out_dtype),
+        name="quantized_matmul",
         grid=(mp // block_m, np_ // block_n, nb),
         in_specs=[
             pl.BlockSpec((block_m, block), lambda i, j, k: (i, k)),
             pl.BlockSpec((block, block_n), lambda i, j, k: (k, j)),
             pl.BlockSpec((block_m, 1), lambda i, j, k: (i, 0)),
-            pl.BlockSpec((1, block_n), lambda i, j, k: (k, j)),
+            pl.BlockSpec((None, 1, block_n), lambda i, j, k: (k, 0, j)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n),
                                lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        interpret=interpret)
-    if _COMPILER_PARAMS is not None:
-        kwargs["compiler_params"] = _COMPILER_PARAMS
-    kernel = functools.partial(_qmm_kernel, nb=nb, out_dtype=out_dtype)
-    try:
-        out = pl.pallas_call(kernel, name="quantized_matmul",
-                             **kwargs)(xq, wq, sx, sw)
-    except TypeError:   # older pallas without the name kwarg
-        out = pl.pallas_call(kernel, **kwargs)(xq, wq, sx, sw)
-    return out[:m, :n]
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret)(xq, wq, sx, sw[:, None, :])
+    return (out[:m, :n],)
 
 
 def _resolve_impl(impl):

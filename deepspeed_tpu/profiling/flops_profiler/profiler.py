@@ -257,20 +257,19 @@ def _sliced_fusion_bytes(body):
 
 def device_peak_specs(device=None):
     """(peak_bf16_flops, hbm_GBps) for the current/given device from
-    the nominal spec table; a generic 100 TF / 800 GB/s off-table
-    (rankings and time_pct are scale-free either way).  Unknown
-    backends (CPU) return the generic numbers; callers that need "no
-    peak known" semantics (MFU) should check the platform first."""
+    the nominal spec table, or None for a device the table does not
+    know — never a default: a utilisation against a made-up peak reads
+    like a measurement."""
     if device is None:
         device = jax.devices()[0]
     kind = getattr(device, "device_kind", "").lower()
     table = {"v4": (275e12, 1228.0), "v5 lite": (197e12, 819.0),
              "v5e": (197e12, 819.0), "v5p": (459e12, 2765.0),
              "v6": (918e12, 1640.0)}
-    for k, (p, b) in table.items():
+    for k, specs in table.items():
         if k in kind:
-            return p, b
-    return 100e12, 800.0
+            return specs
+    return None
 
 
 # Pallas kernels appear in optimized HLO as custom-calls; the kernel
@@ -321,8 +320,8 @@ def per_fusion_costs(fn, *args, peak_flops=None, hbm_gbps=None, **kwargs):
     lower bound).
 
     peak_flops/hbm_gbps default to the current device's nominal specs
-    when known (v4/v5e/v5p table) else a generic 100 TF / 800 GB/s —
-    the ranking and time_pct are scale-free either way."""
+    (v4/v5e/v5p/v6 table); on a device the table does not know (the
+    CPU included) both must be passed."""
     jitted = fn if isinstance(fn, jax.stages.Wrapped) else jax.jit(fn)
     text = jitted.lower(*args, **kwargs).compile().as_text()
     return per_fusion_costs_from_text(text, peak_flops=peak_flops,
@@ -334,9 +333,14 @@ def per_fusion_costs_from_text(text, peak_flops=None, hbm_gbps=None):
     text (also the unit-testable seam for the parsing/labeling
     logic)."""
     if peak_flops is None or hbm_gbps is None:
-        pf, bw = device_peak_specs()
-        peak_flops = peak_flops or pf
-        hbm_gbps = hbm_gbps or bw
+        specs = device_peak_specs()
+        if specs is None:
+            raise ValueError(
+                "no nominal peak is known for device_kind="
+                f"{jax.devices()[0].device_kind!r}; pass peak_flops "
+                "and hbm_gbps")
+        peak_flops = peak_flops or specs[0]
+        hbm_gbps = hbm_gbps or specs[1]
     comps = _parse_hlo_computations(text)
 
     # executed multiplicity per computation (entry = the one whose name

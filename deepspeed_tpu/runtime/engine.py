@@ -213,11 +213,14 @@ class DeepSpeedEngine(ZeroOffloadMixin):
             self._config.loss_scale == 0
 
         # ---- timers / logging (before deepspeed_io, which uses them) ----
-        self.timers = SynchronizedWallClockTimer()
+        # the timers fence on what the last step produced: a host clock
+        # around async dispatch otherwise times the enqueue
+        self.timers = SynchronizedWallClockTimer(sync_on=self._step_outputs)
         self.tput_timer = ThroughputTimer(
             batch_size=self.train_micro_batch_size_per_gpu(),
             num_workers=self.dp_world_size,
-            steps_per_output=self.steps_per_print())
+            steps_per_output=self.steps_per_print(),
+            sync_on=self._step_outputs)
         # ---- telemetry (deepspeed_tpu/monitor): device-side metric
         # accumulators drained at sync fences, pluggable sinks, step
         # tracing, stall watchdog. Every hot-path hook is one attribute
@@ -758,16 +761,33 @@ class DeepSpeedEngine(ZeroOffloadMixin):
     # ------------------------------------------------------------------
     # state init + sharding
     # ------------------------------------------------------------------
+    def _scalars_on_mesh(self, state):
+        """The scalar leaves of a freshly built state, placed on the
+        mesh replicated — the type the step returns them with. Born as
+        plain single-device scalars they change type after the first
+        step, and the whole train step traces and compiles a second
+        time."""
+        scale, skipped, global_steps = jax.device_put(
+            (state.scale, state.skipped, state.global_steps),
+            NamedSharding(self.mesh, PartitionSpec()))
+        return state._replace(scale=scale, skipped=skipped,
+                              global_steps=global_steps)
+
     def _init_state(self):
         # Copy jax arrays: device_put of an already-placed array aliases
         # it, and the step donates its input state — without the copy the
         # caller's (possibly shared) initial params would be invalidated
         # after the first step.
-        # In SR mode no state group stores fp32 values, so the fp32 tree
-        # stays ABSTRACT (at 1.5B params a concrete fp32 copy is 6.2 GB
-        # of HBM that would sit next to the real state just long enough
-        # to OOM the first step).
-        if self.bf16_sr_mode:
+        # On-device state is cast straight from the caller's tree by
+        # jitted programs whose outputs are born with their shardings,
+        # so the fp32 tree stays ABSTRACT: a concrete fp32 copy lands
+        # whole on the default device (6.2 GB at 1.5B params) next to
+        # the real state — enough to OOM that one device while the rest
+        # of the mesh holds only its shard. Only the host-side offload
+        # store and plain fp32 training consume concrete fp32 values.
+        born_sharded = self.bf16_sr_mode or (
+            self.mixed_precision and not self._offload_enabled())
+        if born_sharded:
             params_f32 = jax.tree_util.tree_map(
                 lambda x: jax.ShapeDtypeStruct(np.shape(x), jnp.float32),
                 self._initial_params)
@@ -827,29 +847,38 @@ class DeepSpeedEngine(ZeroOffloadMixin):
                                              self._zero_pad_plan)
         self._master_shardings = self.zero_policy.master_shardings(params_enc)
         self._acc_shardings = self.zero_policy.grad_accum_shardings(params_enc)
-        self._params_enc_template = params_enc
+        # shapes only: every consumer builds zeros or reads paths
+        self._params_enc_template = jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(np.shape(x), jnp.float32),
+            params_enc)
         self._init_zero3_scheduler(effective_stage)
 
-        if self.bf16_sr_mode:
-            # cast straight from the caller's params — no fp32 detour.
+        def cast_tree(dtype):
+            return lambda t: jax.tree_util.tree_map(
+                lambda x: jnp.asarray(x, dtype), t)
+
+        if born_sharded:
             # jitted with out_shardings: outputs are fresh buffers (the
             # donation contract the old copy=True provided) AND born
             # sharded, so no unsharded cast tree transits HBM/RAM
             # (25 GB at 13B).
             params = jax.jit(
-                lambda t: jax.tree_util.tree_map(
-                    lambda x: jnp.asarray(x, self.compute_dtype), t),
+                cast_tree(self.compute_dtype),
                 out_shardings=self._param_shardings)(self._initial_params)
             master = None
-        elif self.mixed_precision or self._offload_enabled():
+            if self.mixed_precision:
+                master = jax.jit(
+                    lambda t: self.zero_policy.encode(
+                        cast_tree(jnp.float32)(t), self._zero_pad_plan),
+                    out_shardings=self._master_shardings)(
+                        self._initial_params)
+        elif self._offload_enabled():
+            # the fp32 master stays in host RAM (_init_offload below)
             params = jax.tree_util.tree_map(
                 lambda x, s: jax.device_put(
                     jnp.asarray(x, self.compute_dtype), s),
                 params_f32, self._param_shardings)
-            # the fp32 master goes to device only in true mixed
-            # precision — offload keeps it in host RAM
-            master = jax.device_put(params_enc, self._master_shardings) \
-                if self.mixed_precision else None
+            master = None
         else:
             master = None
             params = jax.device_put(params_f32, self._param_shardings)
@@ -858,14 +887,14 @@ class DeepSpeedEngine(ZeroOffloadMixin):
             # ZeRO-Offload: no device master/opt state; host-side fp32
             # masters + CPU-Adam moments (runtime/zero/offload.py)
             self._init_offload(params_f32)
-            self.state = EngineState(
+            self.state = self._scalars_on_mesh(EngineState(
                 params=params, master=None, opt_state=(),
                 scale=make_static_loss_scale_state(
                     self._host_scaler.cur_scale),
                 acc_grads=jax.device_put(_zeros_like_f32(params_f32),
                                          self._acc_shardings),
                 skipped=jnp.asarray(0, jnp.int32),
-                global_steps=jnp.asarray(0, jnp.int32))
+                global_steps=jnp.asarray(0, jnp.int32)))
             n_params = sum(np.prod(l.shape) for l in
                            jax.tree_util.tree_leaves(params_f32))
             log_dist(
@@ -934,11 +963,11 @@ class DeepSpeedEngine(ZeroOffloadMixin):
             acc = jax.device_put(_zeros_like_f32(self._params_enc_template),
                                  self._acc_shardings)
 
-        self.state = EngineState(
+        self.state = self._scalars_on_mesh(EngineState(
             params=params, master=master, opt_state=opt_state, scale=scale,
             acc_grads=acc,
             skipped=jnp.asarray(0, jnp.int32),
-            global_steps=jnp.asarray(0, jnp.int32))
+            global_steps=jnp.asarray(0, jnp.int32)))
 
         n_params = self._count_model_params(params_f32)
         # cached for the monitor's in-loop MFU derivation (6·N·tokens/s
@@ -1292,7 +1321,7 @@ class DeepSpeedEngine(ZeroOffloadMixin):
         Only used at ZeRO stage 0 (params replicated), matching the
         reference, whose CSR path lives in the non-ZeRO fallback
         (`engine.py:836,1160`)."""
-        from deepspeed_tpu.runtime.compat import shard_map
+        from jax import shard_map
         from deepspeed_tpu.runtime.csr_tensor import csr_mean_rows
 
         sparse_paths = self._sparse_grad_paths()
@@ -1648,7 +1677,7 @@ class DeepSpeedEngine(ZeroOffloadMixin):
         Params/opt-state are replicated in and provably identical out:
         every shard decodes the same gathered signs, so the update is
         deterministic across workers."""
-        from deepspeed_tpu.runtime.compat import shard_map
+        from jax import shard_map
         from deepspeed_tpu.runtime.fp16.onebit_adam import onebit_adam
 
         transform = onebit_adam(**self._onebit_kwargs,
@@ -2091,6 +2120,24 @@ class DeepSpeedEngine(ZeroOffloadMixin):
                     pass
                 self.monitor.on_crash(e)
             raise
+
+    def _step_outputs(self):
+        """What the last dispatched step produced (the timers' fence)."""
+        return self.state, self.losses
+
+    def lower_train_step(self, batch):
+        """The fused train step, lowered for a stacked [gas, rows, ...]
+        `batch` with the arguments `train_batch` dispatches — for
+        reading the program (its kernels, its memory) without running
+        it or advancing the rng and lr state."""
+        if self._offload_enabled():
+            raise ValueError(
+                "lower_train_step covers the fused device step; under "
+                "ZeRO-Offload the update runs on the host")
+        lr = None if self._async_dispatch else self._current_lr()
+        return self._fused_step_jit.lower(
+            self.state, self.stage_batch(batch), jax.random.PRNGKey(0),
+            lr, self._keep_prob())
 
     def _train_batch_impl(self, data_iter=None, batch=None):
         gas = self._jit_gas()
@@ -2816,13 +2863,13 @@ class DeepSpeedEngine(ZeroOffloadMixin):
             acc_restored = jax.device_put(
                 _zeros_like_f32(self._params_enc_template),
                 self._acc_shardings)
-        self.state = EngineState(
+        self.state = self._scalars_on_mesh(EngineState(
             params=params, master=master, opt_state=opt_state, scale=scale,
             acc_grads=acc_restored,
             skipped=jnp.asarray(sd.get("skipped_steps", 0), jnp.int32),
             global_steps=jnp.asarray(
                 sd.get("global_steps", 0) - sd.get("skipped_steps", 0),
-                jnp.int32))
+                jnp.int32)))
         self.micro_steps = sd.get("micro_steps", 0)
         # the checkpoint's global_steps already counts successful +
         # skipped optimizer steps — deriving from micro_steps instead

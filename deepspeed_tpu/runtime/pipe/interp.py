@@ -56,7 +56,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from deepspeed_tpu.runtime.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.runtime.mesh import (DATA_AXIS, MODEL_AXIS, PIPE_AXIS,

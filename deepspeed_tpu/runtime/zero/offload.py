@@ -3,11 +3,9 @@
 See csrc/adam/cpu_adam.cpp and ops/adam/cpu_adam.py for the native step.
 Counterpart of ref `stage2.py:743-941,1416-1427`.
 
-The offload step is transfer-bound on slow host links (BENCH_r05
-`zero_offload_real_step`: the gpt2-125m step spends nearly all its
-wall time moving bytes at ~10-20 MB/s, and the overlap microbench shows
-software pipelining is already within 0.82 of this link's ceiling), so
-the remaining lever is bytes on the wire. `zero_optimization.
+Where the offload step is transfer-bound (a slow host link; its share
+of a step is not measured on the current installation), the remaining
+lever is bytes on the wire. `zero_optimization.
 offload_wire` configures a compressed wire format for the round trip:
 
   D2H  grad_bits=8  — int8 with one fp32 scale per 4096-element block

@@ -2,8 +2,9 @@
 
 TPU-native analogue of `deepspeed/utils/timer.py:19,97`. Where the reference
 fences with `torch.cuda.synchronize()`, we fence with
-`jax.block_until_ready` on a sentinel / `jax.effects_barrier()` — XLA
-dispatch is async exactly like CUDA streams.
+`jax.block_until_ready` on the outputs of the work being timed — XLA
+dispatch is async exactly like CUDA streams, and JAX has no
+device-wide synchronize: only waiting on a result waits for the device.
 """
 
 import time
@@ -42,34 +43,42 @@ def device_memory_stats():
     return out
 
 
-def _device_sync():
-    try:
-        import jax
-        # Blocks until all outstanding device computations are complete.
-        jax.effects_barrier()
-    except Exception:  # ds-lint: allow[BROADEXC] best-effort barrier: timers degrade to dispatch timing when jax is absent/uninitialized
-        pass
+def _device_sync(outputs):
+    """Wait until `outputs` — a pytree holding the device arrays the
+    timed work produced — are computed. (`jax.effects_barrier` waits
+    for side-effecting computations only; an ordinary jitted step has
+    none, so fencing with it times the enqueue.)"""
+    import jax
+    jax.block_until_ready(outputs)
+
+
+def _nothing_in_flight():
+    return None
 
 
 class SynchronizedWallClockTimer:
-    """Named timers with device-fence on start/stop."""
+    """Named timers with device-fence on start/stop. `sync_on` is a
+    zero-argument callable returning the pytree of device arrays the
+    timed work last produced (the engine passes its state and loss);
+    the default fences nothing, which is right for host-only work."""
 
     class Timer:
-        def __init__(self, name):
+        def __init__(self, name, sync_on=_nothing_in_flight):
             self.name_ = name
+            self.sync_on_ = sync_on
             self.elapsed_ = 0.0
             self.started_ = False
             self.start_time = time.time()
 
         def start(self):
             assert not self.started_, f"timer {self.name_} has already been started"
-            _device_sync()
+            _device_sync(self.sync_on_())
             self.start_time = time.time()
             self.started_ = True
 
         def stop(self, reset=False):
             assert self.started_, "timer is not started"
-            _device_sync()
+            _device_sync(self.sync_on_())
             if reset:
                 self.elapsed_ = time.time() - self.start_time
             else:
@@ -94,12 +103,13 @@ class SynchronizedWallClockTimer:
         def mean(self, reset=True):
             return self.elapsed(reset=reset)
 
-    def __init__(self):
+    def __init__(self, sync_on=_nothing_in_flight):
         self.timers = {}
+        self.sync_on = sync_on
 
     def __call__(self, name):
         if name not in self.timers:
-            self.timers[name] = self.Timer(name)
+            self.timers[name] = self.Timer(name, self.sync_on)
         return self.timers[name]
 
     def has_timer(self, name):
@@ -135,7 +145,11 @@ class ThroughputTimer:
                  start_step=2,
                  steps_per_output=50,
                  monitor_memory=False,
-                 logging_fn=None):
+                 logging_fn=None,
+                 sync_on=_nothing_in_flight):
+        # zero-argument callable returning the pytree of device arrays
+        # the last counted step produced; the window fences wait on it
+        self.sync_on = sync_on
         self.start_time = 0
         self.end_time = 0
         self.started = False
@@ -170,10 +184,9 @@ class ThroughputTimer:
         grad-accum step consumes several at once).
 
         Device fences happen ONLY at measurement-window boundaries (end
-        of warmup, and each steps_per_output report) — a per-step
-        `effects_barrier` would serialize host and device every step,
-        which on a remote-dispatch TPU runtime costs more than the step
-        itself. Between fences the device queue stays full; the
+        of warmup, and each steps_per_output report) — a per-step fence
+        would serialize host and device every step and drain the
+        dispatch queue. Between fences the device queue stays full; the
         window's wall time divided by its step count is exact."""
         if not self.started:
             return
@@ -183,13 +196,13 @@ class ThroughputTimer:
         if self.start_time == 0:
             if self.global_step_count >= self.start_step:
                 # warmup done: fence once and open the window
-                _device_sync()
+                _device_sync(self.sync_on())
                 self.start_time = time.time()
                 self._steps_at_window_start = self.global_step_count
             return
         if report_speed and \
                 self.global_step_count % self.steps_per_output < count:
-            _device_sync()
+            _device_sync(self.sync_on())
             self.end_time = time.time()
             window_elapsed = self.end_time - self.start_time
             # cumulative pair: total_elapsed_time / _measured_steps only

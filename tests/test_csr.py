@@ -40,7 +40,7 @@ def test_csr_add():
 def test_csr_mean_rows_matches_pmean():
     """Inside shard_map, the sparse gather-reduce must equal the dense
     pmean for row-sparse per-device grads."""
-    from deepspeed_tpu.runtime.compat import shard_map
+    from jax import shard_map
     mesh = build_mesh({"pipe": 1, "data": 8, "model": 1})
     rows, cols = 64, 16
     rng = np.random.default_rng(0)

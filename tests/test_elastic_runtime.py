@@ -44,6 +44,7 @@ from deepspeed_tpu.elasticity.runtime import (
     FaultInjector, classify_failure)
 from deepspeed_tpu.runtime import checkpoint as ckpt_io
 from deepspeed_tpu.monitor.watchdog import StallWatchdog
+from deepspeed_tpu.utils.compile_cache import compile_cache_dir
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -1011,9 +1012,7 @@ def test_chaos_sigkill_bit_identical_resume(tmp_path):
     partition, resume from the last committed checkpoint with a loss
     trajectory BIT-IDENTICAL to a clean restart from that same
     checkpoint, and grow back to 8 devices when capacity returns."""
-    cache = os.path.abspath(os.environ.get(
-        "JAX_TEST_COMPILATION_CACHE",
-        os.path.join(REPO, ".jax_test_cache")))
+    cache = compile_cache_dir()
     script = CHAOS_SCRIPT.format(repo=REPO, cache=cache,
                                  save_dir=str(tmp_path / "ckpt"))
     env = dict(os.environ)

@@ -196,7 +196,8 @@ def test_fused_chain_rows_attributable():
     h = 256
     args = [jnp.ones((64, h)), jnp.ones((h,)), jnp.ones((64, h)),
             jnp.ones((h,)), jnp.ones((h,))]
-    rows = per_fusion_costs(jax.grad(f, argnums=(0, 1, 2, 3, 4)), *args)
+    rows = per_fusion_costs(jax.grad(f, argnums=(0, 1, 2, 3, 4)), *args,
+                            peak_flops=1e12, hbm_gbps=100.0)
     assert rows
     ops = " ".join(r["op"] for r in rows)
     assert "fused_bias_residual_layernorm" in ops

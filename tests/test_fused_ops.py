@@ -367,3 +367,35 @@ def test_plain_layernorm_no_nan_on_constant_rows():
         out = plain_layernorm(x, jnp.ones((768,)), jnp.zeros((768,)),
                               1e-5)
         assert np.isfinite(ab(out)).all(), mag
+
+
+def test_kernels_launch_per_device_on_a_mesh():
+    """On a mesh every Pallas launch runs under shard_map, each device
+    on its own rows (ops/per_device.py — GSPMD cannot partition a
+    Mosaic call). Same outputs and gradients as the unsharded launch:
+    the LayerNorm parameter gradients are sums over rows, so they pin
+    the cross-device add; the GeLU width follows the model axis."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.runtime.mesh import build_mesh
+
+    mesh = build_mesh({"pipe": 1, "data": 4, "model": 2})
+    y, b, r, g, bet = _ln_args(128, jnp.float32)
+    x = jnp.concatenate([y, r], axis=-1)               # [4, 16, 256]
+    xb = jnp.concatenate([b, bet])
+
+    def loss(y, b, r, g, bet, x, xb):
+        out, s = fused_bias_residual_layernorm(
+            y, b, r, g, bet, eps=1e-5, impl="interpret")
+        act = fused_bias_gelu(x, xb, approximate=True, impl="interpret")
+        return jnp.sin(out).sum() + jnp.cos(s).sum() + (act ** 2).sum()
+
+    grad = jax.jit(jax.grad(loss, argnums=tuple(range(7))))
+    args = (y, b, r, g, bet, x, xb)
+    want = grad(*args)
+    rows = NamedSharding(mesh, P("data"))
+    whole = NamedSharding(mesh, P())
+    got = grad(*(jax.device_put(a, rows if a.ndim == 3 else whole)
+                 for a in args))
+    for a, w in zip(got, want):
+        np.testing.assert_allclose(ab(a), ab(w), atol=1e-5, rtol=1e-5)

@@ -14,12 +14,14 @@ import textwrap
 
 import pytest
 
+from deepspeed_tpu.utils.compile_cache import compile_cache_dir
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _TRAIN_SCRIPT = textwrap.dedent("""
     import json, os, sys
     sys.path.insert(0, __REPO__)
-    import deepspeed_tpu           # applies DS_TPU_PLATFORM before jax use
+    import deepspeed_tpu
     import jax, numpy as np
 
     dist = os.environ.get("WORLD_SIZE") is not None
@@ -72,11 +74,9 @@ def _write_script(tmp_path):
 
 def _base_env():
     env = dict(os.environ)
-    env["DS_TPU_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)   # 1 real CPU device per process
-    env.setdefault("JAX_COMPILATION_CACHE_DIR",
-                   env.get("JAX_TEST_COMPILATION_CACHE",
-                           os.path.join(REPO, ".jax_test_cache")))
+    env["JAX_COMPILATION_CACHE_DIR"] = compile_cache_dir()
     return env
 
 

@@ -85,7 +85,7 @@ def test_error_feedback_invariant():
 def test_compressed_allreduce_approximates_mean(mesh8):
     """Across 8 shards with distinct inputs, the compressed result must
     approximate the true mean (one sign+scale quantization away)."""
-    from deepspeed_tpu.runtime.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = 128

@@ -33,8 +33,6 @@ class _FakeTime:
 def fake_time(monkeypatch):
     ft = _FakeTime()
     monkeypatch.setattr(timer_mod, "time", ft)
-    # the window fences call jax.effects_barrier; irrelevant here
-    monkeypatch.setattr(timer_mod, "_device_sync", lambda: None)
     return ft
 
 
