@@ -2567,7 +2567,7 @@ def bench_serving_observability():
         # sides equally: the single 4-token warmup request shifts a
         # >=128-sample distribution by well under one bucket)
         ttft_exact = sorted(
-            (q.first_token_at - q.admitted_at) * 1e3
+            (q.first_token_at - q.arrival_time) * 1e3
             for q in on_requests if q.first_token_at is not None)
         token_pairs = []
         for q in on_requests:

@@ -44,7 +44,31 @@ from deepspeed_tpu.inference.quant import (KERNEL_SCALE, int8_matmul,
                                            quantize_param_tree)
 from deepspeed_tpu.monitor import DeepSpeedMonitorConfig, Monitor
 from deepspeed_tpu.monitor import memory as memory_mod
+from deepspeed_tpu.monitor import programs
+from deepspeed_tpu.monitor.trace import profiler_span
 from deepspeed_tpu.utils.logging import logger
+
+# The regions of the serving programs (`jax.named_scope`: metadata on
+# the HLO, no instruction changes). A device profile's operations are
+# joined to these through `monitor/programs.py::op_scopes`. Time in
+# SCOPE_LAYERS outside every inner region is the layer scan itself:
+# slicing each layer's K/V page pool out of its xs and writing it back
+# into its ys.
+SCOPE_EMBED = "embed"
+SCOPE_LAYERS = "layers"            # round the lax.scan call, nothing else
+SCOPE_ATTN_QKV = "attn_qkv"        # inside a layer: ln_1 + c_attn
+SCOPE_KV_WRITE = "kv_write"        # the chunk's K/V into the page pool
+SCOPE_KV_GATHER = "kv_gather"      # the page window through the tables
+SCOPE_ATTN = "attn"                # paged_attention
+SCOPE_ATTN_OUT = "attn_out"        # c_proj + residual
+SCOPE_MLP = "mlp"                  # ln_2, c_fc, gelu, mlp_c_proj
+SCOPE_HEAD = "head"                # ln_f + tied head
+SCOPE_SAMPLE = "sample"
+SCOPE_BOOKKEEPING = "bookkeeping"  # the slot state update
+SCOPES_IN_LAYER = (SCOPE_ATTN_QKV, SCOPE_KV_WRITE, SCOPE_KV_GATHER,
+                   SCOPE_ATTN, SCOPE_ATTN_OUT, SCOPE_MLP)
+SCOPES = (SCOPE_EMBED, SCOPE_LAYERS) + SCOPES_IN_LAYER + \
+    (SCOPE_HEAD, SCOPE_SAMPLE, SCOPE_BOOKKEEPING)
 
 
 def compile_fresh(lowered):
@@ -77,6 +101,29 @@ def compile_fresh(lowered):
         reset_cache()
 
 
+def compile_registered(fn, args, donate_argnums):
+    """`compile_fresh` of `jit(fn)` on `args`, with the executable left
+    in the program registry under the name the profiler gives its
+    launches (`jit_<fn>`). The registry reads the program's name
+    stacks out of the executable, so for these programs they are part
+    of the persistent cache's key: by default jax leaves them out, and
+    a cache that an older build filled would hand back that build's
+    names for the same HLO (seen on the chip: the parent's executables,
+    none of the `SCOPE_*` in them)."""
+    lowered = jax.jit(fn, donate_argnums=donate_argnums).lower(*args)
+    key_had_names = jax.config.jax_compilation_cache_include_metadata_in_key
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    t0 = time.perf_counter()
+    try:
+        compiled = compile_fresh(lowered)
+    finally:
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          key_had_names)
+    programs.register("jit_" + fn.__name__, compiled,
+                      time.perf_counter() - t0)
+    return compiled
+
+
 # ----------------------------------------------------------------------
 # training-math twins: the same flax modules the training forward runs,
 # applied to extracted param leaves (bit-exact by construction)
@@ -101,6 +148,7 @@ def _dense_apply(cfg, p, x, quant_block):
             {"params": {"kernel": p["kernel"], "bias": p["bias"]}}, x)
 
 
+@jax.named_scope(SCOPE_ATTN)
 def paged_attention(q, kc, vc, q_pos, kv_limit):
     """Causal attention of q [B, Tq, H, D] against a gathered page
     window kc/vc [B, Tk, H, D], phrased exactly like the training
@@ -145,35 +193,41 @@ def _block_paged(cfg, lp, hidden, kl, vl, tables, positions, valid,
     b, t, c = hidden.shape
     h, d = cfg.n_head, cfg.head_dim
 
-    x = _ln_apply(cfg, lp["ln_1"], hidden).astype(cfg.dtype)
-    qkv = _dense_apply(cfg, lp["c_attn"], x, quant_block)
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(b, t, h, d)
-    k = k.reshape(b, t, h, d)
-    v = v.reshape(b, t, h, d)
+    with jax.named_scope(SCOPE_ATTN_QKV):
+        x = _ln_apply(cfg, lp["ln_1"], hidden).astype(cfg.dtype)
+        qkv = _dense_apply(cfg, lp["c_attn"], x, quant_block)
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+        q = q.reshape(b, t, h, d)
+        k = k.reshape(b, t, h, d)
+        v = v.reshape(b, t, h, d)
 
     # write-before-read: the chunk's own keys are part of its causal
     # window (a query attends to itself, like the training mask)
-    pidx = positions // page_size
-    off = positions % page_size
-    phys = jnp.take_along_axis(tables, pidx, axis=1)
-    phys = jnp.where(valid, phys, 0).reshape(-1)
-    off = off.reshape(-1)
-    kl = kl.at[phys, off].set(k.reshape(b * t, h, d))
-    vl = vl.at[phys, off].set(v.reshape(b * t, h, d))
+    with jax.named_scope(SCOPE_KV_WRITE):
+        pidx = positions // page_size
+        off = positions % page_size
+        phys = jnp.take_along_axis(tables, pidx, axis=1)
+        phys = jnp.where(valid, phys, 0).reshape(-1)
+        off = off.reshape(-1)
+        kl = kl.at[phys, off].set(k.reshape(b * t, h, d))
+        vl = vl.at[phys, off].set(v.reshape(b * t, h, d))
 
-    kc = kl[tables].reshape(b, -1, h, d)
-    vc = vl[tables].reshape(b, -1, h, d)
+    with jax.named_scope(SCOPE_KV_GATHER):
+        kc = kl[tables].reshape(b, -1, h, d)
+        vc = vl[tables].reshape(b, -1, h, d)
     attn = paged_attention(q, kc, vc, positions, kv_limit)
-    attn = attn.reshape(b, t, c)
-    attn = _dense_apply(cfg, lp["c_proj"], attn, quant_block)
-    hidden = hidden + attn
+    with jax.named_scope(SCOPE_ATTN_OUT):
+        attn = attn.reshape(b, t, c)
+        attn = _dense_apply(cfg, lp["c_proj"], attn, quant_block)
+        hidden = hidden + attn
 
-    y = _ln_apply(cfg, lp["ln_2"], hidden).astype(cfg.dtype)
-    y = _dense_apply(cfg, lp["c_fc"], y, quant_block)
-    y = nn.gelu(y, approximate=True)
-    y = _dense_apply(cfg, lp["mlp_c_proj"], y, quant_block)
-    return hidden + y, kl, vl
+    with jax.named_scope(SCOPE_MLP):
+        y = _ln_apply(cfg, lp["ln_2"], hidden).astype(cfg.dtype)
+        y = _dense_apply(cfg, lp["c_fc"], y, quant_block)
+        y = nn.gelu(y, approximate=True)
+        y = _dense_apply(cfg, lp["mlp_c_proj"], y, quant_block)
+        hidden = hidden + y
+    return hidden, kl, vl
 
 
 class InferenceEngine:
@@ -335,6 +389,7 @@ class InferenceEngine:
         out_w = cfg.max_new_tokens
         top_k_cap = min(cfg.top_k_max, mc.vocab_size)
 
+        @jax.named_scope(SCOPE_SAMPLE)
         def sample(logits, state):
             l32 = logits.astype(jnp.float32)
             greedy = jnp.argmax(l32, axis=-1).astype(jnp.int32)
@@ -358,9 +413,10 @@ class InferenceEngine:
             wte, wpe = params["wte"], params["wpe"]
             # embed_tokens' math for a [S, 1] "sequence" at absolute
             # positions `pos`
-            hidden = wte[state["cur_token"]].astype(mc.dtype) + \
-                wpe[pos].astype(mc.dtype)
-            hidden = hidden[:, None, :]
+            with jax.named_scope(SCOPE_EMBED):
+                hidden = wte[state["cur_token"]].astype(mc.dtype) + \
+                    wpe[pos].astype(mc.dtype)
+                hidden = hidden[:, None, :]
             positions = pos[:, None]
             valid = active[:, None]
             from deepspeed_tpu.models.gpt2 import stacked_block_params
@@ -373,39 +429,43 @@ class InferenceEngine:
                 return h, (kl, vl)
 
             stacked = stacked_block_params(params)
-            hidden, (k_pool, v_pool) = jax.lax.scan(
-                layer, hidden, (stacked, state["k_pool"],
-                                state["v_pool"]))
-            hidden = _ln_apply(mc, params["ln_f"], hidden)
-            logits = jnp.einsum("btc,vc->btv", hidden.astype(mc.dtype),
-                                wte.astype(mc.dtype))[:, 0]
+            with jax.named_scope(SCOPE_LAYERS):
+                hidden, (k_pool, v_pool) = jax.lax.scan(
+                    layer, hidden, (stacked, state["k_pool"],
+                                    state["v_pool"]))
+            with jax.named_scope(SCOPE_HEAD):
+                hidden = _ln_apply(mc, params["ln_f"], hidden)
+                logits = jnp.einsum(
+                    "btc,vc->btv", hidden.astype(mc.dtype),
+                    wte.astype(mc.dtype))[:, 0]
             next_tok = sample(logits, state)
 
-            n = state["n_gen"]
-            idx = jnp.clip(n, 0, out_w - 1)
-            rows = jnp.arange(s)
-            prev = state["out_tokens"][rows, idx]
-            out = state["out_tokens"].at[rows, idx].set(
-                jnp.where(active, next_tok, prev))
-            n2 = n + active.astype(jnp.int32)
-            hit_eos = active & (next_tok == state["eos"])
-            hit_max = active & (n2 >= state["max_new"])
-            new_state = dict(
-                state,
-                k_pool=k_pool, v_pool=v_pool,
-                pos=pos + active.astype(jnp.int32),
-                cur_token=jnp.where(active, next_tok,
-                                    state["cur_token"]),
-                active=active & ~(hit_eos | hit_max),
-                finished_eos=state["finished_eos"] | hit_eos,
-                n_gen=n2,
-                out_tokens=out,
-                step=state["step"] + 1,
-            )
+            with jax.named_scope(SCOPE_BOOKKEEPING):
+                n = state["n_gen"]
+                idx = jnp.clip(n, 0, out_w - 1)
+                rows = jnp.arange(s)
+                prev = state["out_tokens"][rows, idx]
+                out = state["out_tokens"].at[rows, idx].set(
+                    jnp.where(active, next_tok, prev))
+                n2 = n + active.astype(jnp.int32)
+                hit_eos = active & (next_tok == state["eos"])
+                hit_max = active & (n2 >= state["max_new"])
+                new_state = dict(
+                    state,
+                    k_pool=k_pool, v_pool=v_pool,
+                    pos=pos + active.astype(jnp.int32),
+                    cur_token=jnp.where(active, next_tok,
+                                        state["cur_token"]),
+                    active=active & ~(hit_eos | hit_max),
+                    finished_eos=state["finished_eos"] | hit_eos,
+                    n_gen=n2,
+                    out_tokens=out,
+                    step=state["step"] + 1,
+                )
             return new_state, logits
 
-        return compile_fresh(jax.jit(decode_fn, donate_argnums=(1,))
-                             .lower(self._params, self._state))
+        return compile_registered(decode_fn, (self._params, self._state),
+                                  donate_argnums=(1,))
 
     def _build_prefill_step(self):
         cfg, mc = self.config, self.model_config
@@ -418,9 +478,10 @@ class InferenceEngine:
             wte, wpe = params["wte"], params["wpe"]
             posv = start + jnp.arange(chunk, dtype=jnp.int32)
             valid = jnp.arange(chunk) < n_valid
-            hidden = wte[tokens].astype(mc.dtype) + \
-                wpe[posv].astype(mc.dtype)
-            hidden = hidden[None]
+            with jax.named_scope(SCOPE_EMBED):
+                hidden = wte[tokens].astype(mc.dtype) + \
+                    wpe[posv].astype(mc.dtype)
+                hidden = hidden[None]
             positions = posv[None]
             kv_limit = (start + n_valid - 1)[None]
             tables = page_row[None]
@@ -434,8 +495,9 @@ class InferenceEngine:
                 return h, (kl, vl)
 
             stacked = stacked_block_params(params)
-            _, (k_pool, v_pool) = jax.lax.scan(
-                layer, hidden, (stacked, k_pool, v_pool))
+            with jax.named_scope(SCOPE_LAYERS):
+                _, (k_pool, v_pool) = jax.lax.scan(
+                    layer, hidden, (stacked, k_pool, v_pool))
             return k_pool, v_pool
 
         st = self._state
@@ -443,8 +505,7 @@ class InferenceEngine:
                 jnp.asarray(self.cache.tables[0]),
                 jnp.zeros((chunk,), jnp.int32),
                 jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
-        return compile_fresh(jax.jit(prefill_fn, donate_argnums=(1, 2))
-                             .lower(*args))
+        return compile_registered(prefill_fn, args, donate_argnums=(1, 2))
 
     # ------------------------------------------------------------------
     # fence-side slot management (host work, runs between blocks)
@@ -483,25 +544,32 @@ class InferenceEngine:
 
     def activate_slot(self, slot, cur_token, pos, max_new, temperature,
                       top_k, eos):
-        """Flip a fully-prefilled slot live for the decode batch."""
+        """Flip a fully-prefilled slot live for the decode batch. The
+        updates are eager dispatches behind the slot's last prefill
+        chunk; the two profiler spans show whether the host waits at
+        the first of them or pays for each."""
         st = self._state
-        st["cur_token"] = st["cur_token"].at[slot].set(int(cur_token))
-        st["pos"] = st["pos"].at[slot].set(int(pos))
-        st["active"] = st["active"].at[slot].set(True)
-        st["finished_eos"] = st["finished_eos"].at[slot].set(False)
-        st["n_gen"] = st["n_gen"].at[slot].set(0)
-        st["max_new"] = st["max_new"].at[slot].set(int(max_new))
-        st["temperature"] = st["temperature"].at[slot].set(
-            float(temperature))
-        st["top_k"] = st["top_k"].at[slot].set(int(top_k))
-        st["eos"] = st["eos"].at[slot].set(
-            -1 if eos is None else int(eos))
-        if self.speculative_enabled:
-            # new request, fresh speculation posture: optimistic k,
-            # clean acceptance EMA
-            sp = self._spec_state
-            sp["k_slot"] = sp["k_slot"].at[slot].set(self.config.spec_k)
-            sp["acc_ema"] = sp["acc_ema"].at[slot].set(1.0)
+        with profiler_span("serve/activate.first_update"):
+            st["cur_token"] = st["cur_token"].at[slot].set(
+                int(cur_token))
+        with profiler_span("serve/activate.other_updates"):
+            st["pos"] = st["pos"].at[slot].set(int(pos))
+            st["active"] = st["active"].at[slot].set(True)
+            st["finished_eos"] = st["finished_eos"].at[slot].set(False)
+            st["n_gen"] = st["n_gen"].at[slot].set(0)
+            st["max_new"] = st["max_new"].at[slot].set(int(max_new))
+            st["temperature"] = st["temperature"].at[slot].set(
+                float(temperature))
+            st["top_k"] = st["top_k"].at[slot].set(int(top_k))
+            st["eos"] = st["eos"].at[slot].set(
+                -1 if eos is None else int(eos))
+            if self.speculative_enabled:
+                # new request, fresh speculation posture: optimistic
+                # k, clean acceptance EMA
+                sp = self._spec_state
+                sp["k_slot"] = sp["k_slot"].at[slot].set(
+                    self.config.spec_k)
+                sp["acc_ema"] = sp["acc_ema"].at[slot].set(1.0)
 
     def start_request(self, slot, prompt, max_new, temperature=0.0,
                       top_k=0, eos=None):
@@ -616,23 +684,26 @@ class InferenceEngine:
         targets = (st["active"], st["finished_eos"], st["pos"],
                    st["n_gen"], st["out_tokens"])
         if not self.speculative_enabled:
-            active, eos, pos, n_gen, out = jax.device_get(targets)
+            with profiler_span("serve/fence.device_get"):
+                active, eos, pos, n_gen, out = jax.device_get(targets)
             return {"active": active, "finished_eos": eos, "pos": pos,
                     "n_gen": n_gen, "out_tokens": out}
         sp = self._spec_state
-        (active, eos, pos, n_gen, out, k_slot, drafted, accepted,
-         verified, rollbacks, rounds) = jax.device_get(
-            targets + (sp["k_slot"], sp["drafted_total"],
-                       sp["accepted_total"], sp["verified_total"],
-                       sp["rollbacks"], sp["rounds"]))
-        if self.config.spec_adaptive:
-            live = k_slot[active] if active.any() else None
-            self._spec_next_draft = int(live.max()) \
-                if live is not None else self.config.spec_k
-        return {"active": active, "finished_eos": eos, "pos": pos,
-                "n_gen": n_gen, "out_tokens": out,
-                "speculative": {"k_slot": k_slot, "drafted": drafted,
-                                "accepted": accepted,
-                                "verified": verified,
-                                "rollbacks": rollbacks,
-                                "rounds": int(rounds)}}
+        with profiler_span("serve/fence.device_get"):
+            (active, eos, pos, n_gen, out, k_slot, drafted, accepted,
+             verified, rollbacks, rounds) = jax.device_get(
+                targets + (sp["k_slot"], sp["drafted_total"],
+                           sp["accepted_total"], sp["verified_total"],
+                           sp["rollbacks"], sp["rounds"]))
+        with profiler_span("serve/fence.bookkeeping"):
+            if self.config.spec_adaptive:
+                live = k_slot[active] if active.any() else None
+                self._spec_next_draft = int(live.max()) \
+                    if live is not None else self.config.spec_k
+            return {"active": active, "finished_eos": eos, "pos": pos,
+                    "n_gen": n_gen, "out_tokens": out,
+                    "speculative": {"k_slot": k_slot, "drafted": drafted,
+                                    "accepted": accepted,
+                                    "verified": verified,
+                                    "rollbacks": rollbacks,
+                                    "rounds": int(rounds)}}

@@ -419,8 +419,10 @@ class ServingLoop:
             new_tokens=gen,
             queued_ms=round(
                 (req.admitted_at - req.arrival_time) * 1e3, 3),
+            # from ARRIVAL, as the client counts it: queued_ms is
+            # the part of it spent before admission
             ttft_ms=None if req.first_token_at is None else round(
-                (req.first_token_at - req.admitted_at) * 1e3, 3),
+                (req.first_token_at - req.arrival_time) * 1e3, 3),
             prefill_ms=round(max(live_at - req.admitted_at, 0.0) * 1e3,
                              3),
             decode_ms=round(decode_s * 1e3, 3),

@@ -252,8 +252,11 @@ class ServingTracker:
                 row["pages_held"] = self._cache.allocated_pages(slot)
                 if delta > 0 and row["ttft_ms"] is None:
                     # fence-granularity upper bound: the token appeared
-                    # somewhere inside this window
-                    row["ttft_ms"] = (now - row["admitted_t"]) * 1e3
+                    # somewhere inside this window. Counted from the
+                    # request's ARRIVAL (queue wait + time since
+                    # admission): an SLO on it is the client's
+                    row["ttft_ms"] = (row["queued_s"] + now -
+                                      row["admitted_t"]) * 1e3
                     self.hist_ttft_ms.record(row["ttft_ms"])
                 if delta > 0 and decode_t0 is not None:
                     slices.append((int(slot), row["request_id"],

@@ -876,7 +876,7 @@ def test_serving_trace_exports_slot_timeline(obs_setup):
     # fidelity: summary TTFT p50 within one histogram... no — the
     # summary is exact (recomputed from instants); compare against the
     # scheduler's independent Request stamps instead
-    exact = sorted((q.first_token_at - q.admitted_at) * 1e3
+    exact = sorted((q.first_token_at - q.arrival_time) * 1e3
                    for q in results)
     assert abs(serving["ttft_ms"]["p50"] - exact[1]) < \
         max(2.0, 0.5 * exact[1])
