@@ -5,14 +5,16 @@ buffer per request — at high slot counts the padding-to-max waste is
 the first thing that OOMs a serving chip. Instead a single preallocated
 pool of fixed-size pages
 
-    k_pool / v_pool : [n_layer, num_pages, page_size, n_head, head_dim]
+    k_pool / v_pool : [n_layer, num_pages, page_size, n_head * head_dim]
 
-is shared by every request; each request slot owns a page table
-(row of physical page ids) and positions map to (physical page,
-offset) by plain index math inside the compiled programs. Physical
-page 0 is a reserved scratch page: masked writes (inactive decode
-slots, prefill pad rows) are diverted there instead of being
-predicated away, so the compiled step stays branch-free.
+(one token's K or V of one layer on the lanes: the layout the compiled
+programs scatter into and gather from in place, see
+`engine.scan_layers`) is shared by every request; each request slot
+owns a page table (row of physical page ids) and positions map to
+(physical page, offset) by plain index math inside the compiled
+programs. Physical page 0 is a reserved scratch page: masked writes
+(inactive decode slots, prefill pad rows) are diverted there instead
+of being predicated away, so the compiled step stays branch-free.
 
 Allocation is host-side and happens only at serving fences (request
 admission / chunk reservation / finish) — never inside the dispatch
@@ -116,6 +118,14 @@ class PagedKVCache:
                 meta={"num_pages": self.num_pages,
                       "page_size": self.page_size,
                       "n_layer_draft": self.draft_n_layer})
+
+    def pool_shape(self, n_layer):
+        """Shape of ONE device pool (K or V) of `n_layer` layers: one
+        token's K (or V) of one layer is the minor-most row, so the
+        pool has one natural layout inside and outside the compiled
+        programs' layer scan."""
+        return (int(n_layer), self.num_pages, self.page_size,
+                self.n_head * self.head_dim)
 
     # -- accounting -----------------------------------------------------
     def pages_for_tokens(self, n_tokens):

@@ -55,8 +55,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.engine import (_block_paged, _ln_apply,
-                                            compile_fresh)
+from deepspeed_tpu.inference.engine import (_ln_apply, compile_fresh,
+                                            scan_layers)
 
 # fold_in lane separating the draft model's sampling stream from the
 # flagship's (state["rng"] folded by step on one side, by
@@ -136,8 +136,7 @@ def fresh_spec_state(engine):
     cfg, mc = engine.config, engine.model_config
     dmc = engine._draft_config
     s, k = cfg.max_slots, cfg.spec_k
-    pool = (dmc.n_layer, engine.cache.num_pages, engine.cache.page_size,
-            mc.n_head, mc.head_dim)
+    pool = engine.cache.pool_shape(dmc.n_layer)
     return {
         "dk_pool": jnp.zeros(pool, mc.dtype),
         "dv_pool": jnp.zeros(pool, mc.dtype),
@@ -171,7 +170,6 @@ def build_draft_step(engine):
     top_k_cap = min(cfg.top_k_max, mc.vocab_size)
 
     def draft_fn(draft_params, state, spec):
-        from deepspeed_tpu.models.gpt2 import stacked_block_params
         j = spec["n_draft"]
         active = state["active"]
         pos = state["pos"] + j
@@ -190,18 +188,9 @@ def build_draft_step(engine):
         posc = jnp.clip(pos, 0, mc.n_positions - 1)
         hidden = wte[cur].astype(mc.dtype) + wpe[posc].astype(mc.dtype)
         hidden = hidden[:, None, :]
-        positions = pos[:, None]
-
-        def layer(h, xs):
-            lp, kl, vl = xs
-            h, kl, vl = _block_paged(
-                dmc, lp, h, kl, vl, state["tables"], positions,
-                valid[:, None], pos, page, qb)
-            return h, (kl, vl)
-
-        stacked = stacked_block_params(draft_params)
-        hidden, (dk, dv) = jax.lax.scan(
-            layer, hidden, (stacked, spec["dk_pool"], spec["dv_pool"]))
+        hidden, dk, dv = scan_layers(
+            dmc, draft_params, hidden, spec["dk_pool"], spec["dv_pool"],
+            state["tables"], pos[:, None], valid[:, None], pos, page, qb)
         hidden = _ln_apply(dmc, draft_params["ln_f"], hidden)
         logits = jnp.einsum("btc,vc->btv", hidden.astype(mc.dtype),
                             wte.astype(mc.dtype))[:, 0]
@@ -244,7 +233,6 @@ def build_verify_step(engine):
     k_min = cfg.spec_k_min
 
     def verify_fn(params, state, spec):
-        from deepspeed_tpu.models.gpt2 import stacked_block_params
         active = state["active"]
         pos0 = state["pos"]
         n_gen = state["n_gen"]
@@ -264,18 +252,9 @@ def build_verify_step(engine):
         posc = jnp.clip(positions, 0, mc.n_positions - 1)
         hidden = wte[tokens_in].astype(mc.dtype) + \
             wpe[posc].astype(mc.dtype)
-
-        def layer(h, xs):
-            lp, kl, vl = xs
-            h, kl, vl = _block_paged(
-                mc, lp, h, kl, vl, state["tables"], positions,
-                write_ok, kv_limit, page, qb)
-            return h, (kl, vl)
-
-        stacked = stacked_block_params(params)
-        hidden, (k_pool, v_pool) = jax.lax.scan(
-            layer, hidden, (stacked, state["k_pool"],
-                            state["v_pool"]))
+        hidden, k_pool, v_pool = scan_layers(
+            mc, params, hidden, state["k_pool"], state["v_pool"],
+            state["tables"], positions, write_ok, kv_limit, page, qb)
         hidden = _ln_apply(mc, params["ln_f"], hidden)
         logits = jnp.einsum("btc,vc->btv", hidden.astype(mc.dtype),
                             wte.astype(mc.dtype))
@@ -405,7 +384,6 @@ def build_draft_prefill_step(engine):
 
     def draft_prefill_fn(draft_params, dk_pool, dv_pool, page_row,
                          tokens, start, n_valid):
-        from deepspeed_tpu.models.gpt2 import stacked_block_params
         wte, wpe = draft_params["wte"], draft_params["wpe"]
         posv = start + jnp.arange(chunk, dtype=jnp.int32)
         valid = jnp.arange(chunk) < n_valid
@@ -414,18 +392,9 @@ def build_draft_prefill_step(engine):
         hidden = hidden[None]
         positions = posv[None]
         kv_limit = (start + n_valid - 1)[None]
-        tables = page_row[None]
-
-        def layer(h, xs):
-            lp, kl, vl = xs
-            h, kl, vl = _block_paged(
-                dmc, lp, h, kl, vl, tables, positions, valid[None],
-                kv_limit, page, qb)
-            return h, (kl, vl)
-
-        stacked = stacked_block_params(draft_params)
-        _, (dk_pool, dv_pool) = jax.lax.scan(
-            layer, hidden, (stacked, dk_pool, dv_pool))
+        _, dk_pool, dv_pool = scan_layers(
+            dmc, draft_params, hidden, dk_pool, dv_pool, page_row[None],
+            positions, valid[None], kv_limit, page, qb)
         return dk_pool, dv_pool
 
     sp = engine._spec_state

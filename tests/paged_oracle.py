@@ -1,0 +1,169 @@
+"""What the serving programs are held against since the page pools
+ride in the layer scan's carry (ISSUE 25): an oracle that never carries
+anything, and a reader of the programs' jaxprs.
+
+The oracle is the layer stack as it was written before: a Python loop
+over the layers, each with its OWN page pool in the old five-dimensional
+shape ([P, page, H, D]), written with `.at[phys, off].set` and read with
+`pool[tables]`. Everything between the write and the read is the
+program's own (`paged_attention`, `_ln_apply`, `_dense_apply`), so a
+difference is the carry's, the scatter's or the gather's. One jitted
+layer is called n_layer times: the same compiled code for every layer,
+as in a scan's body."""
+
+import contextlib
+import functools
+
+import numpy as np
+import pytest
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.inference.engine import (_dense_apply, _ln_apply,
+                                            paged_attention)
+from deepspeed_tpu.models.gpt2 import stacked_block_params
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "page_size",
+                                              "quant_block"))
+def _oracle_block(cfg, lp, hidden, kl, vl, tables, positions, valid,
+                  kv_limit, page_size, quant_block):
+    b, t, c = hidden.shape
+    h, d = cfg.n_head, cfg.head_dim
+    x = _ln_apply(cfg, lp["ln_1"], hidden).astype(cfg.dtype)
+    qkv = _dense_apply(cfg, lp["c_attn"], x, quant_block)
+    q, k, v = jnp.split(qkv, 3, axis=-1)
+    q = q.reshape(b, t, h, d)
+    k = k.reshape(b, t, h, d)
+    v = v.reshape(b, t, h, d)
+    pidx = positions // page_size
+    off = (positions % page_size).reshape(-1)
+    phys = jnp.take_along_axis(tables, pidx, axis=1)
+    phys = jnp.where(valid, phys, 0).reshape(-1)
+    kl = kl.at[phys, off].set(k.reshape(b * t, h, d))
+    vl = vl.at[phys, off].set(v.reshape(b * t, h, d))
+    kc = kl[tables].reshape(b, -1, h, d)
+    vc = vl[tables].reshape(b, -1, h, d)
+    attn = paged_attention(q, kc, vc, positions, kv_limit)
+    attn = _dense_apply(cfg, lp["c_proj"], attn.reshape(b, t, c),
+                        quant_block)
+    hidden = hidden + attn
+    y = _ln_apply(cfg, lp["ln_2"], hidden).astype(cfg.dtype)
+    y = _dense_apply(cfg, lp["c_fc"], y, quant_block)
+    y = nn.gelu(y, approximate=True)
+    y = _dense_apply(cfg, lp["mlp_c_proj"], y, quant_block)
+    return hidden + y, kl, vl
+
+
+def oracle_forward(cfg, params, tokens, positions, valid, kv_limit,
+                   tables, k_pool, v_pool, page_size, quant_block):
+    """tokens/positions/valid [B, T], kv_limit [B], tables [B, pages];
+    the pools as the engine holds them ([L, P, page, H*D], numpy).
+    Returns (logits of ln_f + tied head [B, T, V], k_pool, v_pool),
+    the pools again in the engine's shape."""
+    dt = cfg.dtype
+    wte, wpe = params["wte"], params["wpe"]
+    posc = jnp.clip(positions, 0, cfg.n_positions - 1)
+    hidden = wte[tokens].astype(dt) + wpe[posc].astype(dt)
+    stacked = stacked_block_params(params)
+    n_layer, pages, page, c = k_pool.shape
+    per_layer = (pages, page, cfg.n_head, cfg.head_dim)
+    ks, vs = [], []
+    for li in range(n_layer):
+        lp = jax.tree_util.tree_map(lambda x: x[li], stacked)
+        hidden, kl, vl = _oracle_block(
+            cfg, lp, hidden, k_pool[li].reshape(per_layer),
+            v_pool[li].reshape(per_layer), tables, positions, valid,
+            kv_limit, page_size=page_size, quant_block=quant_block)
+        ks.append(np.asarray(kl).reshape(pages, page, c))
+        vs.append(np.asarray(vl).reshape(pages, page, c))
+    final = _ln_apply(cfg, params["ln_f"], hidden)
+    logits = jnp.einsum("btc,vc->btv", final.astype(dt), wte.astype(dt))
+    return np.asarray(logits), np.stack(ks), np.stack(vs)
+
+
+def assert_pools_equal(held, names, refs, what):
+    """The pools `names` of the state dict `held`, bit for bit."""
+    for name, ref in zip(names, refs):
+        got = np.asarray(held[name])
+        assert np.array_equal(got, ref), (what, name,
+                                          np.abs(got - ref).max())
+
+
+def prefill_inputs(chunk, tokens, start, page_row):
+    """The prefill programs' view of one chunk of one request."""
+    n = len(tokens)
+    buf = np.zeros((chunk,), np.int32)
+    buf[:n] = tokens
+    return dict(
+        tokens=buf[None],
+        positions=(start + np.arange(chunk, dtype=np.int32))[None],
+        valid=(np.arange(chunk) < n)[None],
+        kv_limit=np.asarray([start + n - 1], np.int32),
+        tables=np.asarray(page_row)[None])
+
+
+# ----------------------------------------------------------------------
+# the programs' jaxprs
+# ----------------------------------------------------------------------
+@contextlib.contextmanager
+def traced_programs():
+    """While this is open, every `jax.jit(fn).lower(*args)` of a serving
+    program (`*_fn`) also leaves `jax.make_jaxpr(fn)(*args)` in the
+    dict it yields, under the function's name."""
+    jaxprs = {}
+    real_jit = jax.jit
+
+    class Recorded:
+        def __init__(self, fn, jitted):
+            self.fn, self.jitted = fn, jitted
+
+        def lower(self, *args):
+            jaxprs[self.fn.__name__] = jax.make_jaxpr(self.fn)(*args).jaxpr
+            return self.jitted.lower(*args)
+
+    def jit(fn, *a, **k):
+        jitted = real_jit(fn, *a, **k)
+        if getattr(fn, "__name__", "").endswith("_fn"):
+            return Recorded(fn, jitted)
+        return jitted
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(jax, "jit", jit)
+        yield jaxprs
+
+
+def scans(jaxpr):
+    """Every `scan` equation of a jaxpr, inner jaxprs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) \
+                    else (value,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from scans(inner)
+
+
+def pools_in_scans(jaxpr, pool_shapes):
+    """(carried, elsewhere): how many scan carries are a whole pool,
+    and [(what, shape)] for every scan operand or result of a pool's
+    shape (whole, or one layer's) that is NOT carry: a consumed `xs`,
+    a stacked `ys`, a loop constant."""
+    shapes = set(pool_shapes) | {s[1:] for s in pool_shapes}
+    carried, elsewhere = 0, []
+    for eqn in scans(jaxpr):
+        n_consts = eqn.params["num_consts"]
+        n_carry = eqn.params["num_carry"]
+        ins = [v.aval.shape for v in eqn.invars]
+        outs = [v.aval.shape for v in eqn.outvars]
+        carried += sum(s in pool_shapes
+                       for s in ins[n_consts:n_consts + n_carry])
+        for what, found in (("const", ins[:n_consts]),
+                            ("xs", ins[n_consts + n_carry:]),
+                            ("ys", outs[n_carry:])):
+            elsewhere += [(what, s) for s in found if s in shapes]
+    return carried, elsewhere

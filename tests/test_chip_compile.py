@@ -1,0 +1,97 @@
+"""The serving programs' layer scan compiled for a v5e that is
+described, not attached (`jax.experimental.topologies`), at the shapes
+of the benchmark's serving cell (GPT-2 1.5B, 1,025 pages of 16, 16
+slots): what decides whether the page pools are copied is the compiled
+program, and this is the chip's compiler at no chip time (ISSUE 25).
+Nothing runs; a compile that passes is not a chip run.
+
+Every test of the suite that describes a topology lives in THIS file,
+and the description happens inside a fixture: one process at a time
+may hold the TPU's library, and each test worker imports every file.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deepspeed_tpu.inference import engine as engine_mod
+from deepspeed_tpu.inference.kv_cache import PagedKVCache
+from deepspeed_tpu.models.gpt2 import GPT2ForCausalLM, gpt2_config
+
+PAGES, PAGE, SLOTS, SEQ, CHUNK = 1025, 16, 16, 1024, 128
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent
+    # cache but cannot be read back without a chip: keep it out
+    from jax.experimental.compilation_cache import compilation_cache
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cached)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def cell(one_chip):
+    """(config, shapes of the weights and of one pool), placed on the
+    described chip."""
+    cfg = gpt2_config("gpt2-1.5b", n_positions=SEQ, dropout=0.0,
+                      param_dtype=jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda k: GPT2ForCausalLM(cfg).init(
+            k, {"input_ids": np.zeros((1, SEQ), np.int32)}),
+        jax.random.PRNGKey(0))
+    cache = PagedKVCache(cfg.n_layer, cfg.n_head, cfg.head_dim, PAGES,
+                         PAGE, SLOTS, SEQ // PAGE)
+    pool = jax.ShapeDtypeStruct(cache.pool_shape(cfg.n_layer), cfg.dtype)
+    place = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip)
+    return cfg, jax.tree_util.tree_map(place, params), place(pool), place
+
+
+@pytest.mark.parametrize("rows, tokens", [(SLOTS, 1), (1, CHUNK)],
+                         ids=["decode", "prefill"])
+def test_layer_scan_holds_no_copy_of_a_pool_on_a_v5e(cell, rows, tokens):
+    cfg, params, pool, place = cell
+
+    def layers(params, hidden, k_pool, v_pool, tables, positions, valid,
+               kv_limit):
+        return engine_mod.scan_layers(
+            cfg, params, hidden, k_pool, v_pool, tables, positions, valid,
+            kv_limit, PAGE, 64)
+
+    sds = lambda shape, dtype: place(jax.ShapeDtypeStruct(shape, dtype))
+    compiled = jax.jit(layers, donate_argnums=(2, 3)).lower(
+        params, sds((rows, tokens, cfg.n_embd), cfg.dtype), pool, pool,
+        sds((rows, SEQ // PAGE), jnp.int32),
+        sds((rows, tokens), jnp.int32), sds((rows, tokens), bool),
+        sds((rows,), jnp.int32)).compile()
+    pool_bytes = int(np.prod(pool.shape)) * 2
+    memory = compiled.memory_analysis()
+    # the parent held two whole pools here (6.7 GB in decode); what is
+    # left is the gathered window and its head-split copy (one layer's
+    # worth: 16 slots x 64 pages are 1,024 of the 1,025 pages)
+    assert memory.temp_size_in_bytes < pool_bytes // 4
+    assert memory.alias_size_in_bytes >= 2 * pool_bytes
+    whole = ",".join(map(str, pool.shape))
+    layer = ",".join(map(str, pool.shape[1:]))
+    moved = re.findall(
+        rf"= \w+\[(?:{whole}|1,{layer}|{layer})\]\S* "
+        r"(copy|dynamic-slice|dynamic-update-slice|transpose)\(",
+        compiled.as_text())
+    assert moved == []

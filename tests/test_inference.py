@@ -1033,3 +1033,108 @@ def test_kv_page_utilization_ledger_vs_cache_twins(obs_setup):
     assert util == pytest.approx(cache.utilization())
     cache.free(0)
     engine.reset()
+
+
+# ----------------------------------------------------------------------
+# the page pools ride in the layer scan's carry (ISSUE 25)
+# ----------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def carried():
+    """An engine with speculation on (so: all five serving programs),
+    built while its programs' jaxprs are recorded. 3 layers, 2 of them
+    the draft's; the pool is large beside everything else a step
+    holds, so that one layer's pool among the temporaries shows."""
+    from deepspeed_tpu.monitor import programs
+    from tests.paged_oracle import traced_programs
+    cfg = tiny_gpt2_config(n_layer=3)
+    params = _params(GPT2ForCausalLM(cfg))
+    with traced_programs() as jaxprs:
+        engine = InferenceEngine(cfg, params, {"inference": {
+            "max_slots": 4, "prefill_chunk": 16, "sync_every": 4,
+            "max_new_tokens": 32,
+            "kv_cache": {"num_pages": 600, "page_size": 4},
+            "speculative": {"enabled": True, "draft_model": "truncate:2",
+                            "k": 3}}})
+    temp = {name: programs.memory("jit_" + name)["temp"]
+            for name in ("decode_fn", "prefill_fn")}
+    return cfg, params, engine, jaxprs, temp
+
+
+@pytest.mark.parametrize("program", [
+    "decode_fn", "prefill_fn", "draft_fn", "verify_fn",
+    "draft_prefill_fn"])
+def test_pools_are_scan_carry_and_never_a_temporary(carried, program):
+    """(a) In the program's jaxpr both pools are carry of the layer
+    scan and nothing of a pool's shape (whole, or one layer's) is an
+    `xs`, a `ys` or a loop constant: a pool that goes in as `xs` and
+    comes back as `ys` is sliced, re-laid and stacked back per layer.
+    (b) The compiled decode and prefill programs hold less than ONE
+    layer's pool in temporaries (the parent held two whole pools)."""
+    from tests.paged_oracle import pools_in_scans
+    cfg, _, engine, jaxprs, temp = carried
+    pool = engine._state["k_pool"]
+    draft_pool = engine._spec_state["dk_pool"]
+    assert pool.shape == (3, 600, 4, cfg.n_head * cfg.head_dim)
+    assert draft_pool.shape == (2,) + pool.shape[1:]
+    carried_pools, elsewhere = pools_in_scans(
+        jaxprs[program], {pool.shape, draft_pool.shape})
+    assert carried_pools == 2, carried_pools
+    assert elsewhere == []
+    if program in temp:
+        assert temp[program] < pool.nbytes // cfg.n_layer, temp
+
+
+@pytest.mark.parametrize("program", ["prefill_fn", "decode_fn"])
+def test_programs_bitexact_vs_per_layer_pool_oracle(carried, program):
+    """Prompts of several chunks into two of four slots, then several
+    decode steps, against the oracle that loops over the layers in
+    Python with one five-dimensional pool a layer: logits and BOTH
+    pools equal bit for bit after every launch, scratch page 0 (the
+    inactive slots' and the pad rows' writes) included."""
+    from tests.paged_oracle import (assert_pools_equal, oracle_forward,
+                                    prefill_inputs)
+    cfg, params, engine, _, _ = carried
+    pools = ("k_pool", "v_pool")
+    engine.reset()
+    r = np.random.RandomState(7)
+    page, chunk = engine.cache.page_size, engine.config.prefill_chunk
+    qb = engine.config.weight_quant_block
+    k_ref = np.asarray(engine._state["k_pool"])
+    v_ref = np.asarray(engine._state["v_pool"])
+    for slot, length in ((2, 38), (0, 21)):       # 3 chunks, 2 chunks
+        prompt = r.randint(0, cfg.vocab_size, size=length)
+        engine.cache.admit(slot, length + 8)
+        engine.cache.ensure(slot, length + 8)
+        engine.push_tables()
+        for start in range(0, length - 1, chunk):
+            toks = prompt[start:min(start + chunk, length - 1)]
+            engine.prefill_chunk(slot, toks, start)
+            if program != "prefill_fn":
+                continue
+            _, k_ref, v_ref = oracle_forward(
+                cfg, params, k_pool=k_ref, v_pool=v_ref, page_size=page,
+                quant_block=qb, **prefill_inputs(
+                    chunk, toks, start, engine.cache.tables[slot]))
+            assert_pools_equal(engine._state, pools, (k_ref, v_ref),
+                               (slot, start))
+        engine.activate_slot(slot, prompt[-1], length - 1, 8, 0.0, 0,
+                             None)
+    if program == "prefill_fn":
+        assert k_ref[:, 0].any() and k_ref[:, 1:].any()
+        engine.reset()
+        return
+    k_ref = np.asarray(engine._state["k_pool"])
+    v_ref = np.asarray(engine._state["v_pool"])
+    for step in range(5):
+        st = jax.device_get({k: engine._state[k] for k in (
+            "cur_token", "pos", "active", "tables")})
+        assert list(st["active"]) == [True, False, True, False]
+        logits = np.asarray(engine.decode_once())
+        ref, k_ref, v_ref = oracle_forward(
+            cfg, params, st["cur_token"][:, None], st["pos"][:, None],
+            st["active"][:, None], st["pos"], st["tables"], k_ref, v_ref,
+            page, qb)
+        assert np.array_equal(logits, ref[:, 0]), \
+            (step, np.abs(logits - ref[:, 0]).max())
+        assert_pools_equal(engine._state, pools, (k_ref, v_ref), step)
+    engine.reset()

@@ -525,3 +525,94 @@ def test_speculative_monitor_event_schema_and_tracker(tmp_path):
     assert sp["tokens_per_verify"] >= 1.0
     assert sp["draft_dispatch_s"] >= 0.0
     assert sp["verify_dispatch_s"] > 0.0
+
+
+# ----------------------------------------------------------------------
+# the draft and verify programs vs the per-layer-pool oracle (ISSUE 25)
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("program", ["draft_prefill_fn", "draft_fn",
+                                     "verify_fn"])
+def test_spec_programs_bitexact_vs_per_layer_pool_oracle(base, program):
+    """The three speculative programs share the layer scan that carries
+    the pools (`scan_layers`): prompts of several chunks into two of
+    four slots, one round of k draft steps and its verify, against the
+    oracle of tests/paged_oracle.py (a Python loop over layers, one
+    five-dimensional pool each). The draft's logits and both of its
+    pools, and the flagship's pools after verify, are equal bit for
+    bit, scratch page 0 included; verify keeps its logits to itself,
+    so what it emitted is held against the oracle's greedy choice."""
+    from tests.paged_oracle import (assert_pools_equal, oracle_forward,
+                                    prefill_inputs)
+    cfg, _, params, _, eng = base
+    eng.reset()
+    dcfg, dparams = eng._draft_config, eng._draft_params
+    page, chunk = eng.cache.page_size, eng.config.prefill_chunk
+    k, qb = eng.config.spec_k, eng.config.weight_quant_block
+    draft_pools, pools = ("dk_pool", "dv_pool"), ("k_pool", "v_pool")
+    r = np.random.RandomState(11)
+    dk, dv = (np.asarray(eng._spec_state[n]) for n in draft_pools)
+    for slot, length in ((1, 37), (3, 19)):
+        prompt = r.randint(0, cfg.vocab_size, size=length)
+        eng.start_request(slot, prompt, max_new=12)
+        for start in range(0, length - 1, chunk):
+            _, dk, dv = oracle_forward(
+                dcfg, dparams, k_pool=dk, v_pool=dv, page_size=page,
+                quant_block=qb, **prefill_inputs(
+                    chunk, prompt[start:min(start + chunk, length - 1)],
+                    start, eng.cache.tables[slot]))
+    if program == "draft_prefill_fn":
+        assert_pools_equal(eng._spec_state, draft_pools, (dk, dv), "")
+        assert dk[:, 0].any() and dk[:, 1:].any()
+        eng.reset()
+        return
+    st = jax.device_get({n: eng._state[n] for n in (
+        "cur_token", "pos", "active", "tables", "n_gen", "max_new")})
+    assert list(st["active"]) == [False, True, False, True]
+    dk, dv = (np.asarray(eng._spec_state[n]) for n in draft_pools)
+    sp = eng._spec_state
+    for j in range(k):
+        k_slot, dtoks = jax.device_get((sp["k_slot"], sp["dtoks"]))
+        cur = st["cur_token"] if j == 0 else dtoks[:, j - 1]
+        k_eff = np.minimum(k_slot, np.maximum(
+            st["max_new"] - st["n_gen"] - 1, 0))
+        sp = eng._draft_decode(dparams, eng._state, sp)
+        if program != "draft_fn":
+            continue
+        pos = st["pos"] + j
+        ref, dk, dv = oracle_forward(
+            dcfg, dparams, cur[:, None], pos[:, None],
+            (st["active"] & (j < k_eff))[:, None], pos, st["tables"],
+            dk, dv, page, qb)
+        got = np.asarray(sp["dlogits"][:, j])
+        assert np.array_equal(got, ref[:, 0].astype(np.float32)), j
+        assert_pools_equal(sp, draft_pools, (dk, dv), j)
+    if program == "verify_fn":
+        k_slot, dtoks, n_draft = jax.device_get(
+            (sp["k_slot"], sp["dtoks"], sp["n_draft"]))
+        k_ref, v_ref = (np.asarray(eng._state[n]) for n in pools)
+        n_valid = np.minimum(np.minimum(k_slot, n_draft), np.maximum(
+            st["max_new"] - st["n_gen"] - 1, 0))
+        steps = np.arange(k + 1)
+        new_state, sp = eng._verify(params, eng._state, sp)
+        eng._state = new_state
+        ref, k_ref, v_ref = oracle_forward(
+            cfg, params,
+            np.concatenate([st["cur_token"][:, None], dtoks], axis=1),
+            st["pos"][:, None] + steps[None],
+            st["active"][:, None] & (steps[None] <= n_valid[:, None]),
+            st["pos"] + n_valid, st["tables"], k_ref, v_ref, page, qb)
+        assert_pools_equal(new_state, pools, (k_ref, v_ref), "verify")
+        greedy = ref.argmax(-1)
+        out, n_gen = jax.device_get((new_state["out_tokens"],
+                                     new_state["n_gen"]))
+        for slot in (1, 3):
+            accepted = 0
+            while accepted < n_valid[slot] and \
+                    dtoks[slot, accepted] == greedy[slot, accepted]:
+                accepted += 1
+            emitted = list(dtoks[slot, :accepted]) + \
+                [greedy[slot, accepted]]
+            assert n_gen[slot] == len(emitted)
+            assert list(out[slot, :len(emitted)]) == emitted
+    eng._spec_state = sp
+    eng.reset()
