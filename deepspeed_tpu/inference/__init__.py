@@ -5,6 +5,10 @@
   * PagedKVCache (kv_cache.py): fixed-size pages in one preallocated
     device pool, per-request page tables, host-side alloc/free at
     serving fences, `kv_cache` memory-ledger category.
+  * RecurrentStateCache (kv_cache.py): the second kind of slot state,
+    a fixed block of recurrent state per slot for a model whose
+    layers are retention (models/brumby.py); same interface towards
+    the scheduler, `recurrent_state` ledger category.
   * ServingLoop / Request / serve_sequential (scheduler.py):
     iteration-level continuous batching with chunked prefill
     interleaving and EOS/max-tokens eviction.
@@ -21,11 +25,13 @@
 from deepspeed_tpu.inference.config import (InferenceConfig,
                                             InferenceConfigError)
 from deepspeed_tpu.inference.engine import InferenceEngine
-from deepspeed_tpu.inference.kv_cache import PagedKVCache
+from deepspeed_tpu.inference.kv_cache import (PagedKVCache,
+                                              RecurrentStateCache)
 from deepspeed_tpu.inference.scheduler import (Request, ServingLoop,
                                                serve_sequential)
 
 __all__ = [
-    "InferenceEngine", "PagedKVCache", "ServingLoop", "Request",
+    "InferenceEngine", "PagedKVCache", "RecurrentStateCache", "ServingLoop",
+    "Request",
     "serve_sequential", "InferenceConfig", "InferenceConfigError",
 ]

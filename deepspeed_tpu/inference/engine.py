@@ -1,34 +1,55 @@
-"""InferenceEngine — AOT prefill + single-token decode over a paged
-KV cache, with device-side sampling and zero per-token host sync.
+"""InferenceEngine — AOT prefill + single-token decode over the
+model's cache, with device-side sampling and zero per-token host sync.
 
 Exactly TWO programs are compiled per model (ahead of time, at engine
 construction — no trace-on-first-request latency spike):
 
   * the **prefill** step: one prompt chunk ([1, prefill_chunk] tokens)
-    through the stack, writing each layer's K/V into the request's
-    cache pages and attending over everything cached so far (chunked,
-    so a long prompt interleaves with decode instead of stalling it);
+    through the stack, into the request's cache (chunked, so a long
+    prompt interleaves with decode instead of stalling it);
   * the **decode** step: one token for EVERY request slot at once
-    ([max_slots] lockstep), paged-attention over each slot's cached
-    prefix, logits through the tied head, and greedy /
-    temperature+top-k sampling device-side — the sampled token, the
-    EOS/max-tokens finish flags, and the output ring all stay on
-    device, so the host dispatches `sync_every` decode iterations
-    back-to-back and reads NOTHING until the serving fence (the PR-2
-    async-dispatch convention applied to serving).
+    ([max_slots] lockstep) from each slot's cache, logits through the
+    head, and greedy / temperature+top-k sampling device-side — the
+    sampled token, the EOS/max-tokens finish flags, and the output
+    ring all stay on device, so the host dispatches `sync_every`
+    decode iterations back-to-back and reads NOTHING until the serving
+    fence (the PR-2 async-dispatch convention applied to serving).
 
-The forward math deliberately mirrors the training path operation for
-operation (the same flax submodules applied to the same param leaves,
-the same einsum phrasings, the same fp32 softmax with -1e30 masking),
-so decode logits are BIT-EXACT against the training forward on the
-same prefix in fp32 — parity is pinned by tests/test_inference.py, the
-serving bench leg, and the training/serving drift that convention
-prevents is the point.
+Which block runs and what the cache is come from the model config:
+its `serving(inference_config, max_seq_len)` hands the engine the
+adapter (cache manager, cache arrays, embedding, layer stack and head;
+the two classes below), and a config without the method is of the
+GPT-2 family:
 
-Weight-only int8 serving (`inference.weight_bits: 8`) quantises the
-projection kernels once at load (inference/quant.py) and the dense
-application below switches onto the dequant-in-matmul epilogue;
-everything else (cache, scheduler, sampling) is unchanged.
+  * the GPT-2 family (`PagedServing`) keeps a paged K/V cache: prefill
+    writes each layer's K/V into the request's pages and attends over
+    everything cached so far; decode is paged attention over each
+    slot's cached prefix, logits through the tied head. The forward
+    math deliberately mirrors the training path operation for
+    operation (the same flax submodules applied to the same param
+    leaves, the same einsum phrasings, the same fp32 softmax with
+    -1e30 masking), so decode logits are BIT-EXACT against the
+    training forward on the same prefix in fp32 — parity is pinned by
+    tests/test_inference.py, the serving bench leg, and the
+    training/serving drift that convention prevents is the point.
+    Weight-only int8 serving (`inference.weight_bits: 8`) quantises
+    the projection kernels once at load (inference/quant.py) and the
+    dense application below switches onto the dequant-in-matmul
+    epilogue; speculative decoding (inference/speculative.py) adds
+    three programs over the same pools.
+  * a model of recurrent state (`RecurrentServing` round the model's
+    module; `models/brumby.py`) keeps a fixed float32 matrix and
+    normaliser per slot, layer and key/value head
+    (`kv_cache.RecurrentStateCache`). Prefill advances
+    one slot's state by a chunk (`ops/retention::retention_chunked`,
+    from zero if the chunk is the request's first); decode advances
+    every live slot's by one token and reads it in the same region
+    (`retention_step`). The block is the model's own, called with the
+    retention call as its mixer. No speculative decoding (state cannot
+    be rewound yet) and no int8 weights.
+
+Everything else (slot state, scheduler, sampling, bookkeeping, the
+fence) is shared.
 """
 
 import time
@@ -39,38 +60,24 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.inference.config import InferenceConfig
-from deepspeed_tpu.inference.kv_cache import PagedKVCache
+from deepspeed_tpu.inference.kv_cache import (PagedKVCache,
+                                              RecurrentStateCache)
 from deepspeed_tpu.inference.quant import (KERNEL_SCALE, int8_matmul,
                                            quantize_param_tree)
 from deepspeed_tpu.monitor import DeepSpeedMonitorConfig, Monitor
 from deepspeed_tpu.monitor import memory as memory_mod
 from deepspeed_tpu.monitor import programs
 from deepspeed_tpu.monitor.trace import profiler_span
+from deepspeed_tpu.ops.retention import retention_chunked, retention_step
 from deepspeed_tpu.utils.logging import logger
 
-# The regions of the serving programs (`jax.named_scope`: metadata on
-# the HLO, no instruction changes). A device profile's operations are
-# joined to these through `monitor/programs.py::op_scopes`. Time in
-# SCOPE_LAYERS outside every inner region is the layer scan itself:
-# the loop and the slicing of each layer's weights out of the stacked
-# tree. The K/V page pools ride in the scan's carry whole (`scan_layers`)
-# and are touched only in SCOPE_KV_WRITE and SCOPE_KV_GATHER; a pool-
-# sized copy showing up under SCOPE_LAYERS alone is a regression.
-SCOPE_EMBED = "embed"
-SCOPE_LAYERS = "layers"            # round the lax.scan call, nothing else
-SCOPE_ATTN_QKV = "attn_qkv"        # inside a layer: ln_1 + c_attn
-SCOPE_KV_WRITE = "kv_write"        # the chunk's K/V into the page pool
-SCOPE_KV_GATHER = "kv_gather"      # the page window through the tables
-SCOPE_ATTN = "attn"                # paged_attention
-SCOPE_ATTN_OUT = "attn_out"        # c_proj + residual
-SCOPE_MLP = "mlp"                  # ln_2, c_fc, gelu, mlp_c_proj
-SCOPE_HEAD = "head"                # ln_f + tied head
-SCOPE_SAMPLE = "sample"
-SCOPE_BOOKKEEPING = "bookkeeping"  # the slot state update
-SCOPES_IN_LAYER = (SCOPE_ATTN_QKV, SCOPE_KV_WRITE, SCOPE_KV_GATHER,
-                   SCOPE_ATTN, SCOPE_ATTN_OUT, SCOPE_MLP)
-SCOPES = (SCOPE_EMBED, SCOPE_LAYERS) + SCOPES_IN_LAYER + \
-    (SCOPE_HEAD, SCOPE_SAMPLE, SCOPE_BOOKKEEPING)
+# the regions of the serving programs: see utils/scopes.py
+from deepspeed_tpu.utils.scopes import (  # noqa: F401
+    SCOPE_ATTN, SCOPE_ATTN_OUT, SCOPE_ATTN_QKV, SCOPE_BOOKKEEPING,
+    SCOPE_EMBED, SCOPE_HEAD, SCOPE_KV_GATHER, SCOPE_KV_WRITE, SCOPE_LAYERS,
+    SCOPE_MLP, SCOPE_RETENTION_CHUNK, SCOPE_SAMPLE, SCOPE_STATE_RESET,
+    SCOPE_STATE_UPDATE, SCOPES, SCOPES_IN_LAYER, SCOPES_IN_LAYER_RECURRENT,
+    SCOPES_RECURRENT, SCOPES_STATE)
 
 
 def compile_fresh(lowered):
@@ -233,31 +240,203 @@ def _block_paged(cfg, lp, hidden, k_pool, v_pool, li, tables, positions,
     return hidden, k_pool, v_pool
 
 
-def scan_layers(cfg, params, hidden, k_pool, v_pool, tables, positions,
-                valid, kv_limit, page_size, quant_block):
-    """The layer stack of all five serving programs (decode, prefill,
-    draft decode, verify, draft prefill): `lax.scan` over the stacked
-    block weights and the layer index, with the hidden state AND both
-    whole page pools as the carry. Nothing pool-shaped is an `xs` or a
-    `ys`: a pool that enters a scan as `xs` and leaves as `ys` is
-    sliced, re-laid and stacked back layer by layer (70% of a decode
-    step at 1.5B before PR 25)."""
-    from deepspeed_tpu.models.gpt2 import stacked_block_params
+def scan_layers(stacked, hidden, cache, layer):
+    """The layer stack of every serving program (decode, prefill, and
+    for a paged model draft decode, verify, draft prefill): `lax.scan`
+    over the stacked block weights and the layer index, with the
+    hidden state AND the model's whole cache as the carry. `cache` is
+    whatever pytree the model keeps between tokens (both K/V page
+    pools; a retention model's state arrays) and `layer(lp, li,
+    hidden, cache) -> (hidden, cache)` the model's block on layer `li`
+    of it. Nothing cache-shaped is an `xs` or a `ys`: a pool that
+    enters a scan as `xs` and leaves as `ys` is sliced, re-laid and
+    stacked back layer by layer (70% of a decode step at 1.5B before
+    PR 25)."""
+    n_layer = jax.tree_util.tree_leaves(stacked)[0].shape[0]
 
-    def layer(carry, xs):
+    def body(carry, xs):
         lp, li = xs
-        return _block_paged(cfg, lp, *carry, li, tables, positions, valid,
-                            kv_limit, page_size, quant_block), None
+        return layer(lp, li, *carry), None
 
     with jax.named_scope(SCOPE_LAYERS):
-        carry, _ = jax.lax.scan(
-            layer, (hidden, k_pool, v_pool),
-            (stacked_block_params(params), jnp.arange(cfg.n_layer)))
+        carry, _ = jax.lax.scan(body, (hidden, cache),
+                                (stacked, jnp.arange(n_layer)))
     return carry
 
 
+def paged_layers(cfg, params, hidden, k_pool, v_pool, tables, positions,
+                 valid, kv_limit, page_size, quant_block):
+    """`scan_layers` for the GPT-2 family: `_block_paged` on both whole
+    page pools. Returns (hidden, k_pool, v_pool)."""
+    from deepspeed_tpu.models.gpt2 import stacked_block_params
+
+    def layer(lp, li, hidden, pools):
+        hidden, k_pool, v_pool = _block_paged(
+            cfg, lp, hidden, *pools, li, tables, positions, valid,
+            kv_limit, page_size, quant_block)
+        return hidden, (k_pool, v_pool)
+
+    hidden, (k_pool, v_pool) = scan_layers(
+        stacked_block_params(params), hidden, (k_pool, v_pool), layer)
+    return hidden, k_pool, v_pool
+
+
+# ----------------------------------------------------------------------
+# what the two programs ask of a model: its cache manager, its cache
+# arrays in the engine's state, and embedding, layer stack and head
+# around them. A model config's `serving()` returns the one that serves
+# it (no such method: the GPT-2 family).
+# ----------------------------------------------------------------------
+class PagedServing:
+    """The GPT-2 family over the paged K/V cache: learned positions,
+    `_block_paged`, ln_f and the head tied to the embedding."""
+    cache_keys = ("k_pool", "v_pool")
+
+    def __init__(self, model_config, config, max_seq_len):
+        self.mc, self.cfg = model_config, config
+        self.max_pages = -(-max_seq_len // config.kv_page_size)
+
+    def make_cache(self, ledger):
+        mc, cfg = self.mc, self.cfg
+        return PagedKVCache(
+            n_layer=mc.n_layer, n_head=mc.n_head, head_dim=mc.head_dim,
+            num_pages=cfg.kv_num_pages, page_size=cfg.kv_page_size,
+            max_slots=cfg.max_slots, max_pages_per_slot=self.max_pages,
+            dtype=np.dtype(mc.dtype), ledger=ledger)
+
+    def fresh_cache(self, cache):
+        pool = cache.pool_shape(self.mc.n_layer)
+        return {"k_pool": jnp.zeros(pool, self.mc.dtype),
+                "v_pool": jnp.zeros(pool, self.mc.dtype),
+                "tables": jnp.asarray(cache.tables)}
+
+    def embed(self, params, tokens, positions):
+        # embed_tokens' math at absolute positions
+        return params["wte"][tokens].astype(self.mc.dtype) + \
+            params["wpe"][positions].astype(self.mc.dtype)
+
+    def decode_layers(self, params, hidden, state):
+        pos = state["pos"]
+        hidden, k_pool, v_pool = paged_layers(
+            self.mc, params, hidden, state["k_pool"], state["v_pool"],
+            state["tables"], pos[:, None], state["active"][:, None], pos,
+            self.cfg.kv_page_size, self.cfg.weight_quant_block)
+        return hidden, {"k_pool": k_pool, "v_pool": v_pool}
+
+    def prefill_layers(self, params, hidden, pools, page_row, posv, valid,
+                       start, n_valid):
+        kv_limit = (start + n_valid - 1)[None]
+        _, k_pool, v_pool = paged_layers(
+            self.mc, params, hidden, *pools, page_row[None], posv[None],
+            valid[None], kv_limit, self.cfg.kv_page_size,
+            self.cfg.weight_quant_block)
+        return k_pool, v_pool
+
+    def head(self, params, hidden):
+        hidden = _ln_apply(self.mc, params["ln_f"], hidden)
+        return jnp.einsum("btc,vc->btv", hidden.astype(self.mc.dtype),
+                          params["wte"].astype(self.mc.dtype))
+
+
+class RecurrentServing:
+    """A model over recurrent state. `model` is its module: `embed(mc,
+    params, tokens)`, `head(mc, params, hidden)` and ONE `block(mc, lp,
+    hidden, positions, mixer, state)` whose `mixer` is the retention
+    call (`models/brumby.py`), run here on layer `li` of the whole
+    state arrays (`RecurrentStateCache.state_shapes`). Decode advances
+    every slot's state by one token and reads it in the same region;
+    inactive slots keep theirs. Prefill advances one slot's state by a
+    chunk, from zero if the chunk is the request's first."""
+    cache_keys = ("state_s", "state_z")
+
+    def __init__(self, model, model_config, config, max_seq_len):
+        self.model = model
+        self.mc, self.cfg, self.max_seq_len = (model_config, config,
+                                               max_seq_len)
+        if config.weight_bits == 8:
+            raise ValueError(
+                "inference.weight_bits: 8 quantises the GPT-2 family's "
+                "projections; this model has no int8 path")
+        if config.spec_enabled:
+            raise ValueError(
+                "inference.speculative.enabled: this model's slots hold "
+                "recurrent state, and a rejected draft is undone by "
+                "rewinding the cache: snapshots of state do not exist "
+                "yet")
+
+    def make_cache(self, ledger):
+        mc = self.mc
+        return RecurrentStateCache(
+            n_layer=mc.n_layer, n_kv_head=mc.num_key_value_heads,
+            state_dim=mc.state_dim, head_dim=mc.head_dim,
+            max_slots=self.cfg.max_slots,
+            max_tokens_per_slot=self.max_seq_len,
+            dtype=np.dtype(mc.state_dtype), ledger=ledger)
+
+    def fresh_cache(self, cache):
+        s_shape, z_shape = cache.state_shapes()
+        return {"state_s": jnp.zeros(s_shape, self.mc.state_dtype),
+                "state_z": jnp.zeros(z_shape, self.mc.state_dtype)}
+
+    def embed(self, params, tokens, positions):
+        return self.model.embed(self.mc, params, tokens)  # positions: rotary
+
+    def decode_layers(self, params, hidden, state):
+        mc, block = self.mc, self.model.block
+        pos, idle = state["pos"], ~state["active"]
+
+        def layer(lp, li, hidden, cache):
+            def mixer(q, k, v, lg, cache):
+                S, z = cache
+                with jax.named_scope(SCOPE_STATE_UPDATE):
+                    o, S_l, z_l = retention_step(
+                        q[:, 0], k[:, 0], v[:, 0], lg[:, 0], S[li], z[li],
+                        mc.retention_scale, mc.retention_eps, keep=idle,
+                        fresh=pos == 0)
+                    return o[:, None], (S.at[li].set(S_l),
+                                        z.at[li].set(z_l))
+            return block(mc, lp, hidden, pos[:, None], mixer, cache)
+
+        hidden, (S, z) = scan_layers(
+            params["layers"], hidden, (state["state_s"], state["state_z"]),
+            layer)
+        return hidden, {"state_s": S, "state_z": z}
+
+    def prefill_layers(self, params, hidden, cache, slot, posv, valid,
+                       start, n_valid):
+        mc, block = self.mc, self.model.block
+
+        def layer(lp, li, hidden, cache):
+            def mixer(q, k, v, lg, cache):
+                S, z = cache
+                with jax.named_scope(SCOPE_STATE_RESET):
+                    zero = jnp.zeros((), S.dtype)
+                    S0 = jnp.where(start == 0, zero, jax.lax.dynamic_slice(
+                        S, (li, slot, 0, 0, 0), (1, 1) + S.shape[2:])[0])
+                    z0 = jnp.where(start == 0, zero, jax.lax.dynamic_slice(
+                        z, (li, slot, 0, 0), (1, 1) + z.shape[2:])[0])
+                with jax.named_scope(SCOPE_RETENTION_CHUNK):
+                    o, S1, z1 = retention_chunked(
+                        q, k, v, lg, S0, z0, mc.retention_scale,
+                        mc.retention_eps, mc.retention_chunk, valid[None])
+                    S = jax.lax.dynamic_update_slice(
+                        S, S1[None], (li, slot, 0, 0, 0))
+                    z = jax.lax.dynamic_update_slice(
+                        z, z1[None], (li, slot, 0, 0))
+                return o, (S, z)
+            return block(mc, lp, hidden, posv[None], mixer, cache)
+
+        _, cache = scan_layers(params["layers"], hidden, cache, layer)
+        return cache
+
+    def head(self, params, hidden):
+        return self.model.head(self.mc, params, hidden)
+
+
 class InferenceEngine:
-    """Serving engine for a GPT-2 family model.
+    """Serving engine for one model: a GPT-2 family model over the
+    paged K/V cache, or one over recurrent state (the model config's
+    `serving()` hands over the adapter; see the module's docstring).
 
     Construction compiles the two programs AOT against the configured
     shapes; `start_request`/`prefill_chunk`/`activate_slot` manage
@@ -279,7 +458,11 @@ class InferenceEngine:
         if cfg.max_seq_len is not None:
             max_seq = min(max_seq, cfg.max_seq_len)
         self.max_seq_len = max_seq
-        max_pages = -(-max_seq // cfg.kv_page_size)
+        # the model's block and the kind of cache it keeps come from
+        # the model config
+        serving = getattr(model_config, "serving", None)
+        self.family = serving(cfg, max_seq) if serving is not None \
+            else PagedServing(model_config, cfg, max_seq)
 
         if cfg.weight_bits == 8:
             params = quantize_param_tree(params, cfg.weight_quant_block)
@@ -288,13 +471,7 @@ class InferenceEngine:
                 f"(block {cfg.weight_quant_block} along the "
                 "contraction dim)")
         self._params = params
-        self.cache = PagedKVCache(
-            n_layer=model_config.n_layer, n_head=model_config.n_head,
-            head_dim=model_config.head_dim, num_pages=cfg.kv_num_pages,
-            page_size=cfg.kv_page_size, max_slots=cfg.max_slots,
-            max_pages_per_slot=max_pages,
-            dtype=np.dtype(model_config.dtype),
-            ledger=self.monitor.ledger)
+        self.cache = self.family.make_cache(self.monitor.ledger)
         self.monitor.ledger.register_tree(
             memory_mod.CAT_PARAMS, "inference.params", params)
 
@@ -307,7 +484,6 @@ class InferenceEngine:
             self.tracker = ServingTracker(self.monitor, self.cache, cfg)
             self.monitor.attach_serving(self.tracker)
 
-        self._tables_version = self.cache.table_version
         self._state = self._fresh_state()
         self._decode = self._build_decode_step()
         self._prefill = self._build_prefill_step()
@@ -367,13 +543,11 @@ class InferenceEngine:
     # state
     # ------------------------------------------------------------------
     def _fresh_state(self):
-        cfg, mc = self.config, self.model_config
+        cfg = self.config
         s, w = cfg.max_slots, cfg.max_new_tokens
-        pool = self.cache.pool_shape(mc.n_layer)
+        self._tables_version = self.cache.table_version
         return {
-            "k_pool": jnp.zeros(pool, mc.dtype),
-            "v_pool": jnp.zeros(pool, mc.dtype),
-            "tables": jnp.asarray(self.cache.tables),
+            **self.family.fresh_cache(self.cache),
             "pos": jnp.zeros((s,), jnp.int32),
             "cur_token": jnp.zeros((s,), jnp.int32),
             "active": jnp.zeros((s,), bool),
@@ -392,8 +566,10 @@ class InferenceEngine:
         """Drop all slots and cached pages (bench A/B hygiene)."""
         for slot in self.cache.slots():
             self.cache.free(slot)
+        # the old cache goes before the new one is made: a retention
+        # model's state is a third of the chip
+        self._state = None
         self._state = self._fresh_state()
-        self._tables_version = self.cache.table_version
         if self.speculative_enabled:
             from deepspeed_tpu.inference import speculative as spec_mod
             self._spec_state = spec_mod.fresh_spec_state(self)
@@ -407,9 +583,7 @@ class InferenceEngine:
     # the two AOT programs
     # ------------------------------------------------------------------
     def _build_decode_step(self):
-        cfg, mc = self.config, self.model_config
-        qb = cfg.weight_quant_block
-        page = self.cache.page_size
+        cfg, mc, family = self.config, self.model_config, self.family
         s = cfg.max_slots
         out_w = cfg.max_new_tokens
         top_k_cap = min(cfg.top_k_max, mc.vocab_size)
@@ -435,23 +609,13 @@ class InferenceEngine:
         def decode_fn(params, state):
             active = state["active"]
             pos = state["pos"]
-            wte, wpe = params["wte"], params["wpe"]
-            # embed_tokens' math for a [S, 1] "sequence" at absolute
-            # positions `pos`
+            # a [S, 1] "sequence" at absolute positions `pos`
             with jax.named_scope(SCOPE_EMBED):
-                hidden = wte[state["cur_token"]].astype(mc.dtype) + \
-                    wpe[pos].astype(mc.dtype)
+                hidden = family.embed(params, state["cur_token"], pos)
                 hidden = hidden[:, None, :]
-            positions = pos[:, None]
-            hidden, k_pool, v_pool = scan_layers(
-                mc, params, hidden, state["k_pool"], state["v_pool"],
-                state["tables"], positions, active[:, None], pos, page,
-                qb)
+            hidden, cache = family.decode_layers(params, hidden, state)
             with jax.named_scope(SCOPE_HEAD):
-                hidden = _ln_apply(mc, params["ln_f"], hidden)
-                logits = jnp.einsum(
-                    "btc,vc->btv", hidden.astype(mc.dtype),
-                    wte.astype(mc.dtype))[:, 0]
+                logits = family.head(params, hidden)[:, 0]
             next_tok = sample(logits, state)
 
             with jax.named_scope(SCOPE_BOOKKEEPING):
@@ -465,8 +629,7 @@ class InferenceEngine:
                 hit_eos = active & (next_tok == state["eos"])
                 hit_max = active & (n2 >= state["max_new"])
                 new_state = dict(
-                    state,
-                    k_pool=k_pool, v_pool=v_pool,
+                    state, **cache,
                     pos=pos + active.astype(jnp.int32),
                     cur_token=jnp.where(active, next_tok,
                                         state["cur_token"]),
@@ -482,33 +645,32 @@ class InferenceEngine:
                                   donate_argnums=(1,))
 
     def _build_prefill_step(self):
-        cfg, mc = self.config, self.model_config
-        qb = cfg.weight_quant_block
-        page = self.cache.page_size
+        cfg, family = self.config, self.family
         chunk = cfg.prefill_chunk
 
-        def prefill_fn(params, k_pool, v_pool, page_row, tokens, start,
-                       n_valid):
-            wte, wpe = params["wte"], params["wpe"]
+        def prefill_fn(params, cache, where, tokens, start, n_valid):
+            """`cache`: the model's cache arrays (`cache_arrays`);
+            `where` finds the slot's part of them: its page-table row,
+            or for recurrent state its index."""
             posv = start + jnp.arange(chunk, dtype=jnp.int32)
             valid = jnp.arange(chunk) < n_valid
             with jax.named_scope(SCOPE_EMBED):
-                hidden = wte[tokens].astype(mc.dtype) + \
-                    wpe[posv].astype(mc.dtype)
-                hidden = hidden[None]
-            positions = posv[None]
-            kv_limit = (start + n_valid - 1)[None]
-            _, k_pool, v_pool = scan_layers(
-                mc, params, hidden, k_pool, v_pool, page_row[None],
-                positions, valid[None], kv_limit, page, qb)
-            return k_pool, v_pool
+                hidden = family.embed(params, tokens, posv)[None]
+            return family.prefill_layers(params, hidden, cache, where, posv,
+                                         valid, start, n_valid)
 
-        st = self._state
-        args = (self._params, st["k_pool"], st["v_pool"],
-                jnp.asarray(self.cache.tables[0]),
+        args = (self._params, self.cache_arrays(),
+                jnp.asarray(self.cache.slot_operand(0)),
                 jnp.zeros((chunk,), jnp.int32),
                 jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
-        return compile_registered(prefill_fn, args, donate_argnums=(1, 2))
+        return compile_registered(prefill_fn, args, donate_argnums=(1,))
+
+    def cache_arrays(self):
+        """The model's cache arrays as the programs hold them, in the
+        order of the adapter's `cache_keys` (the device arrays, not
+        copies: both K/V page pools `PagedKVCache.pool_shape`, or the
+        state and its normaliser `RecurrentStateCache.state_shapes`)."""
+        return tuple(self._state[k] for k in self.family.cache_keys)
 
     # ------------------------------------------------------------------
     # fence-side slot management (host work, runs between blocks)
@@ -528,11 +690,10 @@ class InferenceEngine:
         buf = np.zeros((self.config.prefill_chunk,), np.int32)
         buf[:n] = tokens
         st = self._state
-        k, v = self._prefill(
-            self._params, st["k_pool"], st["v_pool"],
-            jnp.asarray(self.cache.tables[slot]), jnp.asarray(buf),
-            jnp.asarray(start, jnp.int32), jnp.asarray(n, jnp.int32))
-        st["k_pool"], st["v_pool"] = k, v
+        st.update(zip(self.family.cache_keys, self._prefill(
+            self._params, self.cache_arrays(),
+            jnp.asarray(self.cache.slot_operand(slot)), jnp.asarray(buf),
+            jnp.asarray(start, jnp.int32), jnp.asarray(n, jnp.int32))))
         if self.speculative_enabled:
             # the draft attends over the whole committed prefix, so
             # its pool must cache the prompt too (same chunk, same

@@ -29,6 +29,14 @@ plus one dynamic entry per live request — so the category total always
 equals the true preallocated pool bytes while `top_buffers` and the
 category meta give per-request byte attribution, and `oom_hints` can
 name `inference.kv_cache.num_pages` when the cache dominates.
+
+A second kind of slot state lives beside the pages
+(`RecurrentStateCache`): a model whose layers keep a fixed-size
+recurrent state per request (power retention, `models/brumby.py`) owns
+one block of it per slot, sized once and never grown. Both managers
+answer the scheduler's one interface: `can_admit`, `admit`, `ensure`,
+`free`, `reserved_tokens`, `never_fits`, `reservation`, `occupancy`,
+`slot_operand` (and `rollback`, which recurrent state refuses).
 """
 
 import numpy as np
@@ -173,6 +181,61 @@ class PagedKVCache:
         this is the cache-side twin for tests and hints."""
         return self.pages_in_use() / max(self.num_pages - 1, 1)
 
+    # -- what the scheduler, the engine and the monitor ask of any cache
+    kind = "paged"
+
+    def never_fits(self, n_tokens_worst_case):
+        """Why a request of that worst case can NEVER be admitted (a
+        message), or None: `ServingLoop.submit` rejects it at once
+        instead of waiting for an eviction that cannot help."""
+        usable = min(self.max_pages_per_slot, self.num_pages - 1)
+        need = self.pages_for_tokens(n_tokens_worst_case)
+        if need > usable:
+            return (f"worst case {need} pages exceeds the pool's {usable} "
+                    "usable pages (raise inference.kv_cache.num_pages)")
+        return None
+
+    def reservation(self, n_tokens_worst_case):
+        """What admission sets aside, as the `request_admitted` event
+        reports it."""
+        return {"kv_pages_reserved":
+                int(self.pages_for_tokens(n_tokens_worst_case))}
+
+    def occupancy(self):
+        """What every fence reports of the cache."""
+        return {"kv_pages_in_use": int(self.pages_in_use()),
+                "kv_pages_free": int(self.free_pages())}
+
+    def ledger_occupancy(self):
+        """`occupancy` with the utilization, as the serving tracker
+        reports it: derived from the memory ledger's `kv_cache`
+        category, not from the page tables (the per-request dynamic
+        entries are the in-use bytes, `pool.unallocated` the rest: pure
+        host reads of registered shape math). Two independent
+        accounting chains; tests/test_inference.py holds them equal."""
+        if self._ledger is None:
+            in_use = self.pages_in_use()
+        else:
+            rows = self._ledger.category_breakdown(memory_mod.CAT_KV)
+            in_use = int(sum(b for name, b in rows.items()
+                             if name != "pool.unallocated") //
+                         max(self.page_bytes, 1))
+        allocatable = max(self.num_pages - 1, 1)
+        return {"kv_pages_in_use": in_use,
+                "kv_pages_free": max(allocatable - in_use, 0),
+                "kv_page_utilization": round(in_use / allocatable, 4)}
+
+    def utilization_counter(self, occupancy):
+        """(name, values) of the trace export's counter track."""
+        return "kv_page_utilization", {
+            "in_use": occupancy["kv_pages_in_use"],
+            "free": occupancy["kv_pages_free"]}
+
+    def slot_operand(self, slot):
+        """What the prefill program is handed to find `slot`'s cache:
+        its page-table row."""
+        return self.tables[slot]
+
     # -- admission / growth / release -----------------------------------
     def can_admit(self, n_tokens_worst_case):
         """True when a request that may grow to n_tokens_worst_case
@@ -281,3 +344,150 @@ class PagedKVCache:
         if dtoken is not None and self._ledger is not None:
             self._ledger.release(dtoken)
         return len(pages)
+
+
+class RecurrentStateCache:
+    """Slot state that is a fixed block per request, not a page list:
+    for every layer and key/value head a float32 matrix and its
+    normaliser (`models/brumby.py`),
+
+        state_s : [n_layer, max_slots, n_kv_head, state_dim, head_dim]
+        state_z : [n_layer, max_slots, n_kv_head, state_dim]
+
+    sized once at construction. A request is admitted by free slot and
+    its state never grows, so `ensure` has nothing to do and there are
+    no page tables. A slot is not cleared when it is freed: the
+    compiled programs start a slot from zero state when its first
+    chunk (`start == 0`) or, for a one-token prompt, its first decode
+    step (`pos == 0`) runs. There are no snapshots of state yet, so
+    `rollback` raises (speculative decoding is refused at engine
+    construction for such a model).
+
+    Ledger: the whole block is registered under `recurrent_state`, one
+    dynamic `slots.unheld` entry plus one per live request, so the
+    category total always equals the preallocated bytes."""
+
+    kind = "recurrent"
+    table_version = 0                  # no page tables to push
+
+    def __init__(self, n_layer, n_kv_head, state_dim, head_dim, max_slots,
+                 max_tokens_per_slot, dtype=np.float32, ledger=None):
+        self.n_layer = int(n_layer)
+        self.n_kv_head = int(n_kv_head)
+        self.state_dim = int(state_dim)
+        self.head_dim = int(head_dim)
+        self.max_slots = int(max_slots)
+        self.max_tokens_per_slot = int(max_tokens_per_slot)
+        self.dtype = np.dtype(dtype)
+        # bytes of ONE slot across all layers, matrix and normaliser
+        self.slot_state_bytes = (self.n_layer * self.n_kv_head *
+                                 self.state_dim * (self.head_dim + 1) *
+                                 self.dtype.itemsize)
+        self.pool_bytes = self.max_slots * self.slot_state_bytes
+        self._reserved = {}        # slot -> admitted token capacity
+        self._ledger = ledger
+        self._ledger_tokens = {}
+        if ledger is not None:
+            ledger.register_dynamic(
+                memory_mod.CAT_STATE, "slots.unheld",
+                lambda: self.pool_bytes - self.resident_bytes(),
+                meta={"max_slots": self.max_slots,
+                      "slot_state_bytes": self.slot_state_bytes})
+
+    def state_shapes(self):
+        """Shapes of the two device arrays (matrix, normaliser)."""
+        lead = (self.n_layer, self.max_slots, self.n_kv_head,
+                self.state_dim)
+        return lead + (self.head_dim,), lead
+
+    # -- accounting -----------------------------------------------------
+    def slots(self):
+        return list(self._reserved)
+
+    def slots_in_use(self):
+        return len(self._reserved)
+
+    def free_slots(self):
+        return self.max_slots - len(self._reserved)
+
+    def resident_bytes(self):
+        """Bytes of state that live requests hold."""
+        return len(self._reserved) * self.slot_state_bytes
+
+    def reserved_tokens(self, slot):
+        return self._reserved.get(slot, 0)
+
+    def never_fits(self, n_tokens_worst_case):
+        if n_tokens_worst_case > self.max_tokens_per_slot:
+            return (f"worst case {n_tokens_worst_case} tokens exceeds "
+                    f"max_seq_len {self.max_tokens_per_slot}")
+        return None
+
+    def reservation(self, n_tokens_worst_case):
+        return {"state_bytes_reserved": int(self.slot_state_bytes)}
+
+    def occupancy(self):
+        return {"state_slots_in_use": int(self.slots_in_use()),
+                "state_slots_free": int(self.free_slots()),
+                "state_bytes_resident": int(self.resident_bytes())}
+
+    ledger_occupancy = occupancy       # the manager's own counters
+
+    def utilization_counter(self, occupancy):
+        return "state_slot_utilization", {
+            "in_use": occupancy["state_slots_in_use"],
+            "free": occupancy["state_slots_free"]}
+
+    def allocated_pages(self, slot):
+        """A slot of state holds no pages (its block is fixed:
+        `state_bytes_resident` counts it)."""
+        return 0
+
+    def slot_operand(self, slot):
+        """The prefill program finds `slot`'s state by its index."""
+        return np.int32(slot)
+
+    # -- admission / release --------------------------------------------
+    def can_admit(self, n_tokens_worst_case):
+        return self.free_slots() > 0 and \
+            self.never_fits(n_tokens_worst_case) is None
+
+    def admit(self, slot, n_tokens_worst_case, name=None):
+        if slot in self._reserved:
+            raise ValueError(f"slot {slot} is already admitted")
+        if not 0 <= slot < self.max_slots:
+            raise ValueError(f"slot {slot} is outside the state's "
+                             f"{self.max_slots} slots")
+        if not self.can_admit(n_tokens_worst_case):
+            raise RuntimeError(
+                f"recurrent state cannot admit {n_tokens_worst_case} "
+                f"tokens: {self.free_slots()} free slots "
+                "(raise inference.max_slots)")
+        self._reserved[slot] = int(n_tokens_worst_case)
+        if self._ledger is not None:
+            name = name or f"slot{slot}"
+            self._ledger_tokens[slot] = self._ledger.register_dynamic(
+                memory_mod.CAT_STATE, f"request.s{slot}.{name}",
+                lambda: self.slot_state_bytes,
+                meta={"slot": int(slot), "request": name})
+
+    def ensure(self, slot, n_tokens):
+        """Nothing grows; only the admission's bound is held."""
+        if slot not in self._reserved:
+            raise ValueError(f"slot {slot} is not admitted")
+        if n_tokens > self._reserved[slot]:
+            raise RuntimeError(
+                f"slot {slot}: {n_tokens} tokens exceeds the admission "
+                f"reservation of {self._reserved[slot]} tokens")
+
+    def rollback(self, slot, n_tokens):
+        raise NotImplementedError(
+            "recurrent state cannot be rewound: snapshots of state do "
+            "not exist yet")
+
+    def free(self, slot):
+        self._reserved.pop(slot, None)
+        token = self._ledger_tokens.pop(slot, None)
+        if token is not None and self._ledger is not None:
+            self._ledger.release(token)
+        return 0

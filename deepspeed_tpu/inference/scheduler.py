@@ -4,8 +4,9 @@ iteration-level scheduling on the PR-2 fence convention).
 The unit of scheduling is one **serving iteration**:
 
   1. admission — queued requests whose arrival time has passed take
-     free decode slots, IF the paged cache can cover their worst case
-     (admitted requests never fail a page allocation mid-flight);
+     free decode slots, IF the cache can cover their worst case (pages
+     for a paged model: admitted requests never fail a page allocation
+     mid-flight; a slot's fixed block for one of recurrent state);
   2. chunked prefill — every admitted-but-not-yet-live slot advances
      by ONE prompt chunk, so a long prompt shares the loop with the
      decode batch instead of stalling it; a slot whose prompt is fully
@@ -115,17 +116,12 @@ class ServingLoop:
                 f"{req.max_new_tokens} exceeds the engine buffer width "
                 f"inference.max_new_tokens="
                 f"{self._infer.config.max_new_tokens}")
-        cache = self._infer.cache
-        usable = min(cache.max_pages_per_slot, cache.num_pages - 1)
-        if cache.pages_for_tokens(total) > usable:
-            # a request that can NEVER fit the pool must be rejected
+        never = self._infer.cache.never_fits(total)
+        if never is not None:
+            # a request that can NEVER fit the cache must be rejected
             # here: _admit would wait forever for an eviction that
             # cannot help, starving everything queued behind it
-            raise ValueError(
-                f"request {req.rid!r}: worst case "
-                f"{cache.pages_for_tokens(total)} pages exceeds the "
-                f"pool's {usable} usable pages "
-                "(raise inference.kv_cache.num_pages)")
+            raise ValueError(f"request {req.rid!r}: {never}")
         if req.top_k > self._infer.config.top_k_max:
             raise ValueError(
                 f"request {req.rid!r}: top_k {req.top_k} exceeds the "
@@ -215,7 +211,7 @@ class ServingLoop:
                 continue
             worst = len(req.tokens) + req.max_new_tokens
             if not self._infer.cache.can_admit(worst):
-                # pages exhausted: wait for an eviction
+                # the cache is exhausted: wait for an eviction
                 self.queue.appendleft(req)
                 if trk is not None:
                     trk.on_admission_deferred()
@@ -224,13 +220,13 @@ class ServingLoop:
             self._infer.cache.admit(slot, worst, name=str(req.rid))
             req.admitted_at = now
             self.prefilling[slot] = [req, 0]
-            pages_reserved = self._infer.cache.pages_for_tokens(worst)
+            reserved = self._infer.cache.reservation(worst)
             if trk is not None:
                 trk.on_admitted(
                     slot, str(req.rid), len(req.tokens),
                     req.max_new_tokens,
                     queued_s=max(now - req.arrival_time, 0.0),
-                    pages_reserved=pages_reserved)
+                    pages_reserved=reserved.get("kv_pages_reserved", 0))
             self._infer.monitor.event(
                 "request_admitted",
                 request_id=str(req.rid), slot=int(slot),
@@ -238,7 +234,7 @@ class ServingLoop:
                 max_new_tokens=int(req.max_new_tokens),
                 queue_depth=len(self.queue),
                 queued_ms=round((now - req.arrival_time) * 1e3, 3),
-                kv_pages_reserved=int(pages_reserved))
+                **reserved)
         # not-yet-arrived requests go back in their original order
         for req in reversed(future):
             self.queue.appendleft(req)
@@ -334,8 +330,9 @@ class ServingLoop:
             window_ms=round(window_s * 1e3, 3),
             window_tokens=int(new_tokens),
             tokens_per_sec=round(new_tokens / window_s, 3),
-            kv_pages_in_use=int(self._infer.cache.pages_in_use()),
-            kv_pages_free=int(self._infer.cache.free_pages()))
+            # pages in use and free, or for a model of recurrent state
+            # the slots holding state and its bytes
+            **self._infer.cache.occupancy())
         if trk is not None:
             # SLO metrics AFTER evictions: this fence's finishes are in
             # the histograms/counters the event reports

@@ -56,7 +56,7 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.inference.engine import (_ln_apply, compile_fresh,
-                                            scan_layers)
+                                            paged_layers)
 
 # fold_in lane separating the draft model's sampling stream from the
 # flagship's (state["rng"] folded by step on one side, by
@@ -188,7 +188,7 @@ def build_draft_step(engine):
         posc = jnp.clip(pos, 0, mc.n_positions - 1)
         hidden = wte[cur].astype(mc.dtype) + wpe[posc].astype(mc.dtype)
         hidden = hidden[:, None, :]
-        hidden, dk, dv = scan_layers(
+        hidden, dk, dv = paged_layers(
             dmc, draft_params, hidden, spec["dk_pool"], spec["dv_pool"],
             state["tables"], pos[:, None], valid[:, None], pos, page, qb)
         hidden = _ln_apply(dmc, draft_params["ln_f"], hidden)
@@ -252,7 +252,7 @@ def build_verify_step(engine):
         posc = jnp.clip(positions, 0, mc.n_positions - 1)
         hidden = wte[tokens_in].astype(mc.dtype) + \
             wpe[posc].astype(mc.dtype)
-        hidden, k_pool, v_pool = scan_layers(
+        hidden, k_pool, v_pool = paged_layers(
             mc, params, hidden, state["k_pool"], state["v_pool"],
             state["tables"], positions, write_ok, kv_limit, page, qb)
         hidden = _ln_apply(mc, params["ln_f"], hidden)
@@ -392,7 +392,7 @@ def build_draft_prefill_step(engine):
         hidden = hidden[None]
         positions = posv[None]
         kv_limit = (start + n_valid - 1)[None]
-        _, dk_pool, dv_pool = scan_layers(
+        _, dk_pool, dv_pool = paged_layers(
             dmc, draft_params, hidden, dk_pool, dv_pool, page_row[None],
             positions, valid[None], kv_limit, page, qb)
         return dk_pool, dv_pool

@@ -79,6 +79,7 @@ CAT_PREFETCH = "prefetch"
 CAT_PIPE = "pipe_buffers"
 CAT_KV = "kv_cache"
 CAT_KV_DRAFT = "kv_cache_draft"
+CAT_STATE = "recurrent_state"
 CAT_MOE = "moe_dispatch"
 CAT_OVERLAP = "overlap_inflight"
 
@@ -94,9 +95,12 @@ CAT_OVERLAP = "overlap_inflight"
 # overlap_inflight — the comm/compute overlap runtime's in-flight
 # collective staging windows (MoE dispatch pair + ring send/recv
 # rotations, ops/overlap.py) — likewise: per-step working memory that
-# scales with overlap.issue_distance)
+# scales with overlap.issue_distance; recurrent_state — the fixed
+# per-slot state of a retention model, RecurrentStateCache — sits with
+# kv_cache: resident for the engine's lifetime)
 CATEGORIES = (CAT_PARAMS, CAT_MASTER, CAT_OPT, CAT_GRADS, CAT_ZERO3,
-              CAT_MOE, CAT_OVERLAP, CAT_KV, CAT_KV_DRAFT, CAT_HOST_MASTER,
+              CAT_MOE, CAT_OVERLAP, CAT_KV, CAT_KV_DRAFT, CAT_STATE,
+              CAT_HOST_MASTER,
               CAT_HOST_OPT, CAT_WIRE, CAT_CKPT, CAT_PREFETCH,
               CAT_PIPE)
 

@@ -332,7 +332,7 @@ class ServingTracker:
             total = self.total_tokens
             good = self.goodput_tokens
             qw, e2e = self._queue_wait_s, self._e2e_s
-        in_use, free, util = self._kv_pages()
+        occupancy = self._cache.ledger_occupancy()
         window_s = max(window_s, 1e-9)
         tps = window_tokens / window_s
         self._monitor.event(
@@ -343,9 +343,7 @@ class ServingTracker:
             active_slots=int(active_slots),
             prefilling_slots=int(prefilling_slots),
             queue_depth=int(queue_depth),
-            kv_pages_in_use=in_use,
-            kv_pages_free=free,
-            kv_page_utilization=round(util, 4),
+            **occupancy,
             queue_wait_share=round(qw / e2e, 4) if e2e > 0 else None,
             ttft_ms=self.hist_ttft_ms.to_event(),
             token_ms=self.hist_token_ms.to_event(),
@@ -370,8 +368,8 @@ class ServingTracker:
             tr.counter("serving", "batch_occupancy",
                        {"decoding": int(active_slots),
                         "prefilling": int(prefilling_slots)})
-            tr.counter("serving", "kv_page_utilization",
-                       {"in_use": in_use, "free": free})
+            tr.counter("serving",
+                       *self._cache.utilization_counter(occupancy))
             tr.counter("serving", "tokens_per_sec",
                        {"tokens_per_sec": round(tps, 3)})
         if not self._armed:
@@ -427,17 +425,16 @@ class ServingTracker:
         """Forensic snapshot: the live table plus pool geometry,
         utilization, counters and the current percentiles — what
         `Monitor.on_crash` attaches and `serving_oom_hints` ranks."""
-        in_use, free, util = self._kv_pages()
         table = self.live_table()
         with self._lock:
             c = dict(self.counters)
+        if hasattr(self._cache, "num_pages"):
+            table["num_pages"] = self._cache.num_pages
         table.update(
             max_slots=self._max_slots,
             prefill_chunk=self._prefill_chunk,
-            num_pages=self._cache.num_pages,
-            kv_pages_in_use=in_use, kv_pages_free=free,
-            kv_page_utilization=round(util, 4),
             counters=c,
+            **self._cache.ledger_occupancy(),
             ttft_p50_ms=_r(self.hist_ttft_ms.percentile(0.50)),
             ttft_p99_ms=_r(self.hist_ttft_ms.percentile(0.99)),
             token_p50_ms=_r(self.hist_token_ms.percentile(0.50)),
@@ -458,20 +455,6 @@ class ServingTracker:
     def _update_flight(self):
         if self._monitor.flight is not None:
             self._monitor.flight.set_context(serving=self.live_table())
-
-    def _kv_pages(self):
-        """(pages in use, pages free, utilization) derived from the
-        PR-8 ledger's `kv_cache` category: the per-request dynamic
-        entries are the in-use bytes, `pool.unallocated` the rest —
-        pure host reads of registered shape math."""
-        rows = self._monitor.ledger.category_breakdown(memory_mod.CAT_KV)
-        in_use_bytes = sum(b for name, b in rows.items()
-                           if name != "pool.unallocated")
-        page_bytes = max(self._cache.page_bytes, 1)
-        allocatable = max(self._cache.num_pages - 1, 1)
-        in_use = int(in_use_bytes // page_bytes)
-        free = max(allocatable - in_use, 0)
-        return in_use, free, in_use / allocatable
 
 
 def _r(v, nd=3):

@@ -1,0 +1,49 @@
+"""The regions of the serving programs (`jax.named_scope`: metadata on
+the HLO, no instruction changes). A device profile's operations are
+joined to these through `monitor/programs.py::op_scopes`.
+
+Time in SCOPE_LAYERS outside every inner region is the layer scan
+itself: the loop and the slicing of each layer's weights out of the
+stacked tree. The model's cache rides in the scan's carry whole
+(`engine.scan_layers`) and is touched only in the regions named for it:
+SCOPE_KV_WRITE and SCOPE_KV_GATHER for the K/V page pools of a paged
+model, SCOPE_STATE_RESET, SCOPE_RETENTION_CHUNK and SCOPE_STATE_UPDATE
+for the state of a recurrent one. A cache-sized copy showing up under
+SCOPE_LAYERS alone is a regression.
+
+A model's block (`models/brumby.py`) and the engine
+(`inference/engine.py`, which re-exports them) both take the names
+from here: neither the models nor the ops import the serving code.
+"""
+
+SCOPE_EMBED = "embed"
+SCOPE_LAYERS = "layers"            # round the lax.scan call, nothing else
+SCOPE_ATTN_QKV = "attn_qkv"        # inside a layer: the norm + the q/k/v
+#                                    (and gate) projections, q/k-norm, rotary
+SCOPE_KV_WRITE = "kv_write"        # the chunk's K/V into the page pool
+SCOPE_KV_GATHER = "kv_gather"      # the page window through the tables
+SCOPE_ATTN = "attn"                # paged_attention
+SCOPE_ATTN_OUT = "attn_out"        # output projection + residual
+SCOPE_MLP = "mlp"                  # norm, feed-forward, residual
+SCOPE_HEAD = "head"                # final norm + output head (tied to the
+#                                    embedding or its own matrix)
+SCOPE_SAMPLE = "sample"
+SCOPE_BOOKKEEPING = "bookkeeping"  # the slot state update
+SCOPES_IN_LAYER = (SCOPE_ATTN_QKV, SCOPE_KV_WRITE, SCOPE_KV_GATHER,
+                   SCOPE_ATTN, SCOPE_ATTN_OUT, SCOPE_MLP)
+SCOPES = (SCOPE_EMBED, SCOPE_LAYERS) + SCOPES_IN_LAYER + \
+    (SCOPE_HEAD, SCOPE_SAMPLE, SCOPE_BOOKKEEPING)
+
+# a model whose cache is recurrent state (retention): no pages, no
+# attention over a window; the state is zeroed, advanced a chunk
+# (prefill) or advanced one token and read (decode)
+SCOPE_STATE_RESET = "state_reset"          # a reused slot starts from zero
+SCOPE_RETENTION_CHUNK = "retention_chunk"  # prefill: the chunked form
+SCOPE_STATE_UPDATE = "state_update"        # decode: update + read-out
+SCOPES_STATE = (SCOPE_STATE_RESET, SCOPE_RETENTION_CHUNK,
+                SCOPE_STATE_UPDATE)
+SCOPES_IN_LAYER_RECURRENT = (SCOPE_ATTN_QKV,) + SCOPES_STATE + \
+    (SCOPE_ATTN_OUT, SCOPE_MLP)
+SCOPES_RECURRENT = (SCOPE_EMBED, SCOPE_LAYERS) + \
+    SCOPES_IN_LAYER_RECURRENT + (SCOPE_HEAD, SCOPE_SAMPLE,
+                                 SCOPE_BOOKKEEPING)

@@ -71,7 +71,7 @@ def test_layer_scan_holds_no_copy_of_a_pool_on_a_v5e(cell, rows, tokens):
 
     def layers(params, hidden, k_pool, v_pool, tables, positions, valid,
                kv_limit):
-        return engine_mod.scan_layers(
+        return engine_mod.paged_layers(
             cfg, params, hidden, k_pool, v_pool, tables, positions, valid,
             kv_limit, PAGE, 64)
 
@@ -94,4 +94,51 @@ def test_layer_scan_holds_no_copy_of_a_pool_on_a_v5e(cell, rows, tokens):
         rf"= \w+\[(?:{whole}|1,{layer}|{layer})\]\S* "
         r"(copy|dynamic-slice|dynamic-update-slice|transpose)\(",
         compiled.as_text())
+    assert moved == []
+
+
+# ----------------------------------------------------------------------
+# Brumby's layer scans at the shapes of `brumby-14b.serve-longdoc-steady`
+# (8 layers of the published widths, 16 slots, chunk 512; ISSUE 26)
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_state_is_updated_in_place_on_a_v5e(one_chip, program):
+    """4.36 GB of recurrent state ride in the layer scan's carry: the
+    donated arrays are the outputs, the temporaries stay far below one
+    layer's state (545 MB), and the whole state is never copied."""
+    from deepspeed_tpu.inference.config import InferenceConfig
+    from deepspeed_tpu.models import brumby
+    cfg = brumby.BrumbyConfig(num_hidden_layers=8)
+    block = InferenceConfig({"inference": {
+        "max_slots": SLOTS, "prefill_chunk": 512, "max_seq_len": 8192}})
+    family = cfg.serving(block, 8192)
+    place = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip)
+    sds = lambda shape, dtype: place(jax.ShapeDtypeStruct(shape, dtype))
+    params = jax.tree_util.tree_map(place, jax.eval_shape(
+        lambda k: brumby.init_params(cfg, k), jax.random.PRNGKey(0)))
+    lead = (8, SLOTS, 8, cfg.state_dim)
+    S, z = sds(lead + (128,), jnp.float32), sds(lead, jnp.float32)
+    if program == "decode":
+        def layers(params, hidden, S, z, pos, active):
+            return family.decode_layers(params, hidden, {
+                "state_s": S, "state_z": z, "pos": pos, "active": active})
+        args = (params, sds((SLOTS, 1, 5120), cfg.dtype), S, z,
+                sds((SLOTS,), jnp.int32), sds((SLOTS,), bool))
+    else:
+        def layers(params, hidden, S, z, slot, start, n_valid):
+            posv = start + jnp.arange(512, dtype=jnp.int32)
+            return family.prefill_layers(
+                params, hidden, (S, z), slot, posv,
+                jnp.arange(512) < n_valid, start, n_valid)
+        args = (params, sds((1, 512, 5120), cfg.dtype), S, z) + \
+            (sds((), jnp.int32),) * 3
+    compiled = jax.jit(layers, donate_argnums=(2, 3)).lower(*args).compile()
+    state_bytes = 4 * int(np.prod(S.shape) + np.prod(z.shape))
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= state_bytes
+    assert memory.temp_size_in_bytes < state_bytes // 16
+    whole = ",".join(map(str, S.shape))
+    moved = re.findall(rf"= f32\[{whole}\]\S* (copy|transpose)\(",
+                       compiled.as_text())
     assert moved == []

@@ -1015,22 +1015,29 @@ loop.run()
 
 
 def test_kv_page_utilization_ledger_vs_cache_twins(obs_setup):
-    """The tracker derives KV-page utilization from the memory
-    ledger's `kv_cache` category (serving._kv_pages); the cache
-    derives it from its own page tables (pages_in_use/utilization).
+    """The tracker reports KV-page utilization as derived from the
+    memory ledger's `kv_cache` category (the cache's
+    `ledger_occupancy`); the cache derives it from its own page
+    tables (pages_in_use/utilization).
     Two independent accounting chains — they must agree
     page-for-page."""
     cfg, engine, _, _, _ = obs_setup
     engine.reset()
     cache = engine.cache
-    assert engine.tracker._kv_pages() == (0, cache.num_pages - 1, 0.0)
+
+    def ledger_pages():
+        row = cache.ledger_occupancy()
+        return (row["kv_pages_in_use"], row["kv_pages_free"],
+                row["kv_page_utilization"])
+
+    assert ledger_pages() == (0, cache.num_pages - 1, 0.0)
     assert cache.pages_in_use() == 0 and cache.utilization() == 0.0
     cache.admit(0, 12, name="twin")
     cache.ensure(0, 12)
-    in_use, free, util = engine.tracker._kv_pages()
+    in_use, free, util = ledger_pages()
     assert in_use == cache.pages_in_use() > 0
     assert free == (cache.num_pages - 1) - in_use
-    assert util == pytest.approx(cache.utilization())
+    assert util == pytest.approx(cache.utilization(), abs=5e-5)  # rounded
     cache.free(0)
     engine.reset()
 
