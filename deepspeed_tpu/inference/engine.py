@@ -27,11 +27,14 @@ GPT-2 family:
     slot's cached prefix, logits through the tied head. The forward
     math deliberately mirrors the training path operation for
     operation (the same flax submodules applied to the same param
-    leaves, the same einsum phrasings, the same fp32 softmax with
-    -1e30 masking), so decode logits are BIT-EXACT against the
-    training forward on the same prefix in fp32 — parity is pinned by
-    tests/test_inference.py, the serving bench leg, and the
-    training/serving drift that convention prevents is the point.
+    leaves, the same fp32 softmax with -1e30 masking); decode's
+    attention reads the pages where they lie through one kernel
+    (`ops/transformer/paged_decode_attention.py`: the same operands,
+    every sum in fp32, in its own order), so decode logits agree with
+    the training forward on the same prefix to float roundoff in fp32
+    — parity is pinned by tests/test_inference.py, the serving bench
+    leg, and the training/serving drift that convention prevents is
+    the point.
     Weight-only int8 serving (`inference.weight_bits: 8`) quantises
     the projection kernels once at load (inference/quant.py) and the
     dense application below switches onto the dequant-in-matmul
@@ -69,6 +72,8 @@ from deepspeed_tpu.monitor import memory as memory_mod
 from deepspeed_tpu.monitor import programs
 from deepspeed_tpu.monitor.trace import profiler_span
 from deepspeed_tpu.ops.retention import retention_chunked, retention_step
+from deepspeed_tpu.ops.transformer.paged_decode_attention import \
+    paged_decode_attention
 from deepspeed_tpu.utils.logging import logger
 
 # the regions of the serving programs: see utils/scopes.py
@@ -159,16 +164,16 @@ def _dense_apply(cfg, p, x, quant_block):
 
 @jax.named_scope(SCOPE_ATTN)
 def paged_attention(q, kc, vc, q_pos, kv_limit):
-    """Causal attention of q [B, Tq, H, D] against a gathered page
-    window kc/vc [B, Tk, H, D], phrased exactly like the training
-    path's `dense_attention` (same einsum strings, fp32 softmax,
-    -1e30 where-masking) so the result is bit-exact vs a contiguous
-    cache: key positions are their indices, queries sit at absolute
-    positions `q_pos` [B, Tq], and keys beyond `kv_limit` [B] (pages
-    not yet written / scratch) are price-masked AND value-zeroed — a
-    masked key contributes an exact +0.0 to every reduction, which is
-    what keeps the longer padded reductions bit-identical to the
-    unpadded training ones."""
+    """A prefill chunk's attention (the programs with a few query
+    rows a slot attend through `paged_decode_attention` instead; see
+    `_block_paged`). Causal attention of q [B, Tq, H, D] against a
+    gathered page window kc/vc [B, Tk, H, D], phrased like the
+    training path's `dense_attention` (same einsum strings, fp32
+    softmax, -1e30 where-masking): key positions are their indices,
+    queries sit at absolute positions `q_pos` [B, Tq], and keys beyond
+    `kv_limit` [B] (pages not yet written / scratch) are price-masked
+    AND value-zeroed — a masked key contributes an exact +0.0 to every
+    reduction, whatever the unwritten rows hold."""
     sm_scale = 1.0 / np.sqrt(q.shape[-1])
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, kc).astype(jnp.float32)
     scores = scores * sm_scale
@@ -183,33 +188,45 @@ def paged_attention(q, kc, vc, q_pos, kv_limit):
     vc = jnp.where(v_ok, vc, jnp.zeros((), vc.dtype))
     # PV phrased as a (b, h)-batched matmul rather than the einsum
     # string: measured on XLA-CPU this contraction accumulates the
-    # real-key prefix in the same order at every padded width, which
-    # is what keeps decode logits BIT-identical to the training
-    # forward's unpadded attention (the einsum lowering is 1 ulp off
-    # once the padded K dim changes the blocking)
+    # real-key prefix in the same order at every padded width
     out = jnp.matmul(probs, vc.transpose(0, 2, 1, 3))
     return out.transpose(0, 2, 1, 3)
+
+
+# query rows per slot up to which `_block_paged` attends through the
+# decode kernel: decode and draft decode carry 1, speculative verify
+# k + 1. A prefill chunk carries more and keeps the gathered window:
+# one row against pages is a page walk bound by latency and bytes, a
+# chunk of 128 rows against one slot is a matrix-unit problem.
+DECODE_ROWS_MAX = 8
 
 
 def _block_paged(cfg, lp, hidden, k_pool, v_pool, li, tables, positions,
                  valid, kv_limit, page_size, quant_block):
     """One pre-LN transformer block (GPT2Block's unfused math, op for
     op) over hidden [B, Tq, C]: layer `li` of the WHOLE page pools
-    (k_pool/v_pool: [L, P, page, H*D], one token's K or V on the
-    lanes). The chunk's K/V rows are scattered into the pools at
-    (li, physical page, offset) and the window is gathered at
-    (li, tables) through the page tables ([B, max_pages]); no layer's
-    pool is ever sliced out, so the compiler updates the donated pools
-    in place. Rows with valid=False (inactive decode slots, prefill
-    pad rows) divert their writes to scratch page 0."""
+    (k_pool/v_pool: [L, P, page, lanes], one token's K or V on the
+    lanes, zeros from C up to the lane tile). The chunk's K/V rows are
+    scattered into the pools at (li, physical page, offset); no
+    layer's pool is ever sliced out, so the compiler updates the
+    donated pools in place. Rows with valid=False (inactive decode
+    slots, prefill pad rows) divert their writes to scratch page 0.
+
+    How the block attends is chosen by what it can see, Tq at trace
+    time. A few rows a slot (decode, draft decode, verify): one kernel
+    walks each live slot's page table and reads the pages where they
+    lie (`ops/transformer/paged_decode_attention.py`); a slot with no
+    valid row is not live and gets zeros. A prefill chunk: the slot's
+    window is gathered through its table row ([B, max_pages]) and
+    attended to densely (`paged_attention`)."""
     b, t, c = hidden.shape
     h, d = cfg.n_head, cfg.head_dim
+    lanes = k_pool.shape[-1]
 
     with jax.named_scope(SCOPE_ATTN_QKV):
         x = _ln_apply(cfg, lp["ln_1"], hidden).astype(cfg.dtype)
         qkv = _dense_apply(cfg, lp["c_attn"], x, quant_block)
         q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, t, h, d)
 
     # write-before-read: the chunk's own keys are part of its causal
     # window (a query attends to itself, like the training mask)
@@ -219,13 +236,21 @@ def _block_paged(cfg, lp, hidden, k_pool, v_pool, li, tables, positions,
         phys = jnp.take_along_axis(tables, pidx, axis=1)
         phys = jnp.where(valid, phys, 0).reshape(-1)
         off = off.reshape(-1)
-        k_pool = k_pool.at[li, phys, off].set(k.reshape(b * t, c))
-        v_pool = v_pool.at[li, phys, off].set(v.reshape(b * t, c))
+        row = lambda x: jnp.pad(x.reshape(b * t, c), ((0, 0), (0, lanes - c)))
+        k_pool = k_pool.at[li, phys, off].set(row(k))
+        v_pool = v_pool.at[li, phys, off].set(row(v))
 
-    with jax.named_scope(SCOPE_KV_GATHER):
-        kc = k_pool[li, tables].reshape(b, -1, h, d)
-        vc = v_pool[li, tables].reshape(b, -1, h, d)
-    attn = paged_attention(q, kc, vc, positions, kv_limit)
+    if t <= DECODE_ROWS_MAX:
+        with jax.named_scope(SCOPE_ATTN):
+            live_len = jnp.where(valid.any(axis=1), kv_limit + 1, 0)
+            attn = paged_decode_attention(q, k_pool, v_pool, li, tables,
+                                          positions, live_len, h)
+    else:
+        with jax.named_scope(SCOPE_KV_GATHER):
+            kc = k_pool[li, tables][..., :c].reshape(b, -1, h, d)
+            vc = v_pool[li, tables][..., :c].reshape(b, -1, h, d)
+        attn = paged_attention(q.reshape(b, t, h, d), kc, vc, positions,
+                               kv_limit)
     with jax.named_scope(SCOPE_ATTN_OUT):
         attn = attn.reshape(b, t, c)
         attn = _dense_apply(cfg, lp["c_proj"], attn, quant_block)

@@ -5,11 +5,14 @@ buffer per request — at high slot counts the padding-to-max waste is
 the first thing that OOMs a serving chip. Instead a single preallocated
 pool of fixed-size pages
 
-    k_pool / v_pool : [n_layer, num_pages, page_size, n_head * head_dim]
+    k_pool / v_pool : [n_layer, num_pages, page_size, lanes]
 
-(one token's K or V of one layer on the lanes: the layout the compiled
-programs scatter into and gather from in place, see
-`engine.scan_layers`) is shared by every request; each request slot
+(one token's K or V of one layer on the lanes, `lanes` = n_head *
+head_dim rounded up to a whole number of the chip's 128-lane tiles:
+the layout the compiled programs scatter into in place and the decode
+kernel copies single pages out of, see `engine.scan_layers` and
+`ops/transformer/paged_decode_attention.py`) is shared by every
+request; each request slot
 owns a page table (row of physical page ids) and positions map to
 (physical page, offset) by plain index math inside the compiled
 programs. Physical page 0 is a reserved scratch page: masked writes
@@ -36,12 +39,14 @@ recurrent state per request (power retention, `models/brumby.py`) owns
 one block of it per slot, sized once and never grown. Both managers
 answer the scheduler's one interface: `can_admit`, `admit`, `ensure`,
 `free`, `reserved_tokens`, `never_fits`, `reservation`, `occupancy`,
-`slot_operand` (and `rollback`, which recurrent state refuses).
+`attended`, `slot_operand` (and `rollback`, which recurrent state
+refuses).
 """
 
 import numpy as np
 
 from deepspeed_tpu.monitor import memory as memory_mod
+from deepspeed_tpu.ops.transformer.paged_decode_attention import padded_lanes
 
 
 class PagedKVCache:
@@ -71,11 +76,16 @@ class PagedKVCache:
         self.max_slots = int(max_slots)
         self.max_pages_per_slot = int(max_pages_per_slot)
         self.dtype = np.dtype(dtype)
+        # one token's K (or V) of one layer as the pools hold it: the
+        # chip tiles the minor-most dimension by 128 lanes, so a row of
+        # 1,600 takes 1,664 there whether the shape says so or not; the
+        # shape says so, and a page is a whole number of tiles that the
+        # decode kernel can copy alone
+        self.lanes = padded_lanes(self.n_head * self.head_dim)
         # bytes of ONE page across K+V and all layers: the unit every
         # accounting statement below is phrased in
         self.page_bytes = (2 * self.n_layer * self.page_size *
-                           self.n_head * self.head_dim *
-                           self.dtype.itemsize)
+                           self.lanes * self.dtype.itemsize)
         self.pool_bytes = self.num_pages * self.page_bytes
         # page 0 = scratch; pages 1..num_pages-1 allocatable (LIFO free
         # list: recently freed pages are re-assigned first, which keeps
@@ -115,8 +125,7 @@ class PagedKVCache:
         (the flagship's page bytes scaled to the draft's layer count)."""
         self.draft_n_layer = int(n_layer_draft)
         self.draft_page_bytes = (2 * self.draft_n_layer * self.page_size *
-                                 self.n_head * self.head_dim *
-                                 self.dtype.itemsize)
+                                 self.lanes * self.dtype.itemsize)
         self.draft_pool_bytes = self.num_pages * self.draft_page_bytes
         if self._ledger is not None:
             self._ledger.register_dynamic(
@@ -129,11 +138,11 @@ class PagedKVCache:
 
     def pool_shape(self, n_layer):
         """Shape of ONE device pool (K or V) of `n_layer` layers: one
-        token's K (or V) of one layer is the minor-most row, so the
-        pool has one natural layout inside and outside the compiled
-        programs' layer scan."""
-        return (int(n_layer), self.num_pages, self.page_size,
-                self.n_head * self.head_dim)
+        token's K (or V) of one layer is the minor-most row (`lanes`:
+        its n_head * head_dim values, then zeros up to the lane tile),
+        so the pool has one natural layout inside and outside the
+        compiled programs' layer scan."""
+        return (int(n_layer), self.num_pages, self.page_size, self.lanes)
 
     # -- accounting -----------------------------------------------------
     def pages_for_tokens(self, n_tokens):
@@ -205,6 +214,18 @@ class PagedKVCache:
         """What every fence reports of the cache."""
         return {"kv_pages_in_use": int(self.pages_in_use()),
                 "kv_pages_free": int(self.free_pages())}
+
+    def attended(self, active, pos):
+        """How far the decode kernel engages at the next launch, from
+        what the fence fetched (`active`, `pos` of every slot; host
+        arrays): the pages it walks, ceil((pos + 1) / page) summed
+        over the live slots, and their share of the window the
+        gathered path attended to whatever was live (max_slots x
+        max_pages_per_slot)."""
+        pages = int((-(-(pos[active] + 1) // self.page_size)).sum())
+        return {"kv_pages_attended": pages,
+                "kv_pages_attended_share": round(
+                    pages / (self.max_slots * self.max_pages_per_slot), 4)}
 
     def ledger_occupancy(self):
         """`occupancy` with the utilization, as the serving tracker
@@ -432,6 +453,10 @@ class RecurrentStateCache:
                 "state_bytes_resident": int(self.resident_bytes())}
 
     ledger_occupancy = occupancy       # the manager's own counters
+
+    def attended(self, active, pos):
+        """A model of state attends to no pages."""
+        return {}
 
     def utilization_counter(self, occupancy):
         return "state_slot_utilization", {

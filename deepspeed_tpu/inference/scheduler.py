@@ -332,13 +332,17 @@ class ServingLoop:
             tokens_per_sec=round(new_tokens / window_s, 3),
             # pages in use and free, or for a model of recurrent state
             # the slots holding state and its bytes
-            **self._infer.cache.occupancy())
+            **self._infer.cache.occupancy(),
+            # the pages the decode kernel walks at the next launch:
+            # host arithmetic on what the fence already fetched
+            **self._infer.cache.attended(snap["active"], snap["pos"]))
         if trk is not None:
             # SLO metrics AFTER evictions: this fence's finishes are in
             # the histograms/counters the event reports
             trk.on_fence_metrics(window_s, new_tokens,
                                  len(self.queue), len(self.live),
-                                 len(self.prefilling))
+                                 len(self.prefilling), snap["active"],
+                                 snap["pos"])
         if mon.memory_enabled:
             mon._emit_memory_event(self._infer._host_steps)
 

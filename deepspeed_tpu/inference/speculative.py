@@ -13,9 +13,10 @@ module makes each launch emit up to k+1 **verified** tokens:
      verbatim (one admission decision, one table upload; the draft
      pool is the `kv_cache_draft` ledger category);
   2. **verify** (ONE flagship launch): the widened decode program
-     scores all k+1 positions per slot at once — the chunked-prefill
-     path already proved `_block_paged`'s multi-token masking, so
-     verify is that masking at decode shapes — and applies the
+     scores all k+1 positions per slot at once — `_block_paged` with
+     k+1 query rows a slot, through the same decode kernel as the
+     one-row programs (`ops/transformer/paged_decode_attention.py`:
+     each row masked at its own position) — and applies the
      acceptance rule **on device**, so a round adds zero host syncs
      and rounds chain back-to-back under the PR-2 dispatch discipline.
 
@@ -26,9 +27,10 @@ Losslessness (the output distribution is exactly vanilla decode's):
     prefix; the first mismatch position emits the flagship argmax
     instead. By induction every emitted token is the flagship's greedy
     choice, so the stream is BIT-IDENTICAL to vanilla decode (the
-    verify logits are bit-exact vs the single-token decode program by
-    the same padded-reduction phrasing that makes decode bit-exact vs
-    the training forward).
+    decode kernel runs every query row through the same operations on
+    the same page blocks whatever the number of rows, so a verify
+    row's attention equals the single-token decode launch at that
+    position bit for bit).
   * temperature > 0 — modified rejection sampling (Leviathan et al.):
     drafted token x ~ q is accepted with probability min(1, p(x)/q(x));
     the first rejection resamples from the residual
@@ -37,7 +39,9 @@ Losslessness (the output distribution is exactly vanilla decode's):
     as p — pinned statistically by tests/test_speculative.py.
 
 Rollback is free by construction: stale K/V beyond a slot's `pos` is
-already score-masked AND value-zeroed by `paged_attention`, so
+past its live length, which the decode kernel neither reads (whole
+pages) nor weighs (score-masked AND value-zeroed inside the last
+page), so
 rejecting a suffix just rewinds `pos` (device-side, in verify) and
 trims the host page tables (`PagedKVCache.rollback` — LIFO, so
 re-advancing pops the same physical pages back; no page is copied).

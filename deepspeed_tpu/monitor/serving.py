@@ -322,10 +322,12 @@ class ServingTracker:
         self._update_flight()
 
     def on_fence_metrics(self, window_s, window_tokens, queue_depth,
-                         active_slots, prefilling_slots):
+                         active_slots, prefilling_slots, active, pos):
         """The fence's SLO rendezvous: one `serving_slo` event + the
         counter tracks, after evictions settled (so the counts include
-        this fence's finishes)."""
+        this fence's finishes). `active`, `pos`: every slot's flag and
+        position as the fence fetched them (host arrays), for the
+        pages the decode kernel walks next."""
         with self._lock:
             self._queue_depth = int(queue_depth)
             c = dict(self.counters)
@@ -344,6 +346,7 @@ class ServingTracker:
             prefilling_slots=int(prefilling_slots),
             queue_depth=int(queue_depth),
             **occupancy,
+            **self._cache.attended(active, pos),
             queue_wait_share=round(qw / e2e, 4) if e2e > 0 else None,
             ttft_ms=self.hist_ttft_ms.to_event(),
             token_ms=self.hist_token_ms.to_event(),

@@ -1,0 +1,212 @@
+"""Attention of a few query rows per slot against a paged K/V cache,
+read where the pages lie (Pallas TPU kernel).
+
+The serving programs that carry one to a handful of query rows per
+slot (decode, draft decode, speculative verify) used to gather every
+slot's whole page window out of the pools ([B, max_pages * page, H*D]),
+re-lay it to [B, Tk, H, D] and run a dense masked attention over it:
+16 x 1,024 keys in every layer, whatever was live. This kernel takes
+both WHOLE pools as they ride in the layer scan's carry,
+
+    k_pool / v_pool : [n_layer, num_pages, page, lanes]
+
+(`PagedKVCache.pool_shape`: one token's K or V of one layer on the
+lanes, `lanes` = H*D rounded up to a whole number of 128-lane tiles),
+leaves them in HBM, and for each live slot walks that slot's page
+table up to its length: pages are copied HBM -> VMEM one DMA each,
+`_BLOCK_KEYS` keys to a compute block, double-buffered. A slot of
+length 0 does nothing and returns zeros; pages past a slot's length
+are never read.
+
+Heads are never split out of the lanes. With the 0/1 indicator
+E[h, l] = (l // D == h), per query row:
+
+    Qbd    = E * q                       [Hp, lanes]   block-diagonal q
+    scores = Qbd . Kblk^T * sm_scale     [Hp, keys]    fp32 accumulation
+    (online softmax over key blocks, fp32; keys past the row's position
+     or the slot's length masked to -1e30, their V rows zeroed)
+    acc    = alpha * acc + P . Vblk      [Hp, lanes]   P in the pools' dtype
+    out    = sum_h E * acc / l           [lanes]
+
+The matrix unit computes every head against every lane and E keeps the
+diagonal blocks: H times the useful products, on a unit that is
+otherwise idle while the pages stream in. The operands are the pools'
+dtype, every product sum accumulates in fp32, nothing is approximated.
+
+Every query row runs the same sequence of operations on the same page
+blocks whatever Tq is (a static loop over the rows), so a row of a
+Tq = k + 1 verify launch equals the Tq = 1 decode launch at that
+position bit for bit: later keys are masked to an exact zero weight.
+
+On a backend without Mosaic the same kernel runs in the Pallas
+interpreter.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from deepspeed_tpu.ops.transformer.flash_attention import NEG_INF, _on_tpu
+
+LANE = 128
+# keys per compute block: one lane tile of scores
+_BLOCK_KEYS = 128
+_VMEM_LIMIT = 32 * 1024 * 1024
+
+
+def padded_lanes(width):
+    """`width` (H*D) rounded up to a whole number of lane tiles: the
+    row of a page pool."""
+    return -(-int(width) // LANE) * LANE
+
+
+def _kernel(li_ref, tables_ref, lens_ref, qpos_ref, q_ref, k_hbm, v_hbm,
+            o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *, n_head,
+            head_dim, sm_scale, precision):
+    s = pl.program_id(0)
+    tq, lanes = q_ref.shape[1:]
+    hp = acc_ref.shape[1]
+    _, npb, page, _ = kbuf.shape
+    bk = npb * page
+    length = lens_ref[s]
+    n_pages = (length + page - 1) // page
+    n_blocks = (n_pages + npb - 1) // npb
+    li = li_ref[0]
+
+    def copies(blk, slot, i):
+        phys = tables_ref[s, blk * npb + i]
+        return (pltpu.make_async_copy(k_hbm.at[li, phys], kbuf.at[slot, i],
+                                      sem.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[li, phys], vbuf.at[slot, i],
+                                      sem.at[1, slot]))
+
+    def for_pages_of(blk, slot, what):
+        def one(i, carry):
+            for copy in copies(blk, slot, i):
+                what(copy)
+            return carry
+        jax.lax.fori_loop(0, jnp.minimum(n_pages - blk * npb, npb), one, 0)
+
+    @pl.when(length == 0)
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    @pl.when(length > 0)
+    def _():
+        for_pages_of(0, 0, lambda copy: copy.start())
+        head = jax.lax.broadcasted_iota(jnp.int32, (hp, lanes), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (hp, lanes), 1)
+        mine = (lane >= head * head_dim) & (lane < (head + 1) * head_dim) \
+            & (head < n_head)
+        qbd = [jnp.where(mine, q_ref[0, r:r + 1, :], 0.0).astype(kbuf.dtype)
+               for r in range(tq)]
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+        def block(blk, carry):
+            slot = blk % 2
+
+            @pl.when(blk + 1 < n_blocks)
+            def _():
+                for_pages_of(blk + 1, 1 - slot, lambda copy: copy.start())
+
+            for_pages_of(blk, slot, lambda copy: copy.wait())
+            k = kbuf[slot].reshape(bk, lanes)
+            v = vbuf[slot].reshape(bk, lanes)
+            # rows past the slot's length (the tail of its last page,
+            # pages of this block that were not copied) hold anything
+            row = blk * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+            v = jnp.where(row < length, v, jnp.zeros((), v.dtype))
+            kpos = blk * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+            for r in range(tq):
+                scores = jax.lax.dot_general(
+                    qbd[r], k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                    precision=precision) * sm_scale
+                seen = (kpos <= qpos_ref[s, r]) & (kpos < length)
+                scores = jnp.where(seen, scores, NEG_INF)
+                m_prev = m_ref[r]
+                m_new = jnp.maximum(
+                    m_prev, jnp.max(scores, axis=1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.exp(scores - m_new[:, :1])
+                l_ref[r] = alpha * l_ref[r] + \
+                    jnp.sum(p, axis=1, keepdims=True)
+                m_ref[r] = m_new
+                acc_ref[r] = alpha[:, :1] * acc_ref[r] + jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32, precision=precision)
+            return carry
+
+        jax.lax.fori_loop(0, n_blocks, block, 0)
+        for r in range(tq):
+            heads = jnp.where(mine, acc_ref[r] / l_ref[r][:, :1], 0.0)
+            o_ref[0, r:r + 1, :] = jnp.sum(heads, axis=0, keepdims=True)
+
+
+def paged_decode_attention(q, k_pool, v_pool, li, tables, q_pos, lens,
+                           n_head):
+    """Causal attention of q [B, Tq, H*D] (Tq a few rows) against layer
+    `li` of the page pools [L, P, page, lanes], through the page tables
+    [B, max_pages]. Row r of slot b sits at absolute position
+    q_pos[b, r] and sees keys at positions <= it and < lens[b], the
+    slot's live length (0: the slot is not live; it returns zeros and
+    reads nothing). Returns [B, Tq, H*D] in q's dtype. Rows of the
+    pools at or past a slot's length may hold anything finite or not:
+    they contribute exactly nothing."""
+    b, tq, c = q.shape
+    _, _, page, lanes = k_pool.shape
+    if lanes != padded_lanes(c) or v_pool.shape != k_pool.shape:
+        raise ValueError(
+            f"pools {k_pool.shape} / {v_pool.shape} do not hold rows of "
+            f"{c} lanes padded to {padded_lanes(c)}")
+    interpret = not _on_tpu()
+    dtype = k_pool.dtype
+    sublanes = 8 * 4 // dtype.itemsize
+    if not interpret and page % sublanes:
+        raise ValueError(
+            f"a page of {page} tokens is not a whole number of the chip's "
+            f"{sublanes}-row tiles of {dtype}: a page cannot be copied "
+            "alone (inference.kv_cache.page_size)")
+    head_dim = c // n_head
+    hp = -(-n_head // 16) * 16
+    npb = max(1, _BLOCK_KEYS // page)
+    kernel = functools.partial(
+        _kernel, n_head=n_head, head_dim=head_dim,
+        sm_scale=1.0 / np.sqrt(head_dim),
+        precision=jax.lax.Precision.HIGHEST if dtype == jnp.float32
+        else None)
+    row = lambda s, *_: (s, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, tq, lanes), row),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, tq, lanes), row),
+        scratch_shapes=[
+            pltpu.VMEM((2, npb, page, lanes), dtype),
+            pltpu.VMEM((2, npb, page, lanes), dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((tq, hp, LANE), jnp.float32),
+            pltpu.VMEM((tq, hp, LANE), jnp.float32),
+            pltpu.VMEM((tq, hp, lanes), jnp.float32),
+        ])
+    q32 = jnp.pad(q, ((0, 0), (0, 0), (0, lanes - c))).astype(jnp.float32)
+    out = pl.pallas_call(
+        kernel,
+        name="paged_decode_attention",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, tq, lanes), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+    )(jnp.reshape(li, (1,)).astype(jnp.int32), tables.astype(jnp.int32),
+      lens.astype(jnp.int32), q_pos.astype(jnp.int32), q32, k_pool, v_pool)
+    return out[..., :c].astype(q.dtype)

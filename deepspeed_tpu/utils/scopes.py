@@ -6,8 +6,9 @@ Time in SCOPE_LAYERS outside every inner region is the layer scan
 itself: the loop and the slicing of each layer's weights out of the
 stacked tree. The model's cache rides in the scan's carry whole
 (`engine.scan_layers`) and is touched only in the regions named for it:
-SCOPE_KV_WRITE and SCOPE_KV_GATHER for the K/V page pools of a paged
-model, SCOPE_STATE_RESET, SCOPE_RETENTION_CHUNK and SCOPE_STATE_UPDATE
+SCOPE_KV_WRITE, SCOPE_KV_GATHER and, in the programs that attend
+through the decode kernel, SCOPE_ATTN for the K/V page pools of a
+paged model, SCOPE_STATE_RESET, SCOPE_RETENTION_CHUNK and SCOPE_STATE_UPDATE
 for the state of a recurrent one. A cache-sized copy showing up under
 SCOPE_LAYERS alone is a regression.
 
@@ -21,8 +22,11 @@ SCOPE_LAYERS = "layers"            # round the lax.scan call, nothing else
 SCOPE_ATTN_QKV = "attn_qkv"        # inside a layer: the norm + the q/k/v
 #                                    (and gate) projections, q/k-norm, rotary
 SCOPE_KV_WRITE = "kv_write"        # the chunk's K/V into the page pool
-SCOPE_KV_GATHER = "kv_gather"      # the page window through the tables
-SCOPE_ATTN = "attn"                # paged_attention
+SCOPE_KV_GATHER = "kv_gather"      # a prefill chunk's page window through
+#                                    the tables (decode gathers nothing)
+SCOPE_ATTN = "attn"                # a few rows a slot: the kernel that reads
+#                                    the pages where they lie; a prefill
+#                                    chunk: paged_attention on its window
 SCOPE_ATTN_OUT = "attn_out"        # output projection + residual
 SCOPE_MLP = "mlp"                  # norm, feed-forward, residual
 SCOPE_HEAD = "head"                # final norm + output head (tied to the

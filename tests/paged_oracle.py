@@ -3,13 +3,15 @@ ride in the layer scan's carry (ISSUE 25): an oracle that never carries
 anything, and a reader of the programs' jaxprs.
 
 The oracle is the layer stack as it was written before: a Python loop
-over the layers, each with its OWN page pool in the old five-dimensional
-shape ([P, page, H, D]), written with `.at[phys, off].set` and read with
-`pool[tables]`. Everything between the write and the read is the
-program's own (`paged_attention`, `_ln_apply`, `_dense_apply`), so a
-difference is the carry's, the scatter's or the gather's. One jitted
-layer is called n_layer times: the same compiled code for every layer,
-as in a scan's body."""
+over the layers, each with its OWN page pool ([P, page, lanes], the
+engine's row of a token), written with `.at[phys, off].set`.
+Everything between the write and the block's output is the program's
+own (`_ln_apply`, `_dense_apply`, and the program's own attention
+entry: for a few query rows a slot `paged_decode_attention` on the
+layer's pool as a one-layer pool, for a prefill chunk the gathered
+window and `paged_attention`), so a difference is the carry's or the
+scatter's. One jitted layer is called n_layer times: the same compiled
+code for every layer, as in a scan's body."""
 
 import contextlib
 import functools
@@ -21,8 +23,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.engine import (_dense_apply, _ln_apply,
-                                            paged_attention)
+from deepspeed_tpu.inference.engine import (DECODE_ROWS_MAX, _dense_apply,
+                                            _ln_apply, paged_attention)
+from deepspeed_tpu.ops.transformer.paged_decode_attention import \
+    paged_decode_attention
 from deepspeed_tpu.models.gpt2 import stacked_block_params
 
 
@@ -32,23 +36,27 @@ def _oracle_block(cfg, lp, hidden, kl, vl, tables, positions, valid,
                   kv_limit, page_size, quant_block):
     b, t, c = hidden.shape
     h, d = cfg.n_head, cfg.head_dim
+    lanes = kl.shape[-1]
     x = _ln_apply(cfg, lp["ln_1"], hidden).astype(cfg.dtype)
     qkv = _dense_apply(cfg, lp["c_attn"], x, quant_block)
     q, k, v = jnp.split(qkv, 3, axis=-1)
-    q = q.reshape(b, t, h, d)
-    k = k.reshape(b, t, h, d)
-    v = v.reshape(b, t, h, d)
     pidx = positions // page_size
     off = (positions % page_size).reshape(-1)
     phys = jnp.take_along_axis(tables, pidx, axis=1)
     phys = jnp.where(valid, phys, 0).reshape(-1)
-    kl = kl.at[phys, off].set(k.reshape(b * t, h, d))
-    vl = vl.at[phys, off].set(v.reshape(b * t, h, d))
-    kc = kl[tables].reshape(b, -1, h, d)
-    vc = vl[tables].reshape(b, -1, h, d)
-    attn = paged_attention(q, kc, vc, positions, kv_limit)
-    attn = _dense_apply(cfg, lp["c_proj"], attn.reshape(b, t, c),
-                        quant_block)
+    row = lambda x: jnp.pad(x.reshape(b * t, c), ((0, 0), (0, lanes - c)))
+    kl = kl.at[phys, off].set(row(k))
+    vl = vl.at[phys, off].set(row(v))
+    if t <= DECODE_ROWS_MAX:
+        live_len = jnp.where(valid.any(axis=1), kv_limit + 1, 0)
+        attn = paged_decode_attention(q, kl[None], vl[None], 0, tables,
+                                      positions, live_len, h)
+    else:
+        kc = kl[tables][..., :c].reshape(b, -1, h, d)
+        vc = vl[tables][..., :c].reshape(b, -1, h, d)
+        attn = paged_attention(q.reshape(b, t, h, d), kc, vc, positions,
+                               kv_limit).reshape(b, t, c)
+    attn = _dense_apply(cfg, lp["c_proj"], attn, quant_block)
     hidden = hidden + attn
     y = _ln_apply(cfg, lp["ln_2"], hidden).astype(cfg.dtype)
     y = _dense_apply(cfg, lp["c_fc"], y, quant_block)
@@ -60,7 +68,7 @@ def _oracle_block(cfg, lp, hidden, kl, vl, tables, positions, valid,
 def oracle_forward(cfg, params, tokens, positions, valid, kv_limit,
                    tables, k_pool, v_pool, page_size, quant_block):
     """tokens/positions/valid [B, T], kv_limit [B], tables [B, pages];
-    the pools as the engine holds them ([L, P, page, H*D], numpy).
+    the pools as the engine holds them ([L, P, page, lanes], numpy).
     Returns (logits of ln_f + tied head [B, T, V], k_pool, v_pool),
     the pools again in the engine's shape."""
     dt = cfg.dtype
@@ -68,17 +76,14 @@ def oracle_forward(cfg, params, tokens, positions, valid, kv_limit,
     posc = jnp.clip(positions, 0, cfg.n_positions - 1)
     hidden = wte[tokens].astype(dt) + wpe[posc].astype(dt)
     stacked = stacked_block_params(params)
-    n_layer, pages, page, c = k_pool.shape
-    per_layer = (pages, page, cfg.n_head, cfg.head_dim)
     ks, vs = [], []
-    for li in range(n_layer):
+    for li in range(k_pool.shape[0]):
         lp = jax.tree_util.tree_map(lambda x: x[li], stacked)
         hidden, kl, vl = _oracle_block(
-            cfg, lp, hidden, k_pool[li].reshape(per_layer),
-            v_pool[li].reshape(per_layer), tables, positions, valid,
-            kv_limit, page_size=page_size, quant_block=quant_block)
-        ks.append(np.asarray(kl).reshape(pages, page, c))
-        vs.append(np.asarray(vl).reshape(pages, page, c))
+            cfg, lp, hidden, k_pool[li], v_pool[li], tables, positions,
+            valid, kv_limit, page_size=page_size, quant_block=quant_block)
+        ks.append(np.asarray(kl))
+        vs.append(np.asarray(vl))
     final = _ln_apply(cfg, params["ln_f"], hidden)
     logits = jnp.einsum("btc,vc->btv", final.astype(dt), wte.astype(dt))
     return np.asarray(logits), np.stack(ks), np.stack(vs)

@@ -1,8 +1,9 @@
 """The serving programs' layer scan compiled for a v5e that is
 described, not attached (`jax.experimental.topologies`), at the shapes
 of the benchmark's serving cell (GPT-2 1.5B, 1,025 pages of 16, 16
-slots): what decides whether the page pools are copied is the compiled
-program, and this is the chip's compiler at no chip time (ISSUE 25).
+slots): what decides whether the page pools are copied, and whether
+Mosaic takes the decode kernel's page copies, is the compiled program,
+and this is the chip's compiler at no chip time (ISSUEs 25 and 27).
 Nothing runs; a compile that passes is not a chip run.
 
 Every test of the suite that describes a topology lives in THIS file,
@@ -64,10 +65,16 @@ def cell(one_chip):
     return cfg, jax.tree_util.tree_map(place, params), place(pool), place
 
 
-@pytest.mark.parametrize("rows, tokens", [(SLOTS, 1), (1, CHUNK)],
-                         ids=["decode", "prefill"])
-def test_layer_scan_holds_no_copy_of_a_pool_on_a_v5e(cell, rows, tokens):
+@pytest.mark.parametrize("rows, tokens",
+                         [(SLOTS, 1), (1, CHUNK), (SLOTS, 4)],
+                         ids=["decode", "prefill", "verify"])
+def test_layer_scan_holds_no_copy_of_a_pool_on_a_v5e(cell, rows, tokens,
+                                                     monkeypatch):
     cfg, params, pool, place = cell
+    # the kernel's own backend probe answers "TPU": here it sees the
+    # CPU and would hand the chip's compiler the interpreter's XLA
+    from deepspeed_tpu.ops.transformer import paged_decode_attention
+    monkeypatch.setattr(paged_decode_attention, "_on_tpu", lambda: True)
 
     def layers(params, hidden, k_pool, v_pool, tables, positions, valid,
                kv_limit):
@@ -83,18 +90,29 @@ def test_layer_scan_holds_no_copy_of_a_pool_on_a_v5e(cell, rows, tokens):
         sds((rows,), jnp.int32)).compile()
     pool_bytes = int(np.prod(pool.shape)) * 2
     memory = compiled.memory_analysis()
-    # the parent held two whole pools here (6.7 GB in decode); what is
-    # left is the gathered window and its head-split copy (one layer's
-    # worth: 16 slots x 64 pages are 1,024 of the 1,025 pages)
-    assert memory.temp_size_in_bytes < pool_bytes // 4
+    text = compiled.as_text()
     assert memory.alias_size_in_bytes >= 2 * pool_bytes
     whole = ",".join(map(str, pool.shape))
     layer = ",".join(map(str, pool.shape[1:]))
     moved = re.findall(
         rf"= \w+\[(?:{whole}|1,{layer}|{layer})\]\S* "
-        r"(copy|dynamic-slice|dynamic-update-slice|transpose)\(",
-        compiled.as_text())
+        r"(copy|dynamic-slice|dynamic-update-slice|transpose)\(", text)
     assert moved == []
+    window_bytes = SLOTS * SEQ * pool.shape[-1] * 2
+    if tokens == CHUNK:
+        # prefill keeps the gathered window of its one slot and the
+        # head-split copy of it
+        assert memory.temp_size_in_bytes < pool_bytes // 4
+    else:
+        # a few rows a slot: the kernel reads the pages where they
+        # lie. No gathered window (16 slots x 1,024 keys, in any
+        # layout) is left in the program, and what the program holds
+        # beside its arguments stays under one such window (52 MB)
+        assert re.findall(r'custom_call_target="tpu_custom_call"', text)
+        assert "paged_decode_attention" in text
+        assert memory.temp_size_in_bytes < window_bytes
+        assert re.findall(rf"\w+\[{SLOTS},(?:{SEQ}|{SEQ // PAGE},{PAGE}),"
+                          rf"[^\]]*\]", text) == []
 
 
 # ----------------------------------------------------------------------

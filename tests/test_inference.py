@@ -1,12 +1,12 @@
 """Inference/serving engine tests (ISSUE 12).
 
 Covers:
-  * decode-step logits BIT-exact vs the training-path forward on the
-    same prefix (fp32, small-contraction regime) and to float roundoff
-    at larger sizes (the PR-9 precedent: cross-program reduction
-    orders preclude literal bit equality once XLA switches matmul
-    kernels at different static shapes);
-  * paged attention vs a contiguous-cache dense_attention reference;
+  * decode-step logits against the float32 training-path forward on
+    the same prefix, within a written tolerance (float roundoff: the
+    decode kernel sums the same products in another order; ROADMAP
+    Design item 2);
+  * paged attention (prefill's window path) vs a contiguous-cache
+    dense_attention reference;
   * page alloc/free accounting vs independent byte arithmetic, and
     the `kv_cache` ledger category == pool bytes invariant (the PR-9
     ledger window-bound pattern);
@@ -63,12 +63,18 @@ def _train_logits(model, params, tokens):
 # ----------------------------------------------------------------------
 # decode-logits parity vs the training forward
 # ----------------------------------------------------------------------
-def test_decode_logits_bitexact_vs_training_forward(setup):
-    """fp32, total length <= 12: the decode program and the training
-    forward run in the same XLA-CPU kernel regime, so the logits must
-    be LITERALLY bit-identical at every generated position — any math
-    drift between the serving forward and the training forward shows
-    up here as a hard failure."""
+# what float32 roundoff may put between two programs that sum the same
+# products in different orders (observed 2e-7 to 5e-7 at these sizes;
+# a wrong mask, a stale page or a dropped key is 1e-2 and more)
+LOGITS_ATOL = 3e-6
+
+
+def test_decode_logits_match_float32_training_forward(setup):
+    """fp32, total length <= 12: the decode program (the page-walking
+    kernel) against the training forward on the same prefix, at every
+    generated position, within float32 roundoff, and with the same
+    greedy token. Any drift of the serving math from the training
+    math shows up here as a hard failure."""
     cfg, model, params, engine = setup
     engine.reset()
     r = np.random.RandomState(1)
@@ -78,8 +84,9 @@ def test_decode_logits_bitexact_vs_training_forward(setup):
     for step in range(5):
         logits = np.asarray(engine.decode_once()[0])
         ref = _train_logits(model, params, cur)
-        assert np.array_equal(logits, ref), \
-            (step, np.abs(logits - ref).max())
+        np.testing.assert_allclose(logits, ref, atol=LOGITS_ATOL, rtol=0,
+                                   err_msg=f"step {step}")
+        assert logits.argmax() == ref.argmax()
         cur.append(int(logits.argmax()))
     engine.reset()
 
@@ -98,21 +105,23 @@ def test_decode_logits_roundoff_parity_long(setup):
     for _ in range(20):
         logits = np.asarray(engine.decode_once()[0])
         ref = _train_logits(model, params, cur)
-        np.testing.assert_allclose(logits, ref, atol=3e-6, rtol=0)
+        np.testing.assert_allclose(logits, ref, atol=LOGITS_ATOL, rtol=0)
         assert logits.argmax() == ref.argmax()
         cur.append(int(logits.argmax()))
     engine.reset()
 
 
 def test_paged_attention_matches_contiguous_reference():
-    """Unit: paged_attention over a zero-padded page window ==
-    dense_attention over the contiguous cache (bit-exact in the
-    small-kernel regime, float roundoff beyond)."""
+    """Unit: paged_attention (the window path prefill keeps) over a
+    zero-padded page window against dense_attention over the
+    contiguous cache, within float32 roundoff: the padded reduction
+    sums exact zeros for the masked keys, in whatever blocking the
+    backend picks for the longer row."""
     from deepspeed_tpu.inference.engine import paged_attention
     from deepspeed_tpu.ops.transformer.flash_attention import \
         dense_attention
     r = np.random.RandomState(3)
-    for t, exact in ((10, True), (48, False)):
+    for t in (10, 48):
         q = r.randn(1, t, 4, 16).astype(np.float32)
         k = r.randn(1, t, 4, 16).astype(np.float32)
         v = r.randn(1, t, 4, 16).astype(np.float32)
@@ -128,10 +137,7 @@ def test_paged_attention_matches_contiguous_reference():
             q, jnp.asarray(kc), jnp.asarray(vc),
             np.arange(t, dtype=np.int32)[None, :],
             np.asarray([t - 1], np.int32)))
-        if exact:
-            assert np.array_equal(ref, got), np.abs(ref - got).max()
-        else:
-            np.testing.assert_allclose(ref, got, atol=2e-6, rtol=0)
+        np.testing.assert_allclose(ref, got, atol=2e-6, rtol=0)
 
 
 # ----------------------------------------------------------------------
@@ -144,8 +150,10 @@ def test_page_alloc_free_accounting_vs_byte_arithmetic():
                          num_pages=32, page_size=4, max_slots=4,
                          max_pages_per_slot=8, dtype=np.float32,
                          ledger=ledger)
-    # independent arithmetic: one page = 2 (K+V) * L * page * H * D * 4B
-    page_bytes = 2 * 2 * 4 * 4 * 16 * 4
+    # independent arithmetic: one page = 2 (K+V) * L * page * row * 4B,
+    # the row being H * D = 64 values on the one lane tile they take
+    assert cache.lanes == 128 and cache.pool_shape(2) == (2, 32, 4, 128)
+    page_bytes = 2 * 2 * 4 * cache.lanes * 4
     assert cache.page_bytes == page_bytes
     assert cache.pool_bytes == 32 * page_bytes
 
@@ -651,7 +659,8 @@ def test_serving_monitor_events_schema(tmp_path):
     dec = kinds["decode_batch"][0]
     for key in ("iterations", "active_slots", "prefilling_slots",
                 "queue_depth", "window_tokens", "tokens_per_sec",
-                "kv_pages_in_use", "kv_pages_free"):
+                "kv_pages_in_use", "kv_pages_free", "kv_pages_attended",
+                "kv_pages_attended_share"):
         assert key in dec, key
     # the memory event's kv_cache category equals the pool bytes
     mem = kinds["memory"][-1]
@@ -809,7 +818,8 @@ def test_serving_slo_jsonl_schema_roundtrip(obs_setup):
     for key in ("window_ms", "window_tokens", "tokens_per_sec",
                 "active_slots", "prefilling_slots", "queue_depth",
                 "kv_pages_in_use", "kv_pages_free",
-                "kv_page_utilization", "queue_wait_share",
+                "kv_page_utilization", "kv_pages_attended",
+                "kv_pages_attended_share", "queue_wait_share",
                 "ttft_ms", "token_ms", "queue_ms",
                 "ttft_p50_ms", "ttft_p99_ms", "token_p50_ms",
                 "token_p99_ms", "queue_p50_ms", "queue_p99_ms",
@@ -1014,6 +1024,33 @@ loop.run()
     assert "inference.kv_cache.num_pages" in hints
 
 
+def test_fence_rows_report_the_pages_the_decode_kernel_walks(obs_setup):
+    """`kv_pages_attended` on every `decode_batch` and `serving_slo`
+    row: ceil((pos + 1) / page) summed over the slots still live at the
+    fence, host arithmetic on what the fence fetched, and its share of
+    max_slots x max_pages_per_slot (the window the gathered path
+    attended to whatever was live)."""
+    cfg, engine, _, events, _ = obs_setup
+    cache = engine.cache
+    whole = cache.max_slots * cache.max_pages_per_slot
+    active = np.asarray([True, False, True, True])
+    pos = np.asarray([0, 50, 3, 4])        # pages of 4: 1 + 1 + 2
+    assert cache.attended(active, pos) == {
+        "kv_pages_attended": 4,
+        "kv_pages_attended_share": round(4 / whole, 4)}
+    assert cache.attended(~active, pos)["kv_pages_attended"] == 13
+    rows = {kind: [e for e in events if e["kind"] == kind]
+            for kind in ("decode_batch", "serving_slo")}
+    assert len(rows["decode_batch"]) == len(rows["serving_slo"]) > 0
+    for dec, slo in zip(rows["decode_batch"], rows["serving_slo"]):
+        assert dec["kv_pages_attended"] == slo["kv_pages_attended"]
+        assert 0 <= dec["kv_pages_attended"] <= dec["kv_pages_in_use"]
+        assert dec["kv_pages_attended_share"] == \
+            slo["kv_pages_attended_share"] == \
+            round(dec["kv_pages_attended"] / whole, 4)
+    assert any(e["kv_pages_attended"] for e in rows["decode_batch"])
+
+
 def test_kv_page_utilization_ledger_vs_cache_twins(obs_setup):
     """The tracker reports KV-page utilization as derived from the
     memory ledger's `kv_cache` category (the cache's
@@ -1081,7 +1118,7 @@ def test_pools_are_scan_carry_and_never_a_temporary(carried, program):
     cfg, _, engine, jaxprs, temp = carried
     pool = engine._state["k_pool"]
     draft_pool = engine._spec_state["dk_pool"]
-    assert pool.shape == (3, 600, 4, cfg.n_head * cfg.head_dim)
+    assert pool.shape == (3, 600, 4, engine.cache.lanes)
     assert draft_pool.shape == (2,) + pool.shape[1:]
     carried_pools, elsewhere = pools_in_scans(
         jaxprs[program], {pool.shape, draft_pool.shape})
@@ -1095,7 +1132,7 @@ def test_pools_are_scan_carry_and_never_a_temporary(carried, program):
 def test_programs_bitexact_vs_per_layer_pool_oracle(carried, program):
     """Prompts of several chunks into two of four slots, then several
     decode steps, against the oracle that loops over the layers in
-    Python with one five-dimensional pool a layer: logits and BOTH
+    Python with one pool a layer: logits and BOTH
     pools equal bit for bit after every launch, scratch page 0 (the
     inactive slots' and the pad rows' writes) included."""
     from tests.paged_oracle import (assert_pools_equal, oracle_forward,

@@ -105,9 +105,11 @@ def test_rollback_ledger_accounting_with_draft_category():
     count in each category's own page-byte unit."""
     ledger = MemoryLedger()
     cache = _cache(ledger=ledger, draft_layers=1)
-    # independent arithmetic: flagship 2 layers, draft 1 layer
-    page_bytes = 2 * 2 * 4 * 4 * 16 * 4
-    draft_page_bytes = 2 * 1 * 4 * 4 * 16 * 4
+    # independent arithmetic: flagship 2 layers, draft 1 layer; a row
+    # of 4 heads x 16 takes one 128-lane tile
+    assert cache.lanes == 128
+    page_bytes = 2 * 2 * 4 * 128 * 4
+    draft_page_bytes = 2 * 1 * 4 * 128 * 4
     assert cache.page_bytes == page_bytes
     assert cache.draft_page_bytes == draft_page_bytes
 

@@ -51,7 +51,10 @@ def regions(name_stack):
 # the vocabulary in the compiled programs
 # ----------------------------------------------------------------------
 @pytest.mark.parametrize("program, expected", [
-    ("jit_decode_fn", engine_mod.SCOPES),
+    # decode gathers no window: its attention is the kernel that reads
+    # the pages where they lie, under `attn` (ISSUE 27)
+    ("jit_decode_fn", tuple(s for s in engine_mod.SCOPES
+                            if s != engine_mod.SCOPE_KV_GATHER)),
     # prefill stops at the pools: no head, no sampling, no slot state
     ("jit_prefill_fn", (engine_mod.SCOPE_EMBED, engine_mod.SCOPE_LAYERS) +
      engine_mod.SCOPES_IN_LAYER),

@@ -537,7 +537,7 @@ def test_spec_programs_bitexact_vs_per_layer_pool_oracle(base, program):
     the pools (`scan_layers`): prompts of several chunks into two of
     four slots, one round of k draft steps and its verify, against the
     oracle of tests/paged_oracle.py (a Python loop over layers, one
-    five-dimensional pool each). The draft's logits and both of its
+    pool each). The draft's logits and both of its
     pools, and the flagship's pools after verify, are equal bit for
     bit, scratch page 0 included; verify keeps its logits to itself,
     so what it emitted is held against the oracle's greedy choice."""
