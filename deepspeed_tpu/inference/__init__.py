@@ -13,8 +13,9 @@
     iteration-level continuous batching with chunked prefill
     interleaving and EOS/max-tokens eviction.
   * InferenceConfig (config.py): the `inference` config block.
-  * int8 weight-only quantization (quant.py): per-block-scale
-    kernels quantized once at load, dequant-in-matmul epilogue.
+  * int8 weight-only quantization (engine.quantize_param_tree over
+    ops/transformer/quantized_matmul.py): the projection kernels a
+    model names, quantized once at load; dequant-in-matmul epilogue.
   * serving observability (monitor/serving.py, ISSUE 14): with a
     `monitor` block enabled, a ServingTracker stamps each request's
     lifecycle at the serving fences — per-slot Perfetto timeline,

@@ -15,49 +15,40 @@ construction — no trace-on-first-request latency spike):
     decode iterations back-to-back and reads NOTHING until the serving
     fence (the PR-2 async-dispatch convention applied to serving).
 
-Which block runs and what the cache is come from the model config:
-its `serving(inference_config, max_seq_len)` hands the engine the
-adapter (cache manager, cache arrays, embedding, layer stack and head;
-the two classes below), and a config without the method is of the
-GPT-2 family:
+The model supplies the block, the engine supplies the cache (the seam;
+docs/inference.md has it at length):
 
-  * the GPT-2 family (`PagedServing`) keeps a paged K/V cache: prefill
-    writes each layer's K/V into the request's pages and attends over
-    everything cached so far; decode is paged attention over each
-    slot's cached prefix, logits through the tied head. The forward
-    math deliberately mirrors the training path operation for
-    operation (the same flax submodules applied to the same param
-    leaves, the same fp32 softmax with -1e30 masking); decode's
-    attention reads the pages where they lie through one kernel
-    (`ops/transformer/paged_decode_attention.py`: the same operands,
-    every sum in fp32, in its own order), so decode logits agree with
-    the training forward on the same prefix to float roundoff in fp32
-    — parity is pinned by tests/test_inference.py, the serving bench
-    leg, and the training/serving drift that convention prevents is
-    the point.
-    Weight-only int8 serving (`inference.weight_bits: 8`) quantises
-    the projection kernels once at load (inference/quant.py) and the
-    dense application below switches onto the dequant-in-matmul
-    epilogue; speculative decoding (inference/speculative.py) adds
-    three programs over the same pools.
-  * a model of recurrent state (`RecurrentServing` round the model's
-    module; `models/brumby.py`) keeps a fixed float32 matrix and
-    normaliser per slot, layer and key/value head
-    (`kv_cache.RecurrentStateCache`). Prefill advances
-    one slot's state by a chunk (`ops/retention::retention_chunked`,
-    from zero if the chunk is the request's first); decode advances
-    every live slot's by one token and reads it in the same region
-    (`retention_step`). The block is the model's own, called with the
-    retention call as its mixer. No speculative decoding (state cannot
-    be rewound yet) and no int8 weights.
+  * a MODEL MODULE (`models/gpt2.py`, `models/brumby.py`) holds the
+    model's math as plain functions: `embed(mc, params, tokens,
+    positions)`, ONE `block(mc, lp, hidden, positions, mixer, cache)
+    -> (hidden, cache)`, `head(mc, params, hidden)`, `layers(params)`
+    (the stacked [n_layer, ...] weights `block` takes one layer of),
+    `QUANT_KERNEL_MODULES` (the projections an int8 load may quantise;
+    () refuses it) and, where `truncate:N` drafts are served,
+    `first_layers(mc, params, n)`. The block computes its own
+    projections under SCOPE_ATTN_QKV / SCOPE_ATTN_OUT / SCOPE_MLP and
+    calls `mixer` exactly once with what it projected; it knows nothing
+    of pages, tables, slots or state arrays. The MODEL CONFIG names the
+    kind of cache the layers keep (`cache_kind`), carries the geometry
+    that kind's manager needs and points at the module
+    (`serving_module`). `models/` and this package import each other
+    nowhere;
+  * the ENGINE owns the kinds of cache (`PagedKind`, `RecurrentKind`)
+    and nothing of any model: per kind the manager
+    (inference/kv_cache.py), the fresh device arrays and their keys in
+    the engine's state, and the mixers;
+  * ONE adapter (`Serving`) composes model x kind for the two programs
+    here and the three of inference/speculative.py, through the one
+    `scan_layers`.
 
 Everything else (slot state, scheduler, sampling, bookkeeping, the
 fence) is shared.
 """
 
+import dataclasses
+import functools
 import time
 
-import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -65,8 +56,6 @@ import numpy as np
 from deepspeed_tpu.inference.config import InferenceConfig
 from deepspeed_tpu.inference.kv_cache import (PagedKVCache,
                                               RecurrentStateCache)
-from deepspeed_tpu.inference.quant import (KERNEL_SCALE, int8_matmul,
-                                           quantize_param_tree)
 from deepspeed_tpu.monitor import DeepSpeedMonitorConfig, Monitor
 from deepspeed_tpu.monitor import memory as memory_mod
 from deepspeed_tpu.monitor import programs
@@ -74,6 +63,8 @@ from deepspeed_tpu.monitor.trace import profiler_span
 from deepspeed_tpu.ops.retention import retention_chunked, retention_step
 from deepspeed_tpu.ops.transformer.paged_decode_attention import \
     paged_decode_attention
+from deepspeed_tpu.ops.transformer.quantized_matmul import (
+    KERNEL_SCALE, quantize_kernel_int8_np)
 from deepspeed_tpu.utils.logging import logger
 
 # the regions of the serving programs: see utils/scopes.py
@@ -138,35 +129,11 @@ def compile_registered(fn, args, donate_argnums):
     return compiled
 
 
-# ----------------------------------------------------------------------
-# training-math twins: the same flax modules the training forward runs,
-# applied to extracted param leaves (bit-exact by construction)
-# ----------------------------------------------------------------------
-def _ln_apply(cfg, p, x):
-    """nn.LayerNorm exactly as GPT2Block builds it (fp32 stats)."""
-    return nn.LayerNorm(
-        epsilon=cfg.layer_norm_epsilon, dtype=jnp.float32,
-        param_dtype=cfg.param_dtype).apply({"params": p}, x)
-
-
-def _dense_apply(cfg, p, x, quant_block):
-    """nn.Dense as GPT2Block builds it — or, when the leaf carries a
-    KERNEL_SCALE, the int8 dequant-in-matmul epilogue."""
-    if KERNEL_SCALE in p:
-        y = int8_matmul(x.astype(cfg.dtype), p["kernel"],
-                        p[KERNEL_SCALE], quant_block, cfg.dtype)
-        return y + p["bias"].astype(cfg.dtype)
-    return nn.Dense(
-        p["kernel"].shape[-1], dtype=cfg.dtype,
-        param_dtype=cfg.param_dtype).apply(
-            {"params": {"kernel": p["kernel"], "bias": p["bias"]}}, x)
-
-
 @jax.named_scope(SCOPE_ATTN)
 def paged_attention(q, kc, vc, q_pos, kv_limit):
     """A prefill chunk's attention (the programs with a few query
     rows a slot attend through `paged_decode_attention` instead; see
-    `_block_paged`). Causal attention of q [B, Tq, H, D] against a
+    `PagedKind.mixer`). Causal attention of q [B, Tq, H, D] against a
     gathered page window kc/vc [B, Tk, H, D], phrased like the
     training path's `dense_attention` (same einsum strings, fp32
     softmax, -1e30 where-masking): key positions are their indices,
@@ -193,7 +160,7 @@ def paged_attention(q, kc, vc, q_pos, kv_limit):
     return out.transpose(0, 2, 1, 3)
 
 
-# query rows per slot up to which `_block_paged` attends through the
+# query rows per slot up to which the paged mixer attends through the
 # decode kernel: decode and draft decode carry 1, speculative verify
 # k + 1. A prefill chunk carries more and keeps the gathered window:
 # one row against pages is a page walk bound by latency and bytes, a
@@ -201,68 +168,29 @@ def paged_attention(q, kc, vc, q_pos, kv_limit):
 DECODE_ROWS_MAX = 8
 
 
-def _block_paged(cfg, lp, hidden, k_pool, v_pool, li, tables, positions,
-                 valid, kv_limit, page_size, quant_block):
-    """One pre-LN transformer block (GPT2Block's unfused math, op for
-    op) over hidden [B, Tq, C]: layer `li` of the WHOLE page pools
-    (k_pool/v_pool: [L, P, page, lanes], one token's K or V on the
-    lanes, zeros from C up to the lane tile). The chunk's K/V rows are
-    scattered into the pools at (li, physical page, offset); no
-    layer's pool is ever sliced out, so the compiler updates the
-    donated pools in place. Rows with valid=False (inactive decode
-    slots, prefill pad rows) divert their writes to scratch page 0.
+def quantize_param_tree(params, block, modules):
+    """The int8 load (`inference.weight_bits: 8`): a copy of a param
+    tree with every projection kernel under a submodule named in
+    `modules` (the model's `QUANT_KERNEL_MODULES`) quantised ONCE, by
+    the shared primitive's layout (`ops/transformer/
+    quantized_matmul.py`: symmetric int8, one fp32 scale per block of
+    `block` rows of the contraction dim and output column) and a
+    KERNEL_SCALE leaf beside it; dict structure otherwise unchanged. A
+    model's dense application keys on KERNEL_SCALE's presence and
+    dequantises in the matmul (`int8_matmul`). Everything else stays in
+    the storage dtype."""
+    def walk(tree):
+        out = {}
+        for name, sub in tree.items():
+            if isinstance(sub, dict) and name in modules and "kernel" in sub:
+                q, s = quantize_kernel_int8_np(sub["kernel"], block)
+                out[name] = {**sub, "kernel": jnp.asarray(q),
+                             KERNEL_SCALE: jnp.asarray(s)}
+            else:
+                out[name] = walk(sub) if isinstance(sub, dict) else sub
+        return out
 
-    How the block attends is chosen by what it can see, Tq at trace
-    time. A few rows a slot (decode, draft decode, verify): one kernel
-    walks each live slot's page table and reads the pages where they
-    lie (`ops/transformer/paged_decode_attention.py`); a slot with no
-    valid row is not live and gets zeros. A prefill chunk: the slot's
-    window is gathered through its table row ([B, max_pages]) and
-    attended to densely (`paged_attention`)."""
-    b, t, c = hidden.shape
-    h, d = cfg.n_head, cfg.head_dim
-    lanes = k_pool.shape[-1]
-
-    with jax.named_scope(SCOPE_ATTN_QKV):
-        x = _ln_apply(cfg, lp["ln_1"], hidden).astype(cfg.dtype)
-        qkv = _dense_apply(cfg, lp["c_attn"], x, quant_block)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-
-    # write-before-read: the chunk's own keys are part of its causal
-    # window (a query attends to itself, like the training mask)
-    with jax.named_scope(SCOPE_KV_WRITE):
-        pidx = positions // page_size
-        off = positions % page_size
-        phys = jnp.take_along_axis(tables, pidx, axis=1)
-        phys = jnp.where(valid, phys, 0).reshape(-1)
-        off = off.reshape(-1)
-        row = lambda x: jnp.pad(x.reshape(b * t, c), ((0, 0), (0, lanes - c)))
-        k_pool = k_pool.at[li, phys, off].set(row(k))
-        v_pool = v_pool.at[li, phys, off].set(row(v))
-
-    if t <= DECODE_ROWS_MAX:
-        with jax.named_scope(SCOPE_ATTN):
-            live_len = jnp.where(valid.any(axis=1), kv_limit + 1, 0)
-            attn = paged_decode_attention(q, k_pool, v_pool, li, tables,
-                                          positions, live_len, h)
-    else:
-        with jax.named_scope(SCOPE_KV_GATHER):
-            kc = k_pool[li, tables][..., :c].reshape(b, -1, h, d)
-            vc = v_pool[li, tables][..., :c].reshape(b, -1, h, d)
-        attn = paged_attention(q.reshape(b, t, h, d), kc, vc, positions,
-                               kv_limit)
-    with jax.named_scope(SCOPE_ATTN_OUT):
-        attn = attn.reshape(b, t, c)
-        attn = _dense_apply(cfg, lp["c_proj"], attn, quant_block)
-        hidden = hidden + attn
-
-    with jax.named_scope(SCOPE_MLP):
-        y = _ln_apply(cfg, lp["ln_2"], hidden).astype(cfg.dtype)
-        y = _dense_apply(cfg, lp["c_fc"], y, quant_block)
-        y = nn.gelu(y, approximate=True)
-        y = _dense_apply(cfg, lp["mlp_c_proj"], y, quant_block)
-        hidden = hidden + y
-    return hidden, k_pool, v_pool
+    return walk(params)
 
 
 def scan_layers(stacked, hidden, cache, layer):
@@ -289,33 +217,18 @@ def scan_layers(stacked, hidden, cache, layer):
     return carry
 
 
-def paged_layers(cfg, params, hidden, k_pool, v_pool, tables, positions,
-                 valid, kv_limit, page_size, quant_block):
-    """`scan_layers` for the GPT-2 family: `_block_paged` on both whole
-    page pools. Returns (hidden, k_pool, v_pool)."""
-    from deepspeed_tpu.models.gpt2 import stacked_block_params
-
-    def layer(lp, li, hidden, pools):
-        hidden, k_pool, v_pool = _block_paged(
-            cfg, lp, hidden, *pools, li, tables, positions, valid,
-            kv_limit, page_size, quant_block)
-        return hidden, (k_pool, v_pool)
-
-    hidden, (k_pool, v_pool) = scan_layers(
-        stacked_block_params(params), hidden, (k_pool, v_pool), layer)
-    return hidden, k_pool, v_pool
-
-
 # ----------------------------------------------------------------------
-# what the two programs ask of a model: its cache manager, its cache
-# arrays in the engine's state, and embedding, layer stack and head
-# around them. A model config's `serving()` returns the one that serves
-# it (no such method: the GPT-2 family).
+# the two kinds of cache. Per kind: the manager, the fresh device
+# arrays and their keys in the engine's state, and the mixers a model's
+# block is handed (`mix(li, ...)`: on layer `li` of the WHOLE arrays,
+# which ride in the layer scan's carry; no layer's part is ever sliced
+# out, so the compiler updates the donated arrays in place)
 # ----------------------------------------------------------------------
-class PagedServing:
-    """The GPT-2 family over the paged K/V cache: learned positions,
-    `_block_paged`, ln_f and the head tied to the embedding."""
-    cache_keys = ("k_pool", "v_pool")
+class PagedKind:
+    """K/V page pools ([L, P, page, lanes], one token's K or V on the
+    lanes, zeros from n_head * head_dim up to the lane tile) behind
+    per-slot page tables (`kv_cache.PagedKVCache`)."""
+    keys = ("k_pool", "v_pool")
 
     def __init__(self, model_config, config, max_seq_len):
         self.mc, self.cfg = model_config, config
@@ -329,59 +242,84 @@ class PagedServing:
             max_slots=cfg.max_slots, max_pages_per_slot=self.max_pages,
             dtype=np.dtype(mc.dtype), ledger=ledger)
 
-    def fresh_cache(self, cache):
+    def fresh(self, cache):
         pool = cache.pool_shape(self.mc.n_layer)
         return {"k_pool": jnp.zeros(pool, self.mc.dtype),
                 "v_pool": jnp.zeros(pool, self.mc.dtype),
                 "tables": jnp.asarray(cache.tables)}
 
-    def embed(self, params, tokens, positions):
-        # embed_tokens' math at absolute positions
-        return params["wte"][tokens].astype(self.mc.dtype) + \
-            params["wpe"][positions].astype(self.mc.dtype)
+    def mixer(self, tables, positions, valid, kv_limit):
+        """The one mixer of all five paged programs, for rows at
+        `positions` [B, T] of slots whose pages `tables` [B, max_pages]
+        name. The rows' K/V are scattered into the pools at (li,
+        physical page, offset); rows with valid=False (inactive decode
+        slots, prefill pad rows) divert their writes to scratch page 0.
 
-    def decode_layers(self, params, hidden, state):
+        How the rows attend is chosen by what the mixer can see, T at
+        trace time. A few rows a slot (decode, draft decode, verify):
+        one kernel walks each live slot's page table and reads the
+        pages where they lie; a slot with no valid row is not live and
+        gets zeros. A prefill chunk: the slot's window is gathered
+        through its table row and attended to densely
+        (`paged_attention`)."""
+        h, d = self.mc.n_head, self.mc.head_dim
+        page_size = self.cfg.kv_page_size
+
+        def mix(li, q, k, v, pools):
+            k_pool, v_pool = pools
+            b, t, c = q.shape
+            lanes = k_pool.shape[-1]
+            # write-before-read: the chunk's own keys are part of its
+            # causal window (a query attends to itself, like the
+            # training mask)
+            with jax.named_scope(SCOPE_KV_WRITE):
+                pidx = positions // page_size
+                off = positions % page_size
+                phys = jnp.take_along_axis(tables, pidx, axis=1)
+                phys = jnp.where(valid, phys, 0).reshape(-1)
+                off = off.reshape(-1)
+                row = lambda x: jnp.pad(x.reshape(b * t, c),
+                                        ((0, 0), (0, lanes - c)))
+                k_pool = k_pool.at[li, phys, off].set(row(k))
+                v_pool = v_pool.at[li, phys, off].set(row(v))
+
+            if t <= DECODE_ROWS_MAX:
+                with jax.named_scope(SCOPE_ATTN):
+                    live_len = jnp.where(valid.any(axis=1), kv_limit + 1, 0)
+                    attn = paged_decode_attention(
+                        q, k_pool, v_pool, li, tables, positions, live_len,
+                        h)
+            else:
+                with jax.named_scope(SCOPE_KV_GATHER):
+                    kc = k_pool[li, tables][..., :c].reshape(b, -1, h, d)
+                    vc = v_pool[li, tables][..., :c].reshape(b, -1, h, d)
+                attn = paged_attention(q.reshape(b, t, h, d), kc, vc,
+                                       positions, kv_limit).reshape(b, t, c)
+            return attn, (k_pool, v_pool)
+        return mix
+
+    def decode_mixer(self, state):
         pos = state["pos"]
-        hidden, k_pool, v_pool = paged_layers(
-            self.mc, params, hidden, state["k_pool"], state["v_pool"],
-            state["tables"], pos[:, None], state["active"][:, None], pos,
-            self.cfg.kv_page_size, self.cfg.weight_quant_block)
-        return hidden, {"k_pool": k_pool, "v_pool": v_pool}
+        return self.mixer(state["tables"], pos[:, None],
+                          state["active"][:, None], pos)
 
-    def prefill_layers(self, params, hidden, pools, page_row, posv, valid,
-                       start, n_valid):
-        kv_limit = (start + n_valid - 1)[None]
-        _, k_pool, v_pool = paged_layers(
-            self.mc, params, hidden, *pools, page_row[None], posv[None],
-            valid[None], kv_limit, self.cfg.kv_page_size,
-            self.cfg.weight_quant_block)
-        return k_pool, v_pool
-
-    def head(self, params, hidden):
-        hidden = _ln_apply(self.mc, params["ln_f"], hidden)
-        return jnp.einsum("btc,vc->btv", hidden.astype(self.mc.dtype),
-                          params["wte"].astype(self.mc.dtype))
+    def prefill_mixer(self, page_row, posv, valid, start, n_valid):
+        return self.mixer(page_row[None], posv[None], valid[None],
+                          (start + n_valid - 1)[None])
 
 
-class RecurrentServing:
-    """A model over recurrent state. `model` is its module: `embed(mc,
-    params, tokens)`, `head(mc, params, hidden)` and ONE `block(mc, lp,
-    hidden, positions, mixer, state)` whose `mixer` is the retention
-    call (`models/brumby.py`), run here on layer `li` of the whole
-    state arrays (`RecurrentStateCache.state_shapes`). Decode advances
-    every slot's state by one token and reads it in the same region;
-    inactive slots keep theirs. Prefill advances one slot's state by a
-    chunk, from zero if the chunk is the request's first."""
-    cache_keys = ("state_s", "state_z")
+class RecurrentKind:
+    """Recurrent state: per layer, slot and key/value head a matrix
+    and its normaliser (`kv_cache.RecurrentStateCache.state_shapes`).
+    Decode advances every slot's state by one token and reads it in
+    the same region; inactive slots keep theirs. Prefill advances one
+    slot's state by a chunk, from zero if the chunk is the request's
+    first."""
+    keys = ("state_s", "state_z")
 
-    def __init__(self, model, model_config, config, max_seq_len):
-        self.model = model
+    def __init__(self, model_config, config, max_seq_len):
         self.mc, self.cfg, self.max_seq_len = (model_config, config,
                                                max_seq_len)
-        if config.weight_bits == 8:
-            raise ValueError(
-                "inference.weight_bits: 8 quantises the GPT-2 family's "
-                "projections; this model has no int8 path")
         if config.spec_enabled:
             raise ValueError(
                 "inference.speculative.enabled: this model's slots hold "
@@ -398,70 +336,110 @@ class RecurrentServing:
             max_tokens_per_slot=self.max_seq_len,
             dtype=np.dtype(mc.state_dtype), ledger=ledger)
 
-    def fresh_cache(self, cache):
+    def fresh(self, cache):
         s_shape, z_shape = cache.state_shapes()
         return {"state_s": jnp.zeros(s_shape, self.mc.state_dtype),
                 "state_z": jnp.zeros(z_shape, self.mc.state_dtype)}
 
-    def embed(self, params, tokens, positions):
-        return self.model.embed(self.mc, params, tokens)  # positions: rotary
-
-    def decode_layers(self, params, hidden, state):
-        mc, block = self.mc, self.model.block
+    def decode_mixer(self, state):
+        mc = self.mc
         pos, idle = state["pos"], ~state["active"]
 
+        def mix(li, q, k, v, lg, cache):
+            S, z = cache
+            with jax.named_scope(SCOPE_STATE_UPDATE):
+                o, S_l, z_l = retention_step(
+                    q[:, 0], k[:, 0], v[:, 0], lg[:, 0], S[li], z[li],
+                    mc.retention_scale, mc.retention_eps, keep=idle,
+                    fresh=pos == 0)
+                return o[:, None], (S.at[li].set(S_l), z.at[li].set(z_l))
+        return mix
+
+    def prefill_mixer(self, slot, posv, valid, start, n_valid):
+        mc = self.mc
+
+        def mix(li, q, k, v, lg, cache):
+            S, z = cache
+            with jax.named_scope(SCOPE_STATE_RESET):
+                zero = jnp.zeros((), S.dtype)
+                S0 = jnp.where(start == 0, zero, jax.lax.dynamic_slice(
+                    S, (li, slot, 0, 0, 0), (1, 1) + S.shape[2:])[0])
+                z0 = jnp.where(start == 0, zero, jax.lax.dynamic_slice(
+                    z, (li, slot, 0, 0), (1, 1) + z.shape[2:])[0])
+            with jax.named_scope(SCOPE_RETENTION_CHUNK):
+                o, S1, z1 = retention_chunked(
+                    q, k, v, lg, S0, z0, mc.retention_scale,
+                    mc.retention_eps, mc.retention_chunk, valid[None])
+                S = jax.lax.dynamic_update_slice(
+                    S, S1[None], (li, slot, 0, 0, 0))
+                z = jax.lax.dynamic_update_slice(
+                    z, z1[None], (li, slot, 0, 0))
+            return o, (S, z)
+        return mix
+
+
+KINDS = {"paged": PagedKind, "recurrent": RecurrentKind}
+
+
+class Serving:
+    """One model over its kind of cache: what the serving programs
+    call. `model_config.serving_module` is the model's module,
+    `model_config.cache_kind` the kind (the module's docstring has
+    what each supplies). A speculative engine holds a second one for
+    the draft's config."""
+
+    def __init__(self, model_config, config, max_seq_len):
+        self.model = model_config.serving_module
+        if config.weight_bits == 8:
+            if not self.model.QUANT_KERNEL_MODULES:
+                raise ValueError(
+                    "inference.weight_bits: 8: this model names no "
+                    "projection to quantise: it has no int8 path")
+            model_config = dataclasses.replace(
+                model_config, quant_block=config.weight_quant_block)
+        self.mc = model_config
+        self.kind = KINDS[model_config.cache_kind](model_config, config,
+                                                   max_seq_len)
+        self.cache_keys = self.kind.keys
+
+    def quantized(self, params):
+        return quantize_param_tree(params, self.mc.quant_block,
+                                   self.model.QUANT_KERNEL_MODULES)
+
+    def embed(self, params, tokens, positions):
+        return self.model.embed(self.mc, params, tokens, positions)
+
+    def layers(self, params, hidden, cache, positions, mixer):
+        """`scan_layers` of the model's block over `params`' stack, with
+        the kind's `mixer(li, ...)` on layer `li` of the whole `cache`
+        arrays. Returns (hidden, cache)."""
         def layer(lp, li, hidden, cache):
-            def mixer(q, k, v, lg, cache):
-                S, z = cache
-                with jax.named_scope(SCOPE_STATE_UPDATE):
-                    o, S_l, z_l = retention_step(
-                        q[:, 0], k[:, 0], v[:, 0], lg[:, 0], S[li], z[li],
-                        mc.retention_scale, mc.retention_eps, keep=idle,
-                        fresh=pos == 0)
-                    return o[:, None], (S.at[li].set(S_l),
-                                        z.at[li].set(z_l))
-            return block(mc, lp, hidden, pos[:, None], mixer, cache)
+            return self.model.block(self.mc, lp, hidden, positions,
+                                    functools.partial(mixer, li), cache)
 
-        hidden, (S, z) = scan_layers(
-            params["layers"], hidden, (state["state_s"], state["state_z"]),
-            layer)
-        return hidden, {"state_s": S, "state_z": z}
+        return scan_layers(self.model.layers(params), hidden, cache, layer)
 
-    def prefill_layers(self, params, hidden, cache, slot, posv, valid,
+    def decode_layers(self, params, hidden, state):
+        hidden, cache = self.layers(
+            params, hidden, tuple(state[k] for k in self.cache_keys),
+            state["pos"][:, None], self.kind.decode_mixer(state))
+        return hidden, dict(zip(self.cache_keys, cache))
+
+    def prefill_layers(self, params, hidden, cache, where, posv, valid,
                        start, n_valid):
-        mc, block = self.mc, self.model.block
-
-        def layer(lp, li, hidden, cache):
-            def mixer(q, k, v, lg, cache):
-                S, z = cache
-                with jax.named_scope(SCOPE_STATE_RESET):
-                    zero = jnp.zeros((), S.dtype)
-                    S0 = jnp.where(start == 0, zero, jax.lax.dynamic_slice(
-                        S, (li, slot, 0, 0, 0), (1, 1) + S.shape[2:])[0])
-                    z0 = jnp.where(start == 0, zero, jax.lax.dynamic_slice(
-                        z, (li, slot, 0, 0), (1, 1) + z.shape[2:])[0])
-                with jax.named_scope(SCOPE_RETENTION_CHUNK):
-                    o, S1, z1 = retention_chunked(
-                        q, k, v, lg, S0, z0, mc.retention_scale,
-                        mc.retention_eps, mc.retention_chunk, valid[None])
-                    S = jax.lax.dynamic_update_slice(
-                        S, S1[None], (li, slot, 0, 0, 0))
-                    z = jax.lax.dynamic_update_slice(
-                        z, z1[None], (li, slot, 0, 0))
-                return o, (S, z)
-            return block(mc, lp, hidden, posv[None], mixer, cache)
-
-        _, cache = scan_layers(params["layers"], hidden, cache, layer)
-        return cache
+        """`where` finds the slot's part of `cache`: its page-table
+        row, or for recurrent state its index."""
+        return self.layers(
+            params, hidden, cache, posv[None],
+            self.kind.prefill_mixer(where, posv, valid, start, n_valid))[1]
 
     def head(self, params, hidden):
         return self.model.head(self.mc, params, hidden)
 
 
 class InferenceEngine:
-    """Serving engine for one model: a GPT-2 family model over the
-    paged K/V cache, or one over recurrent state (the model config's
-    `serving()` hands over the adapter; see the module's docstring).
+    """Serving engine for one model over the kind of cache its config
+    names: K/V pages or recurrent state (see the module's docstring).
 
     Construction compiles the two programs AOT against the configured
     shapes; `start_request`/`prefill_chunk`/`activate_slot` manage
@@ -483,20 +461,16 @@ class InferenceEngine:
         if cfg.max_seq_len is not None:
             max_seq = min(max_seq, cfg.max_seq_len)
         self.max_seq_len = max_seq
-        # the model's block and the kind of cache it keeps come from
-        # the model config
-        serving = getattr(model_config, "serving", None)
-        self.family = serving(cfg, max_seq) if serving is not None \
-            else PagedServing(model_config, cfg, max_seq)
+        self.serving = Serving(model_config, cfg, max_seq)
 
         if cfg.weight_bits == 8:
-            params = quantize_param_tree(params, cfg.weight_quant_block)
+            params = self.serving.quantized(params)
             logger.info(
                 "inference: int8 weight-only quantization applied "
                 f"(block {cfg.weight_quant_block} along the "
                 "contraction dim)")
         self._params = params
-        self.cache = self.family.make_cache(self.monitor.ledger)
+        self.cache = self.serving.kind.make_cache(self.monitor.ledger)
         self.monitor.ledger.register_tree(
             memory_mod.CAT_PARAMS, "inference.params", params)
 
@@ -526,15 +500,16 @@ class InferenceEngine:
                     raise ValueError(
                         'inference.speculative.draft_model="external" '
                         "requires draft_params and draft_model_config")
-                if cfg.weight_bits == 8:
-                    draft_params = quantize_param_tree(
-                        draft_params, cfg.weight_quant_block)
-                self._draft_config = draft_model_config
-                self._draft_params = draft_params
+                self._draft_config, self._draft_params = (
+                    draft_model_config, draft_params)
             else:
                 self._draft_config, self._draft_params = \
                     spec_mod.derive_draft(model_config, params,
                                           cfg.spec_draft_model)
+            self.draft_serving = Serving(self._draft_config, cfg, max_seq)
+            if cfg.spec_draft_model == "external" and cfg.weight_bits == 8:
+                self._draft_params = self.draft_serving.quantized(
+                    self._draft_params)
             if self._draft_config.n_head != model_config.n_head or \
                     self._draft_config.head_dim != model_config.head_dim:
                 raise ValueError(
@@ -542,11 +517,11 @@ class InferenceEngine:
                     "head geometry (the draft KV pool reuses the "
                     "flagship page-table shapes)")
             self.cache.attach_draft(self._draft_config.n_layer)
-            # only the draft's own block stack is new device bytes —
-            # wte/wpe/ln_f are shared references with the flagship
+            # only the draft's own layer stack is new device bytes: a
+            # truncated draft shares everything else with the flagship
             self.monitor.ledger.register_tree(
                 memory_mod.CAT_PARAMS, "inference.draft_params",
-                self._draft_params["h"])
+                self.draft_serving.model.layers(self._draft_params))
             self._spec_state = spec_mod.fresh_spec_state(self)
             self._draft_decode = spec_mod.build_draft_step(self)
             self._verify = spec_mod.build_verify_step(self)
@@ -572,7 +547,7 @@ class InferenceEngine:
         s, w = cfg.max_slots, cfg.max_new_tokens
         self._tables_version = self.cache.table_version
         return {
-            **self.family.fresh_cache(self.cache),
+            **self.serving.kind.fresh(self.cache),
             "pos": jnp.zeros((s,), jnp.int32),
             "cur_token": jnp.zeros((s,), jnp.int32),
             "active": jnp.zeros((s,), bool),
@@ -608,7 +583,7 @@ class InferenceEngine:
     # the two AOT programs
     # ------------------------------------------------------------------
     def _build_decode_step(self):
-        cfg, mc, family = self.config, self.model_config, self.family
+        cfg, mc, serving = self.config, self.model_config, self.serving
         s = cfg.max_slots
         out_w = cfg.max_new_tokens
         top_k_cap = min(cfg.top_k_max, mc.vocab_size)
@@ -636,11 +611,11 @@ class InferenceEngine:
             pos = state["pos"]
             # a [S, 1] "sequence" at absolute positions `pos`
             with jax.named_scope(SCOPE_EMBED):
-                hidden = family.embed(params, state["cur_token"], pos)
+                hidden = serving.embed(params, state["cur_token"], pos)
                 hidden = hidden[:, None, :]
-            hidden, cache = family.decode_layers(params, hidden, state)
+            hidden, cache = serving.decode_layers(params, hidden, state)
             with jax.named_scope(SCOPE_HEAD):
-                logits = family.head(params, hidden)[:, 0]
+                logits = serving.head(params, hidden)[:, 0]
             next_tok = sample(logits, state)
 
             with jax.named_scope(SCOPE_BOOKKEEPING):
@@ -670,7 +645,7 @@ class InferenceEngine:
                                   donate_argnums=(1,))
 
     def _build_prefill_step(self):
-        cfg, family = self.config, self.family
+        cfg, serving = self.config, self.serving
         chunk = cfg.prefill_chunk
 
         def prefill_fn(params, cache, where, tokens, start, n_valid):
@@ -680,9 +655,9 @@ class InferenceEngine:
             posv = start + jnp.arange(chunk, dtype=jnp.int32)
             valid = jnp.arange(chunk) < n_valid
             with jax.named_scope(SCOPE_EMBED):
-                hidden = family.embed(params, tokens, posv)[None]
-            return family.prefill_layers(params, hidden, cache, where, posv,
-                                         valid, start, n_valid)
+                hidden = serving.embed(params, tokens, posv)[None]
+            return serving.prefill_layers(params, hidden, cache, where,
+                                          posv, valid, start, n_valid)
 
         args = (self._params, self.cache_arrays(),
                 jnp.asarray(self.cache.slot_operand(0)),
@@ -692,10 +667,10 @@ class InferenceEngine:
 
     def cache_arrays(self):
         """The model's cache arrays as the programs hold them, in the
-        order of the adapter's `cache_keys` (the device arrays, not
+        order of the kind's `keys` (the device arrays, not
         copies: both K/V page pools `PagedKVCache.pool_shape`, or the
         state and its normaliser `RecurrentStateCache.state_shapes`)."""
-        return tuple(self._state[k] for k in self.family.cache_keys)
+        return tuple(self._state[k] for k in self.serving.cache_keys)
 
     # ------------------------------------------------------------------
     # fence-side slot management (host work, runs between blocks)
@@ -715,7 +690,7 @@ class InferenceEngine:
         buf = np.zeros((self.config.prefill_chunk,), np.int32)
         buf[:n] = tokens
         st = self._state
-        st.update(zip(self.family.cache_keys, self._prefill(
+        st.update(zip(self.serving.cache_keys, self._prefill(
             self._params, self.cache_arrays(),
             jnp.asarray(self.cache.slot_operand(slot)), jnp.asarray(buf),
             jnp.asarray(start, jnp.int32), jnp.asarray(n, jnp.int32))))

@@ -4,17 +4,17 @@ verify, lossless acceptance on the paged KV cache (ISSUE 18).
 The vanilla engine emits exactly one token per flagship launch; this
 module makes each launch emit up to k+1 **verified** tokens:
 
-  1. **draft-decode** (k cheap steps): a small GPT-2 draft model —
-     by default the flagship's first N transformer layers with shared
-     embeddings / final LN / tied head (`draft_model: "truncate:N"`,
-     zero extra checkpoint) — proposes the next k tokens
+  1. **draft-decode** (k cheap steps): a small draft model — by
+     default the flagship's first N layers with everything round them
+     shared (`draft_model: "truncate:N"`, zero extra checkpoint; the
+     model's own `first_layers`) — proposes the next k tokens
      autoregressively, writing its own K/V into a second paged pool
      that shares the flagship cache's page tables and allocator
      verbatim (one admission decision, one table upload; the draft
      pool is the `kv_cache_draft` ledger category);
   2. **verify** (ONE flagship launch): the widened decode program
-     scores all k+1 positions per slot at once — `_block_paged` with
-     k+1 query rows a slot, through the same decode kernel as the
+     scores all k+1 positions per slot at once — the model's block
+     with k+1 query rows a slot, through the same decode kernel as the
      one-row programs (`ops/transformer/paged_decode_attention.py`:
      each row masked at its own position) — and applies the
      acceptance rule **on device**, so a round adds zero host syncs
@@ -52,15 +52,16 @@ ADAPT_BACKOFF shrinks it toward `speculative.k_min`, and the host
 reads max(live k) at the fence (inside the ONE fused device_get) to
 dispatch fewer draft steps next block when the whole batch is being
 rejected.
-"""
 
-import dataclasses
+All three programs are built from the engine's adapters
+(`engine.Serving`: the flagship's and the draft's `embed`, `layers`
+and `head` over the paged kind's mixer); no model is named here.
+"""
 
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.engine import (_ln_apply, compile_fresh,
-                                            paged_layers)
+from deepspeed_tpu.inference.engine import compile_fresh
 
 # fold_in lane separating the draft model's sampling stream from the
 # flagship's (state["rng"] folded by step on one side, by
@@ -76,10 +77,10 @@ ADAPT_BACKOFF = 0.5
 # ----------------------------------------------------------------------
 def derive_draft(model_config, params, draft_model):
     """Resolve `speculative.draft_model` to (draft_config,
-    draft_params). "truncate:N" slices the nn.scan-stacked block
-    params to the first N layers and shares wte/wpe/ln_f (and the tied
-    head) with the flagship — the sliced leaves are the only new
-    device bytes."""
+    draft_params). "truncate:N" is the model's own `first_layers`: the
+    stacked layer weights sliced to the first N, everything else the
+    flagship's own buffers — the sliced leaves are the only new device
+    bytes."""
     if not draft_model.startswith("truncate:"):
         raise ValueError(
             f"derive_draft cannot resolve draft_model={draft_model!r} "
@@ -89,12 +90,7 @@ def derive_draft(model_config, params, draft_model):
         raise ValueError(
             f"speculative.draft_model={draft_model!r}: the flagship "
             f"has only {model_config.n_layer} layers")
-    (scan_key, stacked), = params["h"].items()
-    sliced = jax.tree_util.tree_map(lambda x: x[:n], stacked)
-    draft_params = {"wte": params["wte"], "wpe": params["wpe"],
-                    "h": {scan_key: sliced}, "ln_f": params["ln_f"]}
-    draft_config = dataclasses.replace(model_config, n_layer=n)
-    return draft_config, draft_params
+    return model_config.serving_module.first_layers(model_config, params, n)
 
 
 # ----------------------------------------------------------------------
@@ -166,10 +162,7 @@ def build_draft_step(engine):
     slot (call it n_draft times per round). Reads the flagship state
     (positions, tables, sampler params) without touching it; mutates
     only the spec state (donated)."""
-    cfg, mc = engine.config, engine.model_config
-    dmc = engine._draft_config
-    qb = cfg.weight_quant_block
-    page = engine.cache.page_size
+    cfg, mc, draft = engine.config, engine.model_config, engine.draft_serving
     s, k = cfg.max_slots, cfg.spec_k
     top_k_cap = min(cfg.top_k_max, mc.vocab_size)
 
@@ -188,16 +181,13 @@ def build_draft_step(engine):
         budget = state["max_new"] - state["n_gen"] - 1
         k_eff = jnp.minimum(spec["k_slot"], jnp.maximum(budget, 0))
         valid = active & (j < k_eff)
-        wte, wpe = draft_params["wte"], draft_params["wpe"]
         posc = jnp.clip(pos, 0, mc.n_positions - 1)
-        hidden = wte[cur].astype(mc.dtype) + wpe[posc].astype(mc.dtype)
-        hidden = hidden[:, None, :]
-        hidden, dk, dv = paged_layers(
-            dmc, draft_params, hidden, spec["dk_pool"], spec["dv_pool"],
-            state["tables"], pos[:, None], valid[:, None], pos, page, qb)
-        hidden = _ln_apply(dmc, draft_params["ln_f"], hidden)
-        logits = jnp.einsum("btc,vc->btv", hidden.astype(mc.dtype),
-                            wte.astype(mc.dtype))[:, 0]
+        hidden = draft.embed(draft_params, cur, posc)[:, None, :]
+        hidden, (dk, dv) = draft.layers(
+            draft_params, hidden, (spec["dk_pool"], spec["dv_pool"]),
+            pos[:, None], draft.kind.mixer(
+                state["tables"], pos[:, None], valid[:, None], pos))
+        logits = draft.head(draft_params, hidden)[:, 0]
         l32 = logits.astype(jnp.float32)
         greedy = jnp.argmax(l32, axis=-1).astype(jnp.int32)
         scaled = process_logits(l32, state["top_k"],
@@ -228,9 +218,7 @@ def build_verify_step(engine):
     positions per slot, plus the device-side acceptance rule, output
     commit, and kv_limit rollback. Consumes (donates) both the
     flagship state and the spec state."""
-    cfg, mc = engine.config, engine.model_config
-    qb = cfg.weight_quant_block
-    page = engine.cache.page_size
+    cfg, mc, serving = engine.config, engine.model_config, engine.serving
     s, k, w = cfg.max_slots, cfg.spec_k, cfg.max_new_tokens
     top_k_cap = min(cfg.top_k_max, mc.vocab_size)
     adaptive = cfg.spec_adaptive
@@ -252,16 +240,13 @@ def build_verify_step(engine):
         positions = pos0[:, None] + steps[None, :]
         write_ok = active[:, None] & (steps[None, :] <= n_valid[:, None])
         kv_limit = pos0 + n_valid
-        wte, wpe = params["wte"], params["wpe"]
         posc = jnp.clip(positions, 0, mc.n_positions - 1)
-        hidden = wte[tokens_in].astype(mc.dtype) + \
-            wpe[posc].astype(mc.dtype)
-        hidden, k_pool, v_pool = paged_layers(
-            mc, params, hidden, state["k_pool"], state["v_pool"],
-            state["tables"], positions, write_ok, kv_limit, page, qb)
-        hidden = _ln_apply(mc, params["ln_f"], hidden)
-        logits = jnp.einsum("btc,vc->btv", hidden.astype(mc.dtype),
-                            wte.astype(mc.dtype))
+        hidden = serving.embed(params, tokens_in, posc)
+        hidden, (k_pool, v_pool) = serving.layers(
+            params, hidden, (state["k_pool"], state["v_pool"]), positions,
+            serving.kind.mixer(state["tables"], positions, write_ok,
+                               kv_limit))
+        logits = serving.head(params, hidden)
         l32 = logits.astype(jnp.float32)       # [s, k+1, V]
 
         d = spec["dtoks"]                      # [s, k]
@@ -380,26 +365,15 @@ def build_draft_prefill_step(engine):
     caching the flagship prefill does, into the draft pools (the draft
     attends over the full committed prefix, so its cache must cover
     the prompt too)."""
-    cfg, mc = engine.config, engine.model_config
-    dmc = engine._draft_config
-    qb = cfg.weight_quant_block
-    page = engine.cache.page_size
-    chunk = cfg.prefill_chunk
+    draft, chunk = engine.draft_serving, engine.config.prefill_chunk
 
     def draft_prefill_fn(draft_params, dk_pool, dv_pool, page_row,
                          tokens, start, n_valid):
-        wte, wpe = draft_params["wte"], draft_params["wpe"]
         posv = start + jnp.arange(chunk, dtype=jnp.int32)
         valid = jnp.arange(chunk) < n_valid
-        hidden = wte[tokens].astype(mc.dtype) + \
-            wpe[posv].astype(mc.dtype)
-        hidden = hidden[None]
-        positions = posv[None]
-        kv_limit = (start + n_valid - 1)[None]
-        _, dk_pool, dv_pool = paged_layers(
-            dmc, draft_params, hidden, dk_pool, dv_pool, page_row[None],
-            positions, valid[None], kv_limit, page, qb)
-        return dk_pool, dv_pool
+        hidden = draft.embed(draft_params, tokens, posv)[None]
+        return draft.prefill_layers(draft_params, hidden, (dk_pool, dv_pool),
+                                    page_row, posv, valid, start, n_valid)
 
     sp = engine._spec_state
     args = (engine._draft_params, sp["dk_pool"], sp["dv_pool"],

@@ -23,9 +23,10 @@ untied from the embedding. No projection has a bias; the gate's bg
 ONE functional `block` holds that. Its `mixer` is the retention call:
 the model's own full-sequence `forward` hands it `retention_chunked`
 from zero state, the serving engine's prefill program the same from
-the slot's state, its decode program `retention_step`
-(`inference/engine.py::RecurrentServing`). There is no second copy of
-the block.
+the slot's state, its decode program `retention_step` (the recurrent
+kind's two mixers in `inference/engine.py`, which composes `embed`,
+`block`, `head` and `layers` and imports nothing from here). There is
+no second copy of the block.
 
 Parameters are a plain dict, the layers' leaves stacked [n_layer, ...]
 under "layers" (what `engine.scan_layers` scans over):
@@ -78,13 +79,11 @@ class BrumbyConfig:
     param_dtype: Any = jnp.bfloat16
     state_dtype: Any = jnp.float32  # the state and its normaliser
 
-    def serving(self, inference_config, max_seq_len):
-        """How `InferenceEngine` serves this model (it asks every model
-        config that has this method): over recurrent state, with this
-        module's `embed`, `block` and `head` round it."""
-        from deepspeed_tpu.inference.engine import RecurrentServing
-        return RecurrentServing(sys.modules[__name__], self,
-                                inference_config, max_seq_len)
+    # what `InferenceEngine` reads off every model config: the kind of
+    # cache the layers keep, and the module whose `embed`, `block`,
+    # `head` and `layers` it composes with that kind's mixers
+    cache_kind = "recurrent"
+    serving_module = property(lambda self: sys.modules[__name__])
 
     def __post_init__(self):
         if self.retention_degree != 2:
@@ -180,7 +179,8 @@ def block(cfg, lp, hidden, positions, mixer, state):
     return hidden, state
 
 
-def embed(cfg, params, tokens):
+def embed(cfg, params, tokens, positions):
+    """Positions are rotary, applied in `block`: not read here."""
     return params["embed"][tokens].astype(cfg.dtype)
 
 
@@ -188,6 +188,15 @@ def head(cfg, params, hidden):
     """[..., H] -> [..., V] logits in the compute type."""
     x = rms_norm(hidden, params["norm_f"], cfg.rms_norm_eps)
     return x.astype(cfg.dtype) @ params["head"].astype(cfg.dtype)
+
+
+def layers(params):
+    """The stacked [n_layer, ...] leaves `block` takes one layer of."""
+    return params["layers"]
+
+
+# no projection an int8 load may quantise: this model has no int8 path
+QUANT_KERNEL_MODULES = ()
 
 
 def zero_state(cfg, rows):
@@ -212,6 +221,6 @@ def forward(cfg, params, ids):
     def layer(hidden, lp):
         return block(cfg, lp, hidden, positions, mixer, None)[0], None
 
-    hidden, _ = jax.lax.scan(layer, embed(cfg, params, ids),
-                             params["layers"])
+    hidden, _ = jax.lax.scan(layer, embed(cfg, params, ids, positions),
+                             layers(params))
     return head(cfg, params, hidden)

@@ -17,6 +17,7 @@ the psum that Megatron codes by hand.
 """
 
 import dataclasses
+import sys
 from functools import partial
 from typing import Any, Optional
 
@@ -30,7 +31,11 @@ from deepspeed_tpu.ops import overlap as _overlap
 from deepspeed_tpu.ops.transformer.flash_attention import (
     dense_attention, flash_attention, flash_attention_rematerializable,
     flash_attention_usable)
+from deepspeed_tpu.ops.transformer.quantized_matmul import (KERNEL_SCALE,
+                                                            int8_matmul)
 from deepspeed_tpu.runtime.mesh import EXPERT_AXIS, MODEL_AXIS
+from deepspeed_tpu.utils.scopes import (SCOPE_ATTN_OUT, SCOPE_ATTN_QKV,
+                                        SCOPE_MLP)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -104,6 +109,12 @@ class GPT2Config:
     # `moe` config block wires the runtime knobs via `configure_moe`.
     moe: Any = None
     initializer_range: float = 0.02
+
+    # what `InferenceEngine` reads off every model config: the kind of
+    # cache the layers keep, and the module whose `embed`, `block`,
+    # `head` and `layers` it composes with that kind's mixer
+    cache_kind = "paged"
+    serving_module = property(lambda self: sys.modules[__name__])
 
     @property
     def head_dim(self):
@@ -497,6 +508,87 @@ def stacked_block_params(params):
     stack through this."""
     (_, stacked), = params["h"].items()
     return stacked
+
+
+# ----------------------------------------------------------------------
+# the model as it is served (`inference/engine.py` composes these with
+# the paged cache's mixer and imports nothing from here). Training-math
+# twins: the same flax modules the unfused training forward runs,
+# applied to extracted param leaves (tests/test_serving_seam.py holds
+# them to `GPT2ForCausalLM.apply`).
+# ----------------------------------------------------------------------
+layers = stacked_block_params
+
+# the projections an int8 load quantises (wte, wpe, ln_* stay as stored)
+QUANT_KERNEL_MODULES = ("c_attn", "c_proj", "c_fc", "mlp_c_proj")
+
+
+def first_layers(cfg, params, n):
+    """(config, params) of the model that is this one's first `n`
+    blocks (a speculative draft): wte, wpe, ln_f and the tied head are
+    the flagship's own buffers, the sliced stack the only new bytes."""
+    (scan_key, stacked), = params["h"].items()
+    sliced = jax.tree_util.tree_map(lambda x: x[:n], stacked)
+    return (dataclasses.replace(cfg, n_layer=n),
+            {**params, "h": {scan_key: sliced}})
+
+
+def _ln_apply(cfg, p, x):
+    """nn.LayerNorm exactly as GPT2Block builds it (fp32 stats)."""
+    return nn.LayerNorm(
+        epsilon=cfg.layer_norm_epsilon, dtype=jnp.float32,
+        param_dtype=cfg.param_dtype).apply({"params": p}, x)
+
+
+def _dense_apply(cfg, p, x):
+    """nn.Dense as GPT2Block builds it — or, when the leaf carries a
+    KERNEL_SCALE, the int8 dequant-in-matmul epilogue (the engine lays
+    `inference.weight_quant_block` over `cfg.quant_block`)."""
+    if KERNEL_SCALE in p:
+        y = int8_matmul(x.astype(cfg.dtype), p["kernel"],
+                        p[KERNEL_SCALE], cfg.quant_block, cfg.dtype)
+        return y + p["bias"].astype(cfg.dtype)
+    return nn.Dense(
+        p["kernel"].shape[-1], dtype=cfg.dtype,
+        param_dtype=cfg.param_dtype).apply(
+            {"params": {"kernel": p["kernel"], "bias": p["bias"]}}, x)
+
+
+def embed(cfg, params, tokens, positions):
+    """`embed_tokens`' math at absolute positions."""
+    return params["wte"][tokens].astype(cfg.dtype) + \
+        params["wpe"][positions].astype(cfg.dtype)
+
+
+def block(cfg, lp, hidden, positions, mixer, cache):
+    """One pre-LN transformer block (GPT2Block's unfused math, op for
+    op) over hidden [B, T, C] with one layer's leaves `lp`.
+    `mixer(q, k, v, cache) -> (attn [B, T, C], cache)` attends over
+    whatever the caller keeps of earlier tokens (the serving engine:
+    the K/V page pools); q, k and v are as projected, heads side by
+    side. Positions are learned and added in `embed`: not read here."""
+    with jax.named_scope(SCOPE_ATTN_QKV):
+        x = _ln_apply(cfg, lp["ln_1"], hidden).astype(cfg.dtype)
+        qkv = _dense_apply(cfg, lp["c_attn"], x)
+        q, k, v = jnp.split(qkv, 3, axis=-1)
+    attn, cache = mixer(q, k, v, cache)
+    with jax.named_scope(SCOPE_ATTN_OUT):
+        attn = _dense_apply(cfg, lp["c_proj"], attn)
+        hidden = hidden + attn
+    with jax.named_scope(SCOPE_MLP):
+        y = _ln_apply(cfg, lp["ln_2"], hidden).astype(cfg.dtype)
+        y = _dense_apply(cfg, lp["c_fc"], y)
+        y = nn.gelu(y, approximate=True)
+        y = _dense_apply(cfg, lp["mlp_c_proj"], y)
+        hidden = hidden + y
+    return hidden, cache
+
+
+def head(cfg, params, hidden):
+    """ln_f and the head tied to the embedding: [.., T, C] -> logits."""
+    hidden = _ln_apply(cfg, params["ln_f"], hidden)
+    return jnp.einsum("btc,vc->btv", hidden.astype(cfg.dtype),
+                      params["wte"].astype(cfg.dtype))
 
 
 class GPT2LMHeadModel(nn.Module):

@@ -3,7 +3,7 @@ the wire into the matmul itself.
 
 The repo already quantizes int8 with per-block scales in two places —
 the ZeRO-Offload compressed wire (PR 1) and int8 weight-only serving
-(PR 12, `inference/quant.py`) — but until now the MXU never saw the
+(PR 12, `inference/engine.py::quantize_param_tree`) — but until now the MXU never saw the
 quantized values: quantization only compressed bytes in flight.  This
 module is the ONE home of that scale layout and of the dequant
 epilogues that consume it, shared by training and inference:
@@ -18,7 +18,8 @@ epilogues that consume it, shared by training and inference:
       * `int8_matmul`  — weight-only: x stays in the compute dtype,
         int8 weights are cast and contracted per K-block and the
         per-block scale multiplies each block's partial sum (the
-        serving path; `inference/quant.py` re-exports this).
+        serving path: a model's block applies it to a leaf the
+        engine's int8 load quantised).
       * `quantized_matmul` / `quantized_dense` — quantized compute:
         BOTH operands int8, the MXU contracts int8xint8 -> int32 and
         the dequant (x-row scale x weight-block scale) rides the GEMM
@@ -67,6 +68,12 @@ from deepspeed_tpu.ops.per_device import ROWS, per_device
 # Pallas kernel's minimum legal int8 K-tile. (Serving keeps its own
 # 64 default — finer blocks, XLA epilogue only.)
 DEFAULT_QUANT_BLOCK = 128
+
+# key beside "kernel" in a projection's leaf dict that marks a kernel
+# quantised once at load (weight-only serving): int8 values under
+# "kernel", their [nb, N] scales under this; a block that finds it
+# applies `int8_matmul` in place of the dense product
+KERNEL_SCALE = "kernel_scale"
 
 _COMPILER_PARAMS = pltpu.CompilerParams(
     vmem_limit_bytes=64 * 1024 * 1024)
@@ -178,8 +185,7 @@ def dequantize_kernel(q, scales, block, k=None, dtype=jnp.float32):
 
 
 # ----------------------------------------------------------------------
-# weight-only epilogue (the serving family; inference/quant.py
-# re-exports this under its legacy name)
+# weight-only epilogue (the serving family)
 # ----------------------------------------------------------------------
 def int8_matmul(x, q, scales, block, out_dtype):
     """The weight-only dequant-in-matmul epilogue: x [.., T, K] @ int8

@@ -12,7 +12,7 @@ paged model, SCOPE_STATE_RESET, SCOPE_RETENTION_CHUNK and SCOPE_STATE_UPDATE
 for the state of a recurrent one. A cache-sized copy showing up under
 SCOPE_LAYERS alone is a regression.
 
-A model's block (`models/brumby.py`) and the engine
+A model's block (`models/gpt2.py`, `models/brumby.py`) and the engine
 (`inference/engine.py`, which re-exports them) both take the names
 from here: neither the models nor the ops import the serving code.
 """

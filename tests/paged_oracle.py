@@ -6,14 +6,15 @@ The oracle is the layer stack as it was written before: a Python loop
 over the layers, each with its OWN page pool ([P, page, lanes], the
 engine's row of a token), written with `.at[phys, off].set`.
 Everything between the write and the block's output is the program's
-own (`_ln_apply`, `_dense_apply`, and the program's own attention
-entry: for a few query rows a slot `paged_decode_attention` on the
+own (`models/gpt2.py`'s `_ln_apply` and `_dense_apply`, and the
+program's own attention entry: for a few query rows a slot `paged_decode_attention` on the
 layer's pool as a one-layer pool, for a prefill chunk the gathered
 window and `paged_attention`), so a difference is the carry's or the
 scatter's. One jitted layer is called n_layer times: the same compiled
 code for every layer, as in a scan's body."""
 
 import contextlib
+import dataclasses
 import functools
 
 import numpy as np
@@ -23,11 +24,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.engine import (DECODE_ROWS_MAX, _dense_apply,
-                                            _ln_apply, paged_attention)
+from deepspeed_tpu.inference.engine import DECODE_ROWS_MAX, paged_attention
 from deepspeed_tpu.ops.transformer.paged_decode_attention import \
     paged_decode_attention
-from deepspeed_tpu.models.gpt2 import stacked_block_params
+from deepspeed_tpu.models.gpt2 import (_dense_apply, _ln_apply,
+                                       stacked_block_params)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "page_size",
@@ -37,8 +38,9 @@ def _oracle_block(cfg, lp, hidden, kl, vl, tables, positions, valid,
     b, t, c = hidden.shape
     h, d = cfg.n_head, cfg.head_dim
     lanes = kl.shape[-1]
+    cfg = dataclasses.replace(cfg, quant_block=quant_block)
     x = _ln_apply(cfg, lp["ln_1"], hidden).astype(cfg.dtype)
-    qkv = _dense_apply(cfg, lp["c_attn"], x, quant_block)
+    qkv = _dense_apply(cfg, lp["c_attn"], x)
     q, k, v = jnp.split(qkv, 3, axis=-1)
     pidx = positions // page_size
     off = (positions % page_size).reshape(-1)
@@ -56,12 +58,12 @@ def _oracle_block(cfg, lp, hidden, kl, vl, tables, positions, valid,
         vc = vl[tables][..., :c].reshape(b, -1, h, d)
         attn = paged_attention(q.reshape(b, t, h, d), kc, vc, positions,
                                kv_limit).reshape(b, t, c)
-    attn = _dense_apply(cfg, lp["c_proj"], attn, quant_block)
+    attn = _dense_apply(cfg, lp["c_proj"], attn)
     hidden = hidden + attn
     y = _ln_apply(cfg, lp["ln_2"], hidden).astype(cfg.dtype)
-    y = _dense_apply(cfg, lp["c_fc"], y, quant_block)
+    y = _dense_apply(cfg, lp["c_fc"], y)
     y = nn.gelu(y, approximate=True)
-    y = _dense_apply(cfg, lp["mlp_c_proj"], y, quant_block)
+    y = _dense_apply(cfg, lp["mlp_c_proj"], y)
     return hidden + y, kl, vl
 
 

@@ -8,7 +8,8 @@ grads and H2D params, and a silent regression to the numpy reference
 implementation (broken native build, wheel without the extension,
 ctypes loader change) would tank offload throughput without failing a
 single numerics test. This guard times native vs numpy at the
-reference's sizes and asserts the native kernel keeps a >= 5x lead
+reference's two larger sizes (the smallest only shows that the native
+kernel ran) and asserts the native kernel keeps a >= 5x lead
 (measured 100-165x on the CI container; the reference observed ~11x on
 its hardware — 5x leaves headroom for a loaded host while still
 catching "accidentally running numpy").
@@ -36,16 +37,7 @@ def _native_or_skip(n):
     return opt
 
 
-def _best_of(fn, reps):
-    best = float("inf")
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        fn()
-        best = min(best, time.perf_counter() - t0)
-    return best
-
-
-def _assert_native_speedup(n, reps=5):
+def _blobs(n):
     rng = np.random.RandomState(7)
     p0 = rng.randn(n).astype(np.float32)
     g = rng.randn(n).astype(np.float32)
@@ -54,8 +46,22 @@ def _assert_native_speedup(n, reps=5):
     pn, pr = p0.copy(), p0.copy()
     nat.step(pn, g)  # warmup: page-in, OpenMP thread-pool spin-up
     ref.step(pr, g)
-    t_nat = _best_of(lambda: nat.step(pn, g), reps)
-    t_ref = _best_of(lambda: ref.step(pr, g), reps)
+    return nat, ref, pn, pr, g
+
+
+def _assert_native_speedup(n, reps=5):
+    nat, ref, pn, pr, g = _blobs(n)
+    # the two sides turn about, each at its best of several: a burst of
+    # other work on the host (the suite runs under several workers)
+    # then falls on both, not on one side's whole measurement
+    t_nat = t_ref = float("inf")
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        nat.step(pn, g)
+        t1 = time.perf_counter()
+        ref.step(pr, g)
+        t_nat = min(t_nat, t1 - t0)
+        t_ref = min(t_ref, time.perf_counter() - t1)
     speedup = t_ref / t_nat
     assert speedup >= MIN_SPEEDUP, (
         f"native CPU-Adam at {n/1e6:.0f}M params: {t_nat*1e3:.2f} ms vs "
@@ -64,8 +70,21 @@ def _assert_native_speedup(n, reps=5):
         "offload path silently fell back to the numpy reference")
 
 
-def test_native_adam_speedup_1m():
-    _assert_native_speedup(1_000_000)
+def test_native_adam_runs_at_1m():
+    """The reference's smallest size holds no wall-clock ratio: one
+    native step is a fraction of a millisecond across the OpenMP
+    threads, and beside other test workers a descheduled thread at a
+    barrier costs it milliseconds (4-6x read here under load, 100x
+    alone). What this size can say whatever the host does: the native
+    kernel is what ran, every thread's share of the blob included,
+    and it took numpy's step."""
+    nat, ref, pn, pr, g = _blobs(1_000_000)
+    assert nat.native and not ref.native
+    for _ in range(3):
+        nat.step(pn, g)
+        ref.step(pr, g)
+    assert nat.step_count == ref.step_count == 4
+    np.testing.assert_allclose(pn, pr, atol=1e-5)
 
 
 def test_native_adam_speedup_10m():

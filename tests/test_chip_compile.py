@@ -21,6 +21,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from deepspeed_tpu.inference import engine as engine_mod
+from deepspeed_tpu.inference.config import InferenceConfig
 from deepspeed_tpu.inference.kv_cache import PagedKVCache
 from deepspeed_tpu.models.gpt2 import GPT2ForCausalLM, gpt2_config
 
@@ -76,11 +77,15 @@ def test_layer_scan_holds_no_copy_of_a_pool_on_a_v5e(cell, rows, tokens,
     from deepspeed_tpu.ops.transformer import paged_decode_attention
     monkeypatch.setattr(paged_decode_attention, "_on_tpu", lambda: True)
 
+    serving = engine_mod.Serving(cfg, InferenceConfig({"inference": {
+        "kv_cache": {"num_pages": PAGES, "page_size": PAGE}}}), SEQ)
+
     def layers(params, hidden, k_pool, v_pool, tables, positions, valid,
                kv_limit):
-        return engine_mod.paged_layers(
-            cfg, params, hidden, k_pool, v_pool, tables, positions, valid,
-            kv_limit, PAGE, 64)
+        hidden, (k_pool, v_pool) = serving.layers(
+            params, hidden, (k_pool, v_pool), positions,
+            serving.kind.mixer(tables, positions, valid, kv_limit))
+        return hidden, k_pool, v_pool
 
     sds = lambda shape, dtype: place(jax.ShapeDtypeStruct(shape, dtype))
     compiled = jax.jit(layers, donate_argnums=(2, 3)).lower(
@@ -124,12 +129,11 @@ def test_state_is_updated_in_place_on_a_v5e(one_chip, program):
     """4.36 GB of recurrent state ride in the layer scan's carry: the
     donated arrays are the outputs, the temporaries stay far below one
     layer's state (545 MB), and the whole state is never copied."""
-    from deepspeed_tpu.inference.config import InferenceConfig
     from deepspeed_tpu.models import brumby
     cfg = brumby.BrumbyConfig(num_hidden_layers=8)
     block = InferenceConfig({"inference": {
         "max_slots": SLOTS, "prefill_chunk": 512, "max_seq_len": 8192}})
-    family = cfg.serving(block, 8192)
+    family = engine_mod.Serving(cfg, block, 8192)
     place = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
                                            sharding=one_chip)
     sds = lambda shape, dtype: place(jax.ShapeDtypeStruct(shape, dtype))

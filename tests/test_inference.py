@@ -512,8 +512,8 @@ def test_int8_weight_quant_within_pinned_tolerance(setup):
 
 
 def test_int8_quant_roundtrip_unit():
-    from deepspeed_tpu.inference.quant import (int8_matmul,
-                                               quantize_kernel_int8)
+    from deepspeed_tpu.ops.transformer.quantized_matmul import (
+        int8_matmul, quantize_kernel_int8_np as quantize_kernel_int8)
     r = np.random.RandomState(14)
     w = (r.randn(48, 24) * 0.05).astype(np.float32)
     q, s = quantize_kernel_int8(w, block=16)

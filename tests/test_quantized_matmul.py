@@ -190,12 +190,25 @@ def test_bf16_fallback_is_bit_identical_without_sr():
 
 
 # ----------------------------------------------------------------------
-# the serving dedupe: inference/quant.py IS the shared primitive
+# the serving dedupe: the int8 load and a served block's dense
+# application ARE the shared primitive
 # ----------------------------------------------------------------------
 def test_inference_quant_is_the_shared_primitive():
-    from deepspeed_tpu.inference import quant as iq
-    assert iq.int8_matmul is qm.int8_matmul
-    assert iq.quantize_kernel_int8 is qm.quantize_kernel_int8_np
+    from deepspeed_tpu.inference import engine
+    from deepspeed_tpu.models import gpt2
+    assert gpt2.int8_matmul is qm.int8_matmul
+    assert gpt2.KERNEL_SCALE is engine.KERNEL_SCALE is qm.KERNEL_SCALE
+    w = np.random.RandomState(0).randn(3, 40, 8).astype(np.float32)
+    tree = {"h": {"c_fc": {"kernel": w, "bias": np.zeros((3, 8))},
+                  "ln_1": {"scale": np.ones((3, 40))}}, "wte": w[0]}
+    got = engine.quantize_param_tree(tree, 16, ("c_fc",))
+    q, s = qm.quantize_kernel_int8_np(w, 16)
+    leaf = got["h"]["c_fc"]
+    assert np.array_equal(leaf["kernel"], q) and leaf["kernel"].dtype == np.int8
+    assert np.array_equal(leaf[qm.KERNEL_SCALE], s)
+    assert got["wte"] is tree["wte"] and got["h"]["ln_1"] == tree["h"]["ln_1"]
+    assert engine.quantize_param_tree(tree, 16, ())["h"]["c_fc"] == \
+        tree["h"]["c_fc"]
 
 
 # ----------------------------------------------------------------------
