@@ -60,7 +60,7 @@ from deepspeed_tpu.monitor import DeepSpeedMonitorConfig, Monitor
 from deepspeed_tpu.monitor import memory as memory_mod
 from deepspeed_tpu.monitor import programs
 from deepspeed_tpu.monitor.trace import profiler_span
-from deepspeed_tpu.ops.retention import retention_chunked, retention_step
+from deepspeed_tpu.ops.retention import retention_chunked, retention_decode
 from deepspeed_tpu.ops.transformer.paged_decode_attention import \
     paged_decode_attention
 from deepspeed_tpu.ops.transformer.quantized_matmul import (
@@ -312,9 +312,11 @@ class RecurrentKind:
     """Recurrent state: per layer, slot and key/value head a matrix
     and its normaliser (`kv_cache.RecurrentStateCache.state_shapes`).
     Decode advances every slot's state by one token and reads it in
-    the same region; inactive slots keep theirs. Prefill advances one
-    slot's state by a chunk, from zero if the chunk is the request's
-    first."""
+    the same region (`retention_decode` on layer `li` of the whole
+    arrays: where Mosaic takes it one kernel call that passes over the
+    state once, in place; else the XLA form); inactive slots keep
+    theirs. Prefill advances one slot's state by a chunk, from zero if
+    the chunk is the request's first."""
     keys = ("state_s", "state_z")
 
     def __init__(self, model_config, config, max_seq_len):
@@ -348,11 +350,11 @@ class RecurrentKind:
         def mix(li, q, k, v, lg, cache):
             S, z = cache
             with jax.named_scope(SCOPE_STATE_UPDATE):
-                o, S_l, z_l = retention_step(
-                    q[:, 0], k[:, 0], v[:, 0], lg[:, 0], S[li], z[li],
+                o, S, z = retention_decode(
+                    q[:, 0], k[:, 0], v[:, 0], lg[:, 0], S, z, li,
                     mc.retention_scale, mc.retention_eps, keep=idle,
                     fresh=pos == 0)
-                return o[:, None], (S.at[li].set(S_l), z.at[li].set(z_l))
+                return o[:, None], (S, z)
         return mix
 
     def prefill_mixer(self, slot, posv, valid, start, n_valid):

@@ -215,13 +215,14 @@ class PagedKVCache:
         return {"kv_pages_in_use": int(self.pages_in_use()),
                 "kv_pages_free": int(self.free_pages())}
 
-    def attended(self, active, pos):
+    def attended(self, active, pos, launches=0, advanced=0):
         """How far the decode kernel engages at the next launch, from
         what the fence fetched (`active`, `pos` of every slot; host
         arrays): the pages it walks, ceil((pos + 1) / page) summed
         over the live slots, and their share of the window the
         gathered path attended to whatever was live (max_slots x
-        max_pages_per_slot)."""
+        max_pages_per_slot). The fence's own launches are not read
+        here (recurrent state counts them)."""
         pages = int((-(-(pos[active] + 1) // self.page_size)).sum())
         return {"kv_pages_attended": pages,
                 "kv_pages_attended_share": round(
@@ -454,9 +455,16 @@ class RecurrentStateCache:
 
     ledger_occupancy = occupancy       # the manager's own counters
 
-    def attended(self, active, pos):
-        """A model of state attends to no pages."""
-        return {}
+    def attended(self, active, pos, launches=0, advanced=0):
+        """A model of state attends to no pages. What its decode
+        kernel moved over the fence just closed: every launch streams
+        every slot's state (`launches` x max_slots), and `advanced` of
+        those slot-steps belonged to a live request (a live slot takes
+        one token a launch, so it is the fence's tokens); their ratio
+        is the share of the kernel's traffic that advanced a
+        request."""
+        return {"state_slots_streamed": int(launches) * self.max_slots,
+                "state_slots_advanced": int(advanced)}
 
     def utilization_counter(self, occupancy):
         return "state_slot_utilization", {

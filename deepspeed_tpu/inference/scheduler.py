@@ -321,6 +321,12 @@ class ServingLoop:
                 [window_s / new_tokens] * new_tokens)
         self._last_fence_t = now
         mon = self._infer.monitor
+        # how far the decode kernel engages: the pages it walks at the
+        # next launch, or for recurrent state the slots it streamed
+        # and advanced over this fence's launches; host arithmetic on
+        # what the fence already fetched
+        engaged = self._infer.cache.attended(
+            snap["active"], snap["pos"], iterations, new_tokens)
         mon.event(
             "decode_batch",
             iterations=int(iterations),
@@ -332,17 +338,13 @@ class ServingLoop:
             tokens_per_sec=round(new_tokens / window_s, 3),
             # pages in use and free, or for a model of recurrent state
             # the slots holding state and its bytes
-            **self._infer.cache.occupancy(),
-            # the pages the decode kernel walks at the next launch:
-            # host arithmetic on what the fence already fetched
-            **self._infer.cache.attended(snap["active"], snap["pos"]))
+            **self._infer.cache.occupancy(), **engaged)
         if trk is not None:
             # SLO metrics AFTER evictions: this fence's finishes are in
             # the histograms/counters the event reports
             trk.on_fence_metrics(window_s, new_tokens,
                                  len(self.queue), len(self.live),
-                                 len(self.prefilling), snap["active"],
-                                 snap["pos"])
+                                 len(self.prefilling), engaged)
         if mon.memory_enabled:
             mon._emit_memory_event(self._infer._host_steps)
 

@@ -23,8 +23,9 @@ untied from the embedding. No projection has a bias; the gate's bg
 ONE functional `block` holds that. Its `mixer` is the retention call:
 the model's own full-sequence `forward` hands it `retention_chunked`
 from zero state, the serving engine's prefill program the same from
-the slot's state, its decode program `retention_step` (the recurrent
-kind's two mixers in `inference/engine.py`, which composes `embed`,
+the slot's state, its decode program `retention_decode` (one token of
+every slot: a kernel on the chip, `retention_step` elsewhere; the
+recurrent kind's two mixers in `inference/engine.py`, which composes `embed`,
 `block`, `head` and `layers` and imports nothing from here). There is
 no second copy of the block.
 

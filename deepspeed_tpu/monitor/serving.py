@@ -322,12 +322,12 @@ class ServingTracker:
         self._update_flight()
 
     def on_fence_metrics(self, window_s, window_tokens, queue_depth,
-                         active_slots, prefilling_slots, active, pos):
+                         active_slots, prefilling_slots, engaged):
         """The fence's SLO rendezvous: one `serving_slo` event + the
         counter tracks, after evictions settled (so the counts include
-        this fence's finishes). `active`, `pos`: every slot's flag and
-        position as the fence fetched them (host arrays), for the
-        pages the decode kernel walks next."""
+        this fence's finishes). `engaged`: the cache manager's
+        `attended` of this fence (the pages the decode kernel walks
+        next, or the slots of state it streamed and advanced)."""
         with self._lock:
             self._queue_depth = int(queue_depth)
             c = dict(self.counters)
@@ -346,7 +346,7 @@ class ServingTracker:
             prefilling_slots=int(prefilling_slots),
             queue_depth=int(queue_depth),
             **occupancy,
-            **self._cache.attended(active, pos),
+            **engaged,
             queue_wait_share=round(qw / e2e, 4) if e2e > 0 else None,
             ttft_ms=self.hist_ttft_ms.to_event(),
             token_ms=self.hist_token_ms.to_event(),

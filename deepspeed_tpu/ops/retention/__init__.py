@@ -1,4 +1,7 @@
 from deepspeed_tpu.ops.retention.retention import (
     phi, retention_chunked, retention_step, state_dim)
+from deepspeed_tpu.ops.retention.decode import (
+    retention_decode, retention_decode_kernel)
 
-__all__ = ["phi", "retention_chunked", "retention_step", "state_dim"]
+__all__ = ["phi", "retention_chunked", "retention_decode",
+           "retention_decode_kernel", "retention_step", "state_dim"]

@@ -300,6 +300,38 @@ def test_fence_rows_report_the_state_where_pages_were(model32, tmp_path):
     assert engine.tracker.snapshot()["state_slots_free"] == 3
 
 
+def test_fence_rows_count_the_slots_streamed_and_advanced(model32, tmp_path):
+    """Every decode launch streams every slot's state; the launches in
+    which a slot was live advanced a request (one token each). Both on
+    every `decode_batch` / `serving_slo` row, host arithmetic at the
+    fence."""
+    cfg, params, _ = model32
+    engine = InferenceEngine(cfg, params, {
+        "inference": BLOCK,
+        "monitor": {"enabled": True, "output_path": str(tmp_path),
+                    "sinks": ["jsonl"]}})
+    rows = {"decode_batch": [], "serving_slo": []}
+    real = engine.monitor.event
+    engine.monitor.event = lambda name, **kw: (
+        rows[name].append(kw) if name in rows else None, real(name, **kw))[1]
+    rng = np.random.default_rng(9)
+    served = ServingLoop(engine).serve([
+        Request(rid=i, tokens=rng.integers(0, 97, n), max_new_tokens=m)
+        for i, (n, m) in enumerate([(20, 6), (3, 5)])])
+    assert len(rows["decode_batch"]) == len(rows["serving_slo"]) > 2
+    for batch, slo in zip(rows["decode_batch"], rows["serving_slo"]):
+        assert batch["state_slots_streamed"] == \
+            batch["iterations"] * BLOCK["max_slots"]
+        assert batch["state_slots_advanced"] == batch["window_tokens"] <= \
+            batch["state_slots_streamed"]
+        assert (slo["state_slots_streamed"], slo["state_slots_advanced"]) \
+            == (batch["state_slots_streamed"], batch["state_slots_advanced"])
+    assert sum(r["state_slots_advanced"] for r in rows["decode_batch"]) == \
+        sum(len(r.out_tokens) for r in served) == 11
+    assert engine.cache.attended(None, None, 4, 7) == {
+        "state_slots_streamed": 12, "state_slots_advanced": 7}
+
+
 def test_programs_carry_the_state_and_name_their_regions(model32):
     """Both state arrays are carry of the layer scan in both programs
     (nothing state-shaped is an xs or a ys), and every region of
