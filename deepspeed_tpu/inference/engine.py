@@ -18,7 +18,8 @@ construction — no trace-on-first-request latency spike):
 The model supplies the block, the engine supplies the cache (the seam;
 docs/inference.md has it at length):
 
-  * a MODEL MODULE (`models/gpt2.py`, `models/brumby.py`) holds the
+  * a MODEL MODULE (`models/gpt2.py`, `models/brumby.py`,
+    `models/falcon_h1.py`) holds the
     model's math as plain functions: `embed(mc, params, tokens,
     positions)`, ONE `block(mc, lp, hidden, positions, mixer, cache)
     -> (hidden, cache)`, `head(mc, params, hidden)`, `layers(params)`
@@ -27,14 +28,17 @@ docs/inference.md has it at length):
     () refuses it) and, where `truncate:N` drafts are served,
     `first_layers(mc, params, n)`. The block computes its own
     projections under SCOPE_ATTN_QKV / SCOPE_ATTN_OUT / SCOPE_MLP and
-    calls `mixer` exactly once with what it projected; it knows nothing
+    calls `mixer` exactly once with what it projected (a block with
+    two branches side by side hands over both branches' projections
+    in that one call and gets both outputs back); it knows nothing
     of pages, tables, slots or state arrays. The MODEL CONFIG names the
     kind of cache the layers keep (`cache_kind`), carries the geometry
     that kind's manager needs and points at the module
     (`serving_module`). `models/` and this package import each other
     nowhere;
-  * the ENGINE owns the kinds of cache (`PagedKind`, `RecurrentKind`)
-    and nothing of any model: per kind the manager
+  * the ENGINE owns the kinds of cache (`PagedKind`, `RecurrentKind`,
+    and `PagedStateKind`: the paged kind and a state kind side by
+    side in every layer) and nothing of any model: per kind the manager
     (inference/kv_cache.py), the fresh device arrays and their keys in
     the engine's state, and the mixers;
   * ONE adapter (`Serving`) composes model x kind for the two programs
@@ -55,12 +59,15 @@ import numpy as np
 
 from deepspeed_tpu.inference.config import InferenceConfig
 from deepspeed_tpu.inference.kv_cache import (PagedKVCache,
+                                              PagedStateCache,
                                               RecurrentStateCache)
 from deepspeed_tpu.monitor import DeepSpeedMonitorConfig, Monitor
 from deepspeed_tpu.monitor import memory as memory_mod
 from deepspeed_tpu.monitor import programs
 from deepspeed_tpu.monitor.trace import profiler_span
 from deepspeed_tpu.ops.retention import retention_chunked, retention_decode
+from deepspeed_tpu.ops.ssm import (causal_conv, split_xbc, ssd_chunked,
+                                   ssm_step)
 from deepspeed_tpu.ops.transformer.paged_decode_attention import \
     paged_decode_attention
 from deepspeed_tpu.ops.transformer.quantized_matmul import (
@@ -71,9 +78,10 @@ from deepspeed_tpu.utils.logging import logger
 from deepspeed_tpu.utils.scopes import (  # noqa: F401
     SCOPE_ATTN, SCOPE_ATTN_OUT, SCOPE_ATTN_QKV, SCOPE_BOOKKEEPING,
     SCOPE_EMBED, SCOPE_HEAD, SCOPE_KV_GATHER, SCOPE_KV_WRITE, SCOPE_LAYERS,
-    SCOPE_MLP, SCOPE_RETENTION_CHUNK, SCOPE_SAMPLE, SCOPE_STATE_RESET,
-    SCOPE_STATE_UPDATE, SCOPES, SCOPES_IN_LAYER, SCOPES_IN_LAYER_RECURRENT,
-    SCOPES_RECURRENT, SCOPES_STATE)
+    SCOPE_MLP, SCOPE_RETENTION_CHUNK, SCOPE_SAMPLE, SCOPE_SSM_CHUNK,
+    SCOPE_SSM_CONV, SCOPE_STATE_RESET, SCOPE_STATE_UPDATE, SCOPES,
+    SCOPES_IN_LAYER, SCOPES_IN_LAYER_RECURRENT,
+    SCOPES_PAGED_STATE, SCOPES_RECURRENT, SCOPES_SSM, SCOPES_STATE)
 
 
 def compile_fresh(lowered):
@@ -218,7 +226,7 @@ def scan_layers(stacked, hidden, cache, layer):
 
 
 # ----------------------------------------------------------------------
-# the two kinds of cache. Per kind: the manager, the fresh device
+# the kinds of cache. Per kind: the manager, the fresh device
 # arrays and their keys in the engine's state, and the mixers a model's
 # block is handed (`mix(li, ...)`: on layer `li` of the WHOLE arrays,
 # which ride in the layer scan's carry; no layer's part is ever sliced
@@ -226,13 +234,17 @@ def scan_layers(stacked, hidden, cache, layer):
 # ----------------------------------------------------------------------
 class PagedKind:
     """K/V page pools ([L, P, page, lanes], one token's K or V on the
-    lanes, zeros from n_head * head_dim up to the lane tile) behind
-    per-slot page tables (`kv_cache.PagedKVCache`)."""
+    lanes, zeros from n_kv_head * head_dim up to the lane tile) behind
+    per-slot page tables (`kv_cache.PagedKVCache`). A model with
+    grouped-query heads names its key/value head count (`n_kv_head`
+    on its config); the pools hold those heads only."""
     keys = ("k_pool", "v_pool")
 
     def __init__(self, model_config, config, max_seq_len):
         self.mc, self.cfg = model_config, config
         self.max_pages = -(-max_seq_len // config.kv_page_size)
+        self.n_kv_head = getattr(model_config, "n_kv_head",
+                                 model_config.n_head)
 
     def make_cache(self, ledger):
         mc, cfg = self.mc, self.cfg
@@ -240,7 +252,8 @@ class PagedKind:
             n_layer=mc.n_layer, n_head=mc.n_head, head_dim=mc.head_dim,
             num_pages=cfg.kv_num_pages, page_size=cfg.kv_page_size,
             max_slots=cfg.max_slots, max_pages_per_slot=self.max_pages,
-            dtype=np.dtype(mc.dtype), ledger=ledger)
+            dtype=np.dtype(mc.dtype), ledger=ledger,
+            n_kv_head=self.n_kv_head)
 
     def fresh(self, cache):
         pool = cache.pool_shape(self.mc.n_layer)
@@ -261,13 +274,17 @@ class PagedKind:
         pages where they lie; a slot with no valid row is not live and
         gets zeros. A prefill chunk: the slot's window is gathered
         through its table row and attended to densely
-        (`paged_attention`)."""
-        h, d = self.mc.n_head, self.mc.head_dim
+        (`paged_attention`), its keys and values repeated to the query
+        head count where heads are grouped.
+
+        q is [B, T, n_head * d], k and v [B, T, n_kv_head * d]."""
+        h, hk, d = self.mc.n_head, self.n_kv_head, self.mc.head_dim
         page_size = self.cfg.kv_page_size
 
         def mix(li, q, k, v, pools):
             k_pool, v_pool = pools
-            b, t, c = q.shape
+            b, t, _ = q.shape
+            c = hk * d
             lanes = k_pool.shape[-1]
             # write-before-read: the chunk's own keys are part of its
             # causal window (a query attends to itself, like the
@@ -288,13 +305,17 @@ class PagedKind:
                     live_len = jnp.where(valid.any(axis=1), kv_limit + 1, 0)
                     attn = paged_decode_attention(
                         q, k_pool, v_pool, li, tables, positions, live_len,
-                        h)
+                        h, hk)
             else:
                 with jax.named_scope(SCOPE_KV_GATHER):
-                    kc = k_pool[li, tables][..., :c].reshape(b, -1, h, d)
-                    vc = v_pool[li, tables][..., :c].reshape(b, -1, h, d)
+                    kc = k_pool[li, tables][..., :c].reshape(b, -1, hk, d)
+                    vc = v_pool[li, tables][..., :c].reshape(b, -1, hk, d)
+                    if h != hk:
+                        kc = jnp.repeat(kc, h // hk, axis=2)
+                        vc = jnp.repeat(vc, h // hk, axis=2)
                 attn = paged_attention(q.reshape(b, t, h, d), kc, vc,
-                                       positions, kv_limit).reshape(b, t, c)
+                                       positions, kv_limit).reshape(
+                                           b, t, h * d)
             return attn, (k_pool, v_pool)
         return mix
 
@@ -308,9 +329,30 @@ class PagedKind:
                           (start + n_valid - 1)[None])
 
 
+NO_SNAPSHOTS = (
+    "inference.speculative.enabled: this model's slots hold recurrent "
+    "state, and a rejected draft is undone by rewinding the cache: "
+    "snapshots of state do not exist yet")
+
+
+def make_state_cache(model_config, config, max_seq_len, ledger):
+    """The manager of a model's recurrent state, in the per-slot
+    shapes its config gives (`state_slot_shapes`)."""
+    return RecurrentStateCache(
+        n_layer=model_config.n_layer,
+        slot_shapes=model_config.state_slot_shapes,
+        max_slots=config.max_slots, max_tokens_per_slot=max_seq_len,
+        ledger=ledger)
+
+
+def fresh_state(keys, cache):
+    return {k: jnp.zeros(shape, dtype) for k, shape, dtype in zip(
+        keys, cache.state_shapes(), cache.state_dtypes())}
+
+
 class RecurrentKind:
     """Recurrent state: per layer, slot and key/value head a matrix
-    and its normaliser (`kv_cache.RecurrentStateCache.state_shapes`).
+    and its normaliser (the config's `state_slot_shapes`).
     Decode advances every slot's state by one token and reads it in
     the same region (`retention_decode` on layer `li` of the whole
     arrays: where Mosaic takes it one kernel call that passes over the
@@ -323,25 +365,13 @@ class RecurrentKind:
         self.mc, self.cfg, self.max_seq_len = (model_config, config,
                                                max_seq_len)
         if config.spec_enabled:
-            raise ValueError(
-                "inference.speculative.enabled: this model's slots hold "
-                "recurrent state, and a rejected draft is undone by "
-                "rewinding the cache: snapshots of state do not exist "
-                "yet")
+            raise ValueError(NO_SNAPSHOTS)
 
     def make_cache(self, ledger):
-        mc = self.mc
-        return RecurrentStateCache(
-            n_layer=mc.n_layer, n_kv_head=mc.num_key_value_heads,
-            state_dim=mc.state_dim, head_dim=mc.head_dim,
-            max_slots=self.cfg.max_slots,
-            max_tokens_per_slot=self.max_seq_len,
-            dtype=np.dtype(mc.state_dtype), ledger=ledger)
+        return make_state_cache(self.mc, self.cfg, self.max_seq_len, ledger)
 
     def fresh(self, cache):
-        s_shape, z_shape = cache.state_shapes()
-        return {"state_s": jnp.zeros(s_shape, self.mc.state_dtype),
-                "state_z": jnp.zeros(z_shape, self.mc.state_dtype)}
+        return fresh_state(self.keys, cache)
 
     def decode_mixer(self, state):
         mc = self.mc
@@ -380,7 +410,97 @@ class RecurrentKind:
         return mix
 
 
-KINDS = {"paged": PagedKind, "recurrent": RecurrentKind}
+class PagedStateKind:
+    """K/V pages AND recurrent state in every layer: a model whose
+    block runs softmax attention and a Mamba-2 state-space mixer side
+    by side (`models/falcon_h1.py`). The two halves lie on their own
+    parts of the cache and neither is copied: the paged half IS
+    `PagedKind` (its pools, its tables, its one mixer); the state half
+    keeps, per layer and slot, the rows the causal convolution carries
+    and the state matrix (the config's `state_slot_shapes`), advanced a
+    chunk by `ssd_chunked` (prefill, from zero if the chunk is the
+    request's first) or one token of every slot by `ssm_step` (decode;
+    inactive slots keep theirs). The block hands both branches'
+    projections over in one call, `mix(li, (q, k, v), (xbc, dt, A, D,
+    conv_w, conv_b), cache)`, and gets both outputs back."""
+    state_keys = ("conv_state", "ssm_state")
+    keys = PagedKind.keys + state_keys
+
+    def __init__(self, model_config, config, max_seq_len):
+        if config.spec_enabled:
+            raise ValueError(NO_SNAPSHOTS)
+        self.mc, self.cfg, self.max_seq_len = (model_config, config,
+                                               max_seq_len)
+        self.paged = PagedKind(model_config, config, max_seq_len)
+
+    def make_cache(self, ledger):
+        return PagedStateCache(
+            self.paged.make_cache(ledger),
+            make_state_cache(self.mc, self.cfg, self.max_seq_len, ledger))
+
+    def fresh(self, cache):
+        return {**self.paged.fresh(cache),
+                **fresh_state(self.state_keys, cache)}
+
+    @staticmethod
+    def both(paged_mix, state_mix):
+        n = len(PagedKind.keys)
+
+        def mix(li, attn_in, ssm_in, cache):
+            o, pools = paged_mix(li, *attn_in, cache[:n])
+            y, state = state_mix(li, *ssm_in, cache[n:])
+            return (o, y), pools + state
+        return mix
+
+    def decode_mixer(self, state):
+        idle, fresh = ~state["active"], state["pos"] == 0
+
+        def mix(li, xbc, dt, A, D, conv_w, conv_b, cache):
+            conv, H = cache
+            with jax.named_scope(SCOPE_SSM_CONV):
+                old = jax.lax.dynamic_index_in_dim(conv, li, 0,
+                                                   keepdims=False)
+                x, rows = causal_conv(
+                    xbc, conv_w, conv_b,
+                    jnp.where(fresh[:, None, None], 0, old))
+                conv = jax.lax.dynamic_update_index_in_dim(
+                    conv, jnp.where(idle[:, None, None], old, rows), li, 0)
+            with jax.named_scope(SCOPE_STATE_UPDATE):
+                xs, B, C = split_xbc(x[:, 0], *H.shape[2:])
+                y, H = ssm_step(xs, dt[:, 0], A, B, C, D, H, li, keep=idle,
+                                fresh=fresh)
+            return y.reshape(y.shape[0], 1, -1), (conv, H)
+        return self.both(self.paged.decode_mixer(state), mix)
+
+    def prefill_mixer(self, where, posv, valid, start, n_valid):
+        page_row, slot = where
+        chunk = self.mc.mamba_chunk_size
+
+        def mix(li, xbc, dt, A, D, conv_w, conv_b, cache):
+            conv, H = cache
+            with jax.named_scope(SCOPE_STATE_RESET):
+                first = start == 0
+                rows0 = jnp.where(first, 0, jax.lax.dynamic_slice(
+                    conv, (li, slot, 0, 0), (1, 1) + conv.shape[2:])[0, 0])
+                H0 = jnp.where(first, 0, jax.lax.dynamic_slice(
+                    H, (li, slot, 0, 0, 0), (1, 1) + H.shape[2:])[0, 0])
+            with jax.named_scope(SCOPE_SSM_CONV):
+                x, rows1 = causal_conv(xbc[0], conv_w, conv_b, rows0,
+                                       n_valid)
+                conv = jax.lax.dynamic_update_slice(
+                    conv, rows1[None, None], (li, slot, 0, 0))
+            with jax.named_scope(SCOPE_SSM_CHUNK):
+                xs, B, C = split_xbc(x, *H.shape[2:])
+                y, H1 = ssd_chunked(xs, dt[0], A, B, C, D, H0, valid, chunk)
+                H = jax.lax.dynamic_update_slice(
+                    H, H1[None, None], (li, slot, 0, 0, 0))
+            return y.reshape(1, y.shape[0], -1), (conv, H)
+        return self.both(self.paged.prefill_mixer(page_row, posv, valid,
+                                                  start, n_valid), mix)
+
+
+KINDS = {"paged": PagedKind, "recurrent": RecurrentKind,
+         "paged+state": PagedStateKind}
 
 
 class Serving:
@@ -430,7 +550,7 @@ class Serving:
     def prefill_layers(self, params, hidden, cache, where, posv, valid,
                        start, n_valid):
         """`where` finds the slot's part of `cache`: its page-table
-        row, or for recurrent state its index."""
+        row, for recurrent state its index, for both the pair."""
         return self.layers(
             params, hidden, cache, posv[None],
             self.kind.prefill_mixer(where, posv, valid, start, n_valid))[1]
@@ -441,7 +561,8 @@ class Serving:
 
 class InferenceEngine:
     """Serving engine for one model over the kind of cache its config
-    names: K/V pages or recurrent state (see the module's docstring).
+    names: K/V pages, recurrent state, or both in every layer (see the
+    module's docstring).
 
     Construction compiles the two programs AOT against the configured
     shapes; `start_request`/`prefill_chunk`/`activate_slot` manage
@@ -653,7 +774,7 @@ class InferenceEngine:
         def prefill_fn(params, cache, where, tokens, start, n_valid):
             """`cache`: the model's cache arrays (`cache_arrays`);
             `where` finds the slot's part of them: its page-table row,
-            or for recurrent state its index."""
+            for recurrent state its index, for both the pair."""
             posv = start + jnp.arange(chunk, dtype=jnp.int32)
             valid = jnp.arange(chunk) < n_valid
             with jax.named_scope(SCOPE_EMBED):
@@ -661,17 +782,22 @@ class InferenceEngine:
             return serving.prefill_layers(params, hidden, cache, where,
                                           posv, valid, start, n_valid)
 
-        args = (self._params, self.cache_arrays(),
-                jnp.asarray(self.cache.slot_operand(0)),
+        args = (self._params, self.cache_arrays(), self._where(0),
                 jnp.zeros((chunk,), jnp.int32),
                 jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
         return compile_registered(prefill_fn, args, donate_argnums=(1,))
 
+    def _where(self, slot):
+        """The cache manager's `slot_operand` as device operands."""
+        return jax.tree_util.tree_map(jnp.asarray,
+                                      self.cache.slot_operand(slot))
+
     def cache_arrays(self):
         """The model's cache arrays as the programs hold them, in the
         order of the kind's `keys` (the device arrays, not
-        copies: both K/V page pools `PagedKVCache.pool_shape`, or the
-        state and its normaliser `RecurrentStateCache.state_shapes`)."""
+        copies: both K/V page pools `PagedKVCache.pool_shape`, the
+        arrays of `RecurrentStateCache.state_shapes`, or the pools and
+        then the state)."""
         return tuple(self._state[k] for k in self.serving.cache_keys)
 
     # ------------------------------------------------------------------
@@ -693,8 +819,8 @@ class InferenceEngine:
         buf[:n] = tokens
         st = self._state
         st.update(zip(self.serving.cache_keys, self._prefill(
-            self._params, self.cache_arrays(),
-            jnp.asarray(self.cache.slot_operand(slot)), jnp.asarray(buf),
+            self._params, self.cache_arrays(), self._where(slot),
+            jnp.asarray(buf),
             jnp.asarray(start, jnp.int32), jnp.asarray(n, jnp.int32))))
         if self.speculative_enabled:
             # the draft attends over the whole committed prefix, so

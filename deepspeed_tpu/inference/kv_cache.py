@@ -7,7 +7,7 @@ pool of fixed-size pages
 
     k_pool / v_pool : [n_layer, num_pages, page_size, lanes]
 
-(one token's K or V of one layer on the lanes, `lanes` = n_head *
+(one token's K or V of one layer on the lanes, `lanes` = n_kv_head *
 head_dim rounded up to a whole number of the chip's 128-lane tiles:
 the layout the compiled programs scatter into in place and the decode
 kernel copies single pages out of, see `engine.scan_layers` and
@@ -35,12 +35,16 @@ name `inference.kv_cache.num_pages` when the cache dominates.
 
 A second kind of slot state lives beside the pages
 (`RecurrentStateCache`): a model whose layers keep a fixed-size
-recurrent state per request (power retention, `models/brumby.py`) owns
-one block of it per slot, sized once and never grown. Both managers
-answer the scheduler's one interface: `can_admit`, `admit`, `ensure`,
-`free`, `reserved_tokens`, `never_fits`, `reservation`, `occupancy`,
-`attended`, `slot_operand` (and `rollback`, which recurrent state
-refuses).
+recurrent state per request (power retention, `models/brumby.py`; a
+state-space mixer's matrix and its convolution's carried rows,
+`models/falcon_h1.py`) owns one block of it per slot, sized once and
+never grown, in the shapes the model's config gives. A model whose
+every layer keeps BOTH (attention over pages and a state-space mixer
+side by side) is served by `PagedStateCache`, the two managers behind
+one. All three answer the scheduler's one interface: `can_admit`,
+`admit`, `ensure`, `free`, `reserved_tokens`, `never_fits`,
+`reservation`, `occupancy`, `attended`, `slot_operand` (and
+`rollback`, which recurrent state refuses).
 """
 
 import numpy as np
@@ -60,7 +64,7 @@ class PagedKVCache:
 
     def __init__(self, n_layer, n_head, head_dim, num_pages, page_size,
                  max_slots, max_pages_per_slot, dtype=np.float32,
-                 ledger=None):
+                 ledger=None, n_kv_head=None):
         if max_pages_per_slot < 1:
             raise ValueError(
                 f"max_pages_per_slot must be >= 1, got {max_pages_per_slot}")
@@ -70,6 +74,8 @@ class PagedKVCache:
                 f"page), got {num_pages}")
         self.n_layer = int(n_layer)
         self.n_head = int(n_head)
+        # grouped-query heads: the pools hold the key/value heads only
+        self.n_kv_head = int(n_head if n_kv_head is None else n_kv_head)
         self.head_dim = int(head_dim)
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
@@ -81,7 +87,7 @@ class PagedKVCache:
         # 1,600 takes 1,664 there whether the shape says so or not; the
         # shape says so, and a page is a whole number of tiles that the
         # decode kernel can copy alone
-        self.lanes = padded_lanes(self.n_head * self.head_dim)
+        self.lanes = padded_lanes(self.n_kv_head * self.head_dim)
         # bytes of ONE page across K+V and all layers: the unit every
         # accounting statement below is phrased in
         self.page_bytes = (2 * self.n_layer * self.page_size *
@@ -139,7 +145,7 @@ class PagedKVCache:
     def pool_shape(self, n_layer):
         """Shape of ONE device pool (K or V) of `n_layer` layers: one
         token's K (or V) of one layer is the minor-most row (`lanes`:
-        its n_head * head_dim values, then zeros up to the lane tile),
+        its n_kv_head * head_dim values, then zeros up to the lane tile),
         so the pool has one natural layout inside and outside the
         compiled programs' layer scan."""
         return (int(n_layer), self.num_pages, self.page_size, self.lanes)
@@ -370,20 +376,23 @@ class PagedKVCache:
 
 class RecurrentStateCache:
     """Slot state that is a fixed block per request, not a page list:
-    for every layer and key/value head a float32 matrix and its
-    normaliser (`models/brumby.py`),
+    for every layer the arrays the model's config names
+    (`state_slot_shapes`: ((shape, dtype), ...) of ONE slot of ONE
+    layer), each held as
 
-        state_s : [n_layer, max_slots, n_kv_head, state_dim, head_dim]
-        state_z : [n_layer, max_slots, n_kv_head, state_dim]
+        [n_layer, max_slots, *shape]
 
-    sized once at construction. A request is admitted by free slot and
-    its state never grows, so `ensure` has nothing to do and there are
-    no page tables. A slot is not cleared when it is freed: the
-    compiled programs start a slot from zero state when its first
-    chunk (`start == 0`) or, for a one-token prompt, its first decode
-    step (`pos == 0`) runs. There are no snapshots of state yet, so
-    `rollback` raises (speculative decoding is refused at engine
-    construction for such a model).
+    and sized once at construction: a retention model's float32 matrix
+    and normaliser per key/value head (`models/brumby.py`: [Hk, D, d]
+    and [Hk, D]), a state-space mixer's convolution rows and state
+    matrix (`models/falcon_h1.py`: [3, 5120] and [32, 128, 256]). A
+    request is admitted by free slot and its state never grows, so
+    `ensure` has nothing to do and there are no page tables. A slot is
+    not cleared when it is freed: the compiled programs start a slot
+    from zero state when its first chunk (`start == 0`) or, for a
+    one-token prompt, its first decode step (`pos == 0`) runs. There
+    are no snapshots of state yet, so `rollback` raises (speculative
+    decoding is refused at engine construction for such a model).
 
     Ledger: the whole block is registered under `recurrent_state`, one
     dynamic `slots.unheld` entry plus one per live request, so the
@@ -392,19 +401,18 @@ class RecurrentStateCache:
     kind = "recurrent"
     table_version = 0                  # no page tables to push
 
-    def __init__(self, n_layer, n_kv_head, state_dim, head_dim, max_slots,
-                 max_tokens_per_slot, dtype=np.float32, ledger=None):
+    def __init__(self, n_layer, slot_shapes, max_slots,
+                 max_tokens_per_slot, ledger=None):
         self.n_layer = int(n_layer)
-        self.n_kv_head = int(n_kv_head)
-        self.state_dim = int(state_dim)
-        self.head_dim = int(head_dim)
+        self.slot_shapes = tuple(
+            (tuple(int(n) for n in shape), np.dtype(dtype))
+            for shape, dtype in slot_shapes)
         self.max_slots = int(max_slots)
         self.max_tokens_per_slot = int(max_tokens_per_slot)
-        self.dtype = np.dtype(dtype)
-        # bytes of ONE slot across all layers, matrix and normaliser
-        self.slot_state_bytes = (self.n_layer * self.n_kv_head *
-                                 self.state_dim * (self.head_dim + 1) *
-                                 self.dtype.itemsize)
+        # bytes of ONE slot across all layers and arrays
+        self.slot_state_bytes = self.n_layer * sum(
+            int(np.prod(shape)) * dtype.itemsize
+            for shape, dtype in self.slot_shapes)
         self.pool_bytes = self.max_slots * self.slot_state_bytes
         self._reserved = {}        # slot -> admitted token capacity
         self._ledger = ledger
@@ -417,10 +425,12 @@ class RecurrentStateCache:
                       "slot_state_bytes": self.slot_state_bytes})
 
     def state_shapes(self):
-        """Shapes of the two device arrays (matrix, normaliser)."""
-        lead = (self.n_layer, self.max_slots, self.n_kv_head,
-                self.state_dim)
-        return lead + (self.head_dim,), lead
+        """Shapes of the device arrays, in the config's order."""
+        return tuple((self.n_layer, self.max_slots) + shape
+                     for shape, _ in self.slot_shapes)
+
+    def state_dtypes(self):
+        return tuple(dtype for _, dtype in self.slot_shapes)
 
     # -- accounting -----------------------------------------------------
     def slots(self):
@@ -524,3 +534,100 @@ class RecurrentStateCache:
         if token is not None and self._ledger is not None:
             self._ledger.release(token)
         return 0
+
+
+class PagedStateCache:
+    """Both kinds of slot state side by side, for a model whose every
+    layer keeps K/V pages AND a fixed block of recurrent state
+    (`models/falcon_h1.py`): `pages` (a `PagedKVCache`) and `state` (a
+    `RecurrentStateCache`) behind the one interface. A request is
+    admitted only if both have room and holds its part of both until it
+    is freed; neither half knows of the other, and every fence row
+    carries both halves' counters (`kv_pages_*` and `state_slots_*`).
+    State cannot be rewound, so `rollback` is refused as for recurrent
+    state alone."""
+
+    kind = "paged+state"
+
+    def __init__(self, pages, state):
+        self.pages, self.state = pages, state
+        self.pool_bytes = pages.pool_bytes + state.pool_bytes
+        self.num_pages = pages.num_pages      # the tracker's snapshot
+
+    # the page tables are the paged half's
+    tables = property(lambda self: self.pages.tables)
+    table_version = property(lambda self: self.pages.table_version)
+
+    def pool_shape(self, n_layer):
+        return self.pages.pool_shape(n_layer)
+
+    def state_shapes(self):
+        return self.state.state_shapes()
+
+    def state_dtypes(self):
+        return self.state.state_dtypes()
+
+    def slots(self):
+        return self.state.slots()
+
+    def reserved_tokens(self, slot):
+        return min(self.pages.reserved_tokens(slot),
+                   self.state.reserved_tokens(slot))
+
+    def allocated_pages(self, slot):
+        return self.pages.allocated_pages(slot)
+
+    def never_fits(self, n_tokens_worst_case):
+        return self.pages.never_fits(n_tokens_worst_case) or \
+            self.state.never_fits(n_tokens_worst_case)
+
+    def reservation(self, n_tokens_worst_case):
+        return {**self.pages.reservation(n_tokens_worst_case),
+                **self.state.reservation(n_tokens_worst_case)}
+
+    def occupancy(self):
+        return {**self.pages.occupancy(), **self.state.occupancy()}
+
+    def ledger_occupancy(self):
+        return {**self.pages.ledger_occupancy(),
+                **self.state.ledger_occupancy()}
+
+    def attended(self, active, pos, launches=0, advanced=0):
+        return {**self.pages.attended(active, pos, launches, advanced),
+                **self.state.attended(active, pos, launches, advanced)}
+
+    def utilization_counter(self, occupancy):
+        """The trace export has one track a cache: the pages' (what
+        fills first; the state's slots follow `batch_occupancy`)."""
+        return self.pages.utilization_counter(occupancy)
+
+    def slot_operand(self, slot):
+        """What the prefill program is handed to find `slot`'s cache:
+        its page-table row AND its index into the state."""
+        return (self.pages.slot_operand(slot),
+                self.state.slot_operand(slot))
+
+    def can_admit(self, n_tokens_worst_case):
+        return self.pages.can_admit(n_tokens_worst_case) and \
+            self.state.can_admit(n_tokens_worst_case)
+
+    def admit(self, slot, n_tokens_worst_case, name=None):
+        if not self.can_admit(n_tokens_worst_case):
+            raise RuntimeError(
+                f"cache cannot admit {n_tokens_worst_case} tokens: "
+                f"{self.pages.free_pages()} free pages, "
+                f"{self.pages.reserved_unallocated()} already reserved, "
+                f"{self.state.free_slots()} free slots of state")
+        self.pages.admit(slot, n_tokens_worst_case, name)
+        self.state.admit(slot, n_tokens_worst_case, name)
+
+    def ensure(self, slot, n_tokens):
+        self.state.ensure(slot, n_tokens)
+        return self.pages.ensure(slot, n_tokens)
+
+    def rollback(self, slot, n_tokens):
+        return self.state.rollback(slot, n_tokens)
+
+    def free(self, slot):
+        self.state.free(slot)
+        return self.pages.free(slot)

@@ -45,6 +45,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from deepspeed_tpu.ops.retention import retention_chunked
 from deepspeed_tpu.ops.retention import state_dim as _state_dim
@@ -100,6 +101,14 @@ class BrumbyConfig:
     n_positions = property(lambda self: self.max_position_embeddings)
     retention_scale = property(lambda self: 1.0 / self.head_dim)
     state_dim = property(lambda self: _state_dim(self.head_dim))
+
+    @property
+    def state_slot_shapes(self):
+        """((shape, dtype), ...) of ONE slot's state in ONE layer: the
+        matrix and its normaliser per key/value head."""
+        lead = (self.num_key_value_heads, self.state_dim)
+        dtype = np.dtype(self.state_dtype)
+        return ((lead + (self.head_dim,), dtype), (lead, dtype))
 
 
 def init_params(cfg, key):

@@ -33,6 +33,15 @@ diagonal blocks: H times the useful products, on a unit that is
 otherwise idle while the pages stream in. The operands are the pools'
 dtype, every product sum accumulates in fp32, nothing is approximated.
 
+Grouped-query heads: the pools hold Hk = H / G key/value heads (a row
+of Hk*D lanes) and G query heads read each. The query rows come one
+group member to a row, q [B, Tq*G, Hk*D] (row r*G + j holds, on
+key/value head kv's lanes, query head kv*G + j), the indicator's row
+j*Hk + kv keeps the lanes of kv from row j, E[h, l] = (l // D ==
+h % Hk), and the result leaves the same way: Hk times the useful
+products where H heads of their own cost H times. With G = 1 every
+expression below is the one it was.
+
 Every query row runs the same sequence of operations on the same page
 blocks whatever Tq is (a static loop over the rows), so a row of a
 Tq = k + 1 verify launch equals the Tq = 1 decode launch at that
@@ -66,9 +75,10 @@ def padded_lanes(width):
 
 def _kernel(li_ref, tables_ref, lens_ref, qpos_ref, q_ref, k_hbm, v_hbm,
             o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *, n_head,
-            head_dim, sm_scale, precision):
+            n_kv_head, head_dim, sm_scale, precision):
     s = pl.program_id(0)
-    tq, lanes = q_ref.shape[1:]
+    group = n_head // n_kv_head
+    tq, lanes = q_ref.shape[1] // group, q_ref.shape[2]
     hp = acc_ref.shape[1]
     _, npb, page, _ = kbuf.shape
     bk = npb * page
@@ -100,10 +110,21 @@ def _kernel(li_ref, tables_ref, lens_ref, qpos_ref, q_ref, k_hbm, v_hbm,
         for_pages_of(0, 0, lambda copy: copy.start())
         head = jax.lax.broadcasted_iota(jnp.int32, (hp, lanes), 0)
         lane = jax.lax.broadcasted_iota(jnp.int32, (hp, lanes), 1)
-        mine = (lane >= head * head_dim) & (lane < (head + 1) * head_dim) \
+        # row `head` of the indicator reads key/value head `kv`
+        kv = head if group == 1 else head % n_kv_head
+        mine = (lane >= kv * head_dim) & (lane < (kv + 1) * head_dim) \
             & (head < n_head)
-        qbd = [jnp.where(mine, q_ref[0, r:r + 1, :], 0.0).astype(kbuf.dtype)
-               for r in range(tq)]
+        # the rows of group member j (all of them where G = 1)
+        member = [mine if group == 1 else mine & (head // n_kv_head == j)
+                  for j in range(group)]
+
+        def block_diagonal(r):
+            rows = [jnp.where(member[j],
+                              q_ref[0, r * group + j:r * group + j + 1, :],
+                              0.0) for j in range(group)]
+            return sum(rows[1:], rows[0]).astype(kbuf.dtype)
+
+        qbd = [block_diagonal(r) for r in range(tq)]
         m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
@@ -146,20 +167,33 @@ def _kernel(li_ref, tables_ref, lens_ref, qpos_ref, q_ref, k_hbm, v_hbm,
         jax.lax.fori_loop(0, n_blocks, block, 0)
         for r in range(tq):
             heads = jnp.where(mine, acc_ref[r] / l_ref[r][:, :1], 0.0)
-            o_ref[0, r:r + 1, :] = jnp.sum(heads, axis=0, keepdims=True)
+            for j in range(group):
+                o_ref[0, r * group + j:r * group + j + 1, :] = jnp.sum(
+                    heads if group == 1 else
+                    jnp.where(member[j], heads, 0.0), axis=0, keepdims=True)
 
 
 def paged_decode_attention(q, k_pool, v_pool, li, tables, q_pos, lens,
-                           n_head):
+                           n_head, n_kv_head=None):
     """Causal attention of q [B, Tq, H*D] (Tq a few rows) against layer
     `li` of the page pools [L, P, page, lanes], through the page tables
-    [B, max_pages]. Row r of slot b sits at absolute position
+    [B, max_pages]. The pools hold `n_kv_head` heads a token (default:
+    `n_head`); query head h reads key/value head h // (n_head //
+    n_kv_head). Row r of slot b sits at absolute position
     q_pos[b, r] and sees keys at positions <= it and < lens[b], the
     slot's live length (0: the slot is not live; it returns zeros and
     reads nothing). Returns [B, Tq, H*D] in q's dtype. Rows of the
     pools at or past a slot's length may hold anything finite or not:
     they contribute exactly nothing."""
     b, tq, c = q.shape
+    n_kv_head = n_head if n_kv_head is None else n_kv_head
+    group = n_head // n_kv_head
+    head_dim = c // n_head
+    if group > 1:
+        # one group member to a row, on its key/value head's lanes
+        q = q.reshape(b, tq, n_kv_head, group, head_dim).transpose(
+            0, 1, 3, 2, 4).reshape(b, tq * group, n_kv_head * head_dim)
+        c = n_kv_head * head_dim
     _, _, page, lanes = k_pool.shape
     if lanes != padded_lanes(c) or v_pool.shape != k_pool.shape:
         raise ValueError(
@@ -173,11 +207,10 @@ def paged_decode_attention(q, k_pool, v_pool, li, tables, q_pos, lens,
             f"a page of {page} tokens is not a whole number of the chip's "
             f"{sublanes}-row tiles of {dtype}: a page cannot be copied "
             "alone (inference.kv_cache.page_size)")
-    head_dim = c // n_head
     hp = -(-n_head // 16) * 16
     npb = max(1, _BLOCK_KEYS // page)
     kernel = functools.partial(
-        _kernel, n_head=n_head, head_dim=head_dim,
+        _kernel, n_head=n_head, n_kv_head=n_kv_head, head_dim=head_dim,
         sm_scale=1.0 / np.sqrt(head_dim),
         precision=jax.lax.Precision.HIGHEST if dtype == jnp.float32
         else None)
@@ -185,10 +218,10 @@ def paged_decode_attention(q, k_pool, v_pool, li, tables, q_pos, lens,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(b,),
-        in_specs=[pl.BlockSpec((1, tq, lanes), row),
+        in_specs=[pl.BlockSpec((1, tq * group, lanes), row),
                   pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((1, tq, lanes), row),
+        out_specs=pl.BlockSpec((1, tq * group, lanes), row),
         scratch_shapes=[
             pltpu.VMEM((2, npb, page, lanes), dtype),
             pltpu.VMEM((2, npb, page, lanes), dtype),
@@ -202,11 +235,15 @@ def paged_decode_attention(q, k_pool, v_pool, li, tables, q_pos, lens,
         kernel,
         name="paged_decode_attention",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, tq, lanes), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, tq * group, lanes), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(jnp.reshape(li, (1,)).astype(jnp.int32), tables.astype(jnp.int32),
       lens.astype(jnp.int32), q_pos.astype(jnp.int32), q32, k_pool, v_pool)
-    return out[..., :c].astype(q.dtype)
+    out = out[..., :c].astype(q.dtype)
+    if group > 1:
+        out = out.reshape(b, tq, group, n_kv_head, head_dim).transpose(
+            0, 1, 3, 2, 4).reshape(b, tq, n_head * head_dim)
+    return out

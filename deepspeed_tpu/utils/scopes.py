@@ -9,10 +9,14 @@ stacked tree. The model's cache rides in the scan's carry whole
 SCOPE_KV_WRITE, SCOPE_KV_GATHER and, in the programs that attend
 through the decode kernel, SCOPE_ATTN for the K/V page pools of a
 paged model, SCOPE_STATE_RESET, SCOPE_RETENTION_CHUNK and SCOPE_STATE_UPDATE
-for the state of a recurrent one. A cache-sized copy showing up under
+for the state of a recurrent one; a model that keeps both in every
+layer (SCOPES_PAGED_STATE) has the paged regions and, for its
+state-space mixer, SCOPE_STATE_RESET, SCOPE_SSM_CONV, SCOPE_SSM_CHUNK
+and SCOPE_STATE_UPDATE. A cache-sized copy showing up under
 SCOPE_LAYERS alone is a regression.
 
-A model's block (`models/gpt2.py`, `models/brumby.py`) and the engine
+A model's block (`models/gpt2.py`, `models/brumby.py`,
+`models/falcon_h1.py`) and the engine
 (`inference/engine.py`, which re-exports them) both take the names
 from here: neither the models nor the ops import the serving code.
 """
@@ -51,3 +55,22 @@ SCOPES_IN_LAYER_RECURRENT = (SCOPE_ATTN_QKV,) + SCOPES_STATE + \
 SCOPES_RECURRENT = (SCOPE_EMBED, SCOPE_LAYERS) + \
     SCOPES_IN_LAYER_RECURRENT + (SCOPE_HEAD, SCOPE_SAMPLE,
                                  SCOPE_BOOKKEEPING)
+
+# a model whose every layer keeps BOTH: K/V pages for its attention
+# branch and, for a state-space mixer beside it, a state matrix and
+# the rows its causal convolution carries (`models/falcon_h1.py`).
+# Both branches' input projections stand under SCOPE_ATTN_QKV, both
+# output projections and the residual under SCOPE_ATTN_OUT; the paged
+# regions keep their meaning; SCOPE_STATE_RESET and SCOPE_STATE_UPDATE
+# keep theirs (decode: every slot's state advanced a token and read)
+SCOPE_SSM_CONV = "ssm_conv"        # the convolution and its carried rows
+SCOPE_SSM_CHUNK = "ssm_chunk"      # prefill: the chunked scan, the slot's
+#                                    state read and written back
+SCOPES_SSM = (SCOPE_STATE_RESET, SCOPE_SSM_CONV, SCOPE_SSM_CHUNK,
+              SCOPE_STATE_UPDATE)
+SCOPES_IN_LAYER_PAGED_STATE = (
+    SCOPE_ATTN_QKV, SCOPE_KV_WRITE, SCOPE_KV_GATHER, SCOPE_ATTN) + \
+    SCOPES_SSM + (SCOPE_ATTN_OUT, SCOPE_MLP)
+SCOPES_PAGED_STATE = (SCOPE_EMBED, SCOPE_LAYERS) + \
+    SCOPES_IN_LAYER_PAGED_STATE + (SCOPE_HEAD, SCOPE_SAMPLE,
+                                   SCOPE_BOOKKEEPING)

@@ -244,3 +244,111 @@ def test_state_regions_survive_the_chips_compiler(brumby_scans, program):
     if program == "decode":
         call, = [n for n in touching if n.startswith("retention_decode")]
         assert touching[call] == "state_update"
+
+
+# ----------------------------------------------------------------------
+# Falcon-H1's layer scans at the shapes of
+# `falcon-h1-34b.serve-longctx-steady` (6 layers of the published
+# widths, 16 slots, chunk 512, 1,537 pages of 128; ISSUE 31)
+# ----------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def falcon_scans(one_chip):
+    """program -> (compiled layer scan, the four cache shapes), with
+    the decode kernel's backend probe answering "TPU" (here it sees
+    the CPU and would hand the chip's compiler the interpreter's
+    XLA)."""
+    from deepspeed_tpu.models import falcon_h1
+    from deepspeed_tpu.ops.transformer import paged_decode_attention
+    cfg = falcon_h1.FalconH1Config(num_hidden_layers=6)
+    block = InferenceConfig({"inference": {
+        "max_slots": SLOTS, "prefill_chunk": 512, "max_seq_len": 16384,
+        "kv_cache": {"num_pages": 1537, "page_size": 128}}})
+    family = engine_mod.Serving(cfg, block, 16384)
+    cache = family.kind.make_cache(None)
+    place = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip)
+    sds = lambda shape, dtype: place(jax.ShapeDtypeStruct(shape, dtype))
+    params = jax.tree_util.tree_map(place, jax.eval_shape(
+        lambda k: falcon_h1.init_params(cfg, k), jax.random.PRNGKey(0)))
+    pool = sds(cache.pool_shape(6), cfg.dtype)
+    conv, H = (sds(shape, dtype) for shape, dtype in zip(
+        cache.state_shapes(), cache.state_dtypes()))
+    arrays = (pool, pool, conv, H)
+
+    def decode_layers(params, hidden, k, v, conv, H, tables, pos, active):
+        return family.decode_layers(params, hidden, {
+            "k_pool": k, "v_pool": v, "conv_state": conv, "ssm_state": H,
+            "tables": tables, "pos": pos, "active": active})
+
+    def prefill_layers(params, hidden, k, v, conv, H, row, slot, start,
+                       n_valid):
+        posv = start + jnp.arange(512, dtype=jnp.int32)
+        return family.prefill_layers(
+            params, hidden, (k, v, conv, H), (row, slot), posv,
+            jnp.arange(512) < n_valid, start, n_valid)
+
+    programs = {
+        "decode": (decode_layers, (
+            params, sds((SLOTS, 1, 5120), cfg.dtype)) + arrays + (
+            sds((SLOTS, 128), jnp.int32), sds((SLOTS,), jnp.int32),
+            sds((SLOTS,), bool))),
+        "prefill": (prefill_layers, (
+            params, sds((1, 512, 5120), cfg.dtype)) + arrays + (
+            sds((128,), jnp.int32),) + (sds((), jnp.int32),) * 3)}
+    compiled = {}
+
+    def get(program):
+        if program not in compiled:
+            layers, args = programs[program]
+            probe = paged_decode_attention._on_tpu
+            paged_decode_attention._on_tpu = lambda: True
+            try:
+                compiled[program] = jax.jit(
+                    layers, donate_argnums=(2, 3, 4, 5)).lower(
+                        *args).compile()
+            finally:
+                paged_decode_attention._on_tpu = probe
+        return compiled[program], arrays
+    return get
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_both_caches_are_updated_in_place_on_a_v5e(falcon_scans, program):
+    """2.42 GB of K/V pools (rows of 4 x 128 lanes: the key/value
+    heads only) and 0.40 GB of state-space state ride in the layer
+    scan's carry side by side: all four donated arrays are the
+    outputs, no pool- or state-shaped array is copied, Mosaic takes
+    the grouped-query decode kernel (one call in the scan's body), and
+    every region of the state half survives the chip's fusions."""
+    from benchmark import region_join
+    from deepspeed_tpu.monitor import programs
+    compiled, arrays = falcon_scans(program)
+    pool, _, conv, H = arrays
+    assert pool.shape == (6, 1537, 128, 512) and \
+        H.shape == (6, SLOTS, 32, 128, 256) and \
+        conv.shape == (6, SLOTS, 3, 5120)
+    cache_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                      for a in arrays)
+    memory = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert memory.alias_size_in_bytes >= cache_bytes
+    for a in (pool, H):
+        whole = ",".join(map(str, a.shape))
+        moved = re.findall(rf"= \w+\[{whole}\]\S* (copy|transpose)\(", text)
+        assert moved == []
+    scopes = programs.parse_op_scopes(text)
+    regions = {region_join.region_of(stack, region_join.PAGED_STATE)
+               for stack in scopes.values()}
+    if program == "decode":
+        assert memory.temp_size_in_bytes < 64 << 20
+        calls = re.findall(
+            r'custom-call\(.*custom_call_target="tpu_custom_call"', text)
+        assert len(calls) == 1 and "paged_decode_attention" in text
+        assert {"kv_write", "attn", "ssm_conv", "state_update"} <= regions
+        assert not regions & {"kv_gather", "ssm_chunk", "state_reset"}
+    else:
+        # the float32 scores of 512 rows against the 16,384-key window
+        assert memory.temp_size_in_bytes < 1 << 30
+        assert {"kv_write", "kv_gather", "attn", "state_reset", "ssm_conv",
+                "ssm_chunk"} <= regions
+        assert "state_update" not in regions

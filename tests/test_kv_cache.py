@@ -23,11 +23,13 @@ from deepspeed_tpu.inference import PagedKVCache
 from deepspeed_tpu.monitor.memory import CAT_KV, CAT_KV_DRAFT, MemoryLedger
 
 
-def _cache(ledger=None, draft_layers=0):
-    cache = PagedKVCache(n_layer=2, n_head=4, head_dim=16,
+def _cache(ledger=None, draft_layers=0, n_head=4, head_dim=16,
+           n_kv_head=None):
+    kv = {} if n_kv_head is None else {"n_kv_head": n_kv_head}
+    cache = PagedKVCache(n_layer=2, n_head=n_head, head_dim=head_dim,
                          num_pages=32, page_size=4, max_slots=4,
                          max_pages_per_slot=8, dtype=np.float32,
-                         ledger=ledger)
+                         ledger=ledger, **kv)
     if draft_layers:
         cache.attach_draft(draft_layers)
     return cache
@@ -99,17 +101,29 @@ def test_rollback_interleaved_with_other_slots():
     assert not set(a) & set(b), "a physical page leaked to two slots"
 
 
-def test_rollback_ledger_accounting_with_draft_category():
+@pytest.mark.parametrize("n_head, head_dim, n_kv_head, lanes", [
+    (4, 16, None, 128),    # a row of 4 heads x 16 takes one 128-lane tile
+    (4, 16, 4, 128),       # G = 1 said aloud: today's pool, byte for byte
+    (20, 128, 4, 512),     # grouped-query heads (ISSUE 31): the pools hold
+    #                        the 4 key/value heads, four lane tiles, no pad
+    (6, 48, 2, 128),       # 2 x 48 = 96 lanes padded to a tile
+    (6, 48, None, 384),    # the same heads ungrouped: 288 padded to 384
+], ids=["4x16", "4over4x16", "20over4x128", "6over2x48", "6x48"])
+def test_rollback_ledger_accounting_with_draft_category(n_head, head_dim,
+                                                        n_kv_head, lanes):
     """Through rollback/regrow churn both ledger categories keep
     total == pool bytes, and the per-request entries track the page
-    count in each category's own page-byte unit."""
+    count in each category's own page-byte unit. The unit follows the
+    key/value head count: a page holds `n_kv_head * head_dim` lanes a
+    token, padded to whole lane tiles."""
     ledger = MemoryLedger()
-    cache = _cache(ledger=ledger, draft_layers=1)
-    # independent arithmetic: flagship 2 layers, draft 1 layer; a row
-    # of 4 heads x 16 takes one 128-lane tile
-    assert cache.lanes == 128
-    page_bytes = 2 * 2 * 4 * 128 * 4
-    draft_page_bytes = 2 * 1 * 4 * 128 * 4
+    cache = _cache(ledger=ledger, draft_layers=1, n_head=n_head,
+                   head_dim=head_dim, n_kv_head=n_kv_head)
+    # independent arithmetic: flagship 2 layers, draft 1 layer
+    assert cache.lanes == lanes and cache.n_kv_head == (n_kv_head or n_head)
+    assert cache.pool_shape(2) == (2, 32, 4, lanes)
+    page_bytes = 2 * 2 * 4 * lanes * 4
+    draft_page_bytes = 2 * 1 * 4 * lanes * 4
     assert cache.page_bytes == page_bytes
     assert cache.draft_page_bytes == draft_page_bytes
 

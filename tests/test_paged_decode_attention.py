@@ -37,32 +37,38 @@ ATOL = {"float32": 1e-5, "bfloat16": 2e-2}
 GARBAGE = 3e4
 
 
-def reference(q, k_pool, v_pool, li, tables, q_pos, lens, n_head):
-    """float32, all keys of a slot gathered, one head at a time."""
+def reference(q, k_pool, v_pool, li, tables, q_pos, lens, n_head,
+              n_kv_head=None):
+    """float32, all keys of a slot gathered, one query head at a time
+    against the key/value head it reads."""
     q, k_pool, v_pool = (np.asarray(x, np.float32)
                          for x in (q, k_pool, v_pool))
     b, tq, c = q.shape
     d = c // n_head
+    group = n_head // (n_kv_head or n_head)
     out = np.zeros((b, tq, c), np.float32)
     for s in range(b):
         if lens[s] == 0:
             continue
-        k = k_pool[li][tables[s]].reshape(-1, k_pool.shape[-1])[:, :c]
-        v = v_pool[li][tables[s]].reshape(-1, k_pool.shape[-1])[:, :c]
+        k = k_pool[li][tables[s]].reshape(-1, k_pool.shape[-1])
+        v = v_pool[li][tables[s]].reshape(-1, k_pool.shape[-1])
         for r in range(tq):
             n = min(lens[s], q_pos[s, r] + 1)
             for h in range(n_head):
                 cols = slice(h * d, (h + 1) * d)
-                scores = k[:n, cols] @ q[s, r, cols] / np.sqrt(d)
+                held = slice(h // group * d, (h // group + 1) * d)
+                scores = k[:n, held] @ q[s, r, cols] / np.sqrt(d)
                 p = np.exp(scores - scores.max())
-                out[s, r, cols] = (p / p.sum()) @ v[:n, cols]
+                out[s, r, cols] = (p / p.sum()) @ v[:n, held]
     return out
 
 
-def make_case(n_head, head_dim, page, max_pages, tq, dtype, seed):
-    """(q, k_pool, v_pool, zeroed pools, li, tables, q_pos, lens)."""
+def make_case(n_head, head_dim, page, max_pages, tq, dtype, seed,
+              n_kv_head=None):
+    """(q, k_pool, v_pool, zeroed pools, li, tables, q_pos, lens). The
+    pools hold `n_kv_head` heads a token (default: `n_head`)."""
     rng = np.random.default_rng(seed)
-    c = n_head * head_dim
+    c = (n_kv_head or n_head) * head_dim
     lanes = padded_lanes(c)
     window = page * max_pages
     lengths = [0, 1, page - 1, page, page + 1, window, window - 3, 0,
@@ -96,38 +102,48 @@ def make_case(n_head, head_dim, page, max_pages, tq, dtype, seed):
         pools.append((jnp.asarray(np.where(held, real, garbage), dtype),
                       jnp.asarray(real, dtype)))
     (k_pool, k_zeroed), (v_pool, v_zeroed) = pools
-    q = jnp.asarray(rng.normal(size=(b, tq, c)), dtype)
+    q = jnp.asarray(rng.normal(size=(b, tq, n_head * head_dim)), dtype)
     return q, k_pool, v_pool, (k_zeroed, v_zeroed), li, tables, q_pos, lens
 
 
-@functools.partial(jax.jit, static_argnames=("n_head",))
-def launch(q, k_pool, v_pool, li, tables, q_pos, lens, n_head):
+@functools.partial(jax.jit, static_argnames=("n_head", "n_kv_head"))
+def launch(q, k_pool, v_pool, li, tables, q_pos, lens, n_head,
+           n_kv_head=None):
     return paged_decode_attention(q, k_pool, v_pool, li, tables, q_pos,
-                                  lens, n_head)
+                                  lens, n_head, n_kv_head)
 
 
 @pytest.mark.parametrize("tq", [1, 4])
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("n_head, head_dim, page, max_pages", [
-    (25, 64, 16, 9),     # GPT-2 1.5B's row: 1,600 lanes padded to 1,664
-    (4, 32, 8, 5),       # a row of exactly one lane tile
-    (6, 16, 4, 40),      # 96 lanes padded to 128; 32 pages a block
-], ids=["25x64", "4x32", "6x16"])
+@pytest.mark.parametrize("n_head, n_kv_head, head_dim, page, max_pages", [
+    (25, None, 64, 16, 9),   # GPT-2 1.5B's row: 1,600 lanes padded to 1,664
+    (4, None, 32, 8, 5),     # a row of exactly one lane tile
+    (6, None, 16, 4, 40),    # 96 lanes padded to 128; 32 pages a block
+    # grouped-query heads (ISSUE 31): the pools hold n_kv_head heads
+    (20, 4, 128, 16, 9),     # Falcon-H1's row: 4 x 128 = four lane tiles
+    (6, 2, 16, 4, 40),       # G = 3 over 32 lanes padded to 128
+    (8, 8, 16, 8, 5),        # G = 1 said aloud: the default, bit for bit
+], ids=["25x64", "4x32", "6x16", "20over4x128", "6over2x16", "8over8x16"])
 def test_kernel_against_float32_all_keys_reference(
-        n_head, head_dim, page, max_pages, dtype, tq):
+        n_head, n_kv_head, head_dim, page, max_pages, dtype, tq):
     q, k_pool, v_pool, zeroed, li, tables, q_pos, lens = make_case(
-        n_head, head_dim, page, max_pages, tq, jnp.dtype(dtype), seed=tq)
-    got = launch(q, k_pool, v_pool, li, tables, q_pos, lens, n_head)
+        n_head, head_dim, page, max_pages, tq, jnp.dtype(dtype), seed=tq,
+        n_kv_head=n_kv_head)
+    got = launch(q, k_pool, v_pool, li, tables, q_pos, lens, n_head,
+                 n_kv_head)
+    if n_kv_head == n_head:
+        default = launch(q, k_pool, v_pool, li, tables, q_pos, lens, n_head)
+        assert np.array_equal(np.asarray(default), np.asarray(got))
     assert got.dtype == q.dtype and got.shape == q.shape
     got32 = np.asarray(got.astype(jnp.float32))
     assert np.isfinite(got32).all()
 
-    want = reference(q, *zeroed, li, tables, q_pos, lens, n_head)
+    want = reference(q, *zeroed, li, tables, q_pos, lens, n_head, n_kv_head)
     np.testing.assert_allclose(got32, want, atol=ATOL[dtype], rtol=0)
     # slots that are not live return zeros and read nothing
     assert not got32[lens == 0].any()
     # what no live slot has written contributes exactly nothing
-    clean = launch(q, *zeroed, li, tables, q_pos, lens, n_head)
+    clean = launch(q, *zeroed, li, tables, q_pos, lens, n_head, n_kv_head)
     assert np.array_equal(np.asarray(clean), np.asarray(got))
 
     # a row of a launch of several equals, bit for bit, the launch of
@@ -135,6 +151,6 @@ def test_kernel_against_float32_all_keys_reference(
     for r in range(tq if tq > 1 else 0):
         alone = launch(q[:, r:r + 1], k_pool, v_pool, li, tables,
                        q_pos[:, r:r + 1],
-                       np.minimum(lens, q_pos[:, r] + 1), n_head)
+                       np.minimum(lens, q_pos[:, r] + 1), n_head, n_kv_head)
         assert np.array_equal(np.asarray(alone[:, 0]),
                               np.asarray(got[:, r])), r
