@@ -65,7 +65,8 @@ from deepspeed_tpu.monitor import DeepSpeedMonitorConfig, Monitor
 from deepspeed_tpu.monitor import memory as memory_mod
 from deepspeed_tpu.monitor import programs
 from deepspeed_tpu.monitor.trace import profiler_span
-from deepspeed_tpu.ops.retention import retention_chunked, retention_decode
+from deepspeed_tpu.ops.retention import (retention_chunked, retention_decode,
+                                         retention_prefill)
 from deepspeed_tpu.ops.ssm import (causal_conv, split_xbc, ssd_chunked,
                                    ssm_step)
 from deepspeed_tpu.ops.transformer.paged_decode_attention import \
@@ -357,8 +358,11 @@ class RecurrentKind:
     the same region (`retention_decode` on layer `li` of the whole
     arrays: where Mosaic takes it one kernel call that passes over the
     state once, in place; else the XLA form); inactive slots keep
-    theirs. Prefill advances one slot's state by a chunk, from zero if
-    the chunk is the request's first."""
+    theirs. Prefill advances one slot's state by a launch, from zero
+    if the launch is the request's first (`retention_prefill` on layer
+    `li` and that slot of the whole arrays: where Mosaic takes it one
+    kernel call that keeps phi in VMEM and the state in place; else
+    the XLA form `retention_chunked` on the slot sliced out)."""
     keys = ("state_s", "state_z")
 
     def __init__(self, model_config, config, max_seq_len):
@@ -392,20 +396,13 @@ class RecurrentKind:
 
         def mix(li, q, k, v, lg, cache):
             S, z = cache
-            with jax.named_scope(SCOPE_STATE_RESET):
-                zero = jnp.zeros((), S.dtype)
-                S0 = jnp.where(start == 0, zero, jax.lax.dynamic_slice(
-                    S, (li, slot, 0, 0, 0), (1, 1) + S.shape[2:])[0])
-                z0 = jnp.where(start == 0, zero, jax.lax.dynamic_slice(
-                    z, (li, slot, 0, 0), (1, 1) + z.shape[2:])[0])
             with jax.named_scope(SCOPE_RETENTION_CHUNK):
-                o, S1, z1 = retention_chunked(
-                    q, k, v, lg, S0, z0, mc.retention_scale,
-                    mc.retention_eps, mc.retention_chunk, valid[None])
-                S = jax.lax.dynamic_update_slice(
-                    S, S1[None], (li, slot, 0, 0, 0))
-                z = jax.lax.dynamic_update_slice(
-                    z, z1[None], (li, slot, 0, 0))
+                # (the start from zero of a request's first launch
+                # lies under SCOPE_STATE_RESET inside)
+                o, S, z = retention_prefill(
+                    q, k, v, lg, S, z, li, slot, start, valid,
+                    mc.retention_scale, mc.retention_eps,
+                    mc.retention_chunk, chunked=retention_chunked)
             return o, (S, z)
         return mix
 

@@ -221,14 +221,15 @@ class PagedKVCache:
         return {"kv_pages_in_use": int(self.pages_in_use()),
                 "kv_pages_free": int(self.free_pages())}
 
-    def attended(self, active, pos, launches=0, advanced=0):
+    def attended(self, active, pos, launches=0, advanced=0, prefill_rows=0,
+                 prefill_tokens=0):
         """How far the decode kernel engages at the next launch, from
         what the fence fetched (`active`, `pos` of every slot; host
         arrays): the pages it walks, ceil((pos + 1) / page) summed
         over the live slots, and their share of the window the
         gathered path attended to whatever was live (max_slots x
-        max_pages_per_slot). The fence's own launches are not read
-        here (recurrent state counts them)."""
+        max_pages_per_slot). The fence's own launches, decode's and
+        prefill's, are not read here (recurrent state counts them)."""
         pages = int((-(-(pos[active] + 1) // self.page_size)).sum())
         return {"kv_pages_attended": pages,
                 "kv_pages_attended_share": round(
@@ -465,16 +466,23 @@ class RecurrentStateCache:
 
     ledger_occupancy = occupancy       # the manager's own counters
 
-    def attended(self, active, pos, launches=0, advanced=0):
+    def attended(self, active, pos, launches=0, advanced=0, prefill_rows=0,
+                 prefill_tokens=0):
         """A model of state attends to no pages. What its decode
         kernel moved over the fence just closed: every launch streams
         every slot's state (`launches` x max_slots), and `advanced` of
         those slot-steps belonged to a live request (a live slot takes
         one token a launch, so it is the fence's tokens); their ratio
         is the share of the kernel's traffic that advanced a
-        request."""
+        request. And what its prefill took through a slot's state: the
+        rows of the fence's prefill launches (`prefill_rows`: launches
+        x prefill_chunk, padding and all) and the prompt tokens among
+        them (`prefill_tokens`); their ratio is the share of the
+        prefill kernel's rows that were a request's."""
         return {"state_slots_streamed": int(launches) * self.max_slots,
-                "state_slots_advanced": int(advanced)}
+                "state_slots_advanced": int(advanced),
+                "state_prefill_rows_streamed": int(prefill_rows),
+                "state_prefill_tokens": int(prefill_tokens)}
 
     def utilization_counter(self, occupancy):
         return "state_slot_utilization", {
@@ -592,9 +600,8 @@ class PagedStateCache:
         return {**self.pages.ledger_occupancy(),
                 **self.state.ledger_occupancy()}
 
-    def attended(self, active, pos, launches=0, advanced=0):
-        return {**self.pages.attended(active, pos, launches, advanced),
-                **self.state.attended(active, pos, launches, advanced)}
+    def attended(self, *fence):
+        return {**self.pages.attended(*fence), **self.state.attended(*fence)}
 
     def utilization_counter(self, occupancy):
         """The trace export has one track a cache: the pages' (what

@@ -76,6 +76,9 @@ class ServingLoop:
         # host dispatch stamp of the current decode block (the serving
         # tracker's per-fence decode window; None = no block in flight)
         self._decode_t0 = None
+        # prefill launches since the last fence and the prompt tokens
+        # they covered (the fence rows' state_prefill_* counters)
+        self._prefill_launches = self._prefill_tokens = 0
         # speculative-decoding fence mirrors: the device counters are
         # cumulative per slot (never reset mid-flight), so the fence
         # diffs them against these to get per-window numbers
@@ -261,6 +264,8 @@ class ServingLoop:
                 if trk is not None:
                     trk.on_prefill_chunk(
                         slot, t0, time.perf_counter() - t0, start, end)
+                self._prefill_launches += 1
+                self._prefill_tokens += end - start
                 self.prefilling[slot][1] = end
                 start = end
             if start >= n_prefill:
@@ -323,10 +328,15 @@ class ServingLoop:
         mon = self._infer.monitor
         # how far the decode kernel engages: the pages it walks at the
         # next launch, or for recurrent state the slots it streamed
-        # and advanced over this fence's launches; host arithmetic on
-        # what the fence already fetched
+        # and advanced over this fence's launches, and the rows its
+        # prefill launches took through a slot's state against the
+        # prompt tokens among them; host arithmetic on what the fence
+        # already holds
         engaged = self._infer.cache.attended(
-            snap["active"], snap["pos"], iterations, new_tokens)
+            snap["active"], snap["pos"], iterations, new_tokens,
+            self._prefill_launches * self._infer.config.prefill_chunk,
+            self._prefill_tokens)
+        self._prefill_launches = self._prefill_tokens = 0
         mon.event(
             "decode_batch",
             iterations=int(iterations),

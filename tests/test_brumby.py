@@ -328,8 +328,19 @@ def test_fence_rows_count_the_slots_streamed_and_advanced(model32, tmp_path):
             == (batch["state_slots_streamed"], batch["state_slots_advanced"])
     assert sum(r["state_slots_advanced"] for r in rows["decode_batch"]) == \
         sum(len(r.out_tokens) for r in served) == 11
-    assert engine.cache.attended(None, None, 4, 7) == {
-        "state_slots_streamed": 12, "state_slots_advanced": 7}
+    # the prefill launches between two fences: their rows, padding
+    # and all, and the prompt tokens among them (19 + 2 are prefilled)
+    chunk = BLOCK["prefill_chunk"]
+    prefilled = [(r["state_prefill_rows_streamed"], r["state_prefill_tokens"])
+                 for r in rows["decode_batch"]]
+    assert all(rows_ % chunk == 0 and 0 <= tokens <= rows_
+               for rows_, tokens in prefilled)
+    assert sum(tokens for _, tokens in prefilled) == 19 + 2
+    assert sum(rows_ for rows_, _ in prefilled) == \
+        (-(-19 // chunk) + 1) * chunk
+    assert engine.cache.attended(None, None, 4, 7, 64, 40) == {
+        "state_slots_streamed": 12, "state_slots_advanced": 7,
+        "state_prefill_rows_streamed": 64, "state_prefill_tokens": 40}
 
 
 def test_programs_carry_the_state_and_name_their_regions(model32):

@@ -184,7 +184,11 @@ def test_state_is_updated_in_place_on_a_v5e(brumby_scans, program):
     Decode passes over a layer's state ONCE: one Mosaic call a layer
     (the scan's body holds one), and no XLA fusion takes a layer's or
     the whole state as an operand (the XLA form had two that read it
-    and one that wrote it)."""
+    and one that wrote it). Prefill keeps phi in VMEM: one Mosaic call
+    a layer, `retention_prefill`, and outside it no float32 array with
+    a dimension of 8,704 but the state itself (S, and the normaliser z
+    of the launch's slot); XLA's chunked form held phi(Q) of a
+    pair-chunk, [128, 40, 8704], and 206 MB of temporaries."""
     compiled, S, z = brumby_scans(program)
     assert S.shape == (8, SLOTS, 8, 8704, 128)
     state_bytes = 4 * int(np.prod(S.shape) + np.prod(z.shape))
@@ -195,11 +199,19 @@ def test_state_is_updated_in_place_on_a_v5e(brumby_scans, program):
     whole = ",".join(map(str, S.shape))
     moved = re.findall(rf"= f32\[{whole}\]\S* (copy|transpose)\(", text)
     assert moved == []
+    calls = re.findall(
+        r'custom-call\(.*custom_call_target="tpu_custom_call"', text)
+    assert len(calls) == 1 and f"retention_{program}" in text
+    if program == "prefill":
+        assert memory.temp_size_in_bytes < 50e6
+        rows = S.shape[-2]
+        own = {S.shape, z.shape, (1, 1) + z.shape[2:], z.shape[2:]}
+        wide = {tuple(map(int, dims.split(",")))
+                for dims in re.findall(r"f32\[([\d,]+)\]", text)
+                if str(rows) in dims.split(",")}
+        assert wide and wide <= own, wide - own
     if program == "decode":
         layer = ",".join(map(str, S.shape[1:]))
-        calls = re.findall(
-            r'custom-call\(.*custom_call_target="tpu_custom_call"', text)
-        assert len(calls) == 1 and "retention_decode" in text
         reads_state = re.findall(
             rf"^%fused_computation\S* \(.*f32\[(?:{whole}|{layer})\]",
             text, re.M)
@@ -241,9 +253,9 @@ def test_state_regions_survive_the_chips_compiler(brumby_scans, program):
                 kind.group(1) not in plumbing:
             touching[name] = state_scopes.region_of(scopes.get(name))
     assert touching and set(touching.values()) <= want, touching
-    if program == "decode":
-        call, = [n for n in touching if n.startswith("retention_decode")]
-        assert touching[call] == "state_update"
+    call, = [n for n in touching if n.startswith(f"retention_{program}")]
+    assert touching[call] == {"decode": "state_update",
+                              "prefill": "retention_chunk"}[program]
 
 
 # ----------------------------------------------------------------------

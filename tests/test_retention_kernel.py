@@ -1,12 +1,13 @@
-"""Decode's one-pass retention kernel (ISSUE 30) in the Pallas
-interpreter against the XLA form it replaces on the chip
-(`retention_step`, the oracle), and the padded layout of the state's
-rows that the kernel rests on: `phi`, `retention_step`,
-`retention_chunked` and the kernel follow the state's row count, so
+"""Decode's one-pass retention kernel (ISSUE 30) and prefill's kernel
+that keeps phi in VMEM (ISSUE 32) in the Pallas interpreter against
+the XLA forms they replace on the chip (`retention_step`,
+`retention_chunked`: the oracles), and the padded layout of the
+state's rows that both rest on: `phi`, `retention_step`,
+`retention_chunked` and the kernels follow the state's row count, so
 the same token sequence through the aligned and the compact layout
 reads the same along any direction.
 
-Tolerances: the kernel sums the products `retention_step` sums, with
+Tolerances: the kernels sum the products the XLA forms sum, with
 the row's factor built as (a k_i)(a k_j), a^2 = sqrt(2) s, where the
 oracle has (k_i k_j)(c s): a few units in the last place of float32.
 """
@@ -20,9 +21,12 @@ from benchmark.architectures import brumby as arch
 from benchmark.reference import brumby as ref
 from deepspeed_tpu.inference import InferenceEngine
 from deepspeed_tpu.models import brumby
+from deepspeed_tpu.inference import Request, ServingLoop
 from deepspeed_tpu.ops.retention import (phi, retention_chunked,
                                          retention_decode,
                                          retention_decode_kernel,
+                                         retention_prefill,
+                                         retention_prefill_kernel,
                                          retention_step, state_dim)
 from deepspeed_tpu.ops.retention import decode as kernel_mod
 from deepspeed_tpu.ops.retention.retention import RUN_ALIGN, _pairs
@@ -134,6 +138,143 @@ def test_kernel_iterated_equals_all_pairs_and_the_chunked_state():
                                   1.0 / d, EPS, 8)
     assert np.allclose(S[0], S1, atol=1e-5) and \
         np.allclose(z[0], z1, atol=1e-5)
+
+
+# ----------------------------------------------------------------------
+# prefill: a launch of one slot through the state where it lies
+# ----------------------------------------------------------------------
+SLOT, CHUNK, LAUNCH = 1, 8, 24
+
+
+def launch_operands(d, groups, dtype, hk=2):
+    """One launch of `LAUNCH` tokens for one slot of three, and whole
+    state arrays that 16 earlier tokens of every layer and slot left
+    (a state of random rows has normalisers near zero)."""
+    rng = np.random.default_rng(0)
+    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True) * d ** 0.5
+    f = lambda x: jnp.asarray(x, jnp.float32)
+
+    def drawn(rows, tokens):
+        return (f(unit(rng.normal(size=(rows, tokens, hk * groups, d)))),
+                f(unit(rng.normal(size=(rows, tokens, hk, d)))),
+                f(rng.normal(size=(rows, tokens, hk, d))),
+                f(np.log(rng.uniform(0.9, 0.9999, (rows, tokens, hk)))))
+
+    n_rows = state_dim(d, RUN_ALIGN)
+    _, S, z = retention_chunked(
+        *drawn(LAYERS * 3, 16), jnp.zeros((LAYERS * 3, hk, n_rows, d)),
+        jnp.zeros((LAYERS * 3, hk, n_rows)), 1.0 / d, EPS, CHUNK)
+    whole = lambda x: x.reshape((LAYERS, 3) + x.shape[1:]).astype(dtype)
+    return drawn(1, LAUNCH) + (whole(S), whole(z))
+
+
+@pytest.mark.parametrize("state_type", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("groups", [1, 5], ids=lambda g: f"G{g}")
+@pytest.mark.parametrize("d", [8, 128], ids=lambda d: f"d{d}")
+def test_prefill_kernel_equals_the_chunked_form(d, groups, state_type):
+    """Output, S and z of a launch against `retention_chunked` on the
+    slot sliced out: a first launch (the block holding junk), a later
+    one from a carried state, a ragged last one whose valid tokens end
+    inside a pair-chunk, and one whose valid tokens end before a whole
+    pair-chunk (skipped); every other layer and slot bit for bit, the
+    padding rows exactly zero."""
+    q, k, v, lg, S, z = launch_operands(d, groups, state_type, hk=1)
+    real = real_rows(d)
+    launch = jax.jit(lambda S, z, start, valid: retention_prefill_kernel(
+        q, k, v, lg, S, z, jnp.asarray(LI), jnp.asarray(SLOT), start, valid,
+        1.0 / d, EPS, CHUNK))
+    as32 = lambda x: np.asarray(x, np.float32)
+    tol = 2e-5 if state_type == jnp.float32 else 1e-2
+    junk = (jnp.full_like(S, 7.0), jnp.full_like(z, 7.0))
+    for name, (S0, z0), start, n_valid in [
+            ("first", junk, 0, LAUNCH), ("later", (S, z), 48, LAUNCH),
+            ("ragged inside", (S, z), 48, 2 * CHUNK + 3),
+            ("ragged before", (S, z), 48, CHUNK)]:
+        valid = jnp.arange(LAUNCH) < n_valid
+        o, S1, z1 = launch(S0, z0, jnp.asarray(start), valid)
+        from_zero = jnp.zeros((1,) + S.shape[2:], S.dtype), \
+            jnp.zeros((1,) + z.shape[2:], z.dtype)
+        want_o, want_S, want_z = retention_chunked(
+            q, k, v, lg, *(from_zero if start == 0 else
+                           (S0[LI, SLOT][None], z0[LI, SLOT][None])),
+            1.0 / d, EPS, CHUNK, valid[None])
+        want_o = as32(want_o)[:, :n_valid]
+        assert np.abs(as32(o)[:, :n_valid] - want_o).max() < \
+            tol * np.abs(want_o).max(), name
+        assert np.isfinite(as32(o)).all(), name
+        assert np.abs(as32(S1[LI, SLOT]) - as32(want_S[0])).max() < \
+            tol * np.abs(as32(want_S)).max(), name
+        assert np.abs(as32(z1[LI, SLOT]) - as32(want_z[0])).max() < \
+            tol * np.abs(as32(want_z)).max(), name
+        assert S1.dtype == S.dtype and z1.dtype == z.dtype
+        # the other layer and the other slots: not a bit moved
+        others = np.arange(3) != SLOT
+        for got, had in ((S1, S0), (z1, z0)):
+            assert np.array_equal(as32(got[0]), as32(had[0])), name
+            assert np.array_equal(as32(got[LI])[others],
+                                  as32(had[LI])[others]), name
+        assert not as32(S1[LI, SLOT])[:, ~real].any(), name
+        assert not as32(z1[LI, SLOT])[:, ~real].any(), name
+
+
+def test_prefill_then_decode_through_the_kernels_equals_all_pairs():
+    """27 tokens of a prompt in two launches of 16 rows (the second
+    ragged) through prefill's kernel, then 13 tokens a step at a time
+    through decode's: the outputs are the all-pairs form's."""
+    d, hq, hk, T, prompt, rows16 = 8, 10, 2, 40, 27, 16
+    rng = np.random.default_rng(0)
+    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True) * d ** 0.5
+    k = unit(rng.normal(size=(T, hk, d)))
+    q = unit(np.repeat(k, hq // hk, axis=1) / d ** 0.5 +
+             0.7 * rng.normal(size=(T, hq, d)) / d ** 0.5)
+    q, k = jnp.asarray(q, jnp.float32), jnp.asarray(k, jnp.float32)
+    v = jnp.asarray(rng.normal(size=(T, hk, d)), jnp.float32)
+    lg = jnp.asarray(np.log(rng.uniform(0.9, 0.9999, (T, hk))), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(ref.retention_all_pairs(q, k, v, lg, 1.0 / d, EPS,
+                                                  rows=16))
+    rows = state_dim(d, RUN_ALIGN)
+    S = jnp.full((1, 2, hk, rows, d), 7.0)               # junk: a freed slot
+    z = jnp.full((1, 2, hk, rows), 7.0)
+    li, slot = jnp.asarray(0), jnp.asarray(1)
+    launch = jax.jit(lambda q, k, v, lg, S, z, start, valid:
+                     retention_prefill_kernel(q, k, v, lg, S, z, li, slot,
+                                              start, valid, 1.0 / d, EPS, 8))
+    outs = []
+    for start in range(0, prompt, rows16):
+        at = slice(start, start + rows16)
+        o, S, z = launch(q[None, at], k[None, at], v[None, at], lg[None, at],
+                         S, z, jnp.asarray(start),
+                         jnp.arange(start, start + rows16) < prompt)
+        outs.extend(np.asarray(o[0, :prompt - start]))
+    step = jax.jit(lambda *a: retention_decode_kernel(
+        *a, li, 1.0 / d, EPS, keep=jnp.asarray([True, False])))
+    pad = lambda x: jnp.stack([jnp.zeros_like(x), x])    # slot 0 idle
+    for t in range(prompt, T):
+        o, S, z = step(pad(q[t]), pad(k[t]), pad(v[t]), pad(lg[t]), S, z)
+        outs.append(np.asarray(o[1]))
+    assert np.abs(np.stack(outs) - want).max() < 2e-5 * np.abs(want).max()
+    assert np.array_equal(S[0, 0], jnp.full_like(S[0, 0], 7.0))
+
+
+def test_off_the_chip_the_prefill_dispatcher_runs_the_chunked_form():
+    d = 8
+    q, k, v, lg, S, z = launch_operands(d, 5, jnp.float32)
+    valid = jnp.arange(LAUNCH) < 19
+    for start in (0, 48):
+        o, S1, z1 = retention_prefill(
+            q, k, v, lg, S, z, jnp.asarray(LI), jnp.asarray(SLOT),
+            jnp.asarray(start), valid, 1.0 / d, EPS, CHUNK)
+        zero = lambda x: x if start else jnp.zeros_like(x)
+        want_o, want_S, want_z = retention_chunked(
+            q, k, v, lg, zero(S[LI, SLOT][None]), zero(z[LI, SLOT][None]),
+            1.0 / d, EPS, CHUNK, valid[None])
+        assert np.array_equal(o, want_o)
+        assert np.array_equal(S1[LI, SLOT], want_S[0]) and \
+            np.array_equal(z1[LI, SLOT], want_z[0])
+        assert np.array_equal(S1[0], S[0]) and \
+            np.array_equal(S1[LI, :SLOT], S[LI, :SLOT])
 
 
 # ----------------------------------------------------------------------
@@ -249,6 +390,55 @@ def test_the_engines_decode_through_the_kernel_equals_the_xla_form(
     # slot 0 never held a request: the kernel copied it through
     assert not got_S[:, 0].any() and not got_z[:, 0].any()
     assert engine.cache.pool_bytes == got_S.nbytes + got_z.nbytes
+
+
+def test_the_engines_prefill_through_the_kernel_equals_the_xla_form(
+        monkeypatch, tmp_path):
+    """A request prefilled in three launches (the last one ragged) and
+    decoded through the serving loop, with `usable` answering as on
+    the chip (both kernels, here in the interpreter): the XLA form's
+    tokens and state. The fence rows carry the rows the prefill
+    launches took through the state and the prompt tokens among them;
+    their ratio is the prompt's valid share."""
+    cfg = brumby.BrumbyConfig(
+        vocab_size=97, hidden_size=64, intermediate_size=96,
+        num_hidden_layers=2, num_attention_heads=2, num_key_value_heads=1,
+        head_dim=128, max_position_embeddings=64, retention_chunk=4,
+        dtype=jnp.float32, param_dtype=jnp.float32)
+    params = brumby.init_params(cfg, jax.random.PRNGKey(0))
+    block = {"max_slots": 2, "prefill_chunk": 8, "sync_every": 2,
+             "max_new_tokens": 4, "max_seq_len": 64}
+    ids = np.random.default_rng(5).integers(0, 97, 21).astype(np.int32)
+
+    def served(with_kernel):
+        if with_kernel:
+            monkeypatch.setattr(kernel_mod, "usable", lambda S: True)
+        engine = InferenceEngine(cfg, params, {
+            "inference": block,
+            "monitor": {"enabled": True, "sinks": ["jsonl"],
+                        "output_path": str(tmp_path / str(with_kernel))}})
+        rows = []
+        real = engine.monitor.event
+        engine.monitor.event = lambda name, **kw: (
+            rows.append(kw) if name == "decode_batch" else None,
+            real(name, **kw))[1]
+        done, = ServingLoop(engine).serve(
+            [Request(rid=0, tokens=ids, max_new_tokens=4)])
+        S, z = engine.cache_arrays()
+        return done.out_tokens, np.asarray(S), np.asarray(z), rows
+
+    want, want_S, want_z, _ = served(False)
+    got, got_S, got_z, rows = served(True)
+    assert np.array_equal(got, want) and len(got) == 4
+    assert np.abs(got_S - want_S).max() < 1e-5 * np.abs(want_S).max()
+    assert np.abs(got_z - want_z).max() < 1e-5 * np.abs(want_z).max()
+    # 20 prompt tokens are prefilled (the last one is decode's first):
+    # launches of 8, 8 and 4 valid rows, one a fence
+    streamed = [r["state_prefill_rows_streamed"] for r in rows]
+    tokens = [r["state_prefill_tokens"] for r in rows]
+    assert streamed[:3] == [8, 8, 8] and not any(streamed[3:])
+    assert tokens[:3] == [8, 8, 4] and not any(tokens[3:])
+    assert sum(tokens) / sum(streamed) == (len(ids) - 1) / 24
 
 
 def test_off_the_chip_the_dispatcher_runs_the_step():
