@@ -19,14 +19,24 @@ The model supplies the block, the engine supplies the cache (the seam;
 docs/inference.md has it at length):
 
   * a MODEL MODULE (`models/gpt2.py`, `models/brumby.py`,
-    `models/falcon_h1.py`) holds the
+    `models/falcon_h1.py`, `models/trinity.py`) holds the
     model's math as plain functions: `embed(mc, params, tokens,
     positions)`, ONE `block(mc, lp, hidden, positions, mixer, cache)
     -> (hidden, cache)`, `head(mc, params, hidden)`, `layers(params)`
     (the stacked [n_layer, ...] weights `block` takes one layer of),
     `QUANT_KERNEL_MODULES` (the projections an int8 load may quantise;
     () refuses it) and, where `truncate:N` drafts are served,
-    `first_layers(mc, params, n)`. The block computes its own
+    `first_layers(mc, params, n)`; a model whose layers do not all
+    have weights of one shape gives `stacks(mc, params)`, the stacks
+    `block` is scanned over one after another, each with the leaves
+    that `block` takes WHOLE beside a layer's slice (what a kernel
+    reads a layer of where it lies), one whose block
+    counts something a launch (`COUNTERS`, the names) returns the
+    counts as a third value, and one whose block reads something off
+    every row (`ROW_READINGS`, the names; Trinity: the experts a row
+    picked) returns those after the counts: the decode program gives
+    its last launch's out, a layer to a row
+    (`InferenceEngine.last_row_readings`). The block computes its own
     projections under SCOPE_ATTN_QKV / SCOPE_ATTN_OUT / SCOPE_MLP and
     calls `mixer` exactly once with what it projected (a block with
     two branches side by side hands over both branches' projections
@@ -37,8 +47,10 @@ docs/inference.md has it at length):
     (`serving_module`). `models/` and this package import each other
     nowhere;
   * the ENGINE owns the kinds of cache (`PagedKind`, `RecurrentKind`,
-    and `PagedStateKind`: the paged kind and a state kind side by
-    side in every layer) and nothing of any model: per kind the manager
+    `PagedStateKind`: the paged kind and a state kind side by side in
+    every layer, and `PagedWindowKind`: pages in two geometries, a
+    layer attending over a sliding window or over everything) and
+    nothing of any model: per kind the manager
     (inference/kv_cache.py), the fresh device arrays and their keys in
     the engine's state, and the mixers;
   * ONE adapter (`Serving`) composes model x kind for the two programs
@@ -60,7 +72,9 @@ import numpy as np
 from deepspeed_tpu.inference.config import InferenceConfig
 from deepspeed_tpu.inference.kv_cache import (PagedKVCache,
                                               PagedStateCache,
-                                              RecurrentStateCache)
+                                              RecurrentStateCache,
+                                              RingKVCache, WindowedKVCache,
+                                              ring_columns)
 from deepspeed_tpu.monitor import DeepSpeedMonitorConfig, Monitor
 from deepspeed_tpu.monitor import memory as memory_mod
 from deepspeed_tpu.monitor import programs
@@ -81,8 +95,9 @@ from deepspeed_tpu.utils.scopes import (  # noqa: F401
     SCOPE_EMBED, SCOPE_HEAD, SCOPE_KV_GATHER, SCOPE_KV_WRITE, SCOPE_LAYERS,
     SCOPE_MLP, SCOPE_RETENTION_CHUNK, SCOPE_SAMPLE, SCOPE_SSM_CHUNK,
     SCOPE_SSM_CONV, SCOPE_STATE_RESET, SCOPE_STATE_UPDATE, SCOPES,
-    SCOPES_IN_LAYER, SCOPES_IN_LAYER_RECURRENT,
-    SCOPES_PAGED_STATE, SCOPES_RECURRENT, SCOPES_SSM, SCOPES_STATE)
+    SCOPES_IN_LAYER, SCOPES_IN_LAYER_RECURRENT, SCOPES_MOE,
+    SCOPES_PAGED_MOE, SCOPES_PAGED_STATE, SCOPES_RECURRENT, SCOPES_SSM,
+    SCOPES_STATE)
 
 
 def compile_fresh(lowered):
@@ -139,7 +154,7 @@ def compile_registered(fn, args, donate_argnums):
 
 
 @jax.named_scope(SCOPE_ATTN)
-def paged_attention(q, kc, vc, q_pos, kv_limit):
+def paged_attention(q, kc, vc, q_pos, kv_limit, first=None, k_pos=None):
     """A prefill chunk's attention (the programs with a few query
     rows a slot attend through `paged_decode_attention` instead; see
     `PagedKind.mixer`). Causal attention of q [B, Tq, H, D] against a
@@ -149,18 +164,29 @@ def paged_attention(q, kc, vc, q_pos, kv_limit):
     queries sit at absolute positions `q_pos` [B, Tq], and keys beyond
     `kv_limit` [B] (pages not yet written / scratch) are price-masked
     AND value-zeroed — a masked key contributes an exact +0.0 to every
-    reduction, whatever the unwritten rows hold."""
+    reduction, whatever the unwritten rows hold.
+
+    A lower bound (a sliding window): a query sees no key below
+    `first` [B, Tq], and keys below the earliest query's are
+    value-zeroed like those beyond `kv_limit`. A window gathered
+    through a ring of pages does not lie in the order of its
+    positions: `k_pos` [B, Tk] then gives each key's (negative: no
+    key)."""
     sm_scale = 1.0 / np.sqrt(q.shape[-1])
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, kc).astype(jnp.float32)
     scores = scores * sm_scale
-    kpos = jnp.arange(kc.shape[1])
-    mask = kpos[None, None, None, :] <= q_pos[:, None, :, None]
+    kpos = jnp.arange(kc.shape[1])[None, :] if k_pos is None else k_pos
+    mask = kpos[:, None, None, :] <= q_pos[:, None, :, None]
+    v_ok = kpos <= kv_limit[:, None]
+    if first is not None:
+        mask = mask & (kpos[:, None, None, :] >= first[:, None, :, None])
+        v_ok = v_ok & (kpos >= first[:, :1])
     scores = jnp.where(mask, scores, jnp.float32(-1e30))
     probs = jax.nn.softmax(scores, axis=-1)
     probs = probs.astype(vc.dtype)
     # scratch/unwritten pages can hold garbage; zero their values so
     # the 0-probability product is exactly 0 regardless
-    v_ok = (kpos[None, :] <= kv_limit[:, None])[:, :, None, None]
+    v_ok = v_ok[:, :, None, None]
     vc = jnp.where(v_ok, vc, jnp.zeros((), vc.dtype))
     # PV phrased as a (b, h)-batched matmul rather than the einsum
     # string: measured on XLA-CPU this contraction accumulates the
@@ -202,7 +228,7 @@ def quantize_param_tree(params, block, modules):
     return walk(params)
 
 
-def scan_layers(stacked, hidden, cache, layer):
+def scan_layers(stacked, hidden, cache, layer, first=0):
     """The layer stack of every serving program (decode, prefill, and
     for a paged model draft decode, verify, draft prefill): `lax.scan`
     over the stacked block weights and the layer index, with the
@@ -213,17 +239,21 @@ def scan_layers(stacked, hidden, cache, layer):
     of it. Nothing cache-shaped is an `xs` or a `ys`: a pool that
     enters a scan as `xs` and leaves as `ys` is sliced, re-laid and
     stacked back layer by layer (70% of a decode step at 1.5B before
-    PR 25)."""
+    PR 25). What `layer` returns after those two (a few numbers a
+    row) IS the scan's `ys`: returned after the carry, stacked
+    [n_layer, ...]."""
     n_layer = jax.tree_util.tree_leaves(stacked)[0].shape[0]
 
     def body(carry, xs):
         lp, li = xs
-        return layer(lp, li, *carry), None
+        hidden, cache, *read = layer(lp, li, *carry)
+        return (hidden, cache), tuple(read)
 
     with jax.named_scope(SCOPE_LAYERS):
-        carry, _ = jax.lax.scan(body, (hidden, cache),
-                                (stacked, jnp.arange(n_layer)))
-    return carry
+        carry, read = jax.lax.scan(
+            body, (hidden, cache),
+            (stacked, jnp.arange(first, first + n_layer)))
+    return carry + read
 
 
 # ----------------------------------------------------------------------
@@ -260,7 +290,13 @@ class PagedKind:
         pool = cache.pool_shape(self.mc.n_layer)
         return {"k_pool": jnp.zeros(pool, self.mc.dtype),
                 "v_pool": jnp.zeros(pool, self.mc.dtype),
-                "tables": jnp.asarray(cache.tables)}
+                **self.tables(cache)}
+
+    @staticmethod
+    def tables(cache):
+        """The manager's page tables as the engine's state holds
+        them (uploaded again after every fence that changed them)."""
+        return {"tables": jnp.asarray(cache.tables)}
 
     def mixer(self, tables, positions, valid, kv_limit):
         """The one mixer of all five paged programs, for rows at
@@ -439,6 +475,8 @@ class PagedStateKind:
         return {**self.paged.fresh(cache),
                 **fresh_state(self.state_keys, cache)}
 
+    tables = staticmethod(PagedKind.tables)
+
     @staticmethod
     def both(paged_mix, state_mix):
         n = len(PagedKind.keys)
@@ -496,8 +534,177 @@ class PagedStateKind:
                                                   start, n_valid), mix)
 
 
+class PagedWindowKind:
+    """K/V pages in two geometries, for a model whose layers attend
+    some over a sliding window and some over everything (the config's
+    `layer_types` and `sliding_window`): two pools and two tables a
+    slot (`kv_cache.WindowedKVCache`). The full layers' pool and table
+    are `PagedKind`'s; a window layer's table is a ring (logical page
+    p in column p % ring) that holds the window's pages only, and its
+    queries see no key below position - window + 1.
+
+    A layer's kind is an operand, looked up by the layer's index in
+    the scan: the rows' K/V are scattered into both pools, the write
+    to the pool that is not the layer's diverted to scratch page 0
+    like an idle slot's, and `lax.cond` picks the pool that is read.
+    A few rows a slot: `paged_decode_attention`, with the first
+    visible key and the ring for a window layer. A prefill chunk: the
+    slot's table row gathered (for a window layer the ring, whose
+    columns' positions are reckoned from the chunk's last page) and
+    `paged_attention`."""
+    keys = ("k_pool", "v_pool", "k_window", "v_window")
+
+    def __init__(self, model_config, config, max_seq_len):
+        if config.spec_enabled:
+            raise ValueError(
+                "inference.speculative.enabled: a draft's pool shares "
+                "the flagship's one page table, and this model's slots "
+                "hold two")
+        self.mc, self.cfg = model_config, config
+        self.max_pages = -(-max_seq_len // config.kv_page_size)
+        self.n_kv_head = getattr(model_config, "n_kv_head",
+                                 model_config.n_head)
+        slides = np.asarray([t == "sliding_attention"
+                             for t in model_config.layer_types])
+        if slides.all() or not slides.any():
+            raise ValueError(
+                "cache_kind 'paged+window' is for window AND full layers "
+                f"side by side; layer_types has {set(model_config.layer_types)}")
+        self.slides = slides
+        # a layer's index in the pool of its kind
+        self.pool_index = np.where(slides, np.cumsum(slides),
+                                   np.cumsum(~slides)) - 1
+        self.window = int(model_config.sliding_window)
+
+    def make_cache(self, ledger):
+        mc, cfg = self.mc, self.cfg
+        common = dict(n_head=mc.n_head, head_dim=mc.head_dim,
+                      page_size=cfg.kv_page_size, max_slots=cfg.max_slots,
+                      dtype=np.dtype(mc.dtype), ledger=ledger,
+                      n_kv_head=self.n_kv_head)
+        # the positions a slot's queries cover between two fences
+        span = max(cfg.prefill_chunk, cfg.sync_every)
+        ring = ring_columns(self.window, cfg.kv_page_size, span)
+        # every slot's ring, beside the scratch page: admission never
+        # waits for a window page
+        window = RingKVCache(
+            self.window, span, n_layer=int(self.slides.sum()),
+            num_pages=cfg.max_slots * ring + 1,
+            category=memory_mod.CAT_KV_WINDOW, **common)
+        full = PagedKVCache(
+            n_layer=int((~self.slides).sum()), num_pages=cfg.kv_num_pages,
+            max_pages_per_slot=self.max_pages, **common)
+        return WindowedKVCache(full, window)
+
+    def fresh(self, cache):
+        full = cache.full.pool_shape(cache.full.n_layer)
+        window = cache.window.pool_shape(cache.window.n_layer)
+        dtype = self.mc.dtype
+        return {"k_pool": jnp.zeros(full, dtype),
+                "v_pool": jnp.zeros(full, dtype),
+                "k_window": jnp.zeros(window, dtype),
+                "v_window": jnp.zeros(window, dtype), **self.tables(cache)}
+
+    @staticmethod
+    def tables(cache):
+        full, window = cache.tables
+        return {"tables": jnp.asarray(full),
+                "window_tables": jnp.asarray(window)}
+
+    def mixer(self, tables, ring_tables, positions, valid, kv_limit):
+        """As `PagedKind.mixer`, over both pools: rows at `positions`
+        [B, T] of slots whose pages `tables` [B, max_pages] (the full
+        layers') and `ring_tables` [B, ring] (the window layers')
+        name."""
+        h, hk, d = self.mc.n_head, self.n_kv_head, self.mc.head_dim
+        page, window = self.cfg.kv_page_size, self.window
+        ring = ring_tables.shape[1]
+        slides_of = jnp.asarray(self.slides)
+        index_of = jnp.asarray(self.pool_index, jnp.int32)
+
+        def mix(li, q, k, v, pools):
+            k_full, v_full, k_ring, v_ring = pools
+            slides, at = slides_of[li], index_of[li]
+            b, t, _ = q.shape
+            c = hk * d
+            lanes = k_full.shape[-1]
+            pidx = positions // page
+            with jax.named_scope(SCOPE_KV_WRITE):
+                off = (positions % page).reshape(-1)
+                row = lambda x: jnp.pad(x.reshape(b * t, c),
+                                        ((0, 0), (0, lanes - c)))
+                to_full = jnp.where(
+                    valid & ~slides,
+                    jnp.take_along_axis(tables, pidx, axis=1), 0).reshape(-1)
+                to_ring = jnp.where(
+                    valid & slides, jnp.take_along_axis(
+                        ring_tables, pidx % ring, axis=1), 0).reshape(-1)
+                fi, ri = jnp.where(slides, 0, at), jnp.where(slides, at, 0)
+                k_full = k_full.at[fi, to_full, off].set(row(k))
+                v_full = v_full.at[fi, to_full, off].set(row(v))
+                k_ring = k_ring.at[ri, to_ring, off].set(row(k))
+                v_ring = v_ring.at[ri, to_ring, off].set(row(v))
+            first = jnp.maximum(positions - window + 1, 0)
+
+            if t <= DECODE_ROWS_MAX:
+                live_len = jnp.where(valid.any(axis=1), kv_limit + 1, 0)
+                kernel = functools.partial(
+                    paged_decode_attention, q, li=at, q_pos=positions,
+                    lens=live_len, n_head=h, n_kv_head=hk)
+                with jax.named_scope(SCOPE_ATTN):
+                    attn = jax.lax.cond(
+                        slides,
+                        lambda: kernel(k_pool=k_ring, v_pool=v_ring,
+                                       tables=ring_tables, first=first,
+                                       ring=ring),
+                        lambda: kernel(k_pool=k_full, v_pool=v_full,
+                                       tables=tables))
+                return attn, (k_full, v_full, k_ring, v_ring)
+
+            def gathered(k_pool, v_pool, table):
+                with jax.named_scope(SCOPE_KV_GATHER):
+                    kc = k_pool[at, table][..., :c].reshape(b, -1, hk, d)
+                    vc = v_pool[at, table][..., :c].reshape(b, -1, hk, d)
+                    return (jnp.repeat(kc, h // hk, axis=2),
+                            jnp.repeat(vc, h // hk, axis=2))
+
+            def over_ring():
+                # the launch's last page is the highest the slot
+                # holds; column c holds the page below it that is
+                # congruent to c (released or never held: its keys lie
+                # below every query's first, or below 0)
+                top = (kv_limit // page)[:, None]
+                held = top - (top - jnp.arange(ring)[None, :]) % ring
+                k_pos = (held[:, :, None] * page +
+                         jnp.arange(page)[None, None, :]).reshape(b, -1)
+                return paged_attention(
+                    q.reshape(b, t, h, d),
+                    *gathered(k_ring, v_ring, ring_tables), positions,
+                    kv_limit, first=first, k_pos=k_pos)
+
+            def over_all():
+                return paged_attention(
+                    q.reshape(b, t, h, d),
+                    *gathered(k_full, v_full, tables), positions, kv_limit)
+
+            attn = jax.lax.cond(slides, over_ring, over_all)
+            return attn.reshape(b, t, h * d), (k_full, v_full, k_ring,
+                                               v_ring)
+        return mix
+
+    def decode_mixer(self, state):
+        pos = state["pos"]
+        return self.mixer(state["tables"], state["window_tables"],
+                          pos[:, None], state["active"][:, None], pos)
+
+    def prefill_mixer(self, rows, posv, valid, start, n_valid):
+        page_row, ring_row = rows
+        return self.mixer(page_row[None], ring_row[None], posv[None],
+                          valid[None], (start + n_valid - 1)[None])
+
+
 KINDS = {"paged": PagedKind, "recurrent": RecurrentKind,
-         "paged+state": PagedStateKind}
+         "paged+state": PagedStateKind, "paged+window": PagedWindowKind}
 
 
 class Serving:
@@ -519,7 +726,14 @@ class Serving:
         self.mc = model_config
         self.kind = KINDS[model_config.cache_kind](model_config, config,
                                                    max_seq_len)
-        self.cache_keys = self.kind.keys
+        # what the model's block counts a launch (`COUNTERS`): one row
+        # for the decode program's launches and one for prefill's,
+        # summed since the engine's reset, beside the kind's arrays
+        self.counters = tuple(getattr(self.model, "COUNTERS", ()))
+        # what it reads off every row, a layer (`ROW_READINGS`)
+        self.row_readings = tuple(getattr(self.model, "ROW_READINGS", ()))
+        self.cache_keys = self.kind.keys + \
+            (("model_counts",) if self.counters else ())
 
     def quantized(self, params):
         return quantize_param_tree(params, self.mc.quant_block,
@@ -528,21 +742,54 @@ class Serving:
     def embed(self, params, tokens, positions):
         return self.model.embed(self.mc, params, tokens, positions)
 
-    def layers(self, params, hidden, cache, positions, mixer):
-        """`scan_layers` of the model's block over `params`' stack, with
-        the kind's `mixer(li, ...)` on layer `li` of the whole `cache`
-        arrays. Returns (hidden, cache)."""
+    def fresh(self, cache):
+        counts = {"model_counts": jnp.zeros((2, len(self.counters)),
+                                            jnp.int32)} \
+            if self.counters else {}
+        return {**self.kind.fresh(cache), **counts}
+
+    def layers(self, params, hidden, cache, positions, mixer, program=0,
+               readings=False):
+        """`scan_layers` of the model's block over `params`' stack (or
+        its stacks, one after another), with the kind's `mixer(li,
+        ...)` on layer `li` of the whole `cache` arrays. Returns
+        (hidden, cache). Where the block counts, the last of `cache` is
+        the counts and row `program` of it takes this launch's. With
+        `readings`, a third value: what the block read off every row
+        ({name of `ROW_READINGS`: [layers, rows, ...]})."""
+        n_arrays = len(cache) - bool(self.counters)
+
         def layer(lp, li, hidden, cache):
-            return self.model.block(self.mc, lp, hidden, positions,
-                                    functools.partial(mixer, li), cache)
+            hidden, arrays, *more = self.model.block(
+                self.mc, lp, hidden, positions, functools.partial(mixer, li),
+                cache[:n_arrays])
+            if self.counters:
+                arrays += (cache[-1].at[program].add(more.pop(0)),)
+            return (hidden, arrays) + (tuple(more[0]) if readings else ())
 
-        return scan_layers(self.model.layers(params), hidden, cache, layer)
+        if not hasattr(self.model, "stacks"):
+            hidden, cache, *read = scan_layers(
+                self.model.layers(params), hidden, cache, layer)
+        else:
+            first, read = 0, []
+            for scanned, whole in self.model.stacks(self.mc, params):
+                hidden, cache, *each = scan_layers(
+                    scanned, hidden, cache,
+                    lambda lp, *a, whole=whole: layer({**lp, **whole}, *a),
+                    first)
+                first += jax.tree_util.tree_leaves(scanned)[0].shape[0]
+                read.append(each)
+            read = [jnp.concatenate(r) for r in zip(*read)]
+        if not readings:
+            return hidden, cache
+        return hidden, cache, dict(zip(self.row_readings, read))
 
-    def decode_layers(self, params, hidden, state):
-        hidden, cache = self.layers(
+    def decode_layers(self, params, hidden, state, readings=False):
+        hidden, cache, *read = self.layers(
             params, hidden, tuple(state[k] for k in self.cache_keys),
-            state["pos"][:, None], self.kind.decode_mixer(state))
-        return hidden, dict(zip(self.cache_keys, cache))
+            state["pos"][:, None], self.kind.decode_mixer(state),
+            readings=readings)
+        return (hidden, dict(zip(self.cache_keys, cache))) + tuple(read)
 
     def prefill_layers(self, params, hidden, cache, where, posv, valid,
                        start, n_valid):
@@ -550,7 +797,8 @@ class Serving:
         row, for recurrent state its index, for both the pair."""
         return self.layers(
             params, hidden, cache, posv[None],
-            self.kind.prefill_mixer(where, posv, valid, start, n_valid))[1]
+            self.kind.prefill_mixer(where, posv, valid, start, n_valid),
+            program=1)[1]
 
     def head(self, params, hidden):
         return self.model.head(self.mc, params, hidden)
@@ -606,7 +854,8 @@ class InferenceEngine:
         self._state = self._fresh_state()
         self._decode = self._build_decode_step()
         self._prefill = self._build_prefill_step()
-        self._last_logits = None
+        self._last_logits = self._last_read = None
+        self._decodes_since_fence = 0
 
         # speculative decoding (ISSUE 18, inference/speculative.py):
         # gated on the config default-off, so the disabled engine's
@@ -667,7 +916,7 @@ class InferenceEngine:
         s, w = cfg.max_slots, cfg.max_new_tokens
         self._tables_version = self.cache.table_version
         return {
-            **self.serving.kind.fresh(self.cache),
+            **self.serving.fresh(self.cache),
             "pos": jnp.zeros((s,), jnp.int32),
             "cur_token": jnp.zeros((s,), jnp.int32),
             "active": jnp.zeros((s,), bool),
@@ -690,6 +939,7 @@ class InferenceEngine:
         # model's state is a third of the chip
         self._state = None
         self._state = self._fresh_state()
+        self._decodes_since_fence = 0
         if self.speculative_enabled:
             from deepspeed_tpu.inference import speculative as spec_mod
             self._spec_state = spec_mod.fresh_spec_state(self)
@@ -733,7 +983,8 @@ class InferenceEngine:
             with jax.named_scope(SCOPE_EMBED):
                 hidden = serving.embed(params, state["cur_token"], pos)
                 hidden = hidden[:, None, :]
-            hidden, cache = serving.decode_layers(params, hidden, state)
+            hidden, cache, *read = serving.decode_layers(
+                params, hidden, state, readings=bool(serving.row_readings))
             with jax.named_scope(SCOPE_HEAD):
                 logits = serving.head(params, hidden)[:, 0]
             next_tok = sample(logits, state)
@@ -759,7 +1010,7 @@ class InferenceEngine:
                     out_tokens=out,
                     step=state["step"] + 1,
                 )
-            return new_state, logits
+            return new_state, logits, (read[0] if read else {})
 
         return compile_registered(decode_fn, (self._params, self._state),
                                   donate_argnums=(1,))
@@ -805,7 +1056,7 @@ class InferenceEngine:
         push — callers invoke this liberally at fences and pay one
         transfer per actual mutation batch."""
         if self._tables_version != self.cache.table_version:
-            self._state["tables"] = jnp.asarray(self.cache.tables)
+            self._state.update(self.serving.kind.tables(self.cache))
             self._tables_version = self.cache.table_version
 
     def prefill_chunk(self, slot, tokens, start):
@@ -885,22 +1136,31 @@ class InferenceEngine:
         self.cache.admit(slot, t + max_new)
         chunk = self.config.prefill_chunk
         n_prefill = t - 1
-        # direct (scheduler-less) use runs decode_block without a
-        # fence-side capacity step, so assign the worst case up front;
-        # ServingLoop allocates incrementally instead
-        self.cache.ensure(slot, t + max_new)
-        self.push_tables()
         for start in range(0, n_prefill, chunk):
             end = min(start + chunk, n_prefill)
+            self.cache.ensure(slot, end, queries_from=start)
             self.prefill_chunk(slot, prompt[start:end], start)
+        # direct (scheduler-less) use runs decode_block without a
+        # fence-side capacity step, so assign the worst case up front
+        # (a ring of pages cannot hold more than a block's steps: it
+        # says so); ServingLoop allocates incrementally instead
+        self.cache.ensure(slot, t + max_new, queries_from=t - 1)
+        self.push_tables()
         self.activate_slot(slot, prompt[-1], t - 1, max_new,
                            temperature, top_k, eos)
 
     def ensure_decode_capacity(self, slot, known_pos, iters):
         """Assign pages covering `iters` more positions for a live
-        slot before a decode block (reservation-backed: cannot fail)."""
+        slot before a decode block (reservation-backed: cannot fail).
+        `known_pos` is the slot's position at the last fence; the
+        decode launches dispatched since (a caller's `decode_once`
+        between two blocks of the loop, which keeps positions by the
+        fence) have moved it on and are counted in: a row past the
+        pages asked for would be written to the scratch page."""
         worst = self.cache.reserved_tokens(slot)
-        self.cache.ensure(slot, min(known_pos + iters, worst))
+        ahead = self._decodes_since_fence + iters
+        self.cache.ensure(slot, min(known_pos + ahead, worst),
+                          queries_from=known_pos)
 
     # ------------------------------------------------------------------
     # the hot dispatch loop + the serving fence
@@ -910,21 +1170,31 @@ class InferenceEngine:
         no device_get, nothing read until `fetch_state` (the dynamic
         guard test and ds_lint's HOTSYNC rule both pin this)."""
         st = self._state
-        logits = self._last_logits
+        logits, read = self._last_logits, self._last_read
         for _ in range(n):
-            st, logits = self._decode(self._params, st)
+            st, logits, read = self._decode(self._params, st)
         self._state = st
-        self._last_logits = logits
+        self._last_logits, self._last_read = logits, read
         self._host_steps += n
+        self._decodes_since_fence += n
 
     def decode_once(self):
         """One decode iteration, returning the pre-sampling logits
         [max_slots, vocab] (parity tests read these)."""
-        st, logits = self._decode(self._params, self._state)
+        st, logits, read = self._decode(self._params, self._state)
         self._state = st
-        self._last_logits = logits
+        self._last_logits, self._last_read = logits, read
         self._host_steps += 1
+        self._decodes_since_fence += 1
         return logits
+
+    def last_row_readings(self):
+        """What the model's block read off every row of the last
+        decode launch ({name of its `ROW_READINGS`: device array
+        [layers, max_slots, ...]}; {} for a model that reads nothing
+        or before the first launch): beside `decode_once`'s logits,
+        the same launch's."""
+        return dict(self._last_read or {})
 
     def spec_block(self, rounds):
         """Dispatch `rounds` speculative rounds back-to-back — each
@@ -970,13 +1240,25 @@ class InferenceEngine:
         speculation is on, the round counters, still inside the SAME
         fused get)."""
         st = self._state
+        self._decodes_since_fence = 0
         targets = (st["active"], st["finished_eos"], st["pos"],
                    st["n_gen"], st["out_tokens"])
         if not self.speculative_enabled:
+            counted = self.serving.counters
+            if counted:
+                targets += (st["model_counts"],)
             with profiler_span("serve/fence.device_get"):
-                active, eos, pos, n_gen, out = jax.device_get(targets)
-            return {"active": active, "finished_eos": eos, "pos": pos,
+                active, eos, pos, n_gen, out, *counts = \
+                    jax.device_get(targets)
+            snap = {"active": active, "finished_eos": eos, "pos": pos,
                     "n_gen": n_gen, "out_tokens": out}
+            if counted:
+                # what the model's block counted since the engine's
+                # reset, the decode program's launches and prefill's
+                snap["counts"] = {
+                    "decode": dict(zip(counted, counts[0][0].tolist())),
+                    "prefill": dict(zip(counted, counts[0][1].tolist()))}
+            return snap
         sp = self._spec_state
         with profiler_span("serve/fence.device_get"):
             (active, eos, pos, n_gen, out, k_slot, drafted, accepted,

@@ -41,10 +41,15 @@ state-space mixer's matrix and its convolution's carried rows,
 never grown, in the shapes the model's config gives. A model whose
 every layer keeps BOTH (attention over pages and a state-space mixer
 side by side) is served by `PagedStateCache`, the two managers behind
-one. All three answer the scheduler's one interface: `can_admit`,
-`admit`, `ensure`, `free`, `reserved_tokens`, `never_fits`,
-`reservation`, `occupancy`, `attended`, `slot_operand` (and
-`rollback`, which recurrent state refuses).
+one. A model whose layers attend some over a sliding window and some
+over everything is served by `WindowedKVCache`: two pools and two
+tables a slot, the window layers' table a ring whose pages go back to
+the free list as the window passes them. All four answer the
+scheduler's one interface: `can_admit`, `admit`, `ensure`, `free`,
+`reserved_tokens`, `never_fits`, `reservation`, `occupancy`,
+`attended`, `slot_operand` (and `rollback`, which recurrent state
+refuses). `ensure` is told where the coming launches' queries begin
+(`queries_from`); only a ring of pages does anything with it.
 """
 
 import numpy as np
@@ -64,7 +69,7 @@ class PagedKVCache:
 
     def __init__(self, n_layer, n_head, head_dim, num_pages, page_size,
                  max_slots, max_pages_per_slot, dtype=np.float32,
-                 ledger=None, n_kv_head=None):
+                 ledger=None, n_kv_head=None, category=memory_mod.CAT_KV):
         if max_pages_per_slot < 1:
             raise ValueError(
                 f"max_pages_per_slot must be >= 1, got {max_pages_per_slot}")
@@ -108,6 +113,7 @@ class PagedKVCache:
                                np.int32)
         self.table_version = 0
         self._ledger = ledger
+        self._category = category
         self._ledger_tokens = {}
         # speculative-decoding draft pool (attach_draft): same page
         # tables/allocator, fewer layers, its own ledger category
@@ -117,7 +123,7 @@ class PagedKVCache:
         self._draft_ledger_tokens = {}
         if ledger is not None:
             ledger.register_dynamic(
-                memory_mod.CAT_KV, "pool.unallocated",
+                category, "pool.unallocated",
                 lambda: self.pool_bytes - self.allocated_bytes(),
                 meta={"num_pages": self.num_pages,
                       "page_size": self.page_size})
@@ -199,12 +205,17 @@ class PagedKVCache:
     # -- what the scheduler, the engine and the monitor ask of any cache
     kind = "paged"
 
+    def pages_to_reserve(self, n_tokens_worst_case):
+        """Pages admission sets aside for that worst case: every one
+        it would fill (a ring of pages: no more than the ring)."""
+        return self.pages_for_tokens(n_tokens_worst_case)
+
     def never_fits(self, n_tokens_worst_case):
         """Why a request of that worst case can NEVER be admitted (a
         message), or None: `ServingLoop.submit` rejects it at once
         instead of waiting for an eviction that cannot help."""
         usable = min(self.max_pages_per_slot, self.num_pages - 1)
-        need = self.pages_for_tokens(n_tokens_worst_case)
+        need = self.pages_to_reserve(n_tokens_worst_case)
         if need > usable:
             return (f"worst case {need} pages exceeds the pool's {usable} "
                     "usable pages (raise inference.kv_cache.num_pages)")
@@ -214,7 +225,7 @@ class PagedKVCache:
         """What admission sets aside, as the `request_admitted` event
         reports it."""
         return {"kv_pages_reserved":
-                int(self.pages_for_tokens(n_tokens_worst_case))}
+                int(self.pages_to_reserve(n_tokens_worst_case))}
 
     def occupancy(self):
         """What every fence reports of the cache."""
@@ -245,7 +256,7 @@ class PagedKVCache:
         if self._ledger is None:
             in_use = self.pages_in_use()
         else:
-            rows = self._ledger.category_breakdown(memory_mod.CAT_KV)
+            rows = self._ledger.category_breakdown(self._category)
             in_use = int(sum(b for name, b in rows.items()
                              if name != "pool.unallocated") //
                          max(self.page_bytes, 1))
@@ -271,7 +282,7 @@ class PagedKVCache:
         positions fits: its worst-case pages AND every other live
         request's still-unassigned reservation must be coverable by the
         free list — admitted requests never fail mid-flight."""
-        need = self.pages_for_tokens(n_tokens_worst_case)
+        need = self.pages_to_reserve(n_tokens_worst_case)
         if need > self.max_pages_per_slot:
             return False
         return need + self.reserved_unallocated() <= len(self._free)
@@ -287,7 +298,7 @@ class PagedKVCache:
                 f"{len(self._free)} free pages, "
                 f"{self.reserved_unallocated()} already reserved "
                 "(raise inference.kv_cache.num_pages)")
-        self._reserved[slot] = self.pages_for_tokens(n_tokens_worst_case)
+        self._reserved[slot] = self.pages_to_reserve(n_tokens_worst_case)
         self._pages[slot] = []
         self._names[slot] = name or f"slot{slot}"
         if self._ledger is not None:
@@ -296,7 +307,7 @@ class PagedKVCache:
             # would let the first free() release the second's entry and
             # break the category-total == pool-bytes invariant
             self._ledger_tokens[slot] = self._ledger.register_dynamic(
-                memory_mod.CAT_KV,
+                self._category,
                 f"request.s{slot}.{self._names[slot]}",
                 (lambda s: lambda: self.slot_bytes(s))(slot),
                 meta={"slot": int(slot),
@@ -311,10 +322,12 @@ class PagedKVCache:
                         meta={"slot": int(slot),
                               "request": self._names[slot]})
 
-    def ensure(self, slot, n_tokens):
+    def ensure(self, slot, n_tokens, queries_from=None):
         """Assign pages so `slot` can hold positions [0, n_tokens).
         Within the admission reservation this cannot fail; beyond it,
-        it raises (the scheduler sizes reservations so it never asks)."""
+        it raises (the scheduler sizes reservations so it never asks).
+        Every key stays visible, so where the coming queries begin
+        (`queries_from`) changes nothing here."""
         if slot not in self._pages:
             raise ValueError(f"slot {slot} is not admitted")
         need = self.pages_for_tokens(n_tokens)
@@ -522,7 +535,7 @@ class RecurrentStateCache:
                 lambda: self.slot_state_bytes,
                 meta={"slot": int(slot), "request": name})
 
-    def ensure(self, slot, n_tokens):
+    def ensure(self, slot, n_tokens, queries_from=None):
         """Nothing grows; only the admission's bound is held."""
         if slot not in self._reserved:
             raise ValueError(f"slot {slot} is not admitted")
@@ -628,7 +641,7 @@ class PagedStateCache:
         self.pages.admit(slot, n_tokens_worst_case, name)
         self.state.admit(slot, n_tokens_worst_case, name)
 
-    def ensure(self, slot, n_tokens):
+    def ensure(self, slot, n_tokens, queries_from=None):
         self.state.ensure(slot, n_tokens)
         return self.pages.ensure(slot, n_tokens)
 
@@ -638,3 +651,216 @@ class PagedStateCache:
     def free(self, slot):
         self.state.free(slot)
         return self.pages.free(slot)
+
+
+def ring_columns(window, page_size, span):
+    """Columns of a window layer's table: the most pages that hold a
+    key some query of one group of launches still sees. Between two
+    fences the queries of a slot cover up to `span` positions (a
+    prefill chunk, or the decode steps of a block), the earliest sees
+    `window` keys back, and an interval of n positions touches at most
+    (n - 2) // page + 2 pages wherever it begins."""
+    return (int(window) + int(span) - 3) // int(page_size) + 2
+
+
+class RingKVCache(PagedKVCache):
+    """The pool of layers that attend over a sliding window: query t
+    sees keys (t - window, t]. A slot's table is a RING of
+    `ring_columns` columns, logical page p in column p % ring; pages
+    wholly behind the window of the earliest coming query go back to
+    the free list at the fence that passes them (`ensure` with
+    `queries_from`), and their columns take the pages ahead. Admission
+    reserves the ring, or the request's worst case where that is
+    smaller. Released pages keep their data until reassigned; the
+    compiled programs never look behind a query's window."""
+
+    def __init__(self, window, span, page_size, **kw):
+        self.window = int(window)
+        self.ring = ring_columns(window, page_size, span)
+        super().__init__(page_size=page_size, max_pages_per_slot=self.ring,
+                         **kw)
+        self._first = {}       # slot -> logical page of _pages[slot][0]
+
+    def pages_to_reserve(self, n_tokens_worst_case):
+        return min(self.ring, self.pages_for_tokens(n_tokens_worst_case))
+
+    def reserved_tokens(self, slot):
+        """A ring bounds the pages, not the tokens: the full layers'
+        pool bounds those."""
+        return np.iinfo(np.int64).max if slot in self._reserved else 0
+
+    def released_pages(self):
+        """Pages the live slots have given back as their windows
+        passed (what whole histories would hold beside `pages_in_use`)."""
+        return sum(self._first.values())
+
+    def admit(self, slot, n_tokens_worst_case, name=None):
+        super().admit(slot, n_tokens_worst_case, name)
+        self._first[slot] = 0
+
+    def ensure(self, slot, n_tokens, queries_from=None):
+        """Pages for positions [lo, n_tokens), lo the first key that
+        the query at `queries_from` sees (0 where none is given:
+        nothing is released). Pages behind lo are released first, so
+        their columns are free for the pages ahead."""
+        if slot not in self._pages:
+            raise ValueError(f"slot {slot} is not admitted")
+        pages = self._pages[slot]
+        lo = 0 if queries_from is None else \
+            max(int(queries_from) - self.window + 1, 0)
+        first = self._first[slot]
+        passed = min(lo // self.page_size - first, len(pages))
+        if passed > 0:
+            self._free.extend(reversed(pages[:passed]))
+            for p in range(first, first + passed):
+                self.tables[slot, p % self.ring] = 0
+            del pages[:passed]
+            first = self._first[slot] = first + passed
+            self.table_version += 1
+        need = self.pages_for_tokens(n_tokens) - first
+        if need > self.ring:
+            raise RuntimeError(
+                f"slot {slot}: positions [{first * self.page_size}, "
+                f"{n_tokens}) do not fit a ring of {self.ring} pages "
+                "(tell ensure() where the coming queries begin)")
+        while len(pages) < need:
+            phys = self._free.pop()
+            self.tables[slot, (first + len(pages)) % self.ring] = phys
+            pages.append(phys)
+            self.table_version += 1
+        return pages
+
+    def rollback(self, slot, n_tokens):
+        """As the base's, on the pages still held: what the window
+        passed stays released (a rejected suffix lies ahead of it)."""
+        if slot not in self._pages:
+            raise ValueError(f"slot {slot} is not admitted")
+        pages, first = self._pages[slot], self._first[slot]
+        need = max(self.pages_for_tokens(n_tokens) - first, 0)
+        if need >= len(pages):
+            return 0
+        freed = pages[need:]
+        del pages[need:]
+        self._free.extend(reversed(freed))
+        for p in range(first + need, first + need + len(freed)):
+            self.tables[slot, p % self.ring] = 0
+        self.table_version += 1
+        return len(freed)
+
+    def free(self, slot):
+        self._first.pop(slot, None)
+        return super().free(slot)
+
+
+class WindowedKVCache:
+    """Two pools and two tables a slot, for a model whose layers
+    attend some over a sliding window and some over everything
+    (`models/trinity.py`): `full` (a `PagedKVCache` over the full
+    layers, whole histories) and `window` (a `RingKVCache` over the
+    window layers) behind the one interface. A request is admitted
+    only if the full pool covers its worst case and the window pool
+    its ring; both grow at the same fences, and the window half gives
+    pages back as the queries move on. Every fence row carries
+    `kv_pages_full_in_use`, `kv_pages_window_in_use` and
+    `kv_pages_window_released` (what the live slots' window layers
+    would hold besides, had they kept whole histories: in_use +
+    released = the full pool's in_use)."""
+
+    kind = "paged+window"
+
+    def __init__(self, full, window):
+        self.full, self.window = full, window
+        self.pool_bytes = full.pool_bytes + window.pool_bytes
+        self.num_pages = full.num_pages       # the tracker's snapshot
+        self.page_size = full.page_size
+        self.max_slots = full.max_slots
+
+    # the tables the engine uploads: the full layers', then the ring
+    tables = property(lambda self: (self.full.tables, self.window.tables))
+    table_version = property(lambda self: self.full.table_version +
+                             self.window.table_version)
+
+    def slots(self):
+        return self.full.slots()
+
+    def reserved_tokens(self, slot):
+        return self.full.reserved_tokens(slot)
+
+    def allocated_pages(self, slot):
+        return self.full.allocated_pages(slot) + \
+            self.window.allocated_pages(slot)
+
+    def never_fits(self, n_tokens_worst_case):
+        return self.full.never_fits(n_tokens_worst_case) or \
+            self.window.never_fits(n_tokens_worst_case)
+
+    def reservation(self, n_tokens_worst_case):
+        return {"kv_pages_reserved": int(
+            self.full.pages_to_reserve(n_tokens_worst_case)),
+            "kv_pages_window_reserved": int(
+                self.window.pages_to_reserve(n_tokens_worst_case))}
+
+    def occupancy(self):
+        return {"kv_pages_full_in_use": int(self.full.pages_in_use()),
+                "kv_pages_window_in_use": int(self.window.pages_in_use()),
+                "kv_pages_window_released": int(
+                    self.window.released_pages()),
+                "kv_pages_free": int(self.full.free_pages())}
+
+    def ledger_occupancy(self):
+        full = self.full.ledger_occupancy()
+        return {**self.occupancy(),
+                "kv_pages_full_in_use": full["kv_pages_in_use"],
+                "kv_pages_window_in_use":
+                self.window.ledger_occupancy()["kv_pages_in_use"],
+                "kv_page_utilization": full["kv_page_utilization"]}
+
+    def attended(self, active, pos, *fence):
+        """The pages the decode kernel walks at the next launch: every
+        page of a live slot in a full layer, the window's in a window
+        layer (one count a pool; a pool's layers walk alike)."""
+        page = self.page_size
+        last = pos[active] // page
+        first = np.maximum(pos[active] - self.window.window + 1, 0) // page
+        return {"kv_pages_attended": int((last + 1).sum()),
+                "kv_pages_window_attended": int((last - first + 1).sum())}
+
+    def utilization_counter(self, occupancy):
+        """The trace export's one track: the full layers' pool, which
+        admission fills first."""
+        return "kv_page_utilization", {
+            "in_use": occupancy["kv_pages_full_in_use"],
+            "free": occupancy["kv_pages_free"]}
+
+    def slot_operand(self, slot):
+        """What the prefill program is handed to find `slot`'s cache:
+        its row of both tables."""
+        return (self.full.tables[slot], self.window.tables[slot])
+
+    def can_admit(self, n_tokens_worst_case):
+        return self.full.can_admit(n_tokens_worst_case) and \
+            self.window.can_admit(n_tokens_worst_case)
+
+    def admit(self, slot, n_tokens_worst_case, name=None):
+        if not self.can_admit(n_tokens_worst_case):
+            raise RuntimeError(
+                f"kv cache cannot admit {n_tokens_worst_case} tokens: "
+                f"{self.full.free_pages()} free pages "
+                f"({self.full.reserved_unallocated()} reserved) for the "
+                f"full layers, {self.window.free_pages()} "
+                f"({self.window.reserved_unallocated()} reserved) for "
+                "the window layers")
+        self.full.admit(slot, n_tokens_worst_case, name)
+        self.window.admit(slot, n_tokens_worst_case, name)
+
+    def ensure(self, slot, n_tokens, queries_from=None):
+        pages = self.full.ensure(slot, n_tokens)   # holds the bound
+        self.window.ensure(slot, n_tokens, queries_from)
+        return pages
+
+    def rollback(self, slot, n_tokens):
+        return self.full.rollback(slot, n_tokens) + \
+            self.window.rollback(slot, n_tokens)
+
+    def free(self, slot):
+        return self.full.free(slot) + self.window.free(slot)

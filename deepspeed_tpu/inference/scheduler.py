@@ -79,6 +79,10 @@ class ServingLoop:
         # prefill launches since the last fence and the prompt tokens
         # they covered (the fence rows' state_prefill_* counters)
         self._prefill_launches = self._prefill_tokens = 0
+        # what the model's block counts (`engine.fetch_state`'s
+        # "counts"): cumulative on the device, so the fence diffs them
+        # against this mirror like the speculative counters below
+        self._last_counts = {}
         # speculative-decoding fence mirrors: the device counters are
         # cumulative per slot (never reset mid-flight), so the fence
         # diffs them against these to get per-window numbers
@@ -257,7 +261,7 @@ class ServingLoop:
                 # prefill reads its table ROW from the host copy; the
                 # device table upload happens once per iteration in
                 # step() (push_tables dedupes by version anyway)
-                self._infer.cache.ensure(slot, end)
+                self._infer.cache.ensure(slot, end, queries_from=start)
                 t0 = time.perf_counter()
                 self._infer.prefill_chunk(slot, req.tokens[start:end],
                                           start)
@@ -336,10 +340,12 @@ class ServingLoop:
             snap["active"], snap["pos"], iterations, new_tokens,
             self._prefill_launches * self._infer.config.prefill_chunk,
             self._prefill_tokens)
-        self._prefill_launches = self._prefill_tokens = 0
         mon.event(
             "decode_batch",
+            # the loop's clock, on which requests arrive
+            loop_s=round(now, 6),
             iterations=int(iterations),
+            prefill_launches=int(self._prefill_launches),
             active_slots=len(self.live),
             prefilling_slots=len(self.prefilling),
             queue_depth=len(self.queue),
@@ -348,7 +354,9 @@ class ServingLoop:
             tokens_per_sec=round(new_tokens / window_s, 3),
             # pages in use and free, or for a model of recurrent state
             # the slots holding state and its bytes
-            **self._infer.cache.occupancy(), **engaged)
+            **self._infer.cache.occupancy(), **engaged,
+            **self._counted(snap.get("counts")))
+        self._prefill_launches = self._prefill_tokens = 0
         if trk is not None:
             # SLO metrics AFTER evictions: this fence's finishes are in
             # the histograms/counters the event reports
@@ -357,6 +365,19 @@ class ServingLoop:
                                  len(self.prefilling), engaged)
         if mon.memory_enabled:
             mon._emit_memory_event(self._infer._host_steps)
+
+    def _counted(self, counts):
+        """What the model's block counted over this fence's launches:
+        the decode program's under the counters' own names, prefill's
+        under `prefill_<name>`. The device's sums are int32 and wrap;
+        a fence's share of them does not."""
+        out = {}
+        for program, prefix in (("decode", ""), ("prefill", "prefill_")):
+            for name, total in (counts or {}).get(program, {}).items():
+                last = self._last_counts.get((program, name), 0)
+                out[prefix + name] = (total - last) & 0xFFFFFFFF
+                self._last_counts[program, name] = total
+        return out
 
     def _spec_fence(self, snap, window_s, iterations, rollback_pages):
         """Per-fence speculative accounting: diff the cumulative

@@ -34,8 +34,20 @@ device-side and drain at the existing monitor fence.
                implementation parity tests and the bench leg pin
                against
 
-See docs/moe.md for the routing math, capacity semantics, and the
-ZeRO-3 / elasticity composition contract.
+  serving.py   the layer as it is SERVED, a different contract and
+               plain functions (no flax, nothing of the above):
+               dropless top-k over experts that are all held or the
+               share this chip is told it holds (`expert_layer(...,
+               first_expert)`), sigmoid scores with a selection bias,
+               normalised and scaled weights (`route`), rows sorted by
+               expert (`sorted_by_expert`), one grouped product a
+               projection over the experts held (`grouped_product`:
+               `megablox.gmm` on a TPU, `lax.ragged_dot` elsewhere),
+               gated SiLU experts and a shared one (`gated_mlp`);
+               what a serving `block` calls (`models/trinity.py`)
+
+See docs/moe.md for the routing math, capacity semantics, the ZeRO-3 /
+elasticity composition contract, and the serving layer beside them.
 """
 
 from deepspeed_tpu.moe.dispatch import (dispatch_bytes_per_layer,
@@ -51,6 +63,9 @@ from deepspeed_tpu.moe.layer import (MoEConfig, MoEMLP,
 from deepspeed_tpu.moe.router import (router_capacity, top_k_gating,
                                       top_k_gating_indexed,
                                       STAT_AUX, STAT_DROP)
+from deepspeed_tpu.moe.serving import (expert_layer, gated_mlp,
+                                       grouped_product, route,
+                                       sorted_by_expert)
 
 __all__ = [
     "MoEConfig", "MoEMLP", "ExpertFFN", "grouped_gemm",
@@ -59,4 +74,7 @@ __all__ = [
     "top_k_gating", "top_k_gating_indexed", "fused_dispatch",
     "fused_combine", "routing_slots", "dispatch_bytes_per_layer",
     "reset_dispatch_accounting", "STAT_AUX", "STAT_DROP",
+    # the layer as it is served (serving.py)
+    "expert_layer", "route", "sorted_by_expert", "grouped_product",
+    "gated_mlp",
 ]

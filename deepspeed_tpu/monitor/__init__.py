@@ -565,9 +565,17 @@ class Monitor:
                         "(suppressing further warnings for this sink)",
                         exc_info=True)
 
+    def attach_sink(self, sink):
+        """A sink of the caller's own (`emit(event)`, sinks.py),
+        beside the configured ones. It is handed the host events
+        (`event`: the serving loop's `decode_batch` rows among them)
+        whether or not the config enabled the monitor: with
+        `monitor.enabled` false nothing else is switched on for it."""
+        self.sinks.append(sink)
+
     def _emit_kind(self, kind, fields):
         """Thread-safe host-event hook (checkpoint writer, watchdog)."""
-        if not self.enabled:
+        if not self.enabled and not self.sinks:
             return
         e = self._engine_ref()
         event = base_event(kind, e._host_steps if e else 0)

@@ -32,6 +32,11 @@ pure index math — NO device sync anywhere in this module):
                   layers; same unallocated + per-request split so the
                   category total is the true draft pool bytes
                   (inference/kv_cache.py attach_draft)
+  kv_cache_window the pool of the layers that attend over a sliding
+                  window, where a model keeps window and full layers
+                  side by side (`kv_cache` then holds the full layers'
+                  pool): a ring of pages a slot, the same split
+                  (inference/kv_cache.py WindowedKVCache)
   ckpt_snapshot   checkpoint snapshot double-buffers — alive only
                   between the jitted snapshot and the writer's commit
   prefetch        staged batches queued ahead of the step loop
@@ -79,6 +84,7 @@ CAT_PREFETCH = "prefetch"
 CAT_PIPE = "pipe_buffers"
 CAT_KV = "kv_cache"
 CAT_KV_DRAFT = "kv_cache_draft"
+CAT_KV_WINDOW = "kv_cache_window"
 CAT_STATE = "recurrent_state"
 CAT_MOE = "moe_dispatch"
 CAT_OVERLAP = "overlap_inflight"

@@ -47,6 +47,15 @@ blocks whatever Tq is (a static loop over the rows), so a row of a
 Tq = k + 1 verify launch equals the Tq = 1 decode launch at that
 position bit for bit: later keys are masked to an exact zero weight.
 
+A lower bound on the keys a query sees (`first`, a first visible key
+a query row): a layer that attends over a sliding window keeps only
+the window's pages, in a table that is a RING of `ring` columns
+(logical page p of a slot lies in column p % ring), and the walk
+starts at the page of the slot's first visible key (row 0's: the rows
+of a slot ascend) instead of at page 0. Keys below a row's `first`
+are masked as keys past its position are. With no `first` the kernel
+is, instruction for instruction, the one without this paragraph.
+
 On a backend without Mosaic the same kernel runs in the Pallas
 interpreter.
 """
@@ -73,9 +82,13 @@ def padded_lanes(width):
     return -(-int(width) // LANE) * LANE
 
 
-def _kernel(li_ref, tables_ref, lens_ref, qpos_ref, q_ref, k_hbm, v_hbm,
-            o_ref, kbuf, vbuf, sem, m_ref, l_ref, acc_ref, *, n_head,
-            n_kv_head, head_dim, sm_scale, precision):
+def _kernel(li_ref, tables_ref, lens_ref, qpos_ref, *refs, n_head,
+            n_kv_head, head_dim, sm_scale, precision, windowed, ring):
+    # with a lower bound: one more scalar operand, the first visible
+    # key of every query row
+    first_ref = refs[0] if windowed else None
+    (q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, m_ref, l_ref,
+     acc_ref) = refs[1:] if windowed else refs
     s = pl.program_id(0)
     group = n_head // n_kv_head
     tq, lanes = q_ref.shape[1] // group, q_ref.shape[2]
@@ -84,11 +97,20 @@ def _kernel(li_ref, tables_ref, lens_ref, qpos_ref, q_ref, k_hbm, v_hbm,
     bk = npb * page
     length = lens_ref[s]
     n_pages = (length + page - 1) // page
+    # the walk's first page and key: 0 without a lower bound, and then
+    # nothing is added anywhere (the kernel as it was, op for op)
+    page0 = key0 = None
+    if windowed:
+        page0 = first_ref[s, 0] // page
+        key0 = page0 * page
+        n_pages = n_pages - page0
+    after = lambda start, x: x if start is None else start + x
     n_blocks = (n_pages + npb - 1) // npb
     li = li_ref[0]
 
     def copies(blk, slot, i):
-        phys = tables_ref[s, blk * npb + i]
+        column = after(page0, blk * npb + i)
+        phys = tables_ref[s, column % ring if ring else column]
         return (pltpu.make_async_copy(k_hbm.at[li, phys], kbuf.at[slot, i],
                                       sem.at[0, slot]),
                 pltpu.make_async_copy(v_hbm.at[li, phys], vbuf.at[slot, i],
@@ -141,15 +163,19 @@ def _kernel(li_ref, tables_ref, lens_ref, qpos_ref, q_ref, k_hbm, v_hbm,
             v = vbuf[slot].reshape(bk, lanes)
             # rows past the slot's length (the tail of its last page,
             # pages of this block that were not copied) hold anything
-            row = blk * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+            row = after(key0, blk * bk + jax.lax.broadcasted_iota(
+                jnp.int32, (bk, 1), 0))
             v = jnp.where(row < length, v, jnp.zeros((), v.dtype))
-            kpos = blk * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+            kpos = after(key0, blk * bk + jax.lax.broadcasted_iota(
+                jnp.int32, (1, bk), 1))
             for r in range(tq):
                 scores = jax.lax.dot_general(
                     qbd[r], k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
                     precision=precision) * sm_scale
                 seen = (kpos <= qpos_ref[s, r]) & (kpos < length)
+                if windowed:
+                    seen = seen & (kpos >= first_ref[s, r])
                 scores = jnp.where(seen, scores, NEG_INF)
                 m_prev = m_ref[r]
                 m_new = jnp.maximum(
@@ -174,7 +200,7 @@ def _kernel(li_ref, tables_ref, lens_ref, qpos_ref, q_ref, k_hbm, v_hbm,
 
 
 def paged_decode_attention(q, k_pool, v_pool, li, tables, q_pos, lens,
-                           n_head, n_kv_head=None):
+                           n_head, n_kv_head=None, first=None, ring=None):
     """Causal attention of q [B, Tq, H*D] (Tq a few rows) against layer
     `li` of the page pools [L, P, page, lanes], through the page tables
     [B, max_pages]. The pools hold `n_kv_head` heads a token (default:
@@ -184,7 +210,16 @@ def paged_decode_attention(q, k_pool, v_pool, li, tables, q_pos, lens,
     slot's live length (0: the slot is not live; it returns zeros and
     reads nothing). Returns [B, Tq, H*D] in q's dtype. Rows of the
     pools at or past a slot's length may hold anything finite or not:
-    they contribute exactly nothing."""
+    they contribute exactly nothing.
+
+    `first` [B, Tq]: row r of slot b sees no key below first[b, r]
+    (a sliding window: q_pos - window + 1, floored at 0), and the page
+    walk starts at the page of first[b, 0]. `ring`: the tables have
+    that many columns and logical page p of a slot lies in column
+    p % ring (a window layer keeps the window's pages only); without
+    it column p."""
+    if ring is not None and first is None:
+        raise ValueError("a ring of pages needs the first visible key")
     b, tq, c = q.shape
     n_kv_head = n_head if n_kv_head is None else n_kv_head
     group = n_head // n_kv_head
@@ -213,10 +248,10 @@ def paged_decode_attention(q, k_pool, v_pool, li, tables, q_pos, lens,
         _kernel, n_head=n_head, n_kv_head=n_kv_head, head_dim=head_dim,
         sm_scale=1.0 / np.sqrt(head_dim),
         precision=jax.lax.Precision.HIGHEST if dtype == jnp.float32
-        else None)
+        else None, windowed=first is not None, ring=ring)
     row = lambda s, *_: (s, 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=4 if first is None else 5,
         grid=(b,),
         in_specs=[pl.BlockSpec((1, tq * group, lanes), row),
                   pl.BlockSpec(memory_space=pl.ANY),
@@ -241,7 +276,9 @@ def paged_decode_attention(q, k_pool, v_pool, li, tables, q_pos, lens,
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(jnp.reshape(li, (1,)).astype(jnp.int32), tables.astype(jnp.int32),
-      lens.astype(jnp.int32), q_pos.astype(jnp.int32), q32, k_pool, v_pool)
+      lens.astype(jnp.int32), q_pos.astype(jnp.int32),
+      *(() if first is None else (first.astype(jnp.int32),)),
+      q32, k_pool, v_pool)
     out = out[..., :c].astype(q.dtype)
     if group > 1:
         out = out.reshape(b, tq, group, n_kv_head, head_dim).transpose(

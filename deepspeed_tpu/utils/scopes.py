@@ -16,7 +16,7 @@ and SCOPE_STATE_UPDATE. A cache-sized copy showing up under
 SCOPE_LAYERS alone is a regression.
 
 A model's block (`models/gpt2.py`, `models/brumby.py`,
-`models/falcon_h1.py`) and the engine
+`models/falcon_h1.py`, `models/trinity.py`) and the engine
 (`inference/engine.py`, which re-exports them) both take the names
 from here: neither the models nor the ops import the serving code.
 """
@@ -74,3 +74,22 @@ SCOPES_IN_LAYER_PAGED_STATE = (
 SCOPES_PAGED_STATE = (SCOPE_EMBED, SCOPE_LAYERS) + \
     SCOPES_IN_LAYER_PAGED_STATE + (SCOPE_HEAD, SCOPE_SAMPLE,
                                    SCOPE_BOOKKEEPING)
+
+# a model whose layers keep K/V pages in two geometries (a sliding
+# window's ring and whole histories) and whose feed-forward is an
+# expert layer (`models/trinity.py`, `moe/serving.py`). The paged
+# regions keep their names and meaning, and so does SCOPE_MLP (the
+# norms round the feed-forward, a dense layer's feed-forward, the
+# residual); the expert layer's parts stand inside it under their own
+SCOPE_MOE_ROUTER = "moe_router"      # sigmoid scores, top-k, weights
+SCOPE_MOE_DISPATCH = "moe_dispatch"  # rows sorted by expert: the counting
+#                                      sort and the gather of the rows
+SCOPE_MOE_EXPERTS = "moe_experts"    # the grouped products and their gate
+SCOPE_MOE_SHARED = "moe_shared"      # the shared expert
+SCOPE_MOE_COMBINE = "moe_combine"    # gathered back, weighted, summed
+SCOPES_MOE = (SCOPE_MOE_ROUTER, SCOPE_MOE_DISPATCH, SCOPE_MOE_EXPERTS,
+              SCOPE_MOE_SHARED, SCOPE_MOE_COMBINE)
+SCOPES_IN_LAYER_PAGED_MOE = SCOPES_IN_LAYER + SCOPES_MOE
+SCOPES_PAGED_MOE = (SCOPE_EMBED, SCOPE_LAYERS) + \
+    SCOPES_IN_LAYER_PAGED_MOE + (SCOPE_HEAD, SCOPE_SAMPLE,
+                                 SCOPE_BOOKKEEPING)

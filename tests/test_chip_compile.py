@@ -364,3 +364,129 @@ def test_both_caches_are_updated_in_place_on_a_v5e(falcon_scans, program):
         assert {"kv_write", "kv_gather", "attn", "state_reset", "ssm_conv",
                 "ssm_chunk"} <= regions
         assert "state_update" not in regions
+
+
+# ----------------------------------------------------------------------
+# Trinity's layer scans at the shapes of
+# `trinity-mini.serve-reason-steady` (a dense layer and one period of
+# four expert layers at the published widths, 96 slots, chunk 512,
+# pages of 128; ISSUE 35)
+# ----------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def trinity_scans(one_chip):
+    """program -> (compiled layer scans, the cache's arrays), with the
+    backend probes of the decode kernel and of the grouped product
+    answering "TPU"."""
+    from deepspeed_tpu.models import trinity
+    from deepspeed_tpu.moe import serving as moe
+    from deepspeed_tpu.ops.transformer import paged_decode_attention
+    slots, chunk, seq = 96, 512, 6144
+    S, F = trinity.SLIDING, trinity.FULL
+    cfg = trinity.TrinityConfig(num_hidden_layers=5, num_dense_layers=1,
+                                layer_types=(S, S, S, S, F))
+    block = InferenceConfig({"inference": {
+        "max_slots": slots, "prefill_chunk": chunk, "sync_every": 4,
+        "max_new_tokens": 2048, "max_seq_len": seq,
+        "kv_cache": {"num_pages": 4097, "page_size": 128}}})
+    family = engine_mod.Serving(cfg, block, seq)
+    cache = family.kind.make_cache(None)
+    ring = cache.window.ring
+    place = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip)
+    sds = lambda shape, dtype: place(jax.ShapeDtypeStruct(shape, dtype))
+    params = jax.tree_util.tree_map(place, jax.eval_shape(
+        lambda k: trinity.init_params(cfg, k), jax.random.PRNGKey(0)))
+    full = sds(cache.full.pool_shape(1), cfg.dtype)
+    window = sds(cache.window.pool_shape(4), cfg.dtype)
+    arrays = (full, full, window, window, sds((2, 3), jnp.int32))
+    keys = family.cache_keys
+
+    def decode_layers(params, hidden, *rest):
+        cache, (tables, rings, pos, active) = rest[:5], rest[5:]
+        # as the decode program asks: the rows' picks beside the carry
+        return family.decode_layers(params, hidden, dict(
+            zip(keys, cache), tables=tables, window_tables=rings, pos=pos,
+            active=active), readings=True)
+
+    def prefill_layers(params, hidden, *rest):
+        cache, (row, ring_row, start, n_valid) = rest[:5], rest[5:]
+        posv = start + jnp.arange(chunk, dtype=jnp.int32)
+        return family.prefill_layers(
+            params, hidden, cache, (row, ring_row), posv,
+            jnp.arange(chunk) < n_valid, start, n_valid)
+
+    pages = seq // 128
+    programs = {
+        "decode": (decode_layers, (
+            params, sds((slots, 1, 2048), cfg.dtype)) + arrays + (
+            sds((slots, pages), jnp.int32), sds((slots, ring), jnp.int32),
+            sds((slots,), jnp.int32), sds((slots,), bool))),
+        "prefill": (prefill_layers, (
+            params, sds((1, chunk, 2048), cfg.dtype)) + arrays + (
+            sds((pages,), jnp.int32), sds((ring,), jnp.int32),
+            sds((), jnp.int32), sds((), jnp.int32)))}
+    compiled = {}
+
+    def get(program):
+        if program not in compiled:
+            layers, args = programs[program]
+            probes = (paged_decode_attention._on_tpu, moe._on_tpu)
+            paged_decode_attention._on_tpu = moe._on_tpu = lambda: True
+            try:
+                compiled[program] = jax.jit(
+                    layers, donate_argnums=(2, 3, 4, 5, 6)).lower(
+                        *args).compile()
+            finally:
+                paged_decode_attention._on_tpu, moe._on_tpu = probes
+        return compiled[program], arrays
+    return get
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_both_pools_pass_the_conditional_in_place_on_a_v5e(trinity_scans,
+                                                           program):
+    """1.07 GB of the full layer's pool and 2.11 GB of the window
+    layers' rings ride in the carry of both layer scans and through
+    the `lax.cond` on the layer's kind: all four donated arrays are
+    the outputs, nothing pool-shaped is copied, Mosaic takes the decode
+    kernel in both branches (with and without a first visible key) and
+    the grouped product (three calls in the expert layers' body), no
+    layer's experts are sliced out of the stack, and every region of
+    the expert layer survives the chip's fusions."""
+    from benchmark import moe_costs, region_join
+    from deepspeed_tpu.monitor import programs
+    compiled, arrays = trinity_scans(program)
+    full, _, window, _, _ = arrays
+    assert full.shape == (1, 4097, 128, 512) and \
+        window.shape == (4, 96 * 21 + 1, 128, 512)
+    cache_bytes = 2 * sum(int(np.prod(a.shape)) * 2 for a in (full, window))
+    memory = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert memory.alias_size_in_bytes >= cache_bytes
+    # the pools, a layer of them, or a layer's experts [128, 2048, 1024]
+    for shape in (full.shape, window.shape, window.shape[1:],
+                  (128, 2048, 1024), (128, 1024, 2048)):
+        whole = ",".join(map(str, shape))
+        moved = re.findall(
+            rf"= \w+\[(?:1,)?{whole}\]\S* "
+            r"(copy|transpose|dynamic-slice)\(", text)
+        assert moved == [], (shape, moved)
+    calls = re.findall(
+        r'custom-call\(.*custom_call_target="tpu_custom_call"', text)
+    scopes = programs.parse_op_scopes(text)
+    regions = {region_join.region_of(stack, moe_costs.PAGED_MOE)
+               for stack in scopes.values()}
+    assert set(moe_costs.MOE) <= regions and "kv_write" in regions
+    if program == "decode":
+        # gate, up, down; the decode kernel in each scan and branch
+        assert len(calls) == 6 and "paged_decode_attention" in text
+        # every layer's picks a row (the dense layer's: -1) leave with
+        # the carry: `ROW_READINGS`, 15 KB
+        assert "s32[5,96,8]" in text
+        assert memory.temp_size_in_bytes < 64 << 20
+        assert "kv_gather" not in regions
+    else:
+        assert len(calls) == 3
+        # the float32 scores of 512 rows against the 6,144-key window
+        assert memory.temp_size_in_bytes < 1 << 30
+        assert "kv_gather" in regions

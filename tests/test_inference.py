@@ -341,6 +341,61 @@ def test_continuous_batch_matches_isolated_runs(setup):
     assert engine.cache.free_pages() == engine.cache.num_pages - 1
 
 
+def test_a_launch_between_two_blocks_of_the_loop_loses_no_row(setup,
+                                                               monkeypatch):
+    """A caller's `decode_once` between two of the loop's blocks (the
+    benchmark reads the live slots' next logits so) advances every
+    live slot by a token that the loop's count of positions, kept by
+    the fence, does not hold: `ensure_decode_capacity` counts the
+    launches since the fence in, so the block after it has its pages
+    and the requests end on the tokens of an undisturbed run (a row
+    past the pages asked for would go to the scratch page)."""
+    cfg, model, params, engine = setup
+    r = np.random.RandomState(11)
+    reqs = [(i, r.randint(0, cfg.vocab_size, size=n).astype(np.int32), m)
+            for i, (n, m) in enumerate([(5, 30), (9, 28), (14, 31)])]
+    make = lambda: [Request(rid=i, tokens=t.copy(), max_new_tokens=m)
+                    for i, t, m in reqs]
+    engine.reset()
+    want = {q.rid: q.out_tokens.tolist()
+            for q in ServingLoop(engine).serve(make())}
+    engine.reset()
+    loop = ServingLoop(engine)
+    for q in make():
+        loop.submit(q)
+    import time
+    loop._t0 = time.monotonic()
+    loop._last_fence_t = loop._now()
+    launches, at, real = 0, {}, engine.decode_block
+    page = engine.cache.page_size
+
+    def block(n):
+        # the rows this block writes lie on pages the slot holds (the
+        # served tokens alone need not show a lost key: greedy
+        # decoding over random weights is hard to move)
+        for slot, first in at.items():
+            last = min(first + n, engine.cache.reserved_tokens(slot)) - 1
+            assert engine.cache.tables[slot][last // page] != 0, (slot, last)
+        at.clear()
+        real(n)
+
+    monkeypatch.setattr(engine, "decode_block", block)
+    while loop.queue or loop.live or loop.prefilling:
+        loop.step()
+        if loop.live:
+            snap = engine.fetch_state()
+            for slot in loop.live:
+                engine.ensure_decode_capacity(slot, int(snap["pos"][slot]), 1)
+                if snap["active"][slot]:
+                    at[slot] = int(snap["pos"][slot]) + 1
+            engine.push_tables()
+            engine.decode_once()
+            launches += 1
+    assert launches >= 6
+    assert {q.rid: q.out_tokens.tolist() for q in loop.results} == want
+    assert engine.cache.free_pages() == engine.cache.num_pages - 1
+
+
 def test_chunked_prefill_interleaves_with_decode(setup):
     """A long prompt (3 chunks) admitted while another request decodes:
     the decoding request keeps generating between the chunks (its

@@ -447,6 +447,28 @@ def test_monitor_disabled_creates_no_watchdog_or_sinks(tmp_path):
     assert set(snap) == set(Monitor.SNAPSHOT_KEYS)
 
 
+def test_an_attached_sink_is_handed_host_events_with_the_monitor_off():
+    """`attach_sink`: a caller's own sink gets the host events
+    (`event`) whether or not the config enabled the monitor; nothing
+    else is switched on for it, and the fence emits nothing."""
+    engine = _engine({"bf16": {"enabled": True}})
+    seen = []
+
+    class Sink:
+        emit = staticmethod(seen.append)
+
+    engine.monitor.event("decode_batch", iterations=4)      # nobody hears
+    engine.monitor.attach_sink(Sink())
+    engine.monitor.event("decode_batch", iterations=4, loop_s=0.5)
+    assert [(e["kind"], e["iterations"], e["loop_s"]) for e in seen] == \
+        [("decode_batch", 4, 0.5)]
+    assert {"v", "ts", "step"} <= set(seen[0])
+    assert engine.monitor.enabled is False
+    assert engine.monitor.watchdog is None and engine.monitor.flight is None
+    engine.train_batch(batch=_make_stacked(0))
+    assert engine.monitor.on_fence() is None and len(seen) == 1
+
+
 # ----------------------------------------------------------------------
 # snapshot schema stability
 # ----------------------------------------------------------------------
