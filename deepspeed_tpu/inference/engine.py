@@ -804,6 +804,45 @@ class Serving:
         return self.model.head(self.mc, params, hidden)
 
 
+def process_logits(l32, top_k, temperature, top_k_cap):
+    """The sampler's per-slot top-k mask + temperature scale (l32
+    [S, V] fp32; top_k/temperature [S]): what the decode program draws
+    from, and what speculative decoding passes both p and q through
+    for the acceptance ratio to target that same distribution."""
+    vals, _ = jax.lax.top_k(l32, top_k_cap)
+    idx = jnp.clip(top_k - 1, 0, top_k_cap - 1)
+    kth = jnp.take_along_axis(vals, idx[:, None], axis=1)[:, 0]
+    masked = jnp.where((top_k > 0)[:, None] & (l32 < kth[:, None]),
+                       -jnp.inf, l32)
+    return masked / jnp.maximum(temperature, 1e-6)[:, None]
+
+
+def sample(logits, state, top_k_cap):
+    """(next token [S], whether the draw ran) from a decode launch's
+    logits [S, V]. The top-k over slots x vocabulary and the
+    categorical draw run only in a launch where a live slot's
+    temperature asks for them: a greedy batch takes the argmax alone.
+    Keys: `fold_in(rng, step)`, then the slot's index. Each side
+    widens the logits itself: one float32 copy shared by both would be
+    an operand of the cond, written out in every launch."""
+    with jax.named_scope(SCOPE_SAMPLE):
+        greedy = jnp.argmax(logits.astype(jnp.float32),
+                            axis=-1).astype(jnp.int32)
+        temp = state["temperature"]
+        asked = jnp.any(state["active"] & (temp > 0.0))
+
+        def draw():
+            scaled = process_logits(logits.astype(jnp.float32),
+                                    state["top_k"], temp, top_k_cap)
+            key = jax.random.fold_in(state["rng"], state["step"])
+            keys = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
+                key, jnp.arange(logits.shape[0]))
+            drawn = jax.vmap(jax.random.categorical)(keys, scaled)
+            return jnp.where(temp > 0.0, drawn.astype(jnp.int32), greedy)
+
+        return jax.lax.cond(asked, draw, lambda: greedy), asked
+
+
 class InferenceEngine:
     """Serving engine for one model over the kind of cache its config
     names: K/V pages, recurrent state, or both in every layer (see the
@@ -929,6 +968,8 @@ class InferenceEngine:
             "eos": jnp.full((s,), -1, jnp.int32),
             "rng": jax.random.PRNGKey(cfg.seed),
             "step": jnp.zeros((), jnp.int32),
+            # decode launches in which a live slot asked for a draw
+            "sample_draws": jnp.zeros((), jnp.int32),
         }
 
     def reset(self):
@@ -958,24 +999,6 @@ class InferenceEngine:
         out_w = cfg.max_new_tokens
         top_k_cap = min(cfg.top_k_max, mc.vocab_size)
 
-        @jax.named_scope(SCOPE_SAMPLE)
-        def sample(logits, state):
-            l32 = logits.astype(jnp.float32)
-            greedy = jnp.argmax(l32, axis=-1).astype(jnp.int32)
-            vals, _ = jax.lax.top_k(l32, top_k_cap)
-            idx = jnp.clip(state["top_k"] - 1, 0, top_k_cap - 1)
-            kth = jnp.take_along_axis(vals, idx[:, None], axis=1)[:, 0]
-            masked = jnp.where(
-                (state["top_k"] > 0)[:, None] & (l32 < kth[:, None]),
-                -jnp.inf, l32)
-            temp = state["temperature"]
-            scaled = masked / jnp.maximum(temp, 1e-6)[:, None]
-            key = jax.random.fold_in(state["rng"], state["step"])
-            keys = jax.vmap(jax.random.fold_in,
-                            in_axes=(None, 0))(key, jnp.arange(s))
-            drawn = jax.vmap(jax.random.categorical)(keys, scaled)
-            return jnp.where(temp > 0.0, drawn.astype(jnp.int32), greedy)
-
         def decode_fn(params, state):
             active = state["active"]
             pos = state["pos"]
@@ -987,7 +1010,7 @@ class InferenceEngine:
                 params, hidden, state, readings=bool(serving.row_readings))
             with jax.named_scope(SCOPE_HEAD):
                 logits = serving.head(params, hidden)[:, 0]
-            next_tok = sample(logits, state)
+            next_tok, drew = sample(logits, state, top_k_cap)
 
             with jax.named_scope(SCOPE_BOOKKEEPING):
                 n = state["n_gen"]
@@ -1009,6 +1032,8 @@ class InferenceEngine:
                     n_gen=n2,
                     out_tokens=out,
                     step=state["step"] + 1,
+                    sample_draws=state["sample_draws"] +
+                    drew.astype(jnp.int32),
                 )
             return new_state, logits, (read[0] if read else {})
 
@@ -1236,29 +1261,32 @@ class InferenceEngine:
     def fetch_state(self):
         """THE serving fence: one fused device_get of the per-slot
         progress the scheduler needs (active flags, eos flags,
-        positions, generated counts, output rings — plus, when
-        speculation is on, the round counters, still inside the SAME
-        fused get)."""
+        positions, generated counts, output rings, and what the
+        programs counted (the decode launches that drew a sample, the
+        model's own counters) — or, when speculation is on, the round
+        counters, still inside the SAME fused get)."""
         st = self._state
         self._decodes_since_fence = 0
         targets = (st["active"], st["finished_eos"], st["pos"],
                    st["n_gen"], st["out_tokens"])
         if not self.speculative_enabled:
             counted = self.serving.counters
+            targets += (st["sample_draws"],)
             if counted:
                 targets += (st["model_counts"],)
             with profiler_span("serve/fence.device_get"):
-                active, eos, pos, n_gen, out, *counts = \
+                active, eos, pos, n_gen, out, draws, *model = \
                     jax.device_get(targets)
-            snap = {"active": active, "finished_eos": eos, "pos": pos,
-                    "n_gen": n_gen, "out_tokens": out}
+            # what the programs counted since the engine's reset: the
+            # decode launches that drew, and what the model's block
+            # counts, the decode program's launches and prefill's
+            counts = {"decode": {"sample_draw_launches": int(draws)},
+                      "prefill": {}}
             if counted:
-                # what the model's block counted since the engine's
-                # reset, the decode program's launches and prefill's
-                snap["counts"] = {
-                    "decode": dict(zip(counted, counts[0][0].tolist())),
-                    "prefill": dict(zip(counted, counts[0][1].tolist()))}
-            return snap
+                for program, row in zip(("decode", "prefill"), model[0]):
+                    counts[program].update(zip(counted, row.tolist()))
+            return {"active": active, "finished_eos": eos, "pos": pos,
+                    "n_gen": n_gen, "out_tokens": out, "counts": counts}
         sp = self._spec_state
         with profiler_span("serve/fence.device_get"):
             (active, eos, pos, n_gen, out, k_slot, drafted, accepted,

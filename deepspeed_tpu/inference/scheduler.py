@@ -79,8 +79,9 @@ class ServingLoop:
         # prefill launches since the last fence and the prompt tokens
         # they covered (the fence rows' state_prefill_* counters)
         self._prefill_launches = self._prefill_tokens = 0
-        # what the model's block counts (`engine.fetch_state`'s
-        # "counts"): cumulative on the device, so the fence diffs them
+        # what the programs count (`engine.fetch_state`'s "counts":
+        # the decode launches that drew a sample, the model's block's
+        # counters): cumulative on the device, so the fence diffs them
         # against this mirror like the speculative counters below
         self._last_counts = {}
         # speculative-decoding fence mirrors: the device counters are
@@ -367,8 +368,9 @@ class ServingLoop:
             mon._emit_memory_event(self._infer._host_steps)
 
     def _counted(self, counts):
-        """What the model's block counted over this fence's launches:
-        the decode program's under the counters' own names, prefill's
+        """What the programs counted over this fence's launches
+        (`sample_draw_launches`, the model's block's counters): the
+        decode program's under the counters' own names, prefill's
         under `prefill_<name>`. The device's sums are int32 and wrap;
         a fence's share of them does not."""
         out = {}

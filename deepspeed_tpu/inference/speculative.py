@@ -61,7 +61,7 @@ and `head` over the paged kind's mixer); no model is named here.
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.engine import compile_fresh
+from deepspeed_tpu.inference.engine import compile_fresh, process_logits
 
 # fold_in lane separating the draft model's sampling stream from the
 # flagship's (state["rng"] folded by step on one side, by
@@ -96,19 +96,6 @@ def derive_draft(model_config, params, draft_model):
 # ----------------------------------------------------------------------
 # acceptance math (pure jnp; unit-tested in isolation)
 # ----------------------------------------------------------------------
-def process_logits(l32, top_k, temperature, top_k_cap):
-    """The vanilla sampler's per-slot top-k mask + temperature scale,
-    verbatim (l32 [S, V] fp32; top_k/temperature [S]). Both p and q
-    must pass through the SAME processing for the acceptance ratio to
-    target the distribution vanilla decode actually samples from."""
-    vals, _ = jax.lax.top_k(l32, top_k_cap)
-    idx = jnp.clip(top_k - 1, 0, top_k_cap - 1)
-    kth = jnp.take_along_axis(vals, idx[:, None], axis=1)[:, 0]
-    masked = jnp.where((top_k > 0)[:, None] & (l32 < kth[:, None]),
-                       -jnp.inf, l32)
-    return masked / jnp.maximum(temperature, 1e-6)[:, None]
-
-
 def leading_accept_count(flags):
     """Length of the leading all-True run along the last axis — the
     number of drafted tokens the acceptance rule keeps."""

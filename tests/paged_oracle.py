@@ -142,17 +142,25 @@ def traced_programs():
         yield jaxprs
 
 
-def scans(jaxpr):
-    """Every `scan` equation of a jaxpr, inner jaxprs included."""
+def equations(jaxpr, but=None):
+    """Every equation of a jaxpr, inner jaxprs included; the equation
+    `but` and what it holds left out."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scan":
-            yield eqn
+        if eqn is but:
+            continue
+        yield eqn
         for value in eqn.params.values():
             for sub in value if isinstance(value, (list, tuple)) \
                     else (value,):
                 inner = getattr(sub, "jaxpr", sub)
                 if hasattr(inner, "eqns"):
-                    yield from scans(inner)
+                    yield from equations(inner)
+
+
+def scans(jaxpr):
+    """Every `scan` equation of a jaxpr, inner jaxprs included."""
+    return (eqn for eqn in equations(jaxpr)
+            if eqn.primitive.name == "scan")
 
 
 def pools_in_scans(jaxpr, pool_shapes):

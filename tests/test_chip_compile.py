@@ -490,3 +490,36 @@ def test_both_pools_pass_the_conditional_in_place_on_a_v5e(trinity_scans,
         # the float32 scores of 512 rows against the 6,144-key window
         assert memory.temp_size_in_bytes < 1 << 30
         assert "kv_gather" in regions
+
+
+@pytest.mark.parametrize("slots, vocab", [(96, 200192), (16, 261120)])
+def test_the_samplers_top_k_stays_in_the_conditional_on_a_v5e(
+        one_chip, slots, vocab):
+    """The sampler at the Trinity and Falcon-H1 cells' shapes: the
+    chip's compiler keeps the `conditional`, the top-k over slots x
+    vocabulary (a custom fusion) lies in its taken branch and not in
+    the entry computation, and no float32 copy of the logits is made
+    beside it (ISSUE 36)."""
+    place = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                      sharding=one_chip)
+    state = {"temperature": place((slots,), jnp.float32),
+             "top_k": place((slots,), jnp.int32),
+             "active": place((slots,), jnp.bool_),
+             "rng": place((2,), jnp.uint32), "step": place((), jnp.int32)}
+    compiled = jax.jit(
+        lambda logits, state: engine_mod.sample(logits, state, 64)).lower(
+            place((slots, vocab), jnp.bfloat16), state).compile()
+    text = compiled.as_text()
+    # computations by name: a header at column 0 down to its "}"
+    bodies = {m.group(1): m.group(0) for m in re.finditer(
+        r"^(?:ENTRY )?%([\w.\-]+) \(.*?^}", text, re.M | re.S)}
+    entry = next(b for b in bodies.values() if b.startswith("ENTRY"))
+    branches = re.search(r"conditional\(.*branch_computations=\{(.*?)\}",
+                         entry).group(1).replace("%", "").split(", ")
+    assert len(branches) == 2
+    # who launches the top-k's fusion
+    launches = [name for name, body in bodies.items() if re.search(
+        r'fusion\(.*kind=kCustom.*op_name="[^"]*/top_k"', body)]
+    assert launches == [branches[1]], (launches, branches)
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        slots * vocab * 2

@@ -540,6 +540,159 @@ def test_sampling_same_seed_is_deterministic(setup):
 
 
 # ----------------------------------------------------------------------
+# the draw runs only when a live slot asks for it (ISSUE 36)
+# ----------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def drawing():
+    """An engine of its own, built while its programs' jaxprs are
+    recorded, with a sink that keeps the loop's `decode_batch` rows."""
+    import types
+    from tests.paged_oracle import traced_programs
+    cfg = tiny_gpt2_config()
+    params = _params(GPT2ForCausalLM(cfg))
+    with traced_programs() as jaxprs:
+        engine = InferenceEngine(cfg, params, {"inference": {
+            "max_slots": 4, "prefill_chunk": 16, "sync_every": 4,
+            "max_new_tokens": 32,
+            "kv_cache": {"num_pages": 120, "page_size": 4}}})
+    events = []
+    engine.monitor.attach_sink(types.SimpleNamespace(emit=events.append))
+    return cfg, engine, jaxprs["decode_fn"], events
+
+
+def _prompts(cfg, seed, n):
+    r = np.random.RandomState(seed)
+    return [r.randint(0, cfg.vocab_size, size=7 + 2 * i).astype(np.int32)
+            for i in range(n)]
+
+
+def _primitives(jaxpr, but=None):
+    """The names of a jaxpr's primitives, inner jaxprs included, the
+    equation `but` and what it holds left out."""
+    from tests.paged_oracle import equations
+    return {eqn.primitive.name for eqn in equations(jaxpr, but)}
+
+
+def test_top_k_and_the_random_bits_stand_in_one_branch_of_one_cond(
+        drawing):
+    """The decode function's jaxpr: one `cond` of its own (the decode
+    kernel's lie inside the layer scan); `top_k` and the random bits in
+    its taken branch and nowhere else, the other branch empty (it
+    hands the argmax through)."""
+    _, _, jaxpr, _ = drawing
+    costly = {"top_k", "random_bits"}
+    conds = [e for e in jaxpr.eqns if e.primitive.name == "cond"]
+    assert len(conds) == 1
+    skipped, taken = (_primitives(b.jaxpr)
+                      for b in conds[0].params["branches"])
+    assert not skipped, skipped
+    assert costly <= taken
+    outside = _primitives(jaxpr, but=conds[0])
+    assert "argmax" in outside
+    assert not costly & outside, costly & outside
+
+
+def test_the_draw_branch_draws_what_the_program_drew_before(drawing):
+    """A greedy request beside one with temperature 0.8 and top_k 16:
+    the greedy one's tokens are those it is served alone, and the
+    sampling one's are `process_logits` + `categorical` on the same
+    launches' logits under the program's keys (`fold_in(rng, step)`,
+    then the slot), as the program computed them for every slot of
+    every launch before it asked whether any slot wanted them."""
+    from deepspeed_tpu.inference.speculative import process_logits
+    cfg, engine, _, _ = drawing
+    greedy_prompt, sampled_prompt = _prompts(cfg, 31, 2)
+    n, slot = 8, 2
+    engine.reset()
+    alone = ServingLoop(engine).serve([Request(
+        rid="g", tokens=greedy_prompt.copy(), max_new_tokens=n)])[0]
+    engine.reset()
+    engine.start_request(0, greedy_prompt, max_new=n)
+    engine.start_request(slot, sampled_prompt, max_new=n,
+                         temperature=0.8, top_k=16)
+    # the launch donates the state: what it needs of it, on the host
+    rng, top_k, temp = jax.device_get(
+        [engine._state[k] for k in ("rng", "top_k", "temperature")])
+    cap = min(engine.config.top_k_max, cfg.vocab_size)
+    expected, argmaxes = [], []
+    for _ in range(n):
+        step = int(engine._state["step"])
+        logits = engine.decode_once()
+        argmaxes.append(int(jnp.argmax(logits[slot])))
+        scaled = process_logits(logits.astype(jnp.float32), top_k, temp,
+                                cap)
+        key = jax.random.fold_in(jax.random.fold_in(rng, step), slot)
+        expected.append(int(jax.random.categorical(key, scaled[slot])))
+    snap = engine.fetch_state()
+    assert snap["out_tokens"][slot][:n].tolist() == expected
+    assert snap["out_tokens"][0][:n].tolist() == alone.out_tokens.tolist()
+    assert snap["counts"]["decode"]["sample_draw_launches"] == n
+    assert expected != argmaxes     # not the argmax by another road
+    engine.reset()
+
+
+def _fence_rows(events):
+    return [e for e in events if e["kind"] == "decode_batch"]
+
+
+def test_an_idle_slot_that_sampled_does_not_switch_the_draw_on(drawing):
+    """A sampling request of one block and a greedy one of four: once
+    the first has finished, its slot lies idle with its temperature
+    still in the state, and the greedy slot's launches draw nothing."""
+    cfg, engine, _, events = drawing
+    greedy_prompt, sampled_prompt = _prompts(cfg, 32, 2)
+    engine.reset()
+    del events[:]
+    done = ServingLoop(engine).serve([
+        Request(rid="g", tokens=greedy_prompt, max_new_tokens=16),
+        Request(rid="s", tokens=sampled_prompt, max_new_tokens=4,
+                temperature=0.8, top_k=16)])
+    assert sorted(len(r.out_tokens) for r in done) == [4, 16]
+    rows = _fence_rows(events)
+    assert [r["iterations"] for r in rows] == [4] * 4
+    assert [r["sample_draw_launches"] for r in rows] == [4, 0, 0, 0]
+    state = jax.device_get({k: engine._state[k]
+                            for k in ("temperature", "active")})
+    assert state["temperature"][1] > 0 and not state["active"].any()
+    engine.reset()
+
+
+def test_fence_rows_count_the_launches_that_drew(drawing):
+    """`sample_draw_launches` on every `decode_batch` row: 0 through a
+    greedy run, `iterations` while a sampling slot is live (here the
+    first two of four blocks), and the rows sum to the device's own
+    count, which a reset clears."""
+    cfg, engine, _, events = drawing
+    prompts = _prompts(cfg, 33, 3)
+    engine.reset()
+    del events[:]
+    ServingLoop(engine).serve([
+        Request(rid=i, tokens=p, max_new_tokens=8 + 4 * i)
+        for i, p in enumerate(prompts)])
+    rows = _fence_rows(events)
+    assert len(rows) == 4
+    assert [r["sample_draw_launches"] for r in rows] == [0] * 4
+    assert engine.fetch_state()["counts"]["decode"] == {
+        "sample_draw_launches": 0}
+
+    engine.reset()
+    del events[:]
+    ServingLoop(engine).serve([
+        Request(rid="g0", tokens=prompts[0], max_new_tokens=16),
+        Request(rid="s", tokens=prompts[1], max_new_tokens=8,
+                temperature=0.8, top_k=16),
+        Request(rid="g1", tokens=prompts[2], max_new_tokens=12)])
+    rows = _fence_rows(events)
+    assert [r["iterations"] for r in rows] == [4] * 4
+    assert [r["sample_draw_launches"] for r in rows] == [4, 4, 0, 0]
+    assert engine.fetch_state()["counts"]["decode"][
+        "sample_draw_launches"] == 8
+    engine.reset()
+    assert engine.fetch_state()["counts"]["decode"][
+        "sample_draw_launches"] == 0
+
+
+# ----------------------------------------------------------------------
 # int8 weight-only quantization
 # ----------------------------------------------------------------------
 def test_int8_weight_quant_within_pinned_tolerance(setup):
