@@ -58,7 +58,8 @@ docs/inference.md has it at length):
     `scan_layers`.
 
 Everything else (slot state, scheduler, sampling, bookkeeping, the
-fence) is shared.
+fence and the spans of the host's phases, `SERVE_PHASES` in
+monitor/trace.py) is shared.
 """
 
 import dataclasses
@@ -78,7 +79,6 @@ from deepspeed_tpu.inference.kv_cache import (PagedKVCache,
 from deepspeed_tpu.monitor import DeepSpeedMonitorConfig, Monitor
 from deepspeed_tpu.monitor import memory as memory_mod
 from deepspeed_tpu.monitor import programs
-from deepspeed_tpu.monitor.trace import profiler_span
 from deepspeed_tpu.ops.retention import (retention_chunked, retention_decode,
                                          retention_prefill)
 from deepspeed_tpu.ops.ssm import (causal_conv, split_xbc, ssd_chunked,
@@ -1111,30 +1111,31 @@ class InferenceEngine:
                       top_k, eos):
         """Flip a fully-prefilled slot live for the decode batch. The
         updates are eager dispatches behind the slot's last prefill
-        chunk; the two profiler spans show whether the host waits at
+        chunk; the two inner spans show whether the host waits at
         the first of them or pays for each."""
-        st = self._state
-        with profiler_span("serve/activate.first_update"):
-            st["cur_token"] = st["cur_token"].at[slot].set(
-                int(cur_token))
-        with profiler_span("serve/activate.other_updates"):
-            st["pos"] = st["pos"].at[slot].set(int(pos))
-            st["active"] = st["active"].at[slot].set(True)
-            st["finished_eos"] = st["finished_eos"].at[slot].set(False)
-            st["n_gen"] = st["n_gen"].at[slot].set(0)
-            st["max_new"] = st["max_new"].at[slot].set(int(max_new))
-            st["temperature"] = st["temperature"].at[slot].set(
-                float(temperature))
-            st["top_k"] = st["top_k"].at[slot].set(int(top_k))
-            st["eos"] = st["eos"].at[slot].set(
-                -1 if eos is None else int(eos))
-            if self.speculative_enabled:
-                # new request, fresh speculation posture: optimistic
-                # k, clean acceptance EMA
-                sp = self._spec_state
-                sp["k_slot"] = sp["k_slot"].at[slot].set(
-                    self.config.spec_k)
-                sp["acc_ema"] = sp["acc_ema"].at[slot].set(1.0)
+        st, trace = self._state, self.monitor.trace
+        with trace.span("serve/activate", slot=int(slot)):
+            with trace.span("serve/activate.first_update"):
+                st["cur_token"] = st["cur_token"].at[slot].set(
+                    int(cur_token))
+            with trace.span("serve/activate.other_updates"):
+                st["pos"] = st["pos"].at[slot].set(int(pos))
+                st["active"] = st["active"].at[slot].set(True)
+                st["finished_eos"] = st["finished_eos"].at[slot].set(False)
+                st["n_gen"] = st["n_gen"].at[slot].set(0)
+                st["max_new"] = st["max_new"].at[slot].set(int(max_new))
+                st["temperature"] = st["temperature"].at[slot].set(
+                    float(temperature))
+                st["top_k"] = st["top_k"].at[slot].set(int(top_k))
+                st["eos"] = st["eos"].at[slot].set(
+                    -1 if eos is None else int(eos))
+                if self.speculative_enabled:
+                    # new request, fresh speculation posture: optimistic
+                    # k, clean acceptance EMA
+                    sp = self._spec_state
+                    sp["k_slot"] = sp["k_slot"].at[slot].set(
+                        self.config.spec_k)
+                    sp["acc_ema"] = sp["acc_ema"].at[slot].set(1.0)
 
     def start_request(self, slot, prompt, max_new, temperature=0.0,
                       top_k=0, eos=None):
@@ -1265,7 +1266,7 @@ class InferenceEngine:
         programs counted (the decode launches that drew a sample, the
         model's own counters) — or, when speculation is on, the round
         counters, still inside the SAME fused get)."""
-        st = self._state
+        st, trace = self._state, self.monitor.trace
         self._decodes_since_fence = 0
         targets = (st["active"], st["finished_eos"], st["pos"],
                    st["n_gen"], st["out_tokens"])
@@ -1274,27 +1275,31 @@ class InferenceEngine:
             targets += (st["sample_draws"],)
             if counted:
                 targets += (st["model_counts"],)
-            with profiler_span("serve/fence.device_get"):
+            with trace.span("serve/fence.device_get"):
                 active, eos, pos, n_gen, out, draws, *model = \
                     jax.device_get(targets)
-            # what the programs counted since the engine's reset: the
-            # decode launches that drew, and what the model's block
-            # counts, the decode program's launches and prefill's
-            counts = {"decode": {"sample_draw_launches": int(draws)},
-                      "prefill": {}}
-            if counted:
-                for program, row in zip(("decode", "prefill"), model[0]):
-                    counts[program].update(zip(counted, row.tolist()))
-            return {"active": active, "finished_eos": eos, "pos": pos,
-                    "n_gen": n_gen, "out_tokens": out, "counts": counts}
+            with trace.span("serve/fence.bookkeeping"):
+                # what the programs counted since the engine's reset:
+                # the decode launches that drew, and what the model's
+                # block counts, the decode program's launches and
+                # prefill's
+                counts = {"decode": {"sample_draw_launches": int(draws)},
+                          "prefill": {}}
+                if counted:
+                    for program, row in zip(("decode", "prefill"),
+                                            model[0]):
+                        counts[program].update(zip(counted, row.tolist()))
+                return {"active": active, "finished_eos": eos, "pos": pos,
+                        "n_gen": n_gen, "out_tokens": out,
+                        "counts": counts}
         sp = self._spec_state
-        with profiler_span("serve/fence.device_get"):
+        with trace.span("serve/fence.device_get"):
             (active, eos, pos, n_gen, out, k_slot, drafted, accepted,
              verified, rollbacks, rounds) = jax.device_get(
                 targets + (sp["k_slot"], sp["drafted_total"],
                            sp["accepted_total"], sp["verified_total"],
                            sp["rollbacks"], sp["rounds"]))
-        with profiler_span("serve/fence.bookkeeping"):
+        with trace.span("serve/fence.bookkeeping"):
             if self.config.spec_adaptive:
                 live = k_slot[active] if active.any() else None
                 self._spec_next_draft = int(live.max()) \

@@ -23,6 +23,13 @@ Requests a slot never waits on each other: a request admitted at
 iteration k starts decoding at iteration k+ceil(prompt/chunk) while
 earlier requests keep decoding — that interleaving is the throughput
 win the serving bench leg measures against request-at-a-time serving.
+
+The loop times its own iteration: every host phase of `step` is one
+span of `monitor/trace.py::SERVE_PHASES` on the engine's `StepTrace`
+(the program's clock and the profiler's), stamped with the loop's id
+and the iteration's number; the `decode_batch` fence row says where
+the host's milliseconds since the last fence went (`host_ms`,
+`host_iter_ms`, `host_longest`).
 """
 
 import dataclasses
@@ -31,6 +38,8 @@ from collections import deque
 from typing import Any, Optional
 
 import numpy as np
+
+from deepspeed_tpu.monitor.trace import SERVE_PREFIX, new_loop_id
 
 
 @dataclasses.dataclass
@@ -67,6 +76,13 @@ class ServingLoop:
         self.token_latencies = []   # seconds per generated token
         self._t0 = None
         self._last_fence_t = None
+        # what the spans of this loop's iterations share, the number
+        # of the iteration under way, and whether the `idle` stretch
+        # (ONE span from the first poll that finds nothing to do to
+        # the next that does) is open
+        self._id = new_loop_id()
+        self._iteration = 0
+        self._idle = False
         self._last_n_gen = np.zeros(
             (engine.config.max_slots,), np.int64)
         # host mirror of each live slot's position as of the last
@@ -158,8 +174,10 @@ class ServingLoop:
             except Exception as exc:
                 # serving forensics: the crash guard the training loop
                 # has had since PR 7 — the flight dump (with the live
-                # request table in its sticky context) survives the
-                # process; the exception still propagates
+                # request table in its sticky context, and the spans of
+                # the iteration that failed) survives the process; the
+                # exception still propagates
+                self._infer.monitor.trace.end_iteration(None)
                 self._infer.monitor.on_crash(exc)
                 raise
             if not progressed:
@@ -171,30 +189,50 @@ class ServingLoop:
         block -> fence). Returns False when there was nothing to do
         but wait for arrivals."""
         now = self._now()
-        self._admit(now)
+        trace = self._infer.monitor.trace
+        if not (self.live or self.prefilling or
+                any(r.arrival_time <= now for r in self.queue)):
+            if not self._idle:
+                self._idle = True
+                trace.start("serve/idle", loop=self._id)
+            return False
+        self._iteration += 1
+        trace.begin_iteration(self._id, self._iteration)
+        if self._idle:
+            self._idle = False
+            trace.stop("serve/idle")
+        with trace.span("serve/admit"):
+            self._admit(now)
         self._prefill_turn()
         if not self.live and not self.prefilling:
+            # a request is due and the cache cannot cover it yet
+            trace.end_iteration(None)
             return False
         if self.live:
-            # a speculative round can commit up to (draft steps + 1)
-            # tokens per slot, so the per-block capacity window widens
-            # from sync_every iterations to sync_every rounds of that
-            # worst case (reservation-backed either way)
-            per_iter = (self._infer.spec_next_draft() + 1) \
-                if self._spec else 1
-            iters = self._infer.config.sync_every * per_iter
-            for slot, req in self.live.items():
-                self._infer.ensure_decode_capacity(
-                    slot, int(self._last_pos[slot]), iters)
-            self._infer.push_tables()
-            self._decode_t0 = time.perf_counter()
-            if self._spec:
-                self._infer.spec_block(self._infer.config.sync_every)
-            else:
-                self._infer.decode_block(self._infer.config.sync_every)
+            with trace.span("serve/decode.pages"):
+                # a speculative round can commit up to (draft steps +
+                # 1) tokens per slot, so the per-block capacity window
+                # widens from sync_every iterations to sync_every
+                # rounds of that worst case (reservation-backed either
+                # way)
+                per_iter = (self._infer.spec_next_draft() + 1) \
+                    if self._spec else 1
+                iters = self._infer.config.sync_every * per_iter
+                for slot, req in self.live.items():
+                    self._infer.ensure_decode_capacity(
+                        slot, int(self._last_pos[slot]), iters)
+                self._infer.push_tables()
+            with trace.span("serve/decode.dispatch"):
+                self._decode_t0 = time.perf_counter()
+                if self._spec:
+                    self._infer.spec_block(self._infer.config.sync_every)
+                else:
+                    self._infer.decode_block(
+                        self._infer.config.sync_every)
         else:
             self._decode_t0 = None
         self._fence(self._infer.config.sync_every if self.live else 0)
+        trace.end_iteration(self._last_fence_t)
         return True
 
     # -- phases ---------------------------------------------------------
@@ -253,6 +291,7 @@ class ServingLoop:
         with the decode batch."""
         chunk = self._infer.config.prefill_chunk
         trk = self._infer.tracker
+        trace = self._infer.monitor.trace
         for slot in list(self.prefilling):
             req, start = self.prefilling[slot]
             t = len(req.tokens)
@@ -262,20 +301,25 @@ class ServingLoop:
                 # prefill reads its table ROW from the host copy; the
                 # device table upload happens once per iteration in
                 # step() (push_tables dedupes by version anyway)
-                self._infer.cache.ensure(slot, end, queries_from=start)
-                t0 = time.perf_counter()
-                self._infer.prefill_chunk(slot, req.tokens[start:end],
-                                          start)
-                if trk is not None:
-                    trk.on_prefill_chunk(
-                        slot, t0, time.perf_counter() - t0, start, end)
-                self._prefill_launches += 1
-                self._prefill_tokens += end - start
-                self.prefilling[slot][1] = end
+                with trace.span("serve/prefill.pages", slot=int(slot)):
+                    self._infer.cache.ensure(slot, end, queries_from=start)
+                with trace.span("serve/prefill.dispatch", slot=int(slot),
+                                start=int(start), end=int(end)):
+                    t0 = time.perf_counter()
+                    self._infer.prefill_chunk(
+                        slot, req.tokens[start:end], start)
+                    if trk is not None:
+                        trk.on_prefill_chunk(
+                            slot, t0, time.perf_counter() - t0, start,
+                            end)
+                    self._prefill_launches += 1
+                    self._prefill_tokens += end - start
+                    self.prefilling[slot][1] = end
                 start = end
             if start >= n_prefill:
                 # decode writes the last prompt token's KV at t-1
-                self._infer.cache.ensure(slot, max(t - 1, 1))
+                with trace.span("serve/prefill.pages", slot=int(slot)):
+                    self._infer.cache.ensure(slot, max(t - 1, 1))
                 self._infer.activate_slot(
                     slot, req.tokens[-1], t - 1, req.max_new_tokens,
                     req.temperature, req.top_k, req.eos_token_id)
@@ -292,6 +336,12 @@ class ServingLoop:
         the tracker hooks are host dict/timestamp arithmetic; the
         sync-guard tests run with the tracker ENABLED)."""
         snap = self._infer.fetch_state()
+        with self._infer.monitor.trace.span("serve/fence.bookkeeping"):
+            self._account(snap, iterations)
+
+    def _account(self, snap, iterations):
+        """What the fence does with what it read: the slots' progress,
+        evictions, the `decode_batch` row, the tracker's hooks."""
         now = self._now()
         window_s = max(now - self._last_fence_t, 1e-9)
         trk = self._infer.tracker
@@ -356,7 +406,7 @@ class ServingLoop:
             # pages in use and free, or for a model of recurrent state
             # the slots holding state and its bytes
             **self._infer.cache.occupancy(), **engaged,
-            **self._counted(snap.get("counts")))
+            **self._counted(snap.get("counts")), **self._host_phases())
         self._prefill_launches = self._prefill_tokens = 0
         if trk is not None:
             # SLO metrics AFTER evictions: this fence's finishes are in
@@ -366,6 +416,26 @@ class ServingLoop:
                                  len(self.prefilling), engaged)
         if mon.memory_enabled:
             mon._emit_memory_event(self._infer._host_steps)
+
+    def _host_phases(self):
+        """Where the host's time since the last fence went, by phase
+        (self times; this fence's own bookkeeping, still open, lands
+        on the next row): `host_ms` sums to the row's `window_ms`,
+        `host_iter_ms` is the host's own part of it (all but the wait
+        inside the `device_get` and for arrivals), `host_longest` the
+        longest single span, which names the phase a stalled loop
+        stood in."""
+        spans = {name[len(SERVE_PREFIX):]: row for name, row in
+                 self._infer.monitor.trace.drain().items()
+                 if name.startswith(SERVE_PREFIX)}
+        longest = max(spans, key=lambda p: spans[p]["max_ms"], default=None)
+        return {
+            "host_ms": {p: row["ms"] for p, row in spans.items()},
+            "host_iter_ms": round(sum(
+                row["ms"] for p, row in spans.items()
+                if p not in ("fence.device_get", "idle")), 3),
+            "host_longest": None if longest is None else
+            [longest, spans[longest]["max_ms"]]}
 
     def _counted(self, counts):
         """What the programs counted over this fence's launches

@@ -60,7 +60,8 @@ from deepspeed_tpu.monitor.sinks import (SCHEMA_VERSION, base_event,
                                          build_sinks)
 from deepspeed_tpu.monitor.trace import (SPAN_BACKWARD, SPAN_CKPT,
                                          SPAN_FORWARD, SPAN_PREFETCH,
-                                         SPAN_STEP, StepTrace)
+                                         SPAN_STEP, StepTrace,
+                                         recent_spans)
 from deepspeed_tpu.monitor.trace_export import (CAT_SUBSYSTEM,
                                                 TraceExporter)
 from deepspeed_tpu.monitor.watchdog import StallWatchdog
@@ -75,6 +76,9 @@ __all__ = [
 ]
 
 _MONITOR_OUTPUT_DEFAULT = "ds_monitor"
+# serving spans of the process-wide ring that a crash dump keeps: the
+# last five or six iterations of the loop
+_FLIGHT_SERVE_SPANS = 64
 
 
 class Monitor:
@@ -152,8 +156,9 @@ class Monitor:
                 rank=rank, max_events=config.trace_max_events,
                 meta={"job_name": config.job_name})
             self.trace.set_export_sink(
-                lambda name, t0, dur: self.trace_export.complete(
-                    f"host/{name}", name, t0, dur))
+                lambda name, t0, dur, args=None:
+                self.trace_export.complete(
+                    f"host/{name}", name, t0, dur, args=args))
         if config.flight_enabled:
             self.flight = FlightRecorder(
                 out_dir=config.flight_path or out_dir,
@@ -625,6 +630,12 @@ class Monitor:
                 extra["serving"] = serving.snapshot()
             except Exception:  # ds-lint: allow[BROADEXC] crash forensics must not mask the original exception mid-propagation
                 serving = None
+        spans = recent_spans()[-_FLIGHT_SERVE_SPANS:]
+        if spans:
+            # the host phases of the last serving iterations (loop id,
+            # iteration, phase, t0, duration s, the loop's clock at
+            # the fence): which one the loop was in, and for how long
+            extra["serve_spans"] = spans
         if self.memory_enabled and memory_mod.classify_oom(exc):
             reason = "oom"
             try:

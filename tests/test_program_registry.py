@@ -237,7 +237,7 @@ def test_fence_and_activate_put_inner_spans_on_the_profilers_clock(
     seen = []
 
     class Recording:
-        def __init__(self, name):
+        def __init__(self, name, **args):
             self.name = name
 
         def __enter__(self):
@@ -250,9 +250,16 @@ def test_fence_and_activate_put_inner_spans_on_the_profilers_clock(
     engine.start_request(1, np.arange(6, dtype=np.int32), max_new=3)
     engine.decode_block(1)
     engine.fetch_state()
-    assert seen == ["ds_tpu/serve/activate.first_update",
-                    "ds_tpu/serve/activate.other_updates",
-                    "ds_tpu/serve/fence.device_get"]
+    # among the phases round them (ISSUE 37: `activate` holds the
+    # first two, `fence.bookkeeping` follows the third)
+    assert set(seen) <= {"ds_tpu/serve/" + p
+                         for p in trace_mod.SERVE_PHASES}
+    inner = ("activate.first_update", "activate.other_updates",
+             "fence.device_get")
+    assert [n for n in seen if n.endswith(inner)] == [
+        "ds_tpu/serve/activate.first_update",
+        "ds_tpu/serve/activate.other_updates",
+        "ds_tpu/serve/fence.device_get"]
     # with no profiler API at all the calls still work
     monkeypatch.setattr(trace_mod, "_TRACE_ANNOTATION", False)
     engine.fetch_state()
