@@ -13,7 +13,13 @@ construction — no trace-on-first-request latency spike):
     sampled token, the EOS/max-tokens finish flags, and the output
     ring all stay on device, so the host dispatches `sync_every`
     decode iterations back-to-back and reads NOTHING until the serving
-    fence (the PR-2 async-dispatch convention applied to serving).
+    fence (the PR-2 async-dispatch convention applied to serving). It
+    gives what the fence reads out a second time, in buffers that its
+    next launch does not donate (`FENCE_KEYS`): the fence reads a
+    block's snapshot while the next block runs (`fetch_state`).
+
+(Beside them one small program of no layer and no weight: the nine
+fields of a slot that `activate_slot` writes, in one dispatch.)
 
 The model supplies the block, the engine supplies the cache (the seam;
 docs/inference.md has it at length):
@@ -62,6 +68,7 @@ fence and the spans of the host's phases, `SERVE_PHASES` in
 monitor/trace.py) is shared.
 """
 
+import collections
 import dataclasses
 import functools
 import time
@@ -128,6 +135,12 @@ def compile_fresh(lowered):
     finally:
         jax.config.update("jax_enable_compilation_cache", True)
         reset_cache()
+
+
+def _uploaded(host_array):
+    """A device array of what `host_array` holds NOW: of a copy that
+    nobody writes again."""
+    return jnp.asarray(np.array(host_array))
 
 
 def compile_registered(fn, args, donate_argnums):
@@ -201,6 +214,18 @@ def paged_attention(q, kc, vc, q_pos, kv_limit, first=None, k_pos=None):
 # one row against pages is a page walk bound by latency and bytes, a
 # chunk of 128 rows against one slot is a matrix-unit problem.
 DECODE_ROWS_MAX = 8
+
+# what the serving fence reads of the decode state. The decode program
+# gives them out a second time, in buffers of their own beside the
+# state that its next launch donates: a block's snapshot
+FENCE_KEYS = ("active", "finished_eos", "pos", "n_gen", "out_tokens",
+              "sample_draws", "model_counts")
+# the blocks that may be dispatched and unfetched at once: the one the
+# device works on while the host reads the one before it
+BLOCKS_KEPT = 2
+# what `activate_slot` writes of a slot, in one dispatch
+SLOT_KEYS = ("cur_token", "pos", "active", "finished_eos", "n_gen",
+             "max_new", "temperature", "top_k", "eos")
 
 
 def quantize_param_tree(params, block, modules):
@@ -295,8 +320,11 @@ class PagedKind:
     @staticmethod
     def tables(cache):
         """The manager's page tables as the engine's state holds
-        them (uploaded again after every fence that changed them)."""
-        return {"tables": jnp.asarray(cache.tables)}
+        them (uploaded again after every fence that changed them). A
+        copy: the manager writes its tables in place while a block
+        that holds the last upload is in flight, and on the CPU
+        `jnp.asarray` may hand the program numpy's own memory."""
+        return {"tables": _uploaded(cache.tables)}
 
     def mixer(self, tables, positions, valid, kv_limit):
         """The one mixer of all five paged programs, for rows at
@@ -582,8 +610,10 @@ class PagedWindowKind:
                       page_size=cfg.kv_page_size, max_slots=cfg.max_slots,
                       dtype=np.dtype(mc.dtype), ledger=ledger,
                       n_kv_head=self.n_kv_head)
-        # the positions a slot's queries cover between two fences
-        span = max(cfg.prefill_chunk, cfg.sync_every)
+        # the positions a slot's queries cover beyond the one the host
+        # last knew: a prefill chunk, or the block in flight and the
+        # one dispatched behind it (`ServingLoop.step`)
+        span = max(cfg.prefill_chunk, 2 * cfg.sync_every)
         ring = ring_columns(self.window, cfg.kv_page_size, span)
         # every slot's ring, beside the scratch page: admission never
         # waits for a window page
@@ -608,8 +638,8 @@ class PagedWindowKind:
     @staticmethod
     def tables(cache):
         full, window = cache.tables
-        return {"tables": jnp.asarray(full),
-                "window_tables": jnp.asarray(window)}
+        return {"tables": _uploaded(full),
+                "window_tables": _uploaded(window)}
 
     def mixer(self, tables, ring_tables, positions, valid, kv_limit):
         """As `PagedKind.mixer`, over both pools: rows at `positions`
@@ -893,8 +923,9 @@ class InferenceEngine:
         self._state = self._fresh_state()
         self._decode = self._build_decode_step()
         self._prefill = self._build_prefill_step()
+        self._activate = self._build_activate_step()
         self._last_logits = self._last_read = None
-        self._decodes_since_fence = 0
+        self._forget_fences()
 
         # speculative decoding (ISSUE 18, inference/speculative.py):
         # gated on the config default-off, so the disabled engine's
@@ -980,7 +1011,7 @@ class InferenceEngine:
         # model's state is a third of the chip
         self._state = None
         self._state = self._fresh_state()
-        self._decodes_since_fence = 0
+        self._forget_fences()
         if self.speculative_enabled:
             from deepspeed_tpu.inference import speculative as spec_mod
             self._spec_state = spec_mod.fresh_spec_state(self)
@@ -1035,7 +1066,11 @@ class InferenceEngine:
                     sample_draws=state["sample_draws"] +
                     drew.astype(jnp.int32),
                 )
-            return new_state, logits, (read[0] if read else {})
+            # what the fence reads, in buffers of their own: the next
+            # launch donates the state's
+            snapshot = {k: new_state[k] for k in FENCE_KEYS
+                        if k in new_state}
+            return new_state, logits, (read[0] if read else {}), snapshot
 
         return compile_registered(decode_fn, (self._params, self._state),
                                   donate_argnums=(1,))
@@ -1060,9 +1095,28 @@ class InferenceEngine:
                 jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
         return compile_registered(prefill_fn, args, donate_argnums=(1,))
 
+    def _build_activate_step(self):
+        """The slot's fields of the state, written in ONE dispatch. As
+        nine eager `.at[].set` they cost the host 1.7 to 2 ms each on
+        the chip, and the seventh waited until the device's queue had
+        drained (PERF.md section 6, PR 38): with a block in flight
+        that stalled the loop at every activation."""
+        def activate_fn(fields, slot, ints, temperature):
+            cur_token, pos, max_new, top_k, eos = ints
+            new = {"cur_token": cur_token, "pos": pos, "active": True,
+                   "finished_eos": False, "n_gen": 0, "max_new": max_new,
+                   "temperature": temperature, "top_k": top_k, "eos": eos}
+            return {k: fields[k].at[slot].set(v) for k, v in new.items()}
+
+        args = ({k: self._state[k] for k in SLOT_KEYS}, np.int32(0),
+                np.zeros((5,), np.int32), np.float32(0))
+        return jax.jit(activate_fn).lower(*args).compile()
+
     def _where(self, slot):
-        """The cache manager's `slot_operand` as device operands."""
-        return jax.tree_util.tree_map(jnp.asarray,
+        """The cache manager's `slot_operand` as device operands
+        (copies, as the kinds' `tables`: a row of a table is written
+        again while the chunk that was handed it is in flight)."""
+        return jax.tree_util.tree_map(_uploaded,
                                       self.cache.slot_operand(slot))
 
     def cache_arrays(self):
@@ -1102,33 +1156,31 @@ class InferenceEngine:
             sp = self._spec_state
             dk, dv = self._draft_prefill(
                 self._draft_params, sp["dk_pool"], sp["dv_pool"],
-                jnp.asarray(self.cache.tables[slot]), jnp.asarray(buf),
+                _uploaded(self.cache.tables[slot]), jnp.asarray(buf),
                 jnp.asarray(start, jnp.int32), jnp.asarray(n, jnp.int32))
             sp["dk_pool"], sp["dv_pool"] = dk, dv
         self._host_steps += 1
+        self._prefilled = (self._prefilled[0] + 1, self._prefilled[1] + n)
 
     def activate_slot(self, slot, cur_token, pos, max_new, temperature,
                       top_k, eos):
-        """Flip a fully-prefilled slot live for the decode batch. The
-        updates are eager dispatches behind the slot's last prefill
-        chunk; the two inner spans show whether the host waits at
-        the first of them or pays for each."""
+        """Flip a fully-prefilled slot live for the decode batch: one
+        dispatch of the slot's fields behind the slot's last prefill
+        chunk (`activate.first_update`: where a wait for the device's
+        queue would show), and a speculative engine's two eager
+        updates after it (`activate.other_updates`)."""
         st, trace = self._state, self.monitor.trace
+        # a snapshot taken before this shows the slot as its last
+        # request left it: `fetch_state` lays this over it
+        self._activated[int(slot)] = (self._launches, int(pos))
         with trace.span("serve/activate", slot=int(slot)):
             with trace.span("serve/activate.first_update"):
-                st["cur_token"] = st["cur_token"].at[slot].set(
-                    int(cur_token))
+                st.update(self._activate(
+                    {k: st[k] for k in SLOT_KEYS}, np.int32(slot),
+                    np.asarray([cur_token, pos, max_new, top_k,
+                                -1 if eos is None else eos], np.int32),
+                    np.float32(temperature)))
             with trace.span("serve/activate.other_updates"):
-                st["pos"] = st["pos"].at[slot].set(int(pos))
-                st["active"] = st["active"].at[slot].set(True)
-                st["finished_eos"] = st["finished_eos"].at[slot].set(False)
-                st["n_gen"] = st["n_gen"].at[slot].set(0)
-                st["max_new"] = st["max_new"].at[slot].set(int(max_new))
-                st["temperature"] = st["temperature"].at[slot].set(
-                    float(temperature))
-                st["top_k"] = st["top_k"].at[slot].set(int(top_k))
-                st["eos"] = st["eos"].at[slot].set(
-                    -1 if eos is None else int(eos))
                 if self.speculative_enabled:
                     # new request, fresh speculation posture: optimistic
                     # k, clean acceptance EMA
@@ -1178,15 +1230,22 @@ class InferenceEngine:
     def ensure_decode_capacity(self, slot, known_pos, iters):
         """Assign pages covering `iters` more positions for a live
         slot before a decode block (reservation-backed: cannot fail).
-        `known_pos` is the slot's position at the last fence; the
-        decode launches dispatched since (a caller's `decode_once`
-        between two blocks of the loop, which keeps positions by the
-        fence) have moved it on and are counted in: a row past the
-        pages asked for would be written to the scratch page."""
+        `known_pos` is the slot's position as the caller last read it.
+        What counts is what `fetch_state` last handed out (a caller
+        that is not the loop may have been handed a newer snapshot
+        than the loop's) and the decode launches dispatched since that
+        snapshot WAS TAKEN: the block in flight behind the one that
+        was fenced, a caller's `decode_once` between two steps of the
+        loop. They have moved the slot on and are counted in: a row
+        past the pages asked for would be written to the scratch
+        page."""
         worst = self.cache.reserved_tokens(slot)
-        ahead = self._decodes_since_fence + iters
-        self.cache.ensure(slot, min(known_pos + ahead, worst),
-                          queries_from=known_pos)
+        at, pos = self._activated.get(
+            int(slot), (self._fenced_at, int(self._fenced_pos[slot])))
+        known = max(int(known_pos), pos)
+        ahead = self._launches - at + iters
+        self.cache.ensure(slot, min(known + ahead, worst),
+                          queries_from=known)
 
     # ------------------------------------------------------------------
     # the hot dispatch loop + the serving fence
@@ -1196,22 +1255,23 @@ class InferenceEngine:
         no device_get, nothing read until `fetch_state` (the dynamic
         guard test and ds_lint's HOTSYNC rule both pin this)."""
         st = self._state
-        logits, read = self._last_logits, self._last_read
+        logits, read, snapshot = self._last_logits, self._last_read, None
         for _ in range(n):
-            st, logits, read = self._decode(self._params, st)
+            st, logits, read, snapshot = self._decode(self._params, st)
         self._state = st
         self._last_logits, self._last_read = logits, read
         self._host_steps += n
-        self._decodes_since_fence += n
+        self._launches += n
+        self._block_dispatched(snapshot)
 
     def decode_once(self):
         """One decode iteration, returning the pre-sampling logits
         [max_slots, vocab] (parity tests read these)."""
-        st, logits, read = self._decode(self._params, self._state)
+        st, logits, read, _ = self._decode(self._params, self._state)
         self._state = st
         self._last_logits, self._last_read = logits, read
         self._host_steps += 1
-        self._decodes_since_fence += 1
+        self._launches += 1
         return logits
 
     def last_row_readings(self):
@@ -1243,6 +1303,7 @@ class InferenceEngine:
             self._spec_verify_dispatch_s += time.perf_counter() - t1
         self._state, self._spec_state = st, sp
         self._host_steps += rounds * (nd + 1)
+        self._block_dispatched(None)
 
     def spec_next_draft(self):
         """Draft steps the next spec_block will dispatch per round
@@ -1259,55 +1320,127 @@ class InferenceEngine:
         self._spec_verify_dispatch_s = 0.0
         return split
 
+    def _forget_fences(self):
+        """Nothing dispatched, nothing unfetched, no slot known."""
+        self._launches = 0       # decode launches dispatched
+        self._prefilled = (0, 0)  # prefill launches, their prompt tokens
+        # the blocks dispatched and unfetched, oldest first: (the
+        # block's snapshot, `_launches` and `_prefilled` when it was
+        # taken); the oldest goes when a caller that never fetches
+        # dispatches a third
+        self._pending = collections.deque(maxlen=BLOCKS_KEPT)
+        # what the host knows, for `ensure_decode_capacity`: the
+        # positions in the snapshot that was last handed out and the
+        # `_launches` at which it was taken, and the slots activated
+        # since ({slot: (`_launches` then, its position)})
+        self._fenced_pos = np.zeros((self.config.max_slots,), np.int64)
+        self._fenced_at = 0
+        self._activated = {}
+
+    def _block_dispatched(self, snapshot):
+        """A block's launches are in the device's queue: its snapshot
+        (the decode program's, of the block's last launch) starts for
+        the host and waits for `fetch_state`. The speculative programs
+        leave none, and a block of theirs is read from the live state:
+        its fence trims the page tables, so the loop dispatches
+        nothing before it."""
+        if self.speculative_enabled:
+            snapshot = None
+        for leaf in (snapshot or {}).values():
+            leaf.copy_to_host_async()
+        self._pending.append((snapshot, self._launches, self._prefilled))
+
+    def blocks_in_flight(self):
+        """The blocks dispatched (`decode_block`, `spec_block`) that no
+        `fetch_state` has read yet."""
+        return len(self._pending)
+
     def fetch_state(self):
         """THE serving fence: one fused device_get of the per-slot
         progress the scheduler needs (active flags, eos flags,
         positions, generated counts, output rings, and what the
         programs counted (the decode launches that drew a sample, the
         model's own counters) — or, when speculation is on, the round
-        counters, still inside the SAME fused get)."""
+        counters, still inside the SAME fused get).
+
+        It reads the OLDEST block that is dispatched and unfetched, in
+        the snapshot that block left: whatever was dispatched behind it
+        stays in the device's queue and runs while the host reads and
+        reacts (`blocks_in_flight` in the result says how many blocks
+        that is). With nothing unfetched (no block since the last
+        fetch, or launches by `decode_once` alone) it reads the live
+        state, and waits for everything dispatched. A slot activated
+        after the snapshot was taken reads as `activate_slot` wrote it,
+        not as its last request left it."""
         st, trace = self._state, self.monitor.trace
-        self._decodes_since_fence = 0
-        targets = (st["active"], st["finished_eos"], st["pos"],
-                   st["n_gen"], st["out_tokens"])
-        if not self.speculative_enabled:
-            counted = self.serving.counters
-            targets += (st["sample_draws"],)
-            if counted:
-                targets += (st["model_counts"],)
-            with trace.span("serve/fence.device_get"):
-                active, eos, pos, n_gen, out, draws, *model = \
-                    jax.device_get(targets)
-            with trace.span("serve/fence.bookkeeping"):
+        snapshot, taken_at, prefilled = self._pending.popleft() \
+            if self._pending else (None, None, None)
+        if snapshot is None:
+            # the live state: no launch has donated it yet, and every
+            # `activate_slot` is in it
+            self._pending.clear()
+            self._activated.clear()
+            snapshot = {k: st[k] for k in FENCE_KEYS if k in st}
+            taken_at, prefilled = self._launches, self._prefilled
+        if self.speculative_enabled:
+            sp = self._spec_state
+            snapshot = dict(snapshot, speculative={
+                "k_slot": sp["k_slot"], "drafted": sp["drafted_total"],
+                "accepted": sp["accepted_total"],
+                "verified": sp["verified_total"],
+                "rollbacks": sp["rollbacks"], "rounds": sp["rounds"]})
+        with trace.span("serve/fence.device_get"):
+            got = jax.device_get(snapshot)
+        with trace.span("serve/fence.bookkeeping"):
+            snap = {k: got[k] for k in ("active", "finished_eos", "pos",
+                                        "n_gen", "out_tokens")}
+            snap["blocks_in_flight"] = len(self._pending)
+            # the prefill launches dispatched before the snapshot was
+            # taken and their prompt tokens, since the engine's reset
+            # (what the programs count of prefill is as old)
+            snap["prefilled"] = prefilled
+            self._lay_activations_over(snap, taken_at)
+            self._fenced_pos, self._fenced_at = snap["pos"], taken_at
+            if not self.speculative_enabled:
                 # what the programs counted since the engine's reset:
                 # the decode launches that drew, and what the model's
                 # block counts, the decode program's launches and
                 # prefill's
-                counts = {"decode": {"sample_draw_launches": int(draws)},
+                counts = {"decode": {"sample_draw_launches":
+                                     int(got["sample_draws"])},
                           "prefill": {}}
-                if counted:
-                    for program, row in zip(("decode", "prefill"),
-                                            model[0]):
-                        counts[program].update(zip(counted, row.tolist()))
-                return {"active": active, "finished_eos": eos, "pos": pos,
-                        "n_gen": n_gen, "out_tokens": out,
-                        "counts": counts}
-        sp = self._spec_state
-        with trace.span("serve/fence.device_get"):
-            (active, eos, pos, n_gen, out, k_slot, drafted, accepted,
-             verified, rollbacks, rounds) = jax.device_get(
-                targets + (sp["k_slot"], sp["drafted_total"],
-                           sp["accepted_total"], sp["verified_total"],
-                           sp["rollbacks"], sp["rounds"]))
-        with trace.span("serve/fence.bookkeeping"):
+                for program, row in zip(("decode", "prefill"),
+                                        got.get("model_counts", ())):
+                    counts[program].update(zip(self.serving.counters,
+                                               row.tolist()))
+                snap["counts"] = counts
+                return snap
+            spec = got["speculative"]
             if self.config.spec_adaptive:
-                live = k_slot[active] if active.any() else None
-                self._spec_next_draft = int(live.max()) \
-                    if live is not None else self.config.spec_k
-            return {"active": active, "finished_eos": eos, "pos": pos,
-                    "n_gen": n_gen, "out_tokens": out,
-                    "speculative": {"k_slot": k_slot, "drafted": drafted,
-                                    "accepted": accepted,
-                                    "verified": verified,
-                                    "rollbacks": rollbacks,
-                                    "rounds": int(rounds)}}
+                active, k_slot = snap["active"], spec["k_slot"]
+                self._spec_next_draft = int(k_slot[active].max()) \
+                    if active.any() else self.config.spec_k
+            snap["speculative"] = dict(spec, rounds=int(spec["rounds"]))
+            return snap
+
+    def _lay_activations_over(self, snap, taken_at):
+        """A slot activated after the snapshot was taken shows in it
+        what its last request left (`active` False, that request's
+        `n_gen` and `pos`): it reads as `activate_slot` wrote it. One
+        activated before is in the snapshot, and forgotten here."""
+        late = {}
+        for slot, (at, pos) in list(self._activated.items()):
+            # a snapshot is taken right behind its block's last launch:
+            # an activation at the same count of launches came after
+            if at >= taken_at:
+                late[slot] = pos
+            else:
+                del self._activated[slot]
+        if not late:
+            return
+        for key in ("active", "finished_eos", "pos", "n_gen", "out_tokens"):
+            snap[key] = snap[key].copy()
+        for slot, pos in late.items():
+            snap["active"][slot], snap["finished_eos"][slot] = True, False
+            snap["pos"][slot], snap["n_gen"][slot] = pos, 0
+            snap["out_tokens"][slot] = 0

@@ -14,10 +14,26 @@ The unit of scheduling is one **serving iteration**:
   3. decode block — `sync_every` single-token decode iterations for
      the whole slot batch, dispatched with zero host syncs;
   4. the fence — ONE `device_get` (engine.fetch_state) reads every
-     slot's progress; finished requests (EOS / max-tokens, decided
-     device-side) are evicted, their pages freed, their results and
-     latency stats recorded, and `request_finished` / `decode_batch`
-     monitor events emitted.
+     slot's progress as the block BEFORE the one just dispatched left
+     it; finished requests (EOS / max-tokens, decided device-side) are
+     evicted, their pages freed, their results and latency stats
+     recorded, and `request_finished` / `decode_batch` monitor events
+     emitted.
+
+The loop keeps the next block in flight: block k is in the device's
+queue before block k-1's snapshot is fetched, so the device works
+through the readback, the bookkeeping and the next step's admission
+and dispatch. Every step fences once: the first after idle dispatches
+two blocks and fences the first; a step with nothing live dispatches
+nothing and fences what is unfetched (or, a step that only prefilled,
+the live state); `run` ends with nothing unfetched. What the host
+knows is therefore one block old. The engine makes that safe (pages
+for the launches in flight, a reused slot read as activated and not as
+its last request left it: `InferenceEngine.ensure_decode_capacity`,
+`fetch_state`); the price is that admission reacts a block later, so
+a new request's prefill chunks queue behind the block in flight. The
+speculative loop fences the block it just dispatched: its fence trims
+the page tables and sets the next block's draft depth.
 
 Requests a slot never waits on each other: a request admitted at
 iteration k starts decoding at iteration k+ceil(prompt/chunk) while
@@ -86,15 +102,17 @@ class ServingLoop:
         self._last_n_gen = np.zeros(
             (engine.config.max_slots,), np.int64)
         # host mirror of each live slot's position as of the last
-        # fence (decode grows it by at most sync_every between fences
-        # — the per-block capacity ensure covers exactly that window)
+        # fence (the engine counts the launches dispatched since that
+        # snapshot was taken into the per-block capacity ensure)
         self._last_pos = np.zeros((engine.config.max_slots,), np.int64)
-        # host dispatch stamp of the current decode block (the serving
-        # tracker's per-fence decode window; None = no block in flight)
-        self._decode_t0 = None
-        # prefill launches since the last fence and the prompt tokens
-        # they covered (the fence rows' state_prefill_* counters)
-        self._prefill_launches = self._prefill_tokens = 0
+        # the decode blocks dispatched and not yet accounted, oldest
+        # first: (host dispatch stamp, launches), the serving tracker's
+        # per-fence decode window
+        self._dispatched = deque()
+        # the engine's prefill launches and the prompt tokens they
+        # covered as of the last fence's snapshot (cumulative like the
+        # programs' counts below: the fence rows take the difference)
+        self._last_prefilled = (0, 0)
         # what the programs count (`engine.fetch_state`'s "counts":
         # the decode launches that drew a sample, the model's block's
         # counters): cumulative on the device, so the fence diffs them
@@ -104,6 +122,11 @@ class ServingLoop:
         # cumulative per slot (never reset mid-flight), so the fence
         # diffs them against these to get per-window numbers
         self._spec = bool(getattr(engine, "speculative_enabled", False))
+        # the blocks in the device's queue behind the one a step
+        # fences: one, but none where the fence decides the next
+        # block's pages (it trims every live slot's table to what was
+        # committed and sets the draft depth)
+        self._keep_in_flight = 0 if self._spec else 1
         s = engine.config.max_slots
         self._last_drafted = np.zeros((s,), np.int64)
         self._last_accepted = np.zeros((s,), np.int64)
@@ -168,7 +191,7 @@ class ServingLoop:
         self._t0 = clock_zero if clock_zero is not None \
             else time.monotonic()
         self._last_fence_t = self._now()
-        while self.queue or self.live or self.prefilling:
+        while self.unfinished():
             try:
                 progressed = self.step()
             except Exception as exc:
@@ -184,13 +207,21 @@ class ServingLoop:
                 # idle: everything queued is in the future
                 time.sleep(0.0005)
 
+    def unfinished(self):
+        """Whether a step has anything left to do, now or later: a
+        request queued, prefilling or live, or a block dispatched and
+        unfetched."""
+        return bool(self.queue or self.live or self.prefilling or
+                    self._infer.blocks_in_flight())
+
     def step(self):
         """One serving iteration (admit -> prefill chunk -> decode
-        block -> fence). Returns False when there was nothing to do
-        but wait for arrivals."""
+        block -> fence of the block before it). Returns False when
+        there was nothing to do but wait for arrivals."""
         now = self._now()
         trace = self._infer.monitor.trace
         if not (self.live or self.prefilling or
+                self._infer.blocks_in_flight() or
                 any(r.arrival_time <= now for r in self.queue)):
             if not self._idle:
                 self._idle = True
@@ -204,36 +235,43 @@ class ServingLoop:
         with trace.span("serve/admit"):
             self._admit(now)
         self._prefill_turn()
-        if not self.live and not self.prefilling:
+        if not (self.live or self.prefilling or
+                self._infer.blocks_in_flight()):
             # a request is due and the cache cannot cover it yet
             trace.end_iteration(None)
             return False
-        if self.live:
-            with trace.span("serve/decode.pages"):
-                # a speculative round can commit up to (draft steps +
-                # 1) tokens per slot, so the per-block capacity window
-                # widens from sync_every iterations to sync_every
-                # rounds of that worst case (reservation-backed either
-                # way)
-                per_iter = (self._infer.spec_next_draft() + 1) \
-                    if self._spec else 1
-                iters = self._infer.config.sync_every * per_iter
-                for slot, req in self.live.items():
-                    self._infer.ensure_decode_capacity(
-                        slot, int(self._last_pos[slot]), iters)
-                self._infer.push_tables()
-            with trace.span("serve/decode.dispatch"):
-                self._decode_t0 = time.perf_counter()
-                if self._spec:
-                    self._infer.spec_block(self._infer.config.sync_every)
-                else:
-                    self._infer.decode_block(
-                        self._infer.config.sync_every)
-        else:
-            self._decode_t0 = None
-        self._fence(self._infer.config.sync_every if self.live else 0)
+        # one block beyond the one this step fences: one more in
+        # steady state, two where none waits to be accounted (the
+        # first step after idle)
+        while self.live and len(self._dispatched) <= self._keep_in_flight:
+            self._decode_block()
+        self._fence()
         trace.end_iteration(self._last_fence_t)
         return True
+
+    def _decode_block(self):
+        """Pages for one more block of every live slot, and its
+        dispatch behind whatever is in flight."""
+        trace = self._infer.monitor.trace
+        with trace.span("serve/decode.pages"):
+            # a speculative round can commit up to (draft steps + 1)
+            # tokens per slot, so the per-block capacity window widens
+            # from sync_every iterations to sync_every rounds of that
+            # worst case (reservation-backed either way)
+            per_iter = (self._infer.spec_next_draft() + 1) \
+                if self._spec else 1
+            iters = self._infer.config.sync_every * per_iter
+            for slot, req in self.live.items():
+                self._infer.ensure_decode_capacity(
+                    slot, int(self._last_pos[slot]), iters)
+            self._infer.push_tables()
+        with trace.span("serve/decode.dispatch"):
+            self._dispatched.append(
+                (time.perf_counter(), self._infer.config.sync_every))
+            if self._spec:
+                self._infer.spec_block(self._infer.config.sync_every)
+            else:
+                self._infer.decode_block(self._infer.config.sync_every)
 
     # -- phases ---------------------------------------------------------
     def _free_slots(self):
@@ -312,8 +350,6 @@ class ServingLoop:
                         trk.on_prefill_chunk(
                             slot, t0, time.perf_counter() - t0, start,
                             end)
-                    self._prefill_launches += 1
-                    self._prefill_tokens += end - start
                     self.prefilling[slot][1] = end
                 start = end
             if start >= n_prefill:
@@ -330,18 +366,31 @@ class ServingLoop:
                 if trk is not None:
                     trk.on_live(slot)
 
-    def _fence(self, iterations):
+    def _fence(self):
         """The serving rendezvous: one device_get via
-        engine.fetch_state, then eviction + events (host-only work —
-        the tracker hooks are host dict/timestamp arithmetic; the
-        sync-guard tests run with the tracker ENABLED)."""
+        engine.fetch_state, of the oldest block unfetched (or, with
+        none, of the live state: a step that only prefilled), then
+        eviction + events (host-only work — the tracker hooks are
+        host dict/timestamp arithmetic; the sync-guard tests run with
+        the tracker ENABLED)."""
         snap = self._infer.fetch_state()
         with self._infer.monitor.trace.span("serve/fence.bookkeeping"):
-            self._account(snap, iterations)
+            self._account(snap)
 
-    def _account(self, snap, iterations):
+    def _account(self, snap):
         """What the fence does with what it read: the slots' progress,
         evictions, the `decode_batch` row, the tracker's hooks."""
+        # the blocks this snapshot covers: all the loop dispatched but
+        # those still unfetched behind it (a caller's own `fetch_state`
+        # between two steps took a snapshot the loop never saw: the
+        # next step's fence then finds its own block the oldest
+        # unfetched, reads it with nothing in flight, and accounts
+        # both)
+        decode_t0, iterations = None, 0
+        while len(self._dispatched) > snap["blocks_in_flight"]:
+            t0, launches = self._dispatched.popleft()
+            decode_t0 = t0 if decode_t0 is None else decode_t0
+            iterations += launches
         now = self._now()
         window_s = max(now - self._last_fence_t, 1e-9)
         trk = self._infer.tracker
@@ -363,7 +412,7 @@ class ServingLoop:
             # TTFT + per-slot decode windows BEFORE evictions, so a
             # request that got its first token and finished inside the
             # same window still records both
-            trk.on_fence_progress(self._decode_t0, iterations, deltas)
+            trk.on_fence_progress(decode_t0, iterations, deltas)
         for slot, req in finished:
             self._finish(slot, req, snap, now)
         rollback_pages = 0
@@ -387,16 +436,23 @@ class ServingLoop:
         # prefill launches took through a slot's state against the
         # prompt tokens among them; host arithmetic on what the fence
         # already holds
+        prefill_launches, prefill_tokens = np.subtract(
+            snap["prefilled"], self._last_prefilled)
+        self._last_prefilled = snap["prefilled"]
         engaged = self._infer.cache.attended(
             snap["active"], snap["pos"], iterations, new_tokens,
-            self._prefill_launches * self._infer.config.prefill_chunk,
-            self._prefill_tokens)
+            prefill_launches * self._infer.config.prefill_chunk,
+            prefill_tokens)
         mon.event(
             "decode_batch",
             # the loop's clock, on which requests arrive
             loop_s=round(now, 6),
             iterations=int(iterations),
-            prefill_launches=int(self._prefill_launches),
+            # the blocks the device still had in its queue when this
+            # fence's device_get began: 1 where the loop kept the next
+            # block in flight, 0 where it read the newest state
+            blocks_in_flight=int(snap["blocks_in_flight"]),
+            prefill_launches=int(prefill_launches),
             active_slots=len(self.live),
             prefilling_slots=len(self.prefilling),
             queue_depth=len(self.queue),
@@ -407,7 +463,6 @@ class ServingLoop:
             # the slots holding state and its bytes
             **self._infer.cache.occupancy(), **engaged,
             **self._counted(snap.get("counts")), **self._host_phases())
-        self._prefill_launches = self._prefill_tokens = 0
         if trk is not None:
             # SLO metrics AFTER evictions: this fence's finishes are in
             # the histograms/counters the event reports
@@ -511,6 +566,10 @@ class ServingLoop:
             # before cache.free: the tracker's final row keeps the
             # pages the request held when it finished
             trk.on_finished(slot, req.finish_reason)
+        # the block in flight runs this slot as a no-op (the device's
+        # `active` is False already) and reads none of its pages; they
+        # go to work that is queued behind that block, and the device
+        # runs its queue in order
         self._infer.cache.free(slot)
         self.results.append(req)
         wall_s = max(now - req.admitted_at, 1e-9)
@@ -550,6 +609,6 @@ def serve_sequential(engine, requests, clock_zero=None):
         while loop._now() < req.arrival_time:
             time.sleep(0.0005)
         loop.submit(req)
-        while loop.queue or loop.live or loop.prefilling:
+        while loop.unfinished():
             loop.step()
     return loop

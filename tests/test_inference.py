@@ -289,8 +289,10 @@ def test_multi_request_decode_loop_has_zero_host_syncs(setup,
 
 
 def test_serving_loop_step_syncs_only_at_fence(setup, monkeypatch):
-    """ServingLoop.step (admit -> prefill -> decode block -> fence)
-    performs exactly one device_get per iteration — the fence."""
+    """ServingLoop.step (admit -> prefill -> decode block -> fence of
+    the block before it) performs exactly one device_get per iteration
+    (the fence), the first after idle too: it dispatches two blocks
+    and fences the first."""
     cfg, model, params, engine = setup
     engine.reset()
     loop = ServingLoop(engine)
@@ -301,16 +303,63 @@ def test_serving_loop_step_syncs_only_at_fence(setup, monkeypatch):
     import time
     loop._t0 = time.monotonic()
     loop._last_fence_t = loop._now()
-    loop.step()    # compile/admission settle
     counters = _SyncCounters(monkeypatch)
+    loop.step()    # compile/admission settle: two blocks, one fenced
+    assert counters.device_get == 1 and engine.blocks_in_flight() == 1
     n = 0
     while (loop.queue or loop.live or loop.prefilling) and n < 50:
         loop.step()
         n += 1
     assert n > 0
-    assert counters.device_get == n, (counters.device_get, n)
+    assert counters.device_get == n + 1, (counters.device_get, n)
     assert counters.effects_barrier == 0
     engine.reset()
+
+
+def _decode_batch_rows(engine):
+    """(the loop's `decode_batch` rows from here on, the sink that
+    collects them)."""
+    import types
+    rows = []
+    sink = types.SimpleNamespace(emit=lambda event: rows.append(event)
+                                 if event["kind"] == "decode_batch" else None)
+    engine.monitor.attach_sink(sink)
+    return rows, sink
+
+
+def test_a_fence_returns_with_the_next_block_in_the_queue(monkeypatch):
+    """The `device_get` of step k is entered after block k's dispatch
+    and reads block k-1: the fence rows' `blocks_in_flight` is 1 in
+    steady state, and 0 where the fence read the newest state (a step
+    that only prefilled, the first after idle here; the last block of
+    a run, fenced with nothing behind it)."""
+    cfg = tiny_gpt2_config()
+    params = _params(GPT2ForCausalLM(cfg))
+    engine = InferenceEngine(cfg, params, {"inference": {
+        "max_slots": 2, "prefill_chunk": 16, "sync_every": 4,
+        "max_new_tokens": 16,
+        "kv_cache": {"num_pages": 40, "page_size": 8}}})
+    rows, _ = _decode_batch_rows(engine)
+    order = []
+    real_block, real_get = engine.decode_block, jax.device_get
+    monkeypatch.setattr(engine, "decode_block",
+                        lambda n: (order.append("block"), real_block(n))[1])
+    monkeypatch.setattr(jax, "device_get",
+                        lambda x: (order.append("get"), real_get(x))[1])
+    r = np.random.RandomState(38)
+    loop = ServingLoop(engine)
+    # 20 prompt tokens: two chunks, so the first step only prefills
+    done = loop.serve([Request(rid="a", tokens=r.randint(
+        0, cfg.vocab_size, size=20), max_new_tokens=16)])
+    assert len(done[0].out_tokens) == 16
+    # prefill | blocks 1 and 2, fence 1 | block 3, fence 2 | block 4,
+    # fence 3 | block 5, fence 4 | fence 5 (a no-op block: the request
+    # ended with the fourth)
+    assert order == ["get", "block"] + ["block", "get"] * 4 + ["get"]
+    assert [r["blocks_in_flight"] for r in rows] == [0, 1, 1, 1, 1, 0]
+    assert [r["iterations"] for r in rows] == [0, 4, 4, 4, 4, 4]
+    assert [r["window_tokens"] for r in rows] == [0, 4, 4, 4, 4, 0]
+    assert engine.blocks_in_flight() == 0
 
 
 # ----------------------------------------------------------------------
@@ -391,9 +440,164 @@ def test_a_launch_between_two_blocks_of_the_loop_loses_no_row(setup,
             engine.push_tables()
             engine.decode_once()
             launches += 1
-    assert launches >= 6
+    assert launches >= 4
     assert {q.rid: q.out_tokens.tolist() for q in loop.results} == want
     assert engine.cache.free_pages() == engine.cache.num_pages - 1
+
+
+def test_a_reused_slot_is_not_read_as_its_last_request_left_it(setup,
+                                                               monkeypatch):
+    """One slot's worth of work at a time: request `b` is activated
+    into the slot that `a` left one block earlier, before the snapshot
+    that still shows `a`'s end (`n_gen` 8, not active) is fetched. The
+    engine lays the activation over it: the loop does not finish `b`
+    on `a`'s count, and a reader of `snap["n_gen"][slot]` over
+    `loop.live` at `fetch_state`'s return (the benchmark's recorder)
+    never sees `a`'s count under `b`'s name."""
+    cfg, model, params, _ = setup
+    engine = InferenceEngine(cfg, params, {"inference": {
+        "max_slots": 1, "prefill_chunk": 16, "sync_every": 4,
+        "max_new_tokens": 16,
+        "kv_cache": {"num_pages": 40, "page_size": 8}}})
+    r = np.random.RandomState(12)
+    tokens = {rid: r.randint(0, cfg.vocab_size, size=n).astype(np.int32)
+              for rid, n in (("a", 6), ("b", 11))}
+    new = {"a": 8, "b": 12}
+    make = lambda rid: Request(rid=rid, tokens=tokens[rid].copy(),
+                               max_new_tokens=new[rid])
+    want = {rid: ServingLoop(engine).serve([make(rid)])[0]
+            .out_tokens.tolist() for rid in ("a", "b")}
+    engine.reset()
+    loop = ServingLoop(engine)
+    seen, stale = {"a": [], "b": []}, []
+    real_fetch, real_lay = engine.fetch_state, engine._lay_activations_over
+
+    def lay(snap, taken_at):
+        before = int(snap["n_gen"][0]), bool(snap["active"][0])
+        real_lay(snap, taken_at)
+        if (int(snap["n_gen"][0]), bool(snap["active"][0])) != before:
+            stale.append(before)
+
+    def fetch():
+        snap = real_fetch()
+        for slot, req in loop.live.items():
+            seen[req.rid].append(int(snap["n_gen"][slot]))
+        return snap
+
+    monkeypatch.setattr(engine, "_lay_activations_over", lay)
+    monkeypatch.setattr(engine, "fetch_state", fetch)
+    done = {q.rid: q for q in loop.serve([make("a"), make("b")])}
+    # the snapshot did show the slot as `a` left it, and was laid over
+    assert stale == [(8, False)]
+    assert seen["a"] == [4, 8]
+    assert seen["b"] == [0, 4, 8, 12]
+    assert {rid: q.out_tokens.tolist() for rid, q in done.items()} == want
+    assert done["b"].finish_reason == "max_tokens"
+    assert done["b"].first_token_at > done["a"].finished_at
+
+
+def test_a_callers_fetch_between_two_steps_gets_the_newest_state(
+        setup, monkeypatch):
+    """What the benchmark does when its window closes, between two
+    steps of the loop: `fetch_state()`, a page for one more row,
+    `decode_once()`. The one block unfetched there is the newest
+    state, so the launch's logits are those of the token after
+    `out_tokens[:n_gen]` of the snapshot it got; the loop's next fence
+    then finds its own block the oldest unfetched (`blocks_in_flight`
+    0), accounts both blocks and the caller's launch, and the one
+    after has a block behind it again. No token and no row is lost:
+    the requests end on the tokens of an undisturbed run."""
+    cfg, model, params, engine = setup
+    r = np.random.RandomState(13)
+    reqs = [(i, r.randint(0, cfg.vocab_size, size=n).astype(np.int32), m)
+            for i, (n, m) in enumerate([(7, 30), (21, 26), (3, 32)])]
+    make = lambda: [Request(rid=i, tokens=t.copy(), max_new_tokens=m)
+                    for i, t, m in reqs]
+    engine.reset()
+    want = {q.rid: q.out_tokens.tolist()
+            for q in ServingLoop(engine).serve(make())}
+    engine.reset()
+    rows, sink = _decode_batch_rows(engine)
+    loop = ServingLoop(engine)
+    for q in make():
+        loop.submit(q)
+    import time
+    loop._t0 = time.monotonic()
+    loop._last_fence_t = loop._now()
+    for _ in range(4):
+        loop.step()
+    assert len(loop.live) == 3 and engine.blocks_in_flight() == 1
+    before = len(rows)
+    snap = engine.fetch_state()
+    assert snap["blocks_in_flight"] == 0 and engine.blocks_in_flight() == 0
+    # newer than what the loop has accounted: its block is unfetched
+    assert all(snap["n_gen"][s] == loop._last_n_gen[s] + 4
+               for s in loop.live)
+    for slot in loop.live:
+        engine.ensure_decode_capacity(slot, int(snap["pos"][slot]), 1)
+    engine.push_tables()
+    logits = np.asarray(engine.decode_once())
+    for slot, req in loop.live.items():
+        so_far = snap["out_tokens"][slot][:int(snap["n_gen"][slot])]
+        ref = _train_logits(model, params,
+                            np.concatenate([req.tokens, so_far]))
+        np.testing.assert_allclose(logits[slot], ref, atol=LOGITS_ATOL,
+                                   rtol=0)
+    while loop.unfinished():
+        loop.step()
+    after = rows[before:]
+    assert [r["blocks_in_flight"] for r in after[:3]] == [0, 1, 1]
+    # the block the caller's fetch took, the caller's launch and the
+    # loop's next block, in one row
+    assert after[0]["iterations"] == 8 and after[0]["window_tokens"] == 27
+    assert {q.rid: q.out_tokens.tolist() for q in loop.results} == want
+    assert engine.cache.free_pages() == engine.cache.num_pages - 1
+    engine.monitor.sinks.remove(sink)
+    engine.reset()
+
+
+def test_nothing_is_left_unfetched_by_run_drain_or_reset(setup):
+    """`run()` ends with nothing unfetched, so does a loop stepped
+    until `unfinished()` is False, and `reset()` drops what is: the
+    next `fetch_state` reads the fresh state, not a snapshot of the
+    state before."""
+    cfg, model, params, engine = setup
+    engine.reset()
+    r = np.random.RandomState(14)
+    make = lambda: [Request(rid=i, tokens=r.randint(
+        0, cfg.vocab_size, size=5 + i), max_new_tokens=6 + i)
+        for i in range(3)]
+    ServingLoop(engine).serve(make())
+    assert engine.blocks_in_flight() == 0
+    loop = ServingLoop(engine)
+    for q in make():
+        loop.submit(q)
+    import time
+    loop._t0 = time.monotonic()
+    loop._last_fence_t = loop._now()
+    steps = 0
+    while loop.queue or loop.live or loop.prefilling:
+        loop.step()
+        steps += 1
+    # the block behind the last fence: one more step fences it
+    assert engine.blocks_in_flight() == 1 and loop.unfinished()
+    assert loop.step() and not loop.unfinished()
+    assert engine.blocks_in_flight() == 0 and not loop.step()
+    assert len(loop.results) == 3
+
+    engine.start_request(0, r.randint(0, cfg.vocab_size, size=6), max_new=12)
+    engine.decode_block(4)
+    engine.decode_block(4)
+    assert engine.blocks_in_flight() == 2
+    # oldest first; a caller that never fetches keeps the newest two
+    engine.decode_block(2)
+    assert engine.blocks_in_flight() == 2
+    assert engine.fetch_state()["n_gen"][0] == 8
+    engine.reset()
+    assert engine.blocks_in_flight() == 0
+    snap = engine.fetch_state()
+    assert not snap["active"].any() and not snap["n_gen"].any()
+    assert snap["blocks_in_flight"] == 0
 
 
 def test_chunked_prefill_interleaves_with_decode(setup):
@@ -649,8 +853,11 @@ def test_an_idle_slot_that_sampled_does_not_switch_the_draw_on(drawing):
                 temperature=0.8, top_k=16)])
     assert sorted(len(r.out_tokens) for r in done) == [4, 16]
     rows = _fence_rows(events)
-    assert [r["iterations"] for r in rows] == [4] * 4
-    assert [r["sample_draw_launches"] for r in rows] == [4, 0, 0, 0]
+    # the fifth block was in flight when the fence read the fourth's
+    # end: it ran every slot as a no-op, and drew nothing either
+    assert [r["iterations"] for r in rows] == [4] * 5
+    assert [r["window_tokens"] for r in rows] == [8, 4, 4, 4, 0]
+    assert [r["sample_draw_launches"] for r in rows] == [4, 0, 0, 0, 0]
     state = jax.device_get({k: engine._state[k]
                             for k in ("temperature", "active")})
     assert state["temperature"][1] > 0 and not state["active"].any()
@@ -670,8 +877,8 @@ def test_fence_rows_count_the_launches_that_drew(drawing):
         Request(rid=i, tokens=p, max_new_tokens=8 + 4 * i)
         for i, p in enumerate(prompts)])
     rows = _fence_rows(events)
-    assert len(rows) == 4
-    assert [r["sample_draw_launches"] for r in rows] == [0] * 4
+    assert len(rows) == 5         # four blocks and the one behind them
+    assert [r["sample_draw_launches"] for r in rows] == [0] * 5
     assert engine.fetch_state()["counts"]["decode"] == {
         "sample_draw_launches": 0}
 
@@ -683,8 +890,8 @@ def test_fence_rows_count_the_launches_that_drew(drawing):
                 temperature=0.8, top_k=16),
         Request(rid="g1", tokens=prompts[2], max_new_tokens=12)])
     rows = _fence_rows(events)
-    assert [r["iterations"] for r in rows] == [4] * 4
-    assert [r["sample_draw_launches"] for r in rows] == [4, 4, 0, 0]
+    assert [r["iterations"] for r in rows] == [4] * 5
+    assert [r["sample_draw_launches"] for r in rows] == [4, 4, 0, 0, 0]
     assert engine.fetch_state()["counts"]["decode"][
         "sample_draw_launches"] == 8
     engine.reset()
@@ -988,14 +1195,15 @@ def test_sync_guards_with_observability_enabled(obs_setup, monkeypatch):
             0, cfg.vocab_size, size=6 + 2 * i), max_new_tokens=8))
     loop._t0 = time.monotonic()
     loop._last_fence_t = loop._now()
-    loop.step()    # admission/compile settle
     counters = _SyncCounters(monkeypatch)
+    loop.step()    # admission/compile settle: two blocks, one fenced
+    assert counters.device_get == 1 and engine.blocks_in_flight() == 1
     n = 0
     while (loop.queue or loop.live or loop.prefilling) and n < 50:
         loop.step()
         n += 1
     assert n > 0
-    assert counters.device_get == n, (counters.device_get, n)
+    assert counters.device_get == n + 1, (counters.device_get, n)
     assert counters.effects_barrier == 0
     # engine-level: a decode block dispatches with zero syncs even
     # with the tracker attached

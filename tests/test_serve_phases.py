@@ -264,7 +264,7 @@ def test_spans_outside_an_iteration_stay_out_of_the_ring(model_and_params):
         "serve/fence.bookkeeping"}
     # totals are self times: the parent's is what its children left
     assert totals["serve/activate"]["ms"] < \
-        totals["serve/activate.other_updates"]["ms"]
+        totals["serve/activate.first_update"]["ms"]
 
 
 def test_without_a_profiler_api_everything_still_runs(model_and_params,

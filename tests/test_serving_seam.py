@@ -269,3 +269,152 @@ def test_gpt2_functional_block_is_the_training_forward(remat):
     dcfg, dparams = gpt2.first_layers(cfg, params, 1)
     assert dcfg.n_layer == 1 and dparams["wte"] is params["wte"]
     assert jax.tree_util.tree_leaves(gpt2.layers(dparams))[0].shape[0] == 1
+
+
+# ----------------------------------------------------------------------
+# the loop keeps the next block in flight (ISSUE 38): every kind of
+# cache under the one overlapped loop, pages of 8 under blocks of 4, so
+# that a block crosses a page
+# ----------------------------------------------------------------------
+OVERLAP_BLOCK = {"max_slots": 3, "prefill_chunk": 16, "sync_every": 4,
+                 "max_new_tokens": 24, "max_seq_len": 128,
+                 "kv_cache": {"num_pages": 60, "page_size": 8}}
+# (prompt tokens, new tokens): more requests than slots, prompts of one
+# token and of four chunks, answers that end inside a block and on one
+OVERLAP_LENGTHS = [(30, 16), (5, 7), (60, 24), (17, 12), (1, 6), (44, 9),
+                   (9, 20)]
+
+
+def _tiny_of(kind):
+    """(model config, params) of the kind's tiny float32 preset."""
+    if kind == "paged":
+        cfg = gpt2.tiny_gpt2_config()
+        model = gpt2.GPT2ForCausalLM(cfg)
+        return cfg, model.init(jax.random.PRNGKey(0),
+                               {"input_ids": np.zeros((1, 8), np.int32)})
+    module = {"recurrent": "tests.test_brumby",
+              "paged+state": "tests.test_falcon_h1",
+              "paged+window": "tests.test_trinity"}[kind]
+    import importlib
+    cfg, params, _ = importlib.import_module(module).tiny(f32)
+    return cfg, params
+
+
+@pytest.fixture(scope="module", params=["paged", "recurrent", "paged+state",
+                                        "paged+window"])
+def overlapped(request):
+    cfg, params = _tiny_of(request.param)
+    assert cfg.cache_kind == request.param
+    block = dict(OVERLAP_BLOCK)
+    if request.param == "recurrent":
+        del block["kv_cache"]
+    return cfg, InferenceEngine(cfg, params, {"inference": block})
+
+
+def _overlap_requests(cfg):
+    r = np.random.RandomState(38)
+    tokens = [r.randint(0, cfg.vocab_size, size=n).astype(np.int32)
+              for n, _ in OVERLAP_LENGTHS]
+    return lambda: [Request(rid=i, tokens=tokens[i].copy(),
+                            max_new_tokens=m)
+                    for i, (_, m) in enumerate(OVERLAP_LENGTHS)]
+
+
+def test_the_overlapped_loop_serves_what_one_request_at_a_time_gets(
+        overlapped):
+    """Seven requests over three slots through the loop that keeps a
+    block in flight: every request's tokens are those of
+    `serve_sequential` (one request at a time, the same loop drained
+    between two) and of the engine driven by hand, a launch at a time
+    with no loop, no block and no snapshot."""
+    from deepspeed_tpu.inference.scheduler import serve_sequential
+    cfg, engine = overlapped
+    make = _overlap_requests(cfg)
+    engine.reset()
+    loop = ServingLoop(engine)
+    together = {q.rid: q.out_tokens.tolist() for q in loop.serve(make())}
+    assert sorted(together) == list(range(len(OVERLAP_LENGTHS)))
+    assert engine.blocks_in_flight() == 0 and engine.cache.slots() == []
+    engine.reset()
+    alone = {q.rid: q.out_tokens.tolist()
+             for q in serve_sequential(engine, make()).results}
+    assert engine.blocks_in_flight() == 0
+    assert alone == together
+    for q in make():
+        engine.reset()
+        engine.start_request(0, q.tokens, q.max_new_tokens)
+        by_hand = [int(np.asarray(engine.decode_once())[0].argmax())
+                   for _ in range(q.max_new_tokens)]
+        assert by_hand == together[q.rid], q.rid
+    engine.reset()
+
+
+def _held(cache, slot, position):
+    """Whether the slot holds a page for `position` in every table it
+    has (a ring's column is the logical page's, modulo the ring)."""
+    if hasattr(cache, "window"):
+        page = position // cache.page_size
+        return cache.full.tables[slot][page] != 0 and \
+            cache.window.tables[slot][page % cache.window.ring] != 0
+    pages = getattr(cache, "pages", cache)    # beside state: its pages
+    return pages.tables[slot][position // pages.page_size] != 0
+
+
+@pytest.mark.parametrize("foreign", [False, True],
+                         ids=["loop-alone", "a-callers-launch-between"])
+def test_two_blocks_ahead_of_the_known_position_lose_no_row(
+        overlapped, foreign, monkeypatch):
+    """When the loop asks for a block's pages it knows the positions
+    of the block before the one in flight: the engine counts the
+    launches dispatched since that snapshot was taken, so every row of
+    every block lies on a page the slot holds, in the pools and in the
+    ring, with a caller's own launch between two steps or without (a
+    row past the pages asked for would go to the scratch page, and the
+    served tokens need not show it)."""
+    cfg, engine = overlapped
+    if cfg.cache_kind == "recurrent":
+        pytest.skip("state has no pages")
+    make = _overlap_requests(cfg)
+    engine.reset()
+    want = {q.rid: q.out_tokens.tolist()
+            for q in ServingLoop(engine).serve(make())}
+    engine.reset()
+    loop = ServingLoop(engine)
+    for q in make():
+        loop.submit(q)
+    import time
+    loop._t0 = time.monotonic()
+    loop._last_fence_t = loop._now()
+    real, checked = engine.decode_block, []
+    page_size = OVERLAP_BLOCK["kv_cache"]["page_size"]
+
+    def block(n):
+        # the device's own positions, read beside the loop (not
+        # through `fetch_state`, which would take the loop's snapshot)
+        pos, active = jax.device_get([engine._state["pos"],
+                                      engine._state["active"]])
+        for slot in loop.live:
+            if active[slot]:
+                last = min(int(pos[slot]) + n,
+                           engine.cache.reserved_tokens(slot)) - 1
+                assert _held(engine.cache, slot, last), (slot, last)
+                checked.append(last % page_size)
+        real(n)
+
+    monkeypatch.setattr(engine, "decode_block", block)
+    launches = 0
+    while loop.unfinished():
+        loop.step()
+        if foreign and loop.live and loop._iteration % 3 == 0:
+            snap = engine.fetch_state()
+            for slot in loop.live:
+                engine.ensure_decode_capacity(slot, int(snap["pos"][slot]), 1)
+            engine.push_tables()
+            engine.decode_once()
+            launches += 1
+    assert not foreign or launches >= 3
+    # blocks whose last row lies on another page than their first
+    assert sum(row < engine.config.sync_every - 1 for row in checked) >= 5
+    assert {q.rid: q.out_tokens.tolist() for q in loop.results} == want
+    assert engine.blocks_in_flight() == 0 and engine.cache.slots() == []
+    engine.reset()
