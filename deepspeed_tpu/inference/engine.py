@@ -24,8 +24,8 @@ fields of a slot that `activate_slot` writes, in one dispatch.)
 The model supplies the block, the engine supplies the cache (the seam;
 docs/inference.md has it at length):
 
-  * a MODEL MODULE (`models/gpt2.py`, `models/brumby.py`,
-    `models/falcon_h1.py`, `models/trinity.py`) holds the
+  * a MODEL MODULE (`models/gpt2.py`, `models/brumby.py`, `models/trinity.py`,
+    `models/falcon_h1.py`, `models/sarvam_mla.py`) holds the
     model's math as plain functions: `embed(mc, params, tokens,
     positions)`, ONE `block(mc, lp, hidden, positions, mixer, cache)
     -> (hidden, cache)`, `head(mc, params, hidden)`, `layers(params)`
@@ -54,8 +54,8 @@ docs/inference.md has it at length):
     nowhere;
   * the ENGINE owns the kinds of cache (`PagedKind`, `RecurrentKind`,
     `PagedStateKind`: the paged kind and a state kind side by side in
-    every layer, and `PagedWindowKind`: pages in two geometries, a
-    layer attending over a sliding window or over everything) and
+    every layer, `PagedWindowKind`: pages in two geometries, a window
+    or everything; `latent_kind.py` adds one pool of latent rows) and
     nothing of any model: per kind the manager
     (inference/kv_cache.py), the fresh device arrays and their keys in
     the engine's state, and the mixers;

@@ -44,7 +44,9 @@ side by side) is served by `PagedStateCache`, the two managers behind
 one. A model whose layers attend some over a sliding window and some
 over everything is served by `WindowedKVCache`: two pools and two
 tables a slot, the window layers' table a ring whose pages go back to
-the free list as the window passes them. All four answer the
+the free list as the window passes them. A model that attends by
+latent attention keeps ONE pool of one compressed row a token
+(`LatentKVCache`, the paged manager counting one pool). All five answer the
 scheduler's one interface: `can_admit`, `admit`, `ensure`, `free`,
 `reserved_tokens`, `never_fits`, `reservation`, `occupancy`,
 `attended`, `slot_operand` (and `rollback`, which recurrent state
@@ -69,7 +71,8 @@ class PagedKVCache:
 
     def __init__(self, n_layer, n_head, head_dim, num_pages, page_size,
                  max_slots, max_pages_per_slot, dtype=np.float32,
-                 ledger=None, n_kv_head=None, category=memory_mod.CAT_KV):
+                 ledger=None, n_kv_head=None, category=memory_mod.CAT_KV,
+                 pools=2):
         if max_pages_per_slot < 1:
             raise ValueError(
                 f"max_pages_per_slot must be >= 1, got {max_pages_per_slot}")
@@ -93,9 +96,10 @@ class PagedKVCache:
         # shape says so, and a page is a whole number of tiles that the
         # decode kernel can copy alone
         self.lanes = padded_lanes(self.n_kv_head * self.head_dim)
-        # bytes of ONE page across K+V and all layers: the unit every
-        # accounting statement below is phrased in
-        self.page_bytes = (2 * self.n_layer * self.page_size *
+        # bytes of ONE page across the pools (K and V; a latent cache
+        # holds one) and all layers: the unit every accounting
+        # statement below is phrased in
+        self.page_bytes = (int(pools) * self.n_layer * self.page_size *
                            self.lanes * self.dtype.itemsize)
         self.pool_bytes = self.num_pages * self.page_bytes
         # page 0 = scratch; pages 1..num_pages-1 allocatable (LIFO free
@@ -386,6 +390,30 @@ class PagedKVCache:
         if dtoken is not None and self._ledger is not None:
             self._ledger.release(dtoken)
         return len(pages)
+
+
+class LatentKVCache(PagedKVCache):
+    """The pages of a model that attends by multi-head latent
+    attention (`models/sarvam_mla.py`): ONE pool
+
+        latent_pool : [n_layer, num_pages, page_size, lanes]
+
+    whose row is what a token leaves in a layer for ALL heads, the
+    compressed vector and the shared rotary key (`row_values`, the
+    config's `latent_row`: 576 values where 64 heads of expanded keys
+    and values would be 20,480), zeros from there up to the lane tile.
+    Tables, admission, growth and release are the paged cache's, and
+    so is the ledger's category; the bytes count one pool, not two,
+    and every fence says what the pool holds
+    (`kv_latent_bytes_resident`)."""
+
+    def __init__(self, row_values, **kw):
+        super().__init__(n_head=1, head_dim=row_values, n_kv_head=1,
+                         pools=1, **kw)
+
+    def occupancy(self):
+        return dict(super().occupancy(),
+                    kv_latent_bytes_resident=int(self.pool_bytes))
 
 
 class RecurrentStateCache:

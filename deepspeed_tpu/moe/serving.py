@@ -48,6 +48,16 @@ experts of this share with at least one row, (token, pick) rows of
 this share, rows of its busiest expert. Every row the launch computes
 is counted, an idle slot's or a pad row's too: those rows are routed
 and multiplied like any other, so this is what the product reads.
+
+**Rows of no request.** A caller that knows which rows are a
+request's hands `expert_layer` `live` [T] bool: the others are sorted
+past every expert's rows (a group E of their own), so that they are in
+no expert's product and in no count. Without it 60 idle slots of 96,
+all one row, put 60 rows on each of the same k experts in every
+launch: experts no request picked are read, and a group of 60 rows
+lies across a row tile's edge of the grouped product, whose two tiles
+each stream that expert's matrices (PERF.md section 6, PR 39). Their
+rows of y are the shared expert's alone.
 """
 
 import jax
@@ -144,7 +154,7 @@ def gated_mlp(x, w_gate, w_up, w_down):
 
 
 def expert_layer(x, lp, experts, layer, top_k, route_scale, first_expert=0,
-                 use_gmm=None):
+                 use_gmm=None, live=None):
     """x [T, H] in the compute type -> (y [T, H], counts int32 [3] in
     the order of `COUNTERS`, picks int32 [T, k]: the experts every row
     was routed to). lp, this layer's: `router` [H, E],
@@ -152,14 +162,21 @@ def expert_layer(x, lp, experts, layer, top_k, route_scale, first_expert=0,
     `shared_down` [Is, H]. experts, EVERY expert layer's, of which
     this is layer `layer`: the held experts' `w_gate`, `w_up` [L,
     held, H, I] and `w_down` [L, held, I, H]. Every matrix is read in
-    x's type."""
+    x's type. `live` [T] bool, where given: the rows that are a
+    request's; the others go to no expert (their picks read E) and are
+    not counted."""
     t, dtype = x.shape[0], x.dtype
     w = lambda name: lp[name].astype(dtype)
     n_experts, held = lp["router"].shape[-1], experts["w_gate"].shape[1]
     picks, weights, _ = route(x, lp["router"], lp["expert_bias"], top_k,
                               route_scale)
     with jax.named_scope(SCOPE_MOE_DISPATCH):
-        where, order, sizes = sorted_by_expert(picks, n_experts)
+        if live is None:
+            where, order, sizes = sorted_by_expert(picks, n_experts)
+        else:
+            picks = jnp.where(live[:, None], picks, n_experts)
+            where, order, sizes = sorted_by_expert(picks, n_experts + 1)
+            sizes = sizes[:n_experts]
         rows = x[order // top_k]
     with jax.named_scope(SCOPE_MOE_EXPERTS):
         product = lambda a, m: grouped_product(
@@ -167,6 +184,10 @@ def expert_layer(x, lp, experts, layer, top_k, route_scale, first_expert=0,
         gate = jax.nn.silu(product(rows, "w_gate").astype(f32)) * \
             product(rows, "w_up").astype(f32)
         out = product(gate.astype(dtype), "w_down")
+        if live is not None and held == n_experts:
+            # the kernel leaves rows past the last group unwritten
+            out = jnp.where((jnp.arange(t * top_k) < sizes.sum())[:, None],
+                            out, 0)
     with jax.named_scope(SCOPE_MOE_SHARED):
         shared = gated_mlp(x, w("shared_gate"), w("shared_up"),
                            w("shared_down"))

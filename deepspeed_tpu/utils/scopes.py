@@ -16,7 +16,8 @@ and SCOPE_STATE_UPDATE. A cache-sized copy showing up under
 SCOPE_LAYERS alone is a regression.
 
 A model's block (`models/gpt2.py`, `models/brumby.py`,
-`models/falcon_h1.py`, `models/trinity.py`) and the engine
+`models/falcon_h1.py`, `models/trinity.py`, `models/sarvam_mla.py`)
+and the engine
 (`inference/engine.py`, which re-exports them) both take the names
 from here: neither the models nor the ops import the serving code.
 """
@@ -93,3 +94,19 @@ SCOPES_IN_LAYER_PAGED_MOE = SCOPES_IN_LAYER + SCOPES_MOE
 SCOPES_PAGED_MOE = (SCOPE_EMBED, SCOPE_LAYERS) + \
     SCOPES_IN_LAYER_PAGED_MOE + (SCOPE_HEAD, SCOPE_SAMPLE,
                                  SCOPE_BOOKKEEPING)
+
+# a model that attends by multi-head latent attention over ONE pool of
+# latent rows and feeds forward through experts (`models/sarvam_mla.py`).
+# The paged regions keep their names and meaning over the one pool
+# (SCOPE_KV_WRITE: the row [c~ ; k_rope]; SCOPE_KV_GATHER: a prefill
+# chunk's rows through the slot's table, a block of pages at a time;
+# SCOPE_ATTN: the decode kernel, or the chunk's products), and so do
+# the expert layer's. What is new stands inside SCOPE_ATTN_QKV and
+# SCOPE_ATTN_OUT under its own name
+SCOPE_MLA_ABSORB = "mla_absorb"      # the two per-head products with W_kvb:
+#                                      W^K into the query, W^V out of the
+#                                      attended latent rows
+SCOPES_IN_LAYER_LATENT_MOE = SCOPES_IN_LAYER_PAGED_MOE + (SCOPE_MLA_ABSORB,)
+SCOPES_LATENT_MOE = (SCOPE_EMBED, SCOPE_LAYERS) + \
+    SCOPES_IN_LAYER_LATENT_MOE + (SCOPE_HEAD, SCOPE_SAMPLE,
+                                  SCOPE_BOOKKEEPING)

@@ -492,6 +492,147 @@ def test_both_pools_pass_the_conditional_in_place_on_a_v5e(trinity_scans,
         assert "kv_gather" in regions
 
 
+# ----------------------------------------------------------------------
+# Sarvam-105B's layer scans at the serving cell's shapes (this chip's 32
+# of 128 experts, 96 slots, one latent pool of 4,601 pages of 128;
+# ISSUE 39)
+# ----------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def sarvam_scans(one_chip):
+    """program -> (compiled layer scans, the latent pool), with the
+    backend probes of the latent decode kernel and of the grouped
+    product answering "TPU"."""
+    from deepspeed_tpu.models import sarvam_mla
+    from deepspeed_tpu.moe import serving as moe
+    from deepspeed_tpu.ops.transformer import latent_attention
+    slots, chunk, seq = 96, 512, 10752
+    cfg = sarvam_mla.SarvamMLAConfig(num_hidden_layers=5, experts_held=32,
+                                     vocab_size=65536)
+    block = InferenceConfig({"inference": {
+        "max_slots": slots, "prefill_chunk": chunk, "sync_every": 4,
+        "max_new_tokens": 2560, "max_seq_len": seq,
+        "kv_cache": {"num_pages": 4601, "page_size": 128}}})
+    family = engine_mod.Serving(cfg, block, seq)
+    cache = family.kind.make_cache(None)
+    place = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip)
+    sds = lambda shape, dtype: place(jax.ShapeDtypeStruct(shape, dtype))
+    params = jax.tree_util.tree_map(place, jax.eval_shape(
+        lambda k: sarvam_mla.init_params(cfg, k), jax.random.PRNGKey(0)))
+    pool = sds(cache.pool_shape(5), cfg.dtype)
+    arrays = (pool, sds((2, 3), jnp.int32))
+    keys = family.cache_keys
+
+    def decode_layers(params, hidden, pool, counts, tables, pos, active):
+        return family.decode_layers(params, hidden, dict(
+            zip(keys, (pool, counts)), tables=tables, pos=pos,
+            active=active), readings=True)
+
+    def prefill_layers(params, hidden, pool, counts, row, start, n_valid):
+        posv = start + jnp.arange(chunk, dtype=jnp.int32)
+        return family.prefill_layers(
+            params, hidden, (pool, counts), row, posv,
+            jnp.arange(chunk) < n_valid, start, n_valid)
+
+    pages = seq // 128
+    programs = {
+        "decode": (decode_layers, (
+            params, sds((slots, 1, 4096), cfg.dtype)) + arrays + (
+            sds((slots, pages), jnp.int32), sds((slots,), jnp.int32),
+            sds((slots,), bool))),
+        "prefill": (prefill_layers, (
+            params, sds((1, chunk, 4096), cfg.dtype)) + arrays + (
+            sds((pages,), jnp.int32), sds((), jnp.int32),
+            sds((), jnp.int32)))}
+    compiled = {}
+
+    def get(program):
+        if program not in compiled:
+            layers, args = programs[program]
+            probes = (latent_attention._on_tpu, moe._on_tpu)
+            latent_attention._on_tpu = moe._on_tpu = lambda: True
+            try:
+                compiled[program] = jax.jit(
+                    layers, donate_argnums=(2, 3)).lower(*args).compile()
+            finally:
+                latent_attention._on_tpu, moe._on_tpu = probes
+        return compiled[program], pool
+    return get
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_the_latent_pool_rides_both_scans_in_place_on_a_v5e(sarvam_scans,
+                                                            program):
+    """3.77 GB of latent rows ride in the carry of both layer scans
+    (the dense layer's and the expert layers'): the donated pool is
+    the output, nothing pool-shaped is copied, Mosaic takes the latent
+    decode kernel (once in each scan's body) and the grouped product
+    over a SHARE of the experts (three calls in the expert layers'
+    body), no layer's held experts are sliced out of the stack, and
+    `mla_absorb` and every region of the expert layer survive the
+    chip's fusions."""
+    from benchmark import mla_costs, region_join
+    from deepspeed_tpu.monitor import programs
+    compiled, pool = sarvam_scans(program)
+    assert pool.shape == (5, 4601, 128, 640)
+    memory = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert memory.alias_size_in_bytes >= int(np.prod(pool.shape)) * 2
+    # the pool, a layer of it, or a layer's held experts [32, 4096, 2048]
+    for shape in (pool.shape, pool.shape[1:], (32, 4096, 2048),
+                  (32, 2048, 4096)):
+        whole = ",".join(map(str, shape))
+        moved = re.findall(
+            rf"= \w+\[(?:1,)?{whole}\]\S* "
+            r"(copy|transpose|dynamic-slice)\(", text)
+        assert moved == [], (shape, moved)
+    calls = re.findall(
+        r'custom-call\(.*custom_call_target="tpu_custom_call"', text)
+    scopes = programs.parse_op_scopes(text)
+    regions = {region_join.region_of(stack, mla_costs.LATENT_MOE)
+               for stack in scopes.values()}
+    assert set(mla_costs.MOE + mla_costs.ABSORB) <= regions and \
+        "kv_write" in regions
+    if program == "decode":
+        # gate, up, down; the decode kernel in each of the two scans
+        assert len(calls) == 5 and "latent_decode_attention" in text
+        assert "s32[5,96,8]" in text
+        assert memory.temp_size_in_bytes < 128 << 20
+        assert "kv_gather" not in regions
+    else:
+        assert len(calls) == 3
+        # the float32 scores of 512 rows x 64 heads against a block of
+        # 512 keys, not against the 10,752-key window
+        assert memory.temp_size_in_bytes < 1 << 30
+        assert "kv_gather" in regions
+
+
+def test_the_bias_is_balanced_beside_the_weights_on_a_v5e(one_chip):
+    """The benchmark balances Sarvam-105B's selection bias on the chip
+    before the engine is built (`weights_sarvam_mla.balance_program`:
+    8,192 rows through the plain reference): beside the 8.5 GB of
+    weights it is handed, the program's temporaries stay under 3 GB,
+    and its result is the bias alone."""
+    import json
+    from benchmark import weights_sarvam_mla as weights
+    from benchmark.reference import sarvam_mla as reference
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmark", "configs",
+                           "sarvam-105b.json")) as f:
+        sizes = json.load(f)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    flat = {name: sds(shape, jnp.float32 if name in weights.FLOAT32_LEAVES
+                      else jnp.bfloat16)
+            for name, (shape, _, _) in weights.weight_shapes(sizes).items()}
+    ids = sds((weights.BALANCE_SEQUENCES, weights.BALANCE_TOKENS), jnp.int32)
+    memory = weights.balance_program(sizes, reference).lower(
+        flat, ids).compile().memory_analysis()
+    assert 8 << 30 > memory.argument_size_in_bytes > 7 << 30
+    assert memory.temp_size_in_bytes < 3 << 30
+    assert memory.output_size_in_bytes == 4 * 128 * 4
+
+
 @pytest.mark.parametrize("slots, vocab", [(96, 200192), (16, 261120)])
 def test_the_samplers_top_k_stays_in_the_conditional_on_a_v5e(
         one_chip, slots, vocab):
