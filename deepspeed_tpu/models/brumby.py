@@ -37,6 +37,11 @@ under "layers" (what `engine.scan_layers` scans over):
             wg [L, H, Hk]   bg [L, Hk]   q_norm, k_norm [L, d]
             wo [L, Hq*d, H]
             norm_post [L, H]  w_gate, w_up [L, H, F]  w_down [L, F, H]
+
+wq, wk and wv are read where they lie, through `head_projection`: the
+chip's compiler would otherwise slice each out of the stack and
+transpose it in every layer of every launch, because their output is
+split into heads.
 """
 
 import dataclasses
@@ -46,6 +51,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from deepspeed_tpu.ops.retention import retention_chunked
 from deepspeed_tpu.ops.retention import state_dim as _state_dim
@@ -146,6 +152,20 @@ def rms_norm(x, weight, eps):
     return (y * weight.astype(f32)).astype(x.dtype)
 
 
+def head_projection(x, w):
+    """x [..., in] @ w [in, out], for a product whose output the
+    caller splits into heads: the output is pinned row-major (`out`
+    minor), so that the split re-lays the ACTIVATION. Left to itself
+    the chip's compiler makes the split free by producing the heads
+    apart, which wants `w` with `in` minor: it then writes a layer's
+    `w` out of the scanned stack and transposes it, in every layer of
+    every launch (117 MB a layer of Sarvam-105B, a tenth of that cell's
+    device time: PERF.md section 6, PR 40). Pinned, the product reads
+    the layer's `w` out of the stack where it lies."""
+    return with_layout_constraint(
+        x @ w, Layout(major_to_minor=tuple(range(x.ndim))))
+
+
 def rope(x, positions, theta):
     """Rotary positions on x [B, T, heads, d], the two halves of a
     head rotated against each other (the source family's
@@ -169,9 +189,9 @@ def block(cfg, lp, hidden, positions, mixer, state):
     eps = cfg.rms_norm_eps
     with jax.named_scope(SCOPE_ATTN_QKV):
         h = rms_norm(hidden, lp["norm_in"], eps).astype(cfg.dtype)
-        q = (h @ lp["wq"].astype(cfg.dtype)).reshape(b, t, hq, d)
-        k = (h @ lp["wk"].astype(cfg.dtype)).reshape(b, t, hk, d)
-        v = (h @ lp["wv"].astype(cfg.dtype)).reshape(b, t, hk, d)
+        heads = lambda name, n: head_projection(
+            h, lp[name].astype(cfg.dtype)).reshape(b, t, n, d)
+        q, k, v = heads("wq", hq), heads("wk", hk), heads("wv", hk)
         lg = jax.nn.log_sigmoid(jnp.dot(
             h, lp["wg"].astype(cfg.dtype), preferred_element_type=f32) +
             lp["bg"].astype(f32))

@@ -53,6 +53,9 @@ under "layers" (what `engine.scan_layers` scans over):
             w_out [L, d_ssm, H]
             wq [L, H, Hq*d]  wk, wv [L, H, Hk*d]  wo [L, Hq*d, H]
             norm_ff [L, H]  w_gate, w_up [L, H, F]  w_down [L, F, H]
+
+wq and wk, whose output is split into heads for the rotation, are read
+where they lie, through `brumby.head_projection` (it has why).
 """
 
 import dataclasses
@@ -63,7 +66,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deepspeed_tpu.models.brumby import rms_norm, rope
+from deepspeed_tpu.models.brumby import head_projection, rms_norm, rope
 from deepspeed_tpu.ops.ssm import causal_conv, split_xbc, ssd_chunked
 from deepspeed_tpu.ops.transformer.flash_attention import dense_attention
 from deepspeed_tpu.utils.scopes import (SCOPE_ATTN_OUT, SCOPE_ATTN_QKV,
@@ -235,8 +238,9 @@ def block(cfg, lp, hidden, positions, mixer, cache):
                              lp["dt_bias"].astype(f32))
         A = -jnp.exp(lp["A_log"].astype(f32))
         ha = h * cfg.attention_in_multiplier
-        q = (ha @ w("wq")).reshape(b, t, hq, d)
-        k = ((ha @ w("wk")) * cfg.key_multiplier).reshape(b, t, hk, d)
+        q = head_projection(ha, w("wq")).reshape(b, t, hq, d)
+        k = (head_projection(ha, w("wk")) *
+             cfg.key_multiplier).reshape(b, t, hk, d)
         v = ha @ w("wv")
         # the config's theta (1e11) is a whole number past 32 bits
         theta = float(cfg.rope_theta)

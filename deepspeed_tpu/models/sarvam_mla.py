@@ -78,6 +78,12 @@ Parameters are a plain dict, a stack's leaves stacked [n, ...]:
     layers:  router [n, H, E]   expert_bias [n, E] float32
              w_gate, w_up [n, held, H, I]   w_down [n, held, I, H]
              shared_gate, shared_up [n, H, Is]   shared_down [n, Is, H]
+
+wq, whose output is split into heads, is read where it lies in both
+stacks, through `brumby.head_projection` (it has why). w_kvb is itself
+split into heads, and the absorbed products batch over them: the chip's
+compiler still re-lays a layer's 16.8 MB of it, heads outermost, in
+every launch (PERF.md section 7, from PR 40).
 """
 
 import dataclasses
@@ -90,7 +96,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deepspeed_tpu.models.brumby import rms_norm
+from deepspeed_tpu.models.brumby import head_projection, rms_norm
 from deepspeed_tpu.moe import serving as moe
 from deepspeed_tpu.utils.scopes import (SCOPE_ATTN_OUT, SCOPE_ATTN_QKV,
                                         SCOPE_MLA_ABSORB, SCOPE_MLP)
@@ -283,7 +289,8 @@ def project(cfg, lp, hidden, positions):
     b, t, _ = hidden.shape
     hq, eps, dtype = cfg.num_attention_heads, cfg.rms_norm_eps, cfg.dtype
     h = rms_norm(hidden, lp["norm_in"], eps).astype(dtype)
-    q = (h @ lp["wq"].astype(dtype)).reshape(b, t, hq, cfg.q_head_dim)
+    q = head_projection(h, lp["wq"].astype(dtype)).reshape(
+        b, t, hq, cfg.q_head_dim)
     q = rms_norm(q, lp["q_norm"], eps)
     kva = h @ lp["w_kva"].astype(dtype)
     c = rms_norm(kva[..., :cfg.kv_lora_rank], lp["kv_norm"], eps)

@@ -58,6 +58,10 @@ Parameters are a plain dict, a stack's leaves stacked [n, ...]:
     layers:  router [n, H, E]   expert_bias [n, E] float32
              w_gate, w_up [n, E, H, I]   w_down [n, E, I, H]
              shared_gate, shared_up [n, H, Is]   shared_down [n, Is, H]
+
+wq and wk, whose output is split into heads for the norm, are read
+where they lie in both stacks, through `brumby.head_projection` (it
+has why).
 """
 
 import dataclasses
@@ -69,7 +73,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deepspeed_tpu.models.brumby import rms_norm, rope
+from deepspeed_tpu.models.brumby import head_projection, rms_norm, rope
 from deepspeed_tpu.moe import serving as moe
 from deepspeed_tpu.utils.scopes import (SCOPE_ATTN_OUT, SCOPE_ATTN_QKV,
                                         SCOPE_MLP)
@@ -199,8 +203,10 @@ def attend(cfg, lp, hidden, positions, mixer, cache):
     w = lambda name: lp[name].astype(dtype)
     with jax.named_scope(SCOPE_ATTN_QKV):
         h = rms_norm(hidden, lp["norm_in"], eps).astype(dtype)
-        q = rms_norm((h @ w("wq")).reshape(b, t, hq, d), lp["q_norm"], eps)
-        k = rms_norm((h @ w("wk")).reshape(b, t, hk, d), lp["k_norm"], eps)
+        q = rms_norm(head_projection(h, w("wq")).reshape(b, t, hq, d),
+                     lp["q_norm"], eps)
+        k = rms_norm(head_projection(h, w("wk")).reshape(b, t, hk, d),
+                     lp["k_norm"], eps)
         v, gate = h @ w("wv"), h @ w("wg")
         theta = float(cfg.rope_theta)
         q = jnp.where(lp["sliding"], rope(q, positions, theta), q)

@@ -157,6 +157,27 @@ def reference_logits(flat, ids):
     return np.asarray(ref.logits(flat, jnp.asarray(ids, jnp.int32), SIZES))
 
 
+@pytest.mark.parametrize("shape", [(5, 64), (3, 7, 64)], ids=["2d", "3d"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_head_projection_is_the_product(shape, dtype):
+    """Pinning the output's layout changes no value: `head_projection`
+    is `x @ w` bit for bit, called eagerly, under `jit` and in a scan's
+    body over stacked weights (where the chip's compiler used to slice
+    and transpose them)."""
+    rng = np.random.default_rng(1)
+    x = jnp.asarray(rng.normal(size=shape), dtype)
+    w = jnp.asarray(rng.normal(size=(4, 64, 48)), dtype)
+    want = np.asarray(x @ w[2], np.float32)
+    assert want.shape == shape[:-1] + (48,)
+    for got in (brumby.head_projection(x, w[2]),
+                jax.jit(brumby.head_projection)(x, w[2]),
+                jax.lax.scan(lambda c, wl: (c, brumby.head_projection(x, wl)),
+                             0, w)[1][2]):
+        assert got.dtype == dtype
+        assert np.array_equal(np.asarray(got, np.float32), want)
+
+
 def test_models_forward_equals_the_reference(model32):
     cfg, params, flat = model32
     ids = np.random.default_rng(3).integers(0, 97, 50)

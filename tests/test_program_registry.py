@@ -114,6 +114,43 @@ ENTRY %main (x: f32[4]) -> f32[4] {
         "dynamic-slice.3": "jit(f)/layers/while/body/dynamic_slice"}
 
 
+def test_parse_relaid_of_hlo_text():
+    """The weights a program writes only to hold them in another
+    arrangement: the copy of a layer's slice of a scan's stacked
+    operand, and the copy (through the compiler's own bitcast) of a
+    leaf of `params`; not a prefetch, not another argument's copy, not
+    a copy inside a product's fusion."""
+    text = """HloModule jit_f, is_scheduled=true
+
+%fused_computation (p: bf16[96,64,192]) -> bf16[96,64,192] {
+  %p = bf16[96,64,192]{2,1,0} parameter(0)
+  ROOT %copy.139 = bf16[96,64,192]{2,1,0:T(8,128)(2,1)} copy(%p), metadata={op_name="jit(f)/layers/while/body/closed_call/attn_qkv/dot_general"}
+}
+
+%body (c: (s32[], bf16[4,8,16])) -> (s32[], bf16[4,8,16]) {
+  %c = (s32[], bf16[4,8,16]{2,1,0}) parameter(0)
+  %i = s32[] get-tuple-element(%c), index=0
+  %w = bf16[4,8,16]{2,1,0} get-tuple-element(%c), index=1
+  %constant_dynamic-slice_fusion.8 = bf16[1,8,16]{2,1,0:T(8,128)(2,1)} fusion(%w, %i), kind=kLoop, calls=%fused_slice, metadata={op_name="jit(f)/layers/while/body/dynamic_slice"}
+  %copy.177 = bf16[1,8,16]{1,2,0:T(8,128)(2,1)S(1)} copy(%constant_dynamic-slice_fusion.8), metadata={op_name="jit(f)/layers/while/body/dynamic_slice" stack_frame_id=10}, backend_config={"window_config":{"kernel_window_bounds":[]}}
+  %copy.178 = f32[2,3]{1,0:T(8,128)S(1)} copy(%act), metadata={op_name="jit(f)/layers/while/body/closed_call/attn_out/convert_element_type"}
+  ROOT %t = (s32[], bf16[4,8,16]{2,1,0}) tuple(%i, %w)
+}
+
+ENTRY %main (wq: bf16[1,8,32], tables: s32[6,4]) -> bf16[8,32] {
+  %params__dense____wq__.1 = bf16[1,8,32]{2,1,0} parameter(0), metadata={op_name="params[\\'dense\\'][\\'wq\\']"}
+  %tables.1 = s32[6,4]{1,0} parameter(1), metadata={op_name="tables"}
+  %copy.93 = s32[6,4]{1,0:T(8,128)S(1)} copy(%tables.1), metadata={op_name="tables"}
+  %bitcast.663 = bf16[32,8]{0,1:T(8,128)(2,1)} bitcast(%params__dense____wq__.1)
+  %copy.172 = bf16[32,8]{1,0:T(8,128)(2,1)S(1)} copy(%bitcast.663), backend_config={"flag_configs":[]}
+  %copy-start.1 = (bf16[1,8,32]{2,1,0:S(1)}, bf16[1,8,32]{2,1,0}, u32[]) copy-start(%params__dense____wq__.1)
+  ROOT %copy-done.1 = bf16[1,8,32]{2,1,0:S(1)} copy-done(%copy-start.1)
+}
+"""
+    assert programs.parse_relaid(text) == (8 * 16 + 32 * 8) * 2
+    assert programs.parse_relaid(text.replace("copy(", "add(")) == 0
+
+
 # ----------------------------------------------------------------------
 # the registry
 # ----------------------------------------------------------------------
@@ -126,8 +163,12 @@ def test_memory_and_the_operators_table(engine):
     assert rows["jit_decode_fn"]["temp"] == \
         programs.memory("jit_decode_fn")["temp"]
     assert rows["jit_prefill_fn"]["compile_seconds"] > 0
+    assert rows["jit_prefill_fn"]["relaid"] == 0
     assert programs.op_scopes("jit_no_such_fn") is None
     assert programs.memory("jit_no_such_fn") is None
+    # XLA:CPU re-lays no weight of GPT-2's
+    assert programs.relaid("jit_decode_fn") == 0
+    assert programs.relaid("jit_no_such_fn") is None
 
 
 def test_the_registry_keeps_no_device_array(model_and_params):
