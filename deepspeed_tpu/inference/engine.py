@@ -24,8 +24,8 @@ fields of a slot that `activate_slot` writes, in one dispatch.)
 The model supplies the block, the engine supplies the cache (the seam;
 docs/inference.md has it at length):
 
-  * a MODEL MODULE (`models/gpt2.py`, `models/brumby.py`, `models/trinity.py`,
-    `models/falcon_h1.py`, `models/sarvam_mla.py`) holds the
+  * a MODEL MODULE (`models/gpt2.py`, `brumby.py`, `trinity.py`,
+    `falcon_h1.py`, `sarvam_mla.py`, `phi4flash.py`) holds the
     model's math as plain functions: `embed(mc, params, tokens,
     positions)`, ONE `block(mc, lp, hidden, positions, mixer, cache)
     -> (hidden, cache)`, `head(mc, params, hidden)`, `layers(params)`
@@ -35,18 +35,18 @@ docs/inference.md has it at length):
     `first_layers(mc, params, n)`; a model whose layers do not all
     have weights of one shape gives `stacks(mc, params)`, the stacks
     `block` is scanned over one after another, each with the leaves
-    that `block` takes WHOLE beside a layer's slice (what a kernel
-    reads a layer of where it lies), one whose block
-    counts something a launch (`COUNTERS`, the names) returns the
-    counts as a third value, and one whose block reads something off
-    every row (`ROW_READINGS`, the names; Trinity: the experts a row
-    picked) returns those after the counts: the decode program gives
-    its last launch's out, a layer to a row
-    (`InferenceEngine.last_row_readings`). The block computes its own
-    projections under SCOPE_ATTN_QKV / SCOPE_ATTN_OUT / SCOPE_MLP and
-    calls `mixer` exactly once with what it projected (a block with
-    two branches side by side hands over both branches' projections
-    in that one call and gets both outputs back); it knows nothing
+    that `block` takes WHOLE beside a layer's slice; one whose block
+    counts something a launch (`COUNTERS`) returns the counts as a
+    third value, and one whose block reads something off every row
+    (`ROW_READINGS`; Trinity: the experts a row picked) returns those
+    after the counts (`InferenceEngine.last_row_readings`). The block
+    computes its own projections under SCOPE_ATTN_QKV / SCOPE_ATTN_OUT
+    / SCOPE_MLP and calls `mixer` exactly once with what it projected
+    (two branches side by side: both in that one call). A model whose
+    layers keep DIFFERENT things (`enter`, `leave`: `layers_with_carry`
+    below) has a block of a PERIOD of layers that calls `mixer(role,
+    ...)` once for each thing a layer keeps or reads, and says which
+    stacks a launch that yields no logits must run. No block knows
     of pages, tables, slots or state arrays. The MODEL CONFIG names the
     kind of cache the layers keep (`cache_kind`), carries the geometry
     that kind's manager needs and points at the module
@@ -55,7 +55,8 @@ docs/inference.md has it at length):
   * the ENGINE owns the kinds of cache (`PagedKind`, `RecurrentKind`,
     `PagedStateKind`: the paged kind and a state kind side by side in
     every layer, `PagedWindowKind`: pages in two geometries, a window
-    or everything; `latent_kind.py` adds one pool of latent rows) and
+    or everything; `latent_kind.py` adds one pool of latent rows,
+    `hybrid_kind.py` state, rings and one shared layer of pages) and
     nothing of any model: per kind the manager
     (inference/kv_cache.py), the fresh device arrays and their keys in
     the engine's state, and the mixers;
@@ -64,8 +65,7 @@ docs/inference.md has it at length):
     `scan_layers`.
 
 Everything else (slot state, scheduler, sampling, bookkeeping, the
-fence and the spans of the host's phases, `SERVE_PHASES` in
-monitor/trace.py) is shared.
+fence, the host's phases `SERVE_PHASES` in monitor/trace.py) is shared.
 """
 
 import collections
@@ -780,13 +780,13 @@ class Serving:
 
     def layers(self, params, hidden, cache, positions, mixer, program=0,
                readings=False):
-        """`scan_layers` of the model's block over `params`' stack (or
-        its stacks, one after another), with the kind's `mixer(li,
-        ...)` on layer `li` of the whole `cache` arrays. Returns
-        (hidden, cache). Where the block counts, the last of `cache` is
-        the counts and row `program` of it takes this launch's. With
-        `readings`, a third value: what the block read off every row
-        ({name of `ROW_READINGS`: [layers, rows, ...]})."""
+        """`scan_layers` of the model's block over `params`' stack(s), the
+        kind's `mixer(li, ...)` on layer `li` of the whole `cache` arrays
+        -> (hidden, cache[, readings: {name of `ROW_READINGS`: [layers,
+        rows, ...]}]); row `program` of the block's counts takes the launch's."""
+        if hasattr(self.model, "enter"):
+            return layers_with_carry(self, params, hidden, cache, positions,
+                                     mixer, caching=program == 1)
         n_arrays = len(cache) - bool(self.counters)
 
         def layer(lp, li, hidden, cache):
@@ -1444,3 +1444,28 @@ class InferenceEngine:
             snap["active"][slot], snap["finished_eos"][slot] = True, False
             snap["pos"][slot], snap["n_gen"][slot] = pos, 0
             snap["out_tokens"][slot] = 0
+
+
+def layers_with_carry(serving, params, hidden, cache, positions, mixer,
+                      caching):
+    """`Serving.layers` for a model whose layers keep DIFFERENT things
+    between tokens (`models/phi4flash.py`). Its `stacks(mc, params,
+    caching)` are scanned one after another over PERIODS (a step of a
+    scan is `block` on as many layers as the pattern repeats after),
+    with the kind's `mixer(li, role, ...)` on period `li`; beside the
+    hidden state the scans carry what the model's `enter(mc, hidden)`
+    makes and its `leave(mc, carry)` drops when the launch ends: a
+    value that later layers of the SAME launch read and no later token
+    does. `caching`: the launch yields no logits (the prefill program),
+    and the model gives only the stacks that write what later tokens
+    read. Returns (hidden, cache)."""
+    model, mc = serving.model, serving.mc
+    carry, first = model.enter(mc, hidden), 0
+    for scanned, whole in model.stacks(mc, params, caching=caching):
+        carry, cache = scan_layers(
+            scanned, carry, cache,
+            lambda lp, li, carry, cache, whole=whole: model.block(
+                mc, {**lp, **whole}, carry, positions,
+                functools.partial(mixer, li), cache), first)
+        first += jax.tree_util.tree_leaves(scanned)[0].shape[0]
+    return model.leave(mc, carry), cache

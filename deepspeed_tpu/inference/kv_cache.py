@@ -46,7 +46,10 @@ over everything is served by `WindowedKVCache`: two pools and two
 tables a slot, the window layers' table a ring whose pages go back to
 the free list as the window passes them. A model that attends by
 latent attention keeps ONE pool of one compressed row a token
-(`LatentKVCache`, the paged manager counting one pool). All five answer the
+(`LatentKVCache`, the paged manager counting one pool). A model whose
+layers keep DIFFERENT things (a state in some, a ring in others, one
+layer of pages that several layers read) is served by `HybridKVCache`:
+the state's, the ring's and the paged manager behind one. All six answer the
 scheduler's one interface: `can_admit`, `admit`, `ensure`, `free`,
 `reserved_tokens`, `never_fits`, `reservation`, `occupancy`,
 `attended`, `slot_operand` (and `rollback`, which recurrent state
@@ -892,3 +895,147 @@ class WindowedKVCache:
 
     def free(self, slot):
         return self.full.free(slot) + self.window.free(slot)
+
+
+class HybridKVCache:
+    """Three kinds of slot state behind the one interface, for a model
+    whose layers keep DIFFERENT things (`models/phi4flash.py`): `state`
+    (a `RecurrentStateCache` over the Mamba layers), `window` (a
+    `RingKVCache` over the layers that attend over a sliding window)
+    and `shared` (a `PagedKVCache` of ONE layer, whole histories, which
+    its writer and `readers - 1` later layers attend to). A request is
+    admitted only if all three have room, grows in the two paged ones
+    at the same fences, and holds its part of each until it is freed;
+    no part knows of another. Every fence row carries the state's
+    counters, the ring's (`kv_pages_window_*`, as the windowed cache
+    names them) and the shared pool's: `kv_pages_shared_in_use`,
+    `kv_pages_shared_attended` (the pages a decode launch reads of it:
+    the live slots' pages x the layers that read them) and
+    `prefill_layers_run` (the fence's prefill launches x
+    `caching_layers`, the layers such a launch runs). State cannot be
+    rewound, so `rollback` is refused."""
+
+    kind = "state+window+shared"
+
+    def __init__(self, state, window, shared, readers, caching_layers,
+                 prefill_chunk):
+        self.state, self.window, self.shared = state, window, shared
+        self.readers, self.caching_layers = int(readers), int(caching_layers)
+        self.prefill_chunk = int(prefill_chunk)
+        self.pool_bytes = (state.pool_bytes + window.pool_bytes +
+                           shared.pool_bytes)
+        self.num_pages = shared.num_pages     # the tracker's snapshot
+        self.page_size = shared.page_size
+        self.max_slots = shared.max_slots
+
+    # the tables the engine uploads: the shared pool's, then the ring
+    tables = property(lambda self: (self.shared.tables, self.window.tables))
+    table_version = property(lambda self: self.shared.table_version +
+                             self.window.table_version)
+
+    def state_shapes(self):
+        return self.state.state_shapes()
+
+    def state_dtypes(self):
+        return self.state.state_dtypes()
+
+    def slots(self):
+        return self.state.slots()
+
+    def reserved_tokens(self, slot):
+        return min(self.shared.reserved_tokens(slot),
+                   self.state.reserved_tokens(slot))
+
+    def allocated_pages(self, slot):
+        return self.shared.allocated_pages(slot) + \
+            self.window.allocated_pages(slot)
+
+    def never_fits(self, n_tokens_worst_case):
+        return self.shared.never_fits(n_tokens_worst_case) or \
+            self.window.never_fits(n_tokens_worst_case) or \
+            self.state.never_fits(n_tokens_worst_case)
+
+    def reservation(self, n_tokens_worst_case):
+        return {"kv_pages_reserved": int(
+            self.shared.pages_to_reserve(n_tokens_worst_case)),
+            "kv_pages_window_reserved": int(
+                self.window.pages_to_reserve(n_tokens_worst_case)),
+            **self.state.reservation(n_tokens_worst_case)}
+
+    def occupancy(self):
+        return {"kv_pages_shared_in_use": int(self.shared.pages_in_use()),
+                "kv_pages_window_in_use": int(self.window.pages_in_use()),
+                "kv_pages_window_released": int(
+                    self.window.released_pages()),
+                "kv_pages_free": int(self.shared.free_pages()),
+                **self.state.occupancy()}
+
+    def ledger_occupancy(self):
+        shared = self.shared.ledger_occupancy()
+        return {**self.occupancy(),
+                "kv_pages_shared_in_use": shared["kv_pages_in_use"],
+                "kv_pages_window_in_use":
+                self.window.ledger_occupancy()["kv_pages_in_use"],
+                "kv_page_utilization": shared["kv_page_utilization"]}
+
+    def attended(self, active, pos, launches=0, advanced=0, prefill_rows=0,
+                 prefill_tokens=0):
+        """What the next decode launch reads: every page of a live
+        slot once a reading layer, the window's pages once a window
+        layer; what the fence's launches took through the state; and
+        the layers its prefill launches ran."""
+        page = self.page_size
+        last = pos[active] // page
+        first = np.maximum(pos[active] - self.window.window + 1, 0) // page
+        return {"kv_pages_shared_attended":
+                int((last + 1).sum()) * self.readers,
+                "kv_pages_window_attended":
+                int((last - first + 1).sum()) * self.window.n_layer,
+                "prefill_layers_run": self.caching_layers * (
+                    int(prefill_rows) // self.prefill_chunk),
+                **self.state.attended(active, pos, launches, advanced,
+                                      prefill_rows, prefill_tokens)}
+
+    def utilization_counter(self, occupancy):
+        """The trace export's one track: the shared pool, which
+        admission fills first."""
+        return "kv_page_utilization", {
+            "in_use": occupancy["kv_pages_shared_in_use"],
+            "free": occupancy["kv_pages_free"]}
+
+    def slot_operand(self, slot):
+        """What the prefill program is handed to find `slot`'s cache:
+        its row of both tables and its index into the state."""
+        return (self.shared.tables[slot], self.window.tables[slot],
+                self.state.slot_operand(slot))
+
+    def can_admit(self, n_tokens_worst_case):
+        return self.shared.can_admit(n_tokens_worst_case) and \
+            self.window.can_admit(n_tokens_worst_case) and \
+            self.state.can_admit(n_tokens_worst_case)
+
+    def admit(self, slot, n_tokens_worst_case, name=None):
+        if not self.can_admit(n_tokens_worst_case):
+            raise RuntimeError(
+                f"cache cannot admit {n_tokens_worst_case} tokens: "
+                f"{self.shared.free_pages()} free pages "
+                f"({self.shared.reserved_unallocated()} reserved) of the "
+                f"shared pool, {self.window.free_pages()} "
+                f"({self.window.reserved_unallocated()} reserved) of the "
+                f"rings, {self.state.free_slots()} free slots of state")
+        self.shared.admit(slot, n_tokens_worst_case, name)
+        self.window.admit(slot, n_tokens_worst_case, name)
+        self.state.admit(slot, n_tokens_worst_case, name)
+
+    def ensure(self, slot, n_tokens, queries_from=None):
+        self.state.ensure(slot, n_tokens)
+        pages = self.shared.ensure(slot, n_tokens)   # holds the bound
+        self.window.ensure(slot, n_tokens, queries_from)
+        return pages
+
+    def rollback(self, slot, n_tokens):
+        return self.state.rollback(slot, n_tokens)
+
+    def free(self, slot):
+        self.state.free(slot)
+        return self.shared.free(slot) + self.window.free(slot)

@@ -1,7 +1,7 @@
-"""Mamba-2's selective state-space mixer (Dao and Gu, "Transformers are
-SSMs", arXiv:2405.21060) as a serving engine needs it: a state per
-head that is a fixed-size matrix, advanced a chunk of tokens at a time
-(prefill) or one token of every slot at a time (decode).
+"""Mamba-2's state-space mixer (arXiv:2405.21060) for serving: a state
+matrix per head with ONE SCALAR decay a head and token, which is what
+lets a chunk be matrix products. Mamba-1's (`mamba1.py`) decays every
+(channel, state) pair apart: neither function here can express it.
 
 For one head of width P with state width N, its group's B_t, C_t [N],
 the token's step dt_t > 0 and the head's A < 0, D:

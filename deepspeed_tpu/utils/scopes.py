@@ -16,8 +16,8 @@ and SCOPE_STATE_UPDATE. A cache-sized copy showing up under
 SCOPE_LAYERS alone is a regression.
 
 A model's block (`models/gpt2.py`, `models/brumby.py`,
-`models/falcon_h1.py`, `models/trinity.py`, `models/sarvam_mla.py`)
-and the engine
+`models/falcon_h1.py`, `models/trinity.py`, `models/sarvam_mla.py`,
+`models/phi4flash.py`) and the engine
 (`inference/engine.py`, which re-exports them) both take the names
 from here: neither the models nor the ops import the serving code.
 """
@@ -110,3 +110,23 @@ SCOPES_IN_LAYER_LATENT_MOE = SCOPES_IN_LAYER_PAGED_MOE + (SCOPE_MLA_ABSORB,)
 SCOPES_LATENT_MOE = (SCOPE_EMBED, SCOPE_LAYERS) + \
     SCOPES_IN_LAYER_LATENT_MOE + (SCOPE_HEAD, SCOPE_SAMPLE,
                                   SCOPE_BOOKKEEPING)
+
+# a model whose layers keep DIFFERENT things (`models/phi4flash.py`,
+# kind "state+window+shared"): a Mamba-1 state in some, a ring of
+# pages in others, one layer of pages that several layers read, and
+# nothing in the rest. The state-space regions keep their names over
+# the Mamba-1 scan (SCOPE_SSM_CONV, SCOPE_SSM_CHUNK: the chunk's
+# selective scan; SCOPE_STATE_UPDATE: decode's step) and the paged
+# regions theirs over the rings and the shared pool. What is new: the
+# attention over the SHARED pool stands inside SCOPE_ATTN under its own
+# name (a ring's attention is SCOPE_ATTN alone), and the gated memory
+# unit, which keeps nothing, under its own
+SCOPE_SHARED_KV = "shared_kv"        # inside attn: a reading layer's walk
+#                                      over the pages another layer wrote
+SCOPE_GMU = "gmu"                    # the memory unit: its gate inside
+#                                      attn_qkv, product and out projection
+#                                      inside attn_out
+SCOPES_IN_LAYER_HYBRID = SCOPES_IN_LAYER_PAGED_STATE + (SCOPE_SHARED_KV,
+                                                        SCOPE_GMU)
+SCOPES_HYBRID = (SCOPE_EMBED, SCOPE_LAYERS) + SCOPES_IN_LAYER_HYBRID + \
+    (SCOPE_HEAD, SCOPE_SAMPLE, SCOPE_BOOKKEEPING)

@@ -609,8 +609,139 @@ def test_the_latent_pool_rides_both_scans_in_place_on_a_v5e(sarvam_scans,
         assert "kv_gather" in regions
 
 
+# ----------------------------------------------------------------------
+# Phi-4-mini-flash-reasoning's layer scans and head at the serving
+# cell's shapes (all 32 layers, 64 slots, rings of 9 pages, ONE layer
+# of 3,500 shared pages of 128; ISSUE 41)
+# ----------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def phi4flash_scans(one_chip):
+    """program -> (compiled layer scans [+ the head, in decode], the
+    cache arrays), with the backend probes of the differential decode
+    kernel and of the Mamba-1 scan answering "TPU"."""
+    from deepspeed_tpu.models import phi4flash
+    from deepspeed_tpu.ops.ssm import mamba1
+    from deepspeed_tpu.ops.transformer import diff_decode_attention as dd
+    slots, chunk, seq = 64, 512, 18432
+    cfg = phi4flash.Phi4FlashConfig()
+    block = InferenceConfig({"inference": {
+        "max_slots": slots, "prefill_chunk": chunk, "sync_every": 4,
+        "max_new_tokens": 2048, "max_seq_len": seq,
+        "kv_cache": {"num_pages": 3500, "page_size": 128}}})
+    family = engine_mod.Serving(cfg, block, seq)
+    cache = family.kind.make_cache(None)
+    place = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip)
+    sds = lambda shape, dtype: place(jax.ShapeDtypeStruct(shape, dtype))
+    params = jax.tree_util.tree_map(place, jax.eval_shape(
+        lambda k: phi4flash.init_params(cfg, k), jax.random.PRNGKey(0)))
+    fresh = jax.eval_shape(lambda: family.kind.fresh(cache))
+    keys = family.cache_keys
+    arrays = tuple(place(fresh[k]) for k in keys)
+    ring, pages = cache.window.ring, seq // 128
+
+    def decode_layers(params, hidden, arrays, tables, ring_tables, pos,
+                      active):
+        hidden, state = family.decode_layers(params, hidden, dict(
+            zip(keys, arrays), tables=tables, window_tables=ring_tables,
+            pos=pos, active=active))
+        return family.head(params, hidden)[:, 0], state
+
+    def prefill_layers(params, hidden, arrays, row, ring_row, slot, start,
+                       n_valid):
+        posv = start + jnp.arange(chunk, dtype=jnp.int32)
+        return family.prefill_layers(
+            params, hidden, arrays, (row, ring_row, slot), posv,
+            jnp.arange(chunk) < n_valid, start, n_valid)
+
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    programs = {
+        "decode": (decode_layers, (
+            params, sds((slots, 1, 2560), cfg.dtype), arrays,
+            i32(slots, pages), i32(slots, ring), i32(slots),
+            sds((slots,), bool))),
+        "prefill": (prefill_layers, (
+            params, sds((1, chunk, 2560), cfg.dtype), arrays, i32(pages),
+            i32(ring), i32(), i32(), i32()))}
+    compiled = {}
+
+    def get(program):
+        if program not in compiled:
+            layers, args = programs[program]
+            probes = (dd._on_tpu, mamba1._on_tpu)
+            dd._on_tpu = mamba1._on_tpu = lambda: True
+            try:
+                compiled[program] = jax.jit(
+                    layers, donate_argnums=(2,)).lower(*args).compile()
+            finally:
+                dd._on_tpu, mamba1._on_tpu = probes
+        return compiled[program], arrays
+    return get
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
-@pytest.mark.parametrize("family", ["brumby", "falcon", "trinity", "sarvam"])
+def test_state_rings_and_the_shared_pool_ride_the_scans_in_place_on_a_v5e(
+        phi4flash_scans, program):
+    """0.21 GB of Mamba-1 state (held [16, 5120] a slot and layer:
+    whole lane tiles), 3.03 GB of rings and 2.29 GB of ONE layer of
+    shared pages ride in the carry of three layer scans of periods: all
+    six donated arrays are the outputs and nothing cache-shaped is
+    copied. Decode: Mosaic takes the differential decode kernel three
+    times (a ring's in the self-decoder's body; the shared pool's in
+    the middle period and in the cross-decoder's body: a shared page is
+    read once a reading layer), the tied head reads the embedding
+    where it lies (no temporary near its 1.02 GB), and `mem` costs no
+    copy. Prefill: Mosaic takes the Mamba-1 scan (in the
+    self-decoder's body and in the middle period), no [chunk, 5120,
+    16] float32 array exists, and no kernel over the shared pool, no
+    memory unit and no cross layer is in the program."""
+    from benchmark import phi4flash_regions, region_join
+    from deepspeed_tpu.inference.hybrid_kind import SHARED_KERNEL
+    from deepspeed_tpu.monitor import programs
+    compiled, arrays = phi4flash_scans(program)
+    conv, state, k_ring, _, k_shared, _ = arrays
+    assert state.shape == (9, 64, 16, 5120) and state.dtype == jnp.float32
+    assert conv.shape == (9, 64, 3, 5120)
+    assert k_ring.shape == (8, 64 * 9 + 1, 128, 1280)
+    assert k_shared.shape == (1, 3500, 128, 1280)
+    cache_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                      for a in arrays)
+    assert 5.5e9 < cache_bytes < 5.6e9
+    memory = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert memory.alias_size_in_bytes >= cache_bytes
+    for a in (state, k_ring, k_shared):
+        whole = ",".join(map(str, a.shape))
+        assert re.findall(rf"= \w+\[{whole}\]\S* (copy|transpose)\(",
+                          text) == []
+    assert programs.parse_relaid(text) == 0
+    calls = re.findall(
+        r'custom-call\(.*custom_call_target="tpu_custom_call"', text)
+    regions = {region_join.region_of(stack, phi4flash_regions.HYBRID)
+               for stack in programs.parse_op_scopes(text).values()}
+    for expanded in ("512,5120,16", "512,16,5120"):
+        assert f"f32[{expanded}]" not in text
+    if program == "decode":
+        assert memory.temp_size_in_bytes < 64 << 20
+        assert len(calls) == 3 and len(re.findall(
+            rf"%{SHARED_KERNEL}[.\d]* = ", text)) == 2
+        assert len(re.findall(r"%diff_decode_attention[.\d]* = ", text)) == 1
+        assert {"kv_write", "attn", "shared_kv", "gmu", "ssm_conv",
+                "state_update", "attn_qkv", "attn_out", "mlp"} <= regions
+        assert not regions & {"kv_gather", "ssm_chunk", "state_reset"}
+    else:
+        # the float32 scores of 512 rows against a ring of 1,152 keys
+        assert memory.temp_size_in_bytes < 256 << 20
+        assert len(calls) == 2 and "mamba1_selective_scan" in text
+        assert SHARED_KERNEL not in text
+        assert {"kv_write", "kv_gather", "attn", "state_reset", "ssm_conv",
+                "ssm_chunk"} <= regions
+        assert not regions & {"state_update", "shared_kv", "gmu"}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+@pytest.mark.parametrize("family", ["brumby", "falcon", "trinity", "sarvam",
+                                    "phi4flash"])
 def test_no_head_projection_is_sliced_out_and_transposed_on_a_v5e(
         request, family, program):
     """W_q, W_k and W_v go through `head_projection`, which pins their
