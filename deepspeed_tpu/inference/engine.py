@@ -92,6 +92,8 @@ from deepspeed_tpu.ops.ssm import (causal_conv, split_xbc, ssd_chunked,
                                    ssm_step)
 from deepspeed_tpu.ops.transformer.paged_decode_attention import \
     paged_decode_attention
+from deepspeed_tpu.ops.transformer.paged_prefill_attention import \
+    paged_prefill_attention
 from deepspeed_tpu.ops.transformer.quantized_matmul import (
     KERNEL_SCALE, quantize_kernel_int8_np)
 from deepspeed_tpu.utils.logging import logger
@@ -166,53 +168,12 @@ def compile_registered(fn, args, donate_argnums):
     return compiled
 
 
-@jax.named_scope(SCOPE_ATTN)
-def paged_attention(q, kc, vc, q_pos, kv_limit, first=None, k_pos=None):
-    """A prefill chunk's attention (the programs with a few query
-    rows a slot attend through `paged_decode_attention` instead; see
-    `PagedKind.mixer`). Causal attention of q [B, Tq, H, D] against a
-    gathered page window kc/vc [B, Tk, H, D], phrased like the
-    training path's `dense_attention` (same einsum strings, fp32
-    softmax, -1e30 where-masking): key positions are their indices,
-    queries sit at absolute positions `q_pos` [B, Tq], and keys beyond
-    `kv_limit` [B] (pages not yet written / scratch) are price-masked
-    AND value-zeroed — a masked key contributes an exact +0.0 to every
-    reduction, whatever the unwritten rows hold.
-
-    A lower bound (a sliding window): a query sees no key below
-    `first` [B, Tq], and keys below the earliest query's are
-    value-zeroed like those beyond `kv_limit`. A window gathered
-    through a ring of pages does not lie in the order of its
-    positions: `k_pos` [B, Tk] then gives each key's (negative: no
-    key)."""
-    sm_scale = 1.0 / np.sqrt(q.shape[-1])
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, kc).astype(jnp.float32)
-    scores = scores * sm_scale
-    kpos = jnp.arange(kc.shape[1])[None, :] if k_pos is None else k_pos
-    mask = kpos[:, None, None, :] <= q_pos[:, None, :, None]
-    v_ok = kpos <= kv_limit[:, None]
-    if first is not None:
-        mask = mask & (kpos[:, None, None, :] >= first[:, None, :, None])
-        v_ok = v_ok & (kpos >= first[:, :1])
-    scores = jnp.where(mask, scores, jnp.float32(-1e30))
-    probs = jax.nn.softmax(scores, axis=-1)
-    probs = probs.astype(vc.dtype)
-    # scratch/unwritten pages can hold garbage; zero their values so
-    # the 0-probability product is exactly 0 regardless
-    v_ok = v_ok[:, :, None, None]
-    vc = jnp.where(v_ok, vc, jnp.zeros((), vc.dtype))
-    # PV phrased as a (b, h)-batched matmul rather than the einsum
-    # string: measured on XLA-CPU this contraction accumulates the
-    # real-key prefix in the same order at every padded width
-    out = jnp.matmul(probs, vc.transpose(0, 2, 1, 3))
-    return out.transpose(0, 2, 1, 3)
-
-
 # query rows per slot up to which the paged mixer attends through the
 # decode kernel: decode and draft decode carry 1, speculative verify
-# k + 1. A prefill chunk carries more and keeps the gathered window:
-# one row against pages is a page walk bound by latency and bytes, a
-# chunk of 128 rows against one slot is a matrix-unit problem.
+# k + 1. A prefill chunk carries more and attends in key blocks
+# (`paged_prefill_attention`): one row against pages is a page walk
+# bound by latency and bytes, a chunk of 128 rows against one slot is
+# a matrix-unit problem.
 DECODE_ROWS_MAX = 8
 
 # what the serving fence reads of the decode state. The decode program
@@ -337,10 +298,10 @@ class PagedKind:
         trace time. A few rows a slot (decode, draft decode, verify):
         one kernel walks each live slot's page table and reads the
         pages where they lie; a slot with no valid row is not live and
-        gets zeros. A prefill chunk: the slot's window is gathered
-        through its table row and attended to densely
-        (`paged_attention`), its keys and values repeated to the query
-        head count where heads are grouped.
+        gets zeros. A prefill chunk: the slot's pages are walked up to
+        the chunk's last key, a block of pages at a time, the grouped
+        query heads side by side against each key/value head
+        (`paged_prefill_attention`); pages past it are never gathered.
 
         q is [B, T, n_head * d], k and v [B, T, n_kv_head * d]."""
         h, hk, d = self.mc.n_head, self.n_kv_head, self.mc.head_dim
@@ -372,15 +333,8 @@ class PagedKind:
                         q, k_pool, v_pool, li, tables, positions, live_len,
                         h, hk)
             else:
-                with jax.named_scope(SCOPE_KV_GATHER):
-                    kc = k_pool[li, tables][..., :c].reshape(b, -1, hk, d)
-                    vc = v_pool[li, tables][..., :c].reshape(b, -1, hk, d)
-                    if h != hk:
-                        kc = jnp.repeat(kc, h // hk, axis=2)
-                        vc = jnp.repeat(vc, h // hk, axis=2)
-                attn = paged_attention(q.reshape(b, t, h, d), kc, vc,
-                                       positions, kv_limit).reshape(
-                                           b, t, h * d)
+                attn = paged_prefill_attention(
+                    q, k_pool, v_pool, li, tables, positions, kv_limit, h, hk)
             return attn, (k_pool, v_pool)
         return mix
 
@@ -576,10 +530,10 @@ class PagedWindowKind:
     to the pool that is not the layer's diverted to scratch page 0
     like an idle slot's, and `lax.cond` picks the pool that is read.
     A few rows a slot: `paged_decode_attention`, with the first
-    visible key and the ring for a window layer. A prefill chunk: the
-    slot's table row gathered (for a window layer the ring, whose
-    columns' positions are reckoned from the chunk's last page) and
-    `paged_attention`."""
+    visible key and the ring for a window layer. A prefill chunk:
+    `paged_prefill_attention`, which walks a full layer's pages up to
+    the chunk's last key and a window layer's from the page of the
+    chunk's first visible key, through the ring."""
     keys = ("k_pool", "v_pool", "k_window", "v_window")
 
     def __init__(self, model_config, config, max_seq_len):
@@ -691,35 +645,15 @@ class PagedWindowKind:
                                        tables=tables))
                 return attn, (k_full, v_full, k_ring, v_ring)
 
-            def gathered(k_pool, v_pool, table):
-                with jax.named_scope(SCOPE_KV_GATHER):
-                    kc = k_pool[at, table][..., :c].reshape(b, -1, hk, d)
-                    vc = v_pool[at, table][..., :c].reshape(b, -1, hk, d)
-                    return (jnp.repeat(kc, h // hk, axis=2),
-                            jnp.repeat(vc, h // hk, axis=2))
-
-            def over_ring():
-                # the launch's last page is the highest the slot
-                # holds; column c holds the page below it that is
-                # congruent to c (released or never held: its keys lie
-                # below every query's first, or below 0)
-                top = (kv_limit // page)[:, None]
-                held = top - (top - jnp.arange(ring)[None, :]) % ring
-                k_pos = (held[:, :, None] * page +
-                         jnp.arange(page)[None, None, :]).reshape(b, -1)
-                return paged_attention(
-                    q.reshape(b, t, h, d),
-                    *gathered(k_ring, v_ring, ring_tables), positions,
-                    kv_limit, first=first, k_pos=k_pos)
-
-            def over_all():
-                return paged_attention(
-                    q.reshape(b, t, h, d),
-                    *gathered(k_full, v_full, tables), positions, kv_limit)
-
-            attn = jax.lax.cond(slides, over_ring, over_all)
-            return attn.reshape(b, t, h * d), (k_full, v_full, k_ring,
-                                               v_ring)
+            chunk = functools.partial(
+                paged_prefill_attention, q, li=at, q_pos=positions,
+                kv_limit=kv_limit, n_head=h, n_kv_head=hk)
+            attn = jax.lax.cond(
+                slides,
+                lambda: chunk(k_pool=k_ring, v_pool=v_ring,
+                              tables=ring_tables, first=first, ring=ring),
+                lambda: chunk(k_pool=k_full, v_pool=v_full, tables=tables))
+            return attn, (k_full, v_full, k_ring, v_ring)
         return mix
 
     def decode_mixer(self, state):
@@ -1160,7 +1094,8 @@ class InferenceEngine:
                 jnp.asarray(start, jnp.int32), jnp.asarray(n, jnp.int32))
             sp["dk_pool"], sp["dv_pool"] = dk, dv
         self._host_steps += 1
-        self._prefilled = (self._prefilled[0] + 1, self._prefilled[1] + n)
+        self._prefilled = self._prefilled + (
+            1, n, *self.cache.prefill_keys(start, n))
 
     def activate_slot(self, slot, cur_token, pos, max_new, temperature,
                       top_k, eos):
@@ -1323,7 +1258,11 @@ class InferenceEngine:
     def _forget_fences(self):
         """Nothing dispatched, nothing unfetched, no slot known."""
         self._launches = 0       # decode launches dispatched
-        self._prefilled = (0, 0)  # prefill launches, their prompt tokens
+        # prefill launches, their prompt tokens, and what their
+        # attention walked (`cache.prefill_keys`; a new array a launch:
+        # the blocks below keep the one they were dispatched behind)
+        self._prefilled = np.zeros(
+            (2 + len(self.cache.prefill_keys(0, 1)),), np.int64)
         # the blocks dispatched and unfetched, oldest first: (the
         # block's snapshot, `_launches` and `_prefilled` when it was
         # taken); the oldest goes when a caller that never fetches
@@ -1396,8 +1335,9 @@ class InferenceEngine:
                                         "n_gen", "out_tokens")}
             snap["blocks_in_flight"] = len(self._pending)
             # the prefill launches dispatched before the snapshot was
-            # taken and their prompt tokens, since the engine's reset
-            # (what the programs count of prefill is as old)
+            # taken, their prompt tokens and the keys they walked,
+            # since the engine's reset (what the programs count of
+            # prefill is as old)
             snap["prefilled"] = prefilled
             self._lay_activations_over(snap, taken_at)
             self._fenced_pos, self._fenced_at = snap["pos"], taken_at

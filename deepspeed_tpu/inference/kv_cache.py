@@ -61,6 +61,7 @@ import numpy as np
 
 from deepspeed_tpu.monitor import memory as memory_mod
 from deepspeed_tpu.ops.transformer.paged_decode_attention import padded_lanes
+from deepspeed_tpu.ops.transformer.paged_prefill_attention import walked_keys
 
 
 class PagedKVCache:
@@ -239,19 +240,40 @@ class PagedKVCache:
         return {"kv_pages_in_use": int(self.pages_in_use()),
                 "kv_pages_free": int(self.free_pages())}
 
+    # what `prefill_keys` counts, as the fence rows name it
+    PREFILL_KEYS = ("kv_prefill_keys_attended", "kv_prefill_keys_tabled")
+
+    def prefill_keys(self, start, n, first=0):
+        """(attended, tabled) of a prefill launch of `n` prompt tokens
+        from position `start`, in one layer of this pool: the keys its
+        attention walks (`paged_prefill_attention`: whole blocks of
+        pages from the page of `first` to the page of the launch's
+        last key), and the keys of the slot's whole table row, which a
+        launch gathered and attended to whatever the slot held before
+        ISSUE 42. Host arithmetic; the engine sums it over its
+        launches and `attended` reports a fence's."""
+        columns = self.max_pages_per_slot
+        return (walked_keys(start + n - 1, self.page_size, columns, first),
+                columns * self.page_size)
+
     def attended(self, active, pos, launches=0, advanced=0, prefill_rows=0,
-                 prefill_tokens=0):
+                 prefill_tokens=0, prefill_keys=()):
         """How far the decode kernel engages at the next launch, from
         what the fence fetched (`active`, `pos` of every slot; host
         arrays): the pages it walks, ceil((pos + 1) / page) summed
         over the live slots, and their share of the window the
         gathered path attended to whatever was live (max_slots x
-        max_pages_per_slot). The fence's own launches, decode's and
+        max_pages_per_slot). And how far prefill's attention engaged
+        over the fence just closed: the keys its launches walked
+        against the keys of their table rows (`prefill_keys`, summed
+        by the engine). The fence's own launches, decode's and
         prefill's, are not read here (recurrent state counts them)."""
         pages = int((-(-(pos[active] + 1) // self.page_size)).sum())
         return {"kv_pages_attended": pages,
                 "kv_pages_attended_share": round(
-                    pages / (self.max_slots * self.max_pages_per_slot), 4)}
+                    pages / (self.max_slots * self.max_pages_per_slot), 4),
+                **{k: int(v) for k, v in zip(self.PREFILL_KEYS,
+                                             prefill_keys)}}
 
     def ledger_occupancy(self):
         """`occupancy` with the utilization, as the serving tracker
@@ -418,6 +440,11 @@ class LatentKVCache(PagedKVCache):
         return dict(super().occupancy(),
                     kv_latent_bytes_resident=int(self.pool_bytes))
 
+    def prefill_keys(self, start, n):
+        """A chunk attends through `latent_attention`, which has
+        always followed the live length: nothing is counted."""
+        return ()
+
 
 class RecurrentStateCache:
     """Slot state that is a fixed block per request, not a page list:
@@ -510,8 +537,11 @@ class RecurrentStateCache:
 
     ledger_occupancy = occupancy       # the manager's own counters
 
+    def prefill_keys(self, start, n):
+        return ()
+
     def attended(self, active, pos, launches=0, advanced=0, prefill_rows=0,
-                 prefill_tokens=0):
+                 prefill_tokens=0, prefill_keys=()):
         """A model of state attends to no pages. What its decode
         kernel moved over the fence just closed: every launch streams
         every slot's state (`launches` x max_slots), and `advanced` of
@@ -644,6 +674,9 @@ class PagedStateCache:
         return {**self.pages.ledger_occupancy(),
                 **self.state.ledger_occupancy()}
 
+    def prefill_keys(self, start, n):
+        return self.pages.prefill_keys(start, n)
+
     def attended(self, *fence):
         return {**self.pages.attended(*fence), **self.state.attended(*fence)}
 
@@ -714,6 +747,12 @@ class RingKVCache(PagedKVCache):
 
     def pages_to_reserve(self, n_tokens_worst_case):
         return min(self.ring, self.pages_for_tokens(n_tokens_worst_case))
+
+    def prefill_keys(self, start, n):
+        """The walk begins at the page of the first query's first
+        visible key."""
+        return super().prefill_keys(start, n,
+                                    max(start - self.window + 1, 0))
 
     def reserved_tokens(self, slot):
         """A ring bounds the pages, not the tokens: the full layers'
@@ -846,15 +885,29 @@ class WindowedKVCache:
                 self.window.ledger_occupancy()["kv_pages_in_use"],
                 "kv_page_utilization": full["kv_page_utilization"]}
 
-    def attended(self, active, pos, *fence):
+    PREFILL_KEYS = PagedKVCache.PREFILL_KEYS + (
+        "kv_prefill_keys_window_attended", "kv_prefill_keys_window_tabled")
+
+    def prefill_keys(self, start, n):
+        """The full layers' (attended, tabled), then the window
+        layers'."""
+        return self.full.prefill_keys(start, n) + \
+            self.window.prefill_keys(start, n)
+
+    def attended(self, active, pos, launches=0, advanced=0, prefill_rows=0,
+                 prefill_tokens=0, prefill_keys=()):
         """The pages the decode kernel walks at the next launch: every
         page of a live slot in a full layer, the window's in a window
-        layer (one count a pool; a pool's layers walk alike)."""
+        layer (one count a pool; a pool's layers walk alike). And the
+        keys the fence's prefill launches walked against their table
+        rows' keys, a full layer's and a window layer's."""
         page = self.page_size
         last = pos[active] // page
         first = np.maximum(pos[active] - self.window.window + 1, 0) // page
         return {"kv_pages_attended": int((last + 1).sum()),
-                "kv_pages_window_attended": int((last - first + 1).sum())}
+                "kv_pages_window_attended": int((last - first + 1).sum()),
+                **{k: int(v) for k, v in zip(self.PREFILL_KEYS,
+                                             prefill_keys)}}
 
     def utilization_counter(self, occupancy):
         """The trace export's one track: the full layers' pool, which
@@ -978,8 +1031,13 @@ class HybridKVCache:
                 self.window.ledger_occupancy()["kv_pages_in_use"],
                 "kv_page_utilization": shared["kv_page_utilization"]}
 
+    def prefill_keys(self, start, n):
+        """Prefill only WRITES the shared pool, and its rings go
+        through `diff_attention`: nothing is counted."""
+        return ()
+
     def attended(self, active, pos, launches=0, advanced=0, prefill_rows=0,
-                 prefill_tokens=0):
+                 prefill_tokens=0, prefill_keys=()):
         """What the next decode launch reads: every page of a live
         slot once a reading layer, the window's pages once a window
         layer; what the fence's launches took through the state; and

@@ -109,10 +109,11 @@ class ServingLoop:
         # first: (host dispatch stamp, launches), the serving tracker's
         # per-fence decode window
         self._dispatched = deque()
-        # the engine's prefill launches and the prompt tokens they
-        # covered as of the last fence's snapshot (cumulative like the
-        # programs' counts below: the fence rows take the difference)
-        self._last_prefilled = (0, 0)
+        # the engine's prefill launches, the prompt tokens they covered
+        # and the keys they walked as of the last fence's snapshot
+        # (`engine._prefilled`, cumulative like the programs' counts
+        # below: the fence rows take the difference)
+        self._last_prefilled = 0
         # what the programs count (`engine.fetch_state`'s "counts":
         # the decode launches that drew a sample, the model's block's
         # counters): cumulative on the device, so the fence diffs them
@@ -434,15 +435,16 @@ class ServingLoop:
         # next launch, or for recurrent state the slots it streamed
         # and advanced over this fence's launches, and the rows its
         # prefill launches took through a slot's state against the
-        # prompt tokens among them; host arithmetic on what the fence
+        # prompt tokens among them, and the keys their attention walked
+        # against their table rows'; host arithmetic on what the fence
         # already holds
-        prefill_launches, prefill_tokens = np.subtract(
-            snap["prefilled"], self._last_prefilled)
+        prefill_launches, prefill_tokens, *prefill_keys = \
+            snap["prefilled"] - self._last_prefilled
         self._last_prefilled = snap["prefilled"]
         engaged = self._infer.cache.attended(
             snap["active"], snap["pos"], iterations, new_tokens,
             prefill_launches * self._infer.config.prefill_chunk,
-            prefill_tokens)
+            prefill_tokens, prefill_keys)
         mon.event(
             "decode_batch",
             # the loop's clock, on which requests arrive

@@ -27,11 +27,13 @@ SCOPE_LAYERS = "layers"            # round the lax.scan call, nothing else
 SCOPE_ATTN_QKV = "attn_qkv"        # inside a layer: the norm + the q/k/v
 #                                    (and gate) projections, q/k-norm, rotary
 SCOPE_KV_WRITE = "kv_write"        # the chunk's K/V into the page pool
-SCOPE_KV_GATHER = "kv_gather"      # a prefill chunk's page window through
-#                                    the tables (decode gathers nothing)
+SCOPE_KV_GATHER = "kv_gather"      # a prefill chunk's live pages through its
+#                                    table row, a block of pages at a time
+#                                    (decode gathers nothing)
 SCOPE_ATTN = "attn"                # a few rows a slot: the kernel that reads
 #                                    the pages where they lie; a prefill
-#                                    chunk: paged_attention on its window
+#                                    chunk: paged_prefill_attention's products
+#                                    and online softmax, a block at a time
 SCOPE_ATTN_OUT = "attn_out"        # output projection + residual
 SCOPE_MLP = "mlp"                  # norm, feed-forward, residual
 SCOPE_HEAD = "head"                # final norm + output head (tied to the

@@ -7,10 +7,10 @@ over the layers, each with its OWN page pool ([P, page, lanes], the
 engine's row of a token), written with `.at[phys, off].set`.
 Everything between the write and the block's output is the program's
 own (`models/gpt2.py`'s `_ln_apply` and `_dense_apply`, and the
-program's own attention entry: for a few query rows a slot `paged_decode_attention` on the
-layer's pool as a one-layer pool, for a prefill chunk the gathered
-window and `paged_attention`), so a difference is the carry's or the
-scatter's. One jitted layer is called n_layer times: the same compiled
+program's own attention entry on the layer's pool as a one-layer pool:
+for a few query rows a slot `paged_decode_attention`, for a prefill
+chunk `paged_prefill_attention`), so a difference is the carry's or
+the scatter's. One jitted layer is called n_layer times: the same compiled
 code for every layer, as in a scan's body."""
 
 import contextlib
@@ -24,11 +24,72 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.engine import DECODE_ROWS_MAX, paged_attention
+from deepspeed_tpu.inference.engine import DECODE_ROWS_MAX
+from deepspeed_tpu.ops.transformer.flash_attention import NEG_INF
 from deepspeed_tpu.ops.transformer.paged_decode_attention import \
     paged_decode_attention
+from deepspeed_tpu.ops.transformer.paged_prefill_attention import \
+    paged_prefill_attention
 from deepspeed_tpu.models.gpt2 import (_dense_apply, _ln_apply,
                                        stacked_block_params)
+
+
+# ----------------------------------------------------------------------
+# a prefill chunk's attention in its plain form: what the programs ran
+# before ISSUE 42 (the slot's WHOLE table row gathered, keys and values
+# repeated to the query head count, one dense masked softmax), kept as
+# the reference `paged_prefill_attention` is held against
+# ----------------------------------------------------------------------
+def paged_attention(q, kc, vc, q_pos, kv_limit, first=None, k_pos=None):
+    """Causal attention of q [B, Tq, H, D] against a gathered page
+    window kc/vc [B, Tk, H, D], phrased like the training path's
+    `dense_attention` (fp32 softmax, -1e30 where-masking): key
+    positions are their indices, queries sit at absolute positions
+    `q_pos` [B, Tq], and keys beyond `kv_limit` [B] are value-zeroed.
+    A lower bound: a query sees no key below `first` [B, Tq], and keys
+    below the earliest query's are value-zeroed. A window gathered
+    through a ring of pages does not lie in the order of its
+    positions: `k_pos` [B, Tk] then gives each key's (negative: no
+    key)."""
+    sm_scale = 1.0 / np.sqrt(q.shape[-1])
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, kc).astype(jnp.float32)
+    scores = scores * sm_scale
+    kpos = jnp.arange(kc.shape[1])[None, :] if k_pos is None else k_pos
+    mask = kpos[:, None, None, :] <= q_pos[:, None, :, None]
+    v_ok = kpos <= kv_limit[:, None]
+    if first is not None:
+        mask = mask & (kpos[:, None, None, :] >= first[:, None, :, None])
+        v_ok = v_ok & (kpos >= first[:, :1])
+    scores = jnp.where(mask, scores, jnp.float32(NEG_INF))
+    probs = jax.nn.softmax(scores, axis=-1).astype(vc.dtype)
+    vc = jnp.where(v_ok[:, :, None, None], vc, jnp.zeros((), vc.dtype))
+    out = jnp.matmul(probs, vc.transpose(0, 2, 1, 3))
+    return out.transpose(0, 2, 1, 3)
+
+
+def dense_prefill_attention(q, k_pool, v_pool, li, tables, q_pos, kv_limit,
+                            n_head, n_kv_head, first=None, ring=0):
+    """`paged_prefill_attention`'s arguments through the plain form:
+    the whole table row gathered, a ring's columns given the positions
+    of the pages they hold (reckoned from the chunk's last page),
+    grouped heads repeated."""
+    b, t, _ = q.shape
+    page = k_pool.shape[2]
+    d = q.shape[-1] // n_head
+    c = n_kv_head * d
+    group = n_head // n_kv_head
+    kc = k_pool[li, tables][..., :c].reshape(b, -1, n_kv_head, d)
+    vc = v_pool[li, tables][..., :c].reshape(b, -1, n_kv_head, d)
+    k_pos = None
+    if ring:
+        top = (kv_limit // page)[:, None]
+        held = top - (top - jnp.arange(ring)[None, :]) % ring
+        k_pos = (held[:, :, None] * page +
+                 jnp.arange(page)[None, None, :]).reshape(b, -1)
+    return paged_attention(
+        q.reshape(b, t, n_head, d), jnp.repeat(kc, group, axis=2),
+        jnp.repeat(vc, group, axis=2), q_pos, kv_limit, first=first,
+        k_pos=k_pos).reshape(b, t, n_head * d)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "page_size",
@@ -54,10 +115,8 @@ def _oracle_block(cfg, lp, hidden, kl, vl, tables, positions, valid,
         attn = paged_decode_attention(q, kl[None], vl[None], 0, tables,
                                       positions, live_len, h)
     else:
-        kc = kl[tables][..., :c].reshape(b, -1, h, d)
-        vc = vl[tables][..., :c].reshape(b, -1, h, d)
-        attn = paged_attention(q.reshape(b, t, h, d), kc, vc, positions,
-                               kv_limit).reshape(b, t, c)
+        attn = paged_prefill_attention(q, kl[None], vl[None], 0, tables,
+                                       positions, kv_limit, h, h)
     attn = _dense_apply(cfg, lp["c_proj"], attn)
     hidden = hidden + attn
     y = _ln_apply(cfg, lp["ln_2"], hidden).astype(cfg.dtype)

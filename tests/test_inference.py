@@ -111,35 +111,6 @@ def test_decode_logits_roundoff_parity_long(setup):
     engine.reset()
 
 
-def test_paged_attention_matches_contiguous_reference():
-    """Unit: paged_attention (the window path prefill keeps) over a
-    zero-padded page window against dense_attention over the
-    contiguous cache, within float32 roundoff: the padded reduction
-    sums exact zeros for the masked keys, in whatever blocking the
-    backend picks for the longer row."""
-    from deepspeed_tpu.inference.engine import paged_attention
-    from deepspeed_tpu.ops.transformer.flash_attention import \
-        dense_attention
-    r = np.random.RandomState(3)
-    for t in (10, 48):
-        q = r.randn(1, t, 4, 16).astype(np.float32)
-        k = r.randn(1, t, 4, 16).astype(np.float32)
-        v = r.randn(1, t, 4, 16).astype(np.float32)
-        tmax = 64
-        kc = np.zeros((1, tmax, 4, 16), np.float32)
-        vc = r.randn(1, tmax, 4, 16).astype(np.float32)  # garbage tail
-        kc[:, :t] = k
-        vc[:, :t] = v
-        ref = np.asarray(jax.jit(
-            lambda q, k, v: dense_attention(q, k, v, causal=True))(
-                q, k, v))
-        got = np.asarray(jax.jit(paged_attention)(
-            q, jnp.asarray(kc), jnp.asarray(vc),
-            np.arange(t, dtype=np.int32)[None, :],
-            np.asarray([t - 1], np.int32)))
-        np.testing.assert_allclose(ref, got, atol=2e-6, rtol=0)
-
-
 # ----------------------------------------------------------------------
 # paged cache accounting vs independent byte arithmetic
 # ----------------------------------------------------------------------

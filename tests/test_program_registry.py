@@ -261,8 +261,6 @@ def test_decode_logits_bitequal_with_and_without_the_scopes(
                programs.op_scopes("jit_decode_fn").values())
     with monkeypatch.context() as m:
         m.setattr(jax, "named_scope", NoScope)
-        m.setattr(engine_mod, "paged_attention",
-                  engine_mod.paged_attention.__wrapped__)
         bare = build(model_and_params)
     assert not any(regions(s) for s in
                    programs.op_scopes("jit_decode_fn").values())

@@ -1,10 +1,10 @@
 """A lower bound on the keys a query sees (ISSUE 35): the decode
-kernel with a first visible key a query row and a ring of pages, and a
-prefill chunk's `paged_attention` with `first` and the keys' own
-positions, each against a dense masked oracle in float32 numpy; and
-with no bound given, both against what they gave before at GPT-2's and
-Falcon-H1's shapes (a bound of 0 through the straight table is the
-kernel without one, bit for bit).
+kernel with a first visible key a query row and a ring of pages,
+against a dense masked oracle in float32 numpy; and with no bound
+given, against what it gave before at GPT-2's and Falcon-H1's shapes (a
+bound of 0 through the straight table is the kernel without one, bit
+for bit). A prefill chunk's bound and ring: `test_paged_prefill_
+attention.py`, where ISSUE 42 moved this file's two chunk tests.
 
 The ring. A window layer's table has `ring` columns and logical page p
 of a slot lies in column p % ring; pages behind the window have been
@@ -21,7 +21,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.engine import paged_attention
 from deepspeed_tpu.inference.kv_cache import ring_columns
 from deepspeed_tpu.ops.transformer.paged_decode_attention import (
     padded_lanes, paged_decode_attention)
@@ -176,73 +175,3 @@ def test_no_bound_is_the_kernel_as_it_was(n_head, n_kv_head, head_dim, page,
                "pallas_call"]
     # li, tables, lens, q_pos; q, the two pools
     assert len(call.invars) == 7
-
-
-# ----------------------------------------------------------------------
-# a prefill chunk
-# ----------------------------------------------------------------------
-def chunk_case(h, hk, d, t_q, n_keys, start, seed, shuffled):
-    rng = np.random.default_rng(seed)
-    q = rng.normal(size=(1, t_q, h, d)).astype(np.float32)
-    k = rng.normal(size=(1, n_keys, hk, d)).astype(np.float32)
-    v = rng.normal(size=(1, n_keys, hk, d)).astype(np.float32)
-    q_pos = (start + np.arange(t_q))[None].astype(np.int32)
-    # the keys' positions: the straight order, or a ring's (rotated,
-    # with columns that hold no key: negative)
-    k_pos = np.arange(n_keys)
-    if shuffled:
-        k_pos = np.roll(k_pos + start + t_q - n_keys, seed % n_keys)
-    return q, k, v, q_pos, k_pos[None].astype(np.int32)
-
-
-def dense_chunk(q, k, v, q_pos, k_pos, first, kv_limit):
-    _, t_q, h, d = q.shape
-    group = h // k.shape[2]
-    out = np.zeros((1, t_q, h, d), np.float32)
-    for r in range(t_q):
-        seen = (k_pos[0] <= q_pos[0, r]) & (k_pos[0] >= first[0, r]) & \
-            (k_pos[0] <= kv_limit[0]) & (k_pos[0] >= 0)
-        for j in range(h):
-            scores = k[0, seen, j // group] @ q[0, r, j] / np.sqrt(d)
-            p = np.exp(scores - scores.max())
-            out[0, r, j] = (p / p.sum()) @ v[0, seen, j // group]
-    return out
-
-
-@pytest.mark.parametrize("shuffled", [False, True], ids=["straight", "ring"])
-@pytest.mark.parametrize("start", [0, 5, 40])
-def test_prefill_attention_with_a_first_visible_key(start, shuffled):
-    h, hk, d, t_q, window = 6, 2, 8, 16, 24
-    n_keys = 64
-    q, k, v, q_pos, k_pos = chunk_case(h, hk, d, t_q, n_keys, start,
-                                       3 + start, shuffled)
-    first = np.maximum(q_pos - window + 1, 0)
-    kv_limit = q_pos[:, -1]
-    # what lies past the limit or below every query's first holds
-    # garbage and must contribute exactly nothing
-    dead = (k_pos[0] > kv_limit[0]) | (k_pos[0] < first[0, 0])
-    k[0, dead] = GARBAGE
-    v[0, dead] = np.inf
-    rep = lambda x: jnp.repeat(jnp.asarray(x), h // hk, axis=2)
-    got = paged_attention(jnp.asarray(q), rep(k), rep(v), jnp.asarray(q_pos),
-                          jnp.asarray(kv_limit), first=jnp.asarray(first),
-                          k_pos=jnp.asarray(k_pos) if shuffled else None)
-    want = dense_chunk(q, k, v, q_pos, k_pos, first, kv_limit)
-    assert np.isfinite(np.asarray(got)).all()
-    np.testing.assert_allclose(np.asarray(got), want, atol=1e-5, rtol=0)
-
-
-@pytest.mark.parametrize("h, d", [(25, 64), (20, 128)],
-                         ids=["gpt2", "falcon_h1"])
-def test_prefill_attention_without_a_bound_is_as_it_was(h, d):
-    """No `first`, no `k_pos`: the causal mask over keys in order, as
-    before; a bound of 0 masks nothing more, bit for bit."""
-    q, k, v, q_pos, k_pos = chunk_case(h, h, d, 16, 48, 20, 11, False)
-    kv_limit = q_pos[:, -1]
-    args = (jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
-            jnp.asarray(q_pos), jnp.asarray(kv_limit))
-    plain = paged_attention(*args)
-    want = dense_chunk(q, k, v, q_pos, k_pos, np.zeros_like(q_pos), kv_limit)
-    np.testing.assert_allclose(np.asarray(plain), want, atol=1e-5, rtol=0)
-    bounded = paged_attention(*args, first=jnp.zeros_like(args[3]))
-    assert np.array_equal(np.asarray(plain), np.asarray(bounded))
