@@ -60,6 +60,7 @@ refuses). `ensure` is told where the coming launches' queries begin
 import numpy as np
 
 from deepspeed_tpu.monitor import memory as memory_mod
+from deepspeed_tpu.ops.transformer import latent_attention
 from deepspeed_tpu.ops.transformer.paged_decode_attention import padded_lanes
 from deepspeed_tpu.ops.transformer.paged_prefill_attention import walked_keys
 
@@ -441,9 +442,16 @@ class LatentKVCache(PagedKVCache):
                     kv_latent_bytes_resident=int(self.pool_bytes))
 
     def prefill_keys(self, start, n):
-        """A chunk attends through `latent_attention`, which has
-        always followed the live length: nothing is counted."""
-        return ()
+        """(attended, tabled) of a prefill launch, under the paged
+        cache's names: the keys of the whole blocks from the slot's
+        first key to the launch's last, which both latent forms walk
+        (`latent_attention.walked_keys`; what the kernel's query tiles
+        skip inside that extent is not taken off), and the keys of the
+        slot's table row. Host arithmetic."""
+        columns = self.max_pages_per_slot
+        return (latent_attention.walked_keys(
+            start + n - 1, self.page_size, columns),
+            columns * self.page_size)
 
 
 class RecurrentStateCache:

@@ -29,16 +29,17 @@ class PagedLatentKind:
     not an idle slot's, not a chunk's pad rows), which the block keeps
     out of its experts' products.
 
-    One row a slot (decode): `latent_decode_attention`, a kernel that
-    walks each live slot's table and reads the pages where they lie,
-    the page block keys and values at once; off a TPU the XLA form
-    (`latent.usable`: ISSUE 39 keeps the kernel's oracle as the CPU's
-    path, where `PagedKind` interprets its kernel; a test runs this
-    kernel interpreted through the engine). A prefill chunk:
-    `latent_attention`, the slot's rows gathered through its table row a block of pages at a time as
-    far as the chunk's last key (absorbed like decode: the products
-    are 3.4 times the expanded form's and nothing is expanded; PERF.md
-    has the chip's reading of both)."""
+    Both programs attend through a kernel that walks the slot's table
+    and reads the pages where they lie, the page block keys and values
+    at once: one row a slot (decode) through `latent_decode_attention`,
+    a prefill chunk through `latent_prefill_attention` (the 64 heads'
+    rows of 16 tokens a query tile, scores and accumulator in VMEM;
+    absorbed like decode: the products are 3.4 times the expanded
+    form's and nothing is expanded; PERF.md has the chip's reading of
+    both). Off a TPU both take the XLA form `latent_attention`
+    (`latent.usable`: ISSUE 39 keeps the kernels' oracle as the CPU's
+    path, where `PagedKind` interprets its kernel; tests run both
+    kernels interpreted through the engine)."""
     keys = ("latent_pool",)
 
     def __init__(self, model_config, config, max_seq_len):
@@ -85,13 +86,18 @@ class PagedLatentKind:
                     row.reshape(b * t, width),
                     ((0, 0), (0, pool.shape[-1] - width))).astype(pool.dtype))
             live_len = jnp.where(valid.any(axis=1), kv_limit + 1, 0)
-            if t == 1 and latent.usable():
-                with jax.named_scope(SCOPE_ATTN):
-                    o = latent.latent_decode_attention(
-                        q[:, 0], pool, li, tables, live_len, rank)[:, None]
-            else:
+            if not latent.usable():
                 o = latent.latent_attention(q, pool, li, tables, positions,
                                             live_len, rank)
+            else:
+                with jax.named_scope(SCOPE_ATTN):
+                    if t == 1:
+                        o = latent.latent_decode_attention(
+                            q[:, 0], pool, li, tables, live_len,
+                            rank)[:, None]
+                    else:
+                        o = latent.latent_prefill_attention(
+                            q, pool, li, tables, positions, live_len, rank)
             return o, (pool,), valid
         return mix
 

@@ -567,10 +567,10 @@ def test_the_latent_pool_rides_both_scans_in_place_on_a_v5e(sarvam_scans,
                                                             program):
     """3.77 GB of latent rows ride in the carry of both layer scans
     (the dense layer's and the expert layers'): the donated pool is
-    the output, nothing pool-shaped is copied, Mosaic takes the latent
-    decode kernel (once in each scan's body) and the grouped product
-    over a SHARE of the experts (three calls in the expert layers'
-    body), no layer's held experts are sliced out of the stack, and
+    the output, nothing pool-shaped is copied, Mosaic takes the
+    program's latent kernel (once in each scan's body) and the grouped
+    product over a SHARE of the experts (three calls in the expert
+    layers' body), no layer's held experts are sliced out of the stack, and
     `mla_absorb` and every region of the expert layer survive the
     chip's fusions."""
     from benchmark import mla_costs, region_join
@@ -604,11 +604,30 @@ def test_the_latent_pool_rides_both_scans_in_place_on_a_v5e(sarvam_scans,
         assert memory.temp_size_in_bytes < 16 << 20
         assert "kv_gather" not in regions
     else:
-        assert len(calls) == 3
-        # the float32 scores of 512 rows x 64 heads against a block of
-        # 512 keys, not against the 10,752-key window
-        assert memory.temp_size_in_bytes < 1 << 30
-        assert "kv_gather" in regions
+        # gate, up, down; the prefill kernel in each of the two scans
+        assert len(calls) == 5
+        assert "kv_gather" not in regions
+
+
+def test_a_latent_chunk_keeps_its_scores_on_the_chip_on_a_v5e(sarvam_scans):
+    """Mosaic takes `latent_prefill_attention` at the cell's shapes
+    (the 640-lane page block sliced to 512 value lanes, the page
+    copies, a contraction over the pool's 640 lanes), once in each
+    scan's body; no page of the pool is gathered through the table and
+    no float32 array has the chunk's 32,768 query rows (the XLA form's
+    scores, probabilities' sums and accumulator [32768, 512], 67 MB
+    each, which it wrote out every block): the program's temporaries
+    are 48 MB where they were 137 (ISSUE 43)."""
+    compiled, pool = sarvam_scans("prefill")
+    text = compiled.as_text()
+    kernels = re.findall(
+        r'%latent_prefill_attention\S* = bf16\[1,32768,512\]\S* '
+        r'custom-call\(.*custom_call_target="tpu_custom_call"', text)
+    assert len(kernels) == 2
+    page = ",".join(map(str, pool.shape[2:]))
+    assert re.findall(rf"= \w+\[(?:\d+,)*{page}\]\S* gather\(", text) == []
+    assert re.findall(r"f32\[(?:\d+,)*32768[,\]]", text) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 # ----------------------------------------------------------------------

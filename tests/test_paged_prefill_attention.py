@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.inference.kv_cache import ring_columns
+from deepspeed_tpu.ops.transformer import latent_attention
 from deepspeed_tpu.ops.transformer import paged_prefill_attention as ppa
 from deepspeed_tpu.ops.transformer.paged_decode_attention import \
     padded_lanes
@@ -311,12 +312,16 @@ def tiny_engine(kind, monkeypatch):
     from deepspeed_tpu.inference import InferenceEngine
     from paged_oracle import traced_programs
     monkeypatch.setattr(ppa, "BLOCK_KEYS", BLOCK)
+    monkeypatch.setattr(latent_attention, "_BLOCK_KEYS", BLOCK)
     if kind == "paged":
         from deepspeed_tpu.models.gpt2 import (GPT2ForCausalLM,
                                                tiny_gpt2_config)
         cfg = tiny_gpt2_config()
         params = GPT2ForCausalLM(cfg).init(
             jax.random.PRNGKey(0), {"input_ids": np.zeros((1, 8), np.int32)})
+    elif kind == "paged+latent":
+        import test_sarvam_mla
+        _, cfg, params, _ = test_sarvam_mla.tiny(4)
     else:
         import test_falcon_h1
         import test_trinity
@@ -325,7 +330,7 @@ def tiny_engine(kind, monkeypatch):
                               jnp.float32)
     with traced_programs() as jaxprs:
         engine = InferenceEngine(cfg, params, {"inference": SERVE})
-    assert getattr(engine.cache, "kind", "paged") == kind
+    assert engine.serving.mc.cache_kind == kind
     return engine, jaxprs
 
 
@@ -373,11 +378,13 @@ def keys_by_hand(start, n, page, columns, window=0):
     return -(-pages // bp) * bp * page
 
 
-@pytest.mark.parametrize("kind", ["paged", "paged+window"])
+@pytest.mark.parametrize("kind", ["paged", "paged+window", "paged+latent"])
 def test_fence_rows_count_the_keys_prefill_walked(kind, monkeypatch):
     """`kv_prefill_keys_attended` / `kv_prefill_keys_tabled` (and the
     window pool's pair) over the fence rows of a short serving run
-    against the schedule's own (start, n) pairs, by hand."""
+    against the schedule's own (start, n) pairs, by hand; the latent
+    kind's one pool counts under the same names, in blocks of the
+    latent forms' own size."""
     from deepspeed_tpu.inference import Request, ServingLoop
     engine, _ = tiny_engine(kind, monkeypatch)
     page = SERVE["kv_cache"]["page_size"]

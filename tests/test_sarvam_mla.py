@@ -251,6 +251,111 @@ def test_a_chunks_rows_see_their_own_past_only():
         np.testing.assert_allclose(got[:, t], want, atol=5e-6)
 
 
+def chunk_case(lens, starts, t, dtype=f32, heads=64, width=40, rank=32,
+               page=128, seed=0):
+    """A chunk of `t` rows a slot from position starts[b], of which the
+    rows below lens[b] are the request's, over pages of 128: the
+    kernel's blocks are then the cell's, 512 keys, and 64 heads make a
+    query tile 16 tokens."""
+    rng = np.random.default_rng(seed)
+    b, max_pages = len(lens), -(-max(max(lens), 1) // page) + 1
+    pool = jnp.asarray(rng.normal(size=(2, b * max_pages + 2, page, 128)),
+                       f32).at[..., width:].set(0).astype(dtype)
+    tables = jnp.asarray(1 + rng.permutation(b * max_pages).reshape(
+        b, max_pages), jnp.int32)
+    q = jnp.asarray(rng.normal(size=(b, t, heads, width)), f32).astype(dtype)
+    pos = jnp.asarray(starts, jnp.int32)[:, None] + jnp.arange(t)[None]
+    return q, pool, tables, pos, jnp.asarray(lens, jnp.int32), rank
+
+
+def requests_rows(x, pos, lens):
+    """x [B, T, ...] with the rows at or past their slot's length (a
+    chunk's pad rows) zeroed."""
+    live = np.asarray(pos) < np.asarray(lens)[:, None]
+    return np.where(live[:, :, None, None], np.asarray(x, np.float32), 0)
+
+
+@pytest.mark.parametrize("dtype", [f32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("lens, starts, t", [
+    ((24,), (0,), 24), ((205,), (200,), 5), ((560,), (520,), 40),
+    ((1348,), (1300,), 48), ((1030,), (1000,), 48), ((3,), (0,), 40),
+    ((0, 700, 0, 1100), (0, 660, 0, 1060), 40)],
+    ids=["from-0", "mid-page-short-of-a-tile", "past-a-block-three-tiles",
+         "past-blocks", "pad-rows-across-a-block", "pad-tiles",
+         "idle-among-live"])
+def test_prefill_kernel_equals_the_xla_form(lens, starts, t, dtype):
+    """`latent_prefill_attention` (interpreted) against
+    `latent_attention` on a chunk's rows: the same online softmax over
+    the same blocks of 512 keys, so float32 agrees to the last bits of
+    a sum taken in another order and bfloat16 to one rounding of the
+    output. A chunk's pad rows and a slot of length 0 read zeros."""
+    q, pool, tables, pos, lens, rank = chunk_case(lens, starts, t, dtype)
+    got = latent.latent_prefill_attention(q, pool, jnp.asarray(1), tables,
+                                          pos, lens, rank, interpret=True)
+    want = latent.latent_attention(q, pool, 1, tables, pos, lens, rank)
+    assert got.dtype == dtype and got.shape == want.shape
+    want = requests_rows(want, pos, lens)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), want,
+        atol=5e-6 if dtype == f32 else 2 ** -8 * np.abs(want).max())
+    assert np.abs(want).max() > 0.1
+
+
+def test_the_prefill_kernel_reads_nothing_past_the_length():
+    """What lies past the slot's length (the tail of its last page,
+    the table's other pages) and in pages the table does not name may
+    be anything, not finite either."""
+    q, pool, tables, pos, lens, rank = chunk_case((1030,), (1000,), 48)
+    call = lambda p: latent.latent_prefill_attention(
+        q, p, jnp.asarray(0), tables, pos, lens, rank, interpret=True)
+    want = call(pool)
+    dirty = np.full(pool.shape, np.nan, np.float32)
+    named = np.asarray(tables[0])
+    rows = np.array(pool[0, named]).reshape(-1, 128)
+    rows[1030:] = np.inf
+    dirty[0, named] = rows.reshape(-1, 128, 128)
+    np.testing.assert_array_equal(call(jnp.asarray(dirty)), want)
+
+
+@pytest.mark.parametrize("start, valid", [
+    (0, 512), (2048, 512), (4096, 200), (1000, 512), (8192 - 512, 512)],
+    ids=["first-chunk", "aligned", "last-chunk-with-pad-rows", "unaligned",
+         "longest"])
+def test_a_tiles_walk_on_host_numbers(start, valid):
+    """`tile_walk` by hand in the cell's geometry (pages of 128, four a
+    block, 16 tokens a tile of a chunk of 512): a tile copies the pages
+    up to the one that holds its last token's position (or the slot's
+    last key) and no page past it, walks whole only blocks that lie
+    below its first token, and a tile of pad rows alone copies
+    nothing."""
+    page, npb, tq, chunk = 128, latent.block_pages(84, 128), \
+        latent.tile_tokens(512, 64), 512
+    assert (npb, tq) == (4, 16)
+    length, walked = start + valid, 0
+    for first in range(start, start + chunk, tq):
+        low, high = first, first + tq - 1
+        extent, n_pages, n_blocks, whole = (int(x) for x in latent.tile_walk(
+            low, high, length, page, npb))
+        if low >= length:
+            assert (extent, n_pages, n_blocks) == (0, 0, 0)
+            continue
+        last = min(high, length - 1)
+        assert extent == last + 1 and n_pages == last // page + 1
+        assert n_blocks == last // (npb * page) + 1
+        # whole blocks end at or below the tile's first position
+        assert whole * npb * page <= low + 1 < (whole + 1) * npb * page + 1
+        assert whole <= n_blocks
+        walked += n_pages * page
+    # against the launch's extent in whole blocks, which the fence rows
+    # count for every tile (`walked_keys`): an aligned chunk's tiles
+    # walk 320 of its own 512 keys on average
+    full = latent.walked_keys(length - 1, page, 84) * (chunk // tq)
+    assert walked < full
+    if start % 512 == 0 and valid == chunk:
+        assert walked == (start + 320) * (chunk // tq)
+
+
 @pytest.mark.parametrize("held", [16, 4])
 def test_prefill_in_chunks_then_decode_equals_the_reference(held):
     """42 prompt tokens are two whole launches of 16 and one of 10
@@ -308,6 +413,35 @@ def test_decode_through_the_kernel_equals_the_xla_form(monkeypatch):
                          for _ in range(5)])
 
     with_kernel, without = logits(True), logits(False)
+    np.testing.assert_allclose(with_kernel[:, (0, 2)], without[:, (0, 2)],
+                               atol=2e-6)
+
+
+def test_prefill_through_the_kernel_equals_the_xla_form(monkeypatch):
+    """The engine's chunked prefill AND decode with both kernels taken
+    (interpreted) against both through the XLA form: 23 prompt tokens
+    are a whole launch of 16 and one of 7 with pad rows behind them,
+    into pages of 4; the prefill program holds the kernel where it
+    held the gathered loop."""
+    from paged_oracle import equations, traced_programs
+    _, cfg, params, _ = tiny(4)
+    ids = np.random.default_rng(6).integers(0, VOCAB, 60).astype(np.int32)
+
+    def logits(usable):
+        monkeypatch.setattr(latent, "usable", lambda: usable)
+        with traced_programs() as jaxprs:
+            engine = InferenceEngine(cfg, params, {"inference": BLOCK})
+        calls = [str(e.params.get("name", e.params.get("name_and_src_info")))
+                 for e in equations(jaxprs["prefill_fn"])
+                 if e.primitive.name == "pallas_call"]
+        engine.start_request(0, ids[:23], 8)
+        engine.start_request(2, ids[:58], 8)
+        return calls, np.stack([np.asarray(engine.decode_once())
+                                for _ in range(3)])
+
+    (calls, with_kernel), (none, without) = logits(True), logits(False)
+    assert none == [] and calls and all(
+        "latent_prefill_attention" in c for c in calls)
     np.testing.assert_allclose(with_kernel[:, (0, 2)], without[:, (0, 2)],
                                atol=2e-6)
 
