@@ -3,7 +3,7 @@
 The baseline file is a JSON document mapping finding fingerprints to
 their human-readable record — rule, location, message — so the tree
 lints clean from day one while every NEW finding still fails CI (the
-same trick the bench smoke tests use for perf numbers).
+trick of a ratchet: what stands is recorded, what is new fails).
 
 Fingerprints hash (rule, relative path, enclosing qualname,
 normalized source line text) — NOT line numbers — so edits elsewhere

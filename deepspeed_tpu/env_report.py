@@ -150,7 +150,7 @@ def feature_report():
             "kernel autotuner",
             f"{SUCCESS} block-size table at "
             f"{_autotune.table_path()} (autotune block; "
-            "bench.py --only autotune_flash)"))
+            "docs/quantized-compute.md)"))
     except Exception as e:  # ds-lint: allow[BROADEXC] environment probe: the failure text IS the report row
         rows.append(("kernel autotuner", f"{FAIL} {e}"))
     try:
@@ -225,8 +225,7 @@ def feature_report():
             "speculative decoding",
             f"{SUCCESS} draft propose + batched verify, lossless "
             "acceptance sampling, paged-KV rollback, adaptive k "
-            "(inference.speculative; bench.py --only "
-            "speculative_decode; docs/inference.md)"))
+            "(inference.speculative; docs/inference.md)"))
     except Exception as e:  # ds-lint: allow[BROADEXC] environment probe: the failure text IS the report row
         rows.append(("speculative decoding", f"{FAIL} {e}"))
     try:
@@ -244,7 +243,7 @@ def feature_report():
             "comm/compute overlap",
             f"{SUCCESS} async-collective scheduling at "
             f"{', '.join(_overlap.SITES)} (overlap block; "
-            "bench.py --only comm_overlap; docs/overlap.md)"))
+            "docs/overlap.md)"))
     except Exception as e:  # ds-lint: allow[BROADEXC] environment probe: the failure text IS the report row
         rows.append(("comm/compute overlap", f"{FAIL} {e}"))
     try:
@@ -253,7 +252,7 @@ def feature_report():
             "fused MoE dispatch",
             f"{SUCCESS} Pallas gather-scatter dispatch/combine "
             "kernels over capacity-indexed rows (moe.fused_dispatch; "
-            "bench.py --only moe_dispatch_kernel)"))
+            "docs/overlap.md)"))
     except Exception as e:  # ds-lint: allow[BROADEXC] environment probe: the failure text IS the report row
         rows.append(("fused MoE dispatch", f"{FAIL} {e}"))
     try:

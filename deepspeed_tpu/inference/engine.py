@@ -938,7 +938,7 @@ class InferenceEngine:
         }
 
     def reset(self):
-        """Drop all slots and cached pages (bench A/B hygiene)."""
+        """Drop all slots and cached pages (between two runs)."""
         for slot in self.cache.slots():
             self.cache.free(slot)
         # the old cache goes before the new one is made: a retention
@@ -1127,7 +1127,7 @@ class InferenceEngine:
     def start_request(self, slot, prompt, max_new, temperature=0.0,
                       top_k=0, eos=None):
         """Admit + fully prefill + activate one request in one call
-        (test/bench convenience; ServingLoop does the same piecewise,
+        (a test's convenience; ServingLoop does the same piecewise,
         chunk-interleaved with decode)."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         t = len(prompt)

@@ -38,7 +38,7 @@ the page tables and sets the next block's draft depth.
 Requests a slot never waits on each other: a request admitted at
 iteration k starts decoding at iteration k+ceil(prompt/chunk) while
 earlier requests keep decoding — that interleaving is the throughput
-win the serving bench leg measures against request-at-a-time serving.
+win over request-at-a-time serving (`serve_sequential`, below).
 
 The loop times its own iteration: every host phase of `step` is one
 span of `monitor/trace.py::SERVE_PHASES` on the engine's `StepTrace`

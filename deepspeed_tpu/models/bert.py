@@ -53,13 +53,13 @@ class BertConfig:
     # Run the MLM head (transform + vocab decoder) matmuls in the
     # compute dtype instead of fp32. The [hidden, vocab] decoder
     # projection is ~10% of the model's flops; in fp32 it runs at a
-    # fraction of the MXU's bf16 rate and was the top per-fusion time
-    # sink of the seq-128 pretraining step (bench.py
-    # bert_mlm_head_dtype leg). LayerNorm stats stay fp32 and the loss
+    # fraction of the MXU's bf16 rate (its share of the seq-128
+    # pretraining step: not measured on the chip; no cell of the
+    # benchmark runs BERT). LayerNorm stats stay fp32 and the loss
     # upcasts logits to fp32, so only the matmul precision changes —
     # the same contract as every encoder-layer matmul. "auto" enables
-    # it on real TPU only (CPU XLA emulates bf16 dots ~11% SLOWER than
-    # fp32, measured in the bench leg); True/False force. Resolved at
+    # it on real TPU only (XLA's CPU backend emulates bf16 dots, so
+    # there fp32 is the faster side); True/False force. Resolved at
     # trace time off jax.default_backend() — same AOT caveat as the
     # flash kernel's interpret auto-select.
     mlm_head_in_compute_dtype: Any = "auto"

@@ -248,10 +248,10 @@ def _quant_dense(features, cfg, name, init_scale=1.0, split=False,
 class GPT2Block(nn.Module):
     """Pre-LN transformer block (attention + MLP).
 
-    Boundary-fusion contract (tentpole of ISSUE 13(c) — the
-    kernel-labeled `top_fusion_sinks` table ranks the unfused
-    mlp_c_proj-bias + residual-add + next-layer ln_1 chain as the top
-    remaining non-matmul sink of the fused flagship step): when the
+    Boundary-fusion contract (tentpole of ISSUE 13(c) — after the
+    fused epilogues, what is left unfused between two blocks is the
+    mlp_c_proj-bias + residual-add + next-layer ln_1 chain, which
+    this contract folds into one launch): when the
     caller passes `boundary=(prev_mlp_y, prev_mlp_b)` the TRUE hidden
     state is `hidden + prev_mlp_y + prev_mlp_b`, and this block folds
     that add into its leading LayerNorm as one fused

@@ -31,7 +31,7 @@ device-side and drain at the existing monitor fence.
                QuantizedDense expert projections
   layer.py     `MoEMLP` — the flax module models drop in for a dense
                MLP — plus the unpacked per-expert-loop reference
-               implementation parity tests and the bench leg pin
+               implementation the parity tests pin
                against
 
   serving.py   the layer as it is SERVED, a different contract and

@@ -14,7 +14,7 @@ the expert dimension: experts (2g, 2g+1) fuse into one GEMM
 — half the GEMM count at double the contraction width, exact to fp
 addition with zeros (the off-diagonal blocks contribute 0*x). An odd
 expert count pads one zero expert. `pack=False` is the plain batched
-einsum reference the parity tests and the bench leg pin against.
+einsum reference the parity tests pin against.
 
 The epilogues reuse the PR-6 fused ops: bias+GeLU runs as the fused
 launch vmapped over the expert dim (custom-VJP batching — Pallas adds
@@ -147,8 +147,8 @@ class ExpertFFN(nn.Module):
 def expert_ffn_reference(params, xe, dtype=jnp.float32):
     """Unpacked per-expert-loop reference: a Python loop of single
     GEMMs + plain (jnp) bias/GeLU — no packing, no fused epilogues.
-    The parity oracle for grouped_gemm/ExpertFFN (tests + the
-    moe_vs_dense bench leg's gate-parity assertion)."""
+    The parity oracle for grouped_gemm/ExpertFFN
+    (tests/test_moe.py)."""
     wi, bi = params["wi"], params["bi"]
     wo, bo = params["wo"], params["bo"]
     outs = []

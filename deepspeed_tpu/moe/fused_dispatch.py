@@ -2,8 +2,8 @@
 capacity-indexed rows.
 
 The PR-15 einsum pair materializes O(N*E*C) one-hot dispatch/combine
-tensors and contracts them against the tokens — at the bench point
-(N=2048, E=8, C=640) that is ~10M mask elements and ~E*C/k times more
+tensors and contracts them against the tokens — at, say,
+N=2048, E=8, C=640 that is ~10M mask elements and ~E*C/k times more
 FMAs than the k rows per token that actually move. This module is the
 replacement: routing in INDEX form (`top_k_gating_indexed` —
 e_idx/slot/keep/w, each [N, k]) drives
@@ -32,8 +32,8 @@ CPU CI, interpret mode and the TPU kernels differentiate identically:
                 gradient path of the dense combine einsum, preserved.
 
 Parity against the einsum pair (forward <= 5e-7 fp32, grads too) is
-pinned in tests/test_overlap.py; the `moe_dispatch_kernel` bench leg
-asserts the >= 1.15x step-time contract. The `moe_dispatch` autotune
+pinned in tests/test_overlap.py; its step time against the einsum
+pair is not measured on the chip. The `moe_dispatch` autotune
 family hashes THIS module's source for table invalidation.
 
 Selection: `MoEConfig.fused_dispatch` ("auto"|"on"|"off") —

@@ -12,8 +12,8 @@ fences.
 a Python per-expert loop of single GEMMs with plain jnp epilogues —
 no block-diagonal packing, no fused launches, no sharding
 constraints. Parity against it (<=1e-5 fp32) is the tentpole's
-correctness contract (tests/test_moe.py + the moe_vs_dense bench
-leg).
+correctness contract
+(tests/test_moe.py).
 """
 
 import dataclasses

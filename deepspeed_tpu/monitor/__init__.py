@@ -10,8 +10,8 @@ One subsystem, three parts (docs/monitoring.md):
     occupancy, device memory) sample at the same fences.
   * Pluggable sinks (sinks.py): schema-versioned JSONL event log and a
     dependency-free native tfevents writer (tfevents.py) — plus the
-    in-process `engine.monitor.snapshot()` API bench.py reuses, so
-    bench extras and training telemetry share one schema.
+    in-process `engine.monitor.snapshot()` API, so a caller's own
+    report and the training telemetry share one schema.
   * Step tracing + stall watchdog (trace.py / watchdog.py): named
     spans via `jax.profiler.TraceAnnotation` recorded fence-aligned
     (`wall_clock_breakdown=true` rides this path instead of the
@@ -375,10 +375,10 @@ class Monitor:
     def _throughput_derived(self):
         """tokens/s/chip + MFU once the throughput timer has a warmed
         measurement window (None before that, and MFU None on a device
-        whose nominal peak is not in the profiler's table).  Same convention as bench.py's
-        headline: conservative 6·N·tokens/s against the chip's nominal
-        bf16 peak — MFU becomes observable IN-LOOP instead of
-        bench-only."""
+        whose nominal peak is not in the profiler's table).  The convention
+        is conservative: 6·N·tokens/s against the chip's nominal
+        bf16 peak — MFU is observable IN-LOOP, not only in an
+        offline report."""
         e = self._engine_ref()
         if e is None:
             return {"tokens_per_sec_per_chip": None, "mfu": None}
@@ -718,7 +718,7 @@ class Monitor:
                     pass
 
     # ------------------------------------------------------------------
-    # snapshot API (bench.py shares this schema)
+    # snapshot API (one schema for every reader of a run)
     # ------------------------------------------------------------------
     SNAPSHOT_KEYS = (
         "schema", "enabled", "step", "micro_steps", "loss", "grad_norm",

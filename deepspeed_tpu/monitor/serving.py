@@ -400,7 +400,7 @@ class ServingTracker:
             sp["rollback_events"] += int(rollbacks)
 
     def on_reset(self):
-        """engine.reset() dropped every slot (bench A/B hygiene): the
+        """engine.reset() dropped every slot (between two runs): the
         live table empties; cumulative histograms/counters survive —
         they describe the run, not the batch."""
         with self._lock:

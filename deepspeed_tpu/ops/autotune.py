@@ -10,7 +10,7 @@ table:
 
   * `search(...)` enumerates grid/block candidates per
     (kernel, backend, dtype, shape-class), measures each with the
-    bench-harness timing discipline (warmup, interleaved best-of-N
+    same timing discipline every time (warmup, interleaved best-of-N
     windows so load drift hits every candidate equally), and keeps the
     winner ONLY if it beats the hand-picked default — the table is
     never-slower by construction.
@@ -81,7 +81,7 @@ _state = {
 
 
 def configure(enabled=None, table_path=None, monitor=None):
-    """Engine/bench wiring: toggle lookups, point at a table file, and
+    """Engine/caller wiring: toggle lookups, point at a table file, and
     attach a monitor for `autotune_search`/`autotune_hit` events
     (monitor=False detaches — a later engine without telemetry must
     not leave events flowing to a closed monitor). Changing the path
@@ -314,7 +314,7 @@ def search(kernel, shape_class, dtype, candidates, default_params,
     `build(params) -> zero-arg jitted callable` timed by
     `measure_callable`. Candidate rounds INTERLEAVE (round-robin over
     candidates, best-of-`reps` per candidate) so machine-load drift
-    lands on every candidate equally — the bench harness's interleaved
+    lands on every candidate equally — an interleaved
     A/B discipline.
 
     Returns {params, best_us, default_us, speedup_vs_default,
@@ -370,7 +370,7 @@ def search(kernel, shape_class, dtype, candidates, default_params,
 # ----------------------------------------------------------------------
 # kernel-family helpers: shape classes + candidate enumeration. The
 # kernel entry points call the *_params lookups at trace time; the
-# bench legs / operators call the *_candidates enumerators to search.
+# operators (and tests) call the *_candidates enumerators to search.
 # ----------------------------------------------------------------------
 def flash_shape_class(t, d, causal, packed):
     return f"t{t}_d{d}_{'causal' if causal else 'bidir'}" + \

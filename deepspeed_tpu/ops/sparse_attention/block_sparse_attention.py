@@ -47,7 +47,7 @@ def _element_spec(shape, index_map):
 
 
 def _compiler_params(kind):
-    # Measured on v5e at the 16k bench point: the BACKWARD kernels want
+    # On v5e at 16k, before the ledger (no cell now): BACKWARD kernels want
     # ("parallel","parallel","arbitrary") (+40% over default), while
     # the forward's online-softmax carry pipelines better with Mosaic's
     # own scheduling (declared semantics cost it ~25%).
@@ -926,8 +926,8 @@ def block_sparse_attention(q, k, v, layout, block, causal=False,
     # pipeline full at small batch); the bwd kernels have shorter
     # carries and prefer the fattest tiles. Any divisor of g keeps
     # layout-uniform groups, so the two passes pick independently
-    # (measured at the 16k bench point: fwd g=2 + bwd g=8 is ~20%
-    # faster than a shared g).
+    # (at 16k context, before the ledger: fwd g=2 + bwd g=8 was ~20%
+    # faster than a shared g; no cell holds it now).
     g_fwd = g
     while g_fwd > 1 and (b * h) // g_fwd < _FWD_MIN_OUTER:
         g_fwd //= 2

@@ -4,8 +4,8 @@ elementwise chains as single Pallas launches.
 TPU-native rebuild of the reference's fused transformer kernel scope
 (`csrc/transformer/ds_transformer_cuda.cpp` — `launch_bias_add`,
 `launch_bias_gelu`, `launch_fused_add2` + `normalize_kernels.cu`): the
-two chains the PR-4 fusion roofline (`top_fusion_sinks`) ranks as the
-largest non-matmul sinks of the GPT-2/BERT step are
+two elementwise chains that stand between the matmuls of the
+GPT-2/BERT block (the reference fuses the same two) are
 
   (a) bias + residual-add + LayerNorm   (the block epilogue)
   (b) bias + GeLU                       (the MLP activation; exact-erf
@@ -45,8 +45,8 @@ pass).
 jnp formulation (same custom VJP, same saved set) elsewhere —
 CPU CI validates the kernel logic itself via `impl="interpret"`.
 Every entry point runs inside a `jax.named_scope` carrying the op name,
-which is what the flops profiler's per-fusion table uses to attribute
-the custom-calls/fusions (`per_fusion_costs` kernel labeling).
+which is what a device trace's rows are attributed by (the names
+`benchmark/scope_reduce.py` sums a traced run's device time under).
 """
 
 import functools

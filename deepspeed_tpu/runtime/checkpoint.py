@@ -593,7 +593,7 @@ def commit_staging_dir(save_dir, tag):
 def checkpoint_dirs_bit_identical(d1, d2):
     """True when two checkpoint dirs are byte-identical: same file
     names, every npz entry equal in dtype and raw bytes, every json
-    manifest equal.  Used by tests and the async_checkpoint bench to
+    manifest equal.  Used by tests (tests/test_async_checkpoint.py) to
     prove async and sync saves of the same state match exactly."""
     f1, f2 = sorted(os.listdir(d1)), sorted(os.listdir(d2))
     if f1 != f2:

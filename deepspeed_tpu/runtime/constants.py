@@ -515,7 +515,7 @@ OFFLOAD_WIRE_PARAM_BITS_VALID = (8, 32)
 #   bounded by (prefetch_layers + 1) layers. 0 = gather at use.
 # release_after_use: false = naive baseline (whole stack gathered up
 #   front, held live through fwd+bwd; full stacked grad materializes
-#   before one bulk reduce-scatter) — the zero3_overlap bench A/B leg.
+#   before one bulk reduce-scatter) — the other side of an A/B.
 # gather_dtype: cast params to this dtype BEFORE the all-gather
 #   (null = storage dtype; "bf16" halves gather bytes for fp32 params).
 #############################################
@@ -573,7 +573,7 @@ QUANTIZED_COMPUTE_STOCHASTIC_ROUNDING_DEFAULT = False
 # hash — a kernel edit invalidates them (defaults, one warning).
 #   {"autotune": {"enabled": true, "table_path": ""}}
 # enabled: consult the table at trace time (searches are explicit —
-#   the autotune_flash bench leg or ops.autotune.search; nothing
+#   a caller of ops.autotune.search runs them; nothing
 #   searches inside a training step).
 # table_path: "" = next to the jax compilation cache
 #   (autotune_table_v2.json), else an explicit JSON path.

@@ -971,7 +971,7 @@ class DeepSpeedEngine(ZeroOffloadMixin):
 
         n_params = self._count_model_params(params_f32)
         # cached for the monitor's in-loop MFU derivation (6·N·tokens/s
-        # against the chip's nominal peak — the bench convention)
+        # against the chip's nominal peak — the conservative convention)
         self._n_model_params = n_params
         log_dist(
             f"engine initialized: {n_params/1e6:.1f}M params, "
@@ -1168,7 +1168,7 @@ class DeepSpeedEngine(ZeroOffloadMixin):
             "ZeRO-3 runtime: NAIVE up-front gather "
             "(stage3.release_after_use=false) — the whole param stack "
             "is gathered at step start and held live; this is the "
-            "bench baseline, not a memory-bounded mode", ranks=[0])
+            "A/B baseline, not a memory-bounded mode", ranks=[0])
 
     def zero_stage3_config(self):
         """The zero_optimization.stage3 block (explicit stage-3

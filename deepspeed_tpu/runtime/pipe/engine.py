@@ -15,16 +15,16 @@ communication are *compiled*:
     `num_pipe_buffers()` saved stage inputs, and per-stage parameter
     memory partitioning (`pipe/flat_params.py`). This is the
     RECOMMENDED substrate: 1F1B's activation bound beats GPipe's m
-    residual sets, parameters divide by the stage count, and it
-    measures faster end-to-end on the same model (bench
-    `pipe_interp_vs_spmd`: 1918 ms vs 2758 ms — on the serialized
+    residual sets, and parameters divide by the stage count. Which
+    of the two is faster end to end is not measured on the chip
+    (no pipeline cell yet: ROADMAP, Design item 4). On a serialized
     virtual test mesh the scan's fill/drain bubble executes as real
-    garbage compute, an overhead factor of 1 + (S-1)/m = 1.375x,
-    matching the measured 1.44x;
-    on parallel hardware both paths pay the bubble as idle stages, so
-    the gap is EXPECTED to narrow without inverting — an analytic
-    claim; no multi-chip pipe hardware exists in this environment to
-    measure it). On a pipe=1 mesh the layer chain runs sequentially
+    garbage compute, an overhead factor of 1 + (S-1)/m, which says
+    nothing about parallel hardware:
+    there both paths pay the bubble as idle stages (an analytic
+    claim; the four-chip host is where it would be measured, and
+    nothing has been measured there yet).
+    On a pipe=1 mesh the layer chain runs sequentially
     inside the fused step (pure microbatching semantics, no overlap to
     be had).
   * homogeneous-stage models (the PipelinedGPT2 protocol: stacked

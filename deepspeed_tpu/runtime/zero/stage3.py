@@ -43,8 +43,8 @@ discipline's one home) ties layer k's gather to the activation
 entering layer k-prefetch so XLA cannot hoist every gather to the top
 of the program.
 
-`release_after_use=False` is the naive stage-3 baseline the bench leg
-`zero3_overlap` A/Bs against: the whole stack is gathered up front,
+`release_after_use=False` is the naive stage-3 baseline the windowed
+schedule is compared against: the whole stack is gathered up front,
 stays live through forward AND backward, and its gradient materializes
 as a full stacked tree before one bulk reduce-scatter.
 
@@ -165,7 +165,7 @@ class Zero3GatherScheduler:
                       gathers each layer at its point of use.
     release_after_use True (default): the windowed schedule with the
                       O(prefetch+1 layers) live bound. False: naive
-                      up-front gather of the whole stack (the bench
+                      up-front gather of the whole stack (the A/B
                       baseline; also what implicit GSPMD may pick).
     gather_dtype      cast params to this dtype BEFORE the all-gather
                       (None = storage dtype): halves gather bytes for
@@ -185,7 +185,7 @@ class Zero3GatherScheduler:
             if isinstance(gather_dtype, (str, type(None))) else gather_dtype
         self.dp_size = mesh.shape[DATA_AXIS]
         # trace-time byte accounting, read by the memory ledger's
-        # dynamic `zero3_gather` entry and the bench's window assertion:
+        # dynamic `zero3_gather` entry and the tests' window assertion:
         # {name: live gathered bytes} per layer stack / standalone tree
         self._gather_bytes = {}
         # per-stack schedule facts for introspection/tests
@@ -515,8 +515,8 @@ class Zero3GatherScheduler:
         return h
 
     def describe(self):
-        """Schedule facts, reported in the zero3_overlap bench leg's
-        JSON (`schedule` key) and available for logs."""
+        """Schedule facts, for logs and for tests
+        (tests/test_zero3_runtime.py)."""
         return dict(prefetch_layers=self.prefetch_layers,
                     release_after_use=self.release_after_use,
                     gather_dtype=None if self.gather_dtype is None

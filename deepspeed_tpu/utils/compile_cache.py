@@ -1,7 +1,7 @@
 """Where compiled programs persist between processes.
 
 One rule, shared by every entry point that compiles (chip_smoke.py,
-the examples, bench.py, the test suite): when the environment names a
+the examples, benchmark/, the test suite): when the environment names a
 cache with `JAX_COMPILATION_CACHE_DIR`, jax already reads it from
 there and nothing here touches the setting; otherwise the cache sits at
 one fixed, git-ignored path inside the checkout. The path never holds

@@ -1191,6 +1191,49 @@ def test_sync_guards_with_observability_enabled(obs_setup, monkeypatch):
     engine.reset()
 
 
+def test_tracker_percentiles_agree_with_the_requests_own_stamps():
+    """The tracker's streaming histograms against the latencies the
+    scheduler stamps on each `Request` (another code path, another
+    chain of clock readings of the same fences): p50 and p99 of the
+    time to first token and of the time a token, within one bucket of
+    the fixed edges (2^(1/3) wide; held at 1.45 for what lies between
+    the two readings of one fence)."""
+    cfg = tiny_gpt2_config()
+    engine = InferenceEngine(cfg, _params(GPT2ForCausalLM(cfg)), {
+        "inference": {"max_slots": 4, "prefill_chunk": 8,
+                      "sync_every": 4, "max_new_tokens": 16,
+                      "kv_cache": {"num_pages": 64, "page_size": 4}},
+        "monitor": {"enabled": True, "sinks": []}})
+    trk = engine.tracker
+    r = np.random.RandomState(5)
+    results = ServingLoop(engine).serve(
+        [Request(rid=i, tokens=r.randint(0, cfg.vocab_size,
+                                         size=int(r.randint(4, 29))),
+                 max_new_tokens=int(r.randint(6, 15)),
+                 arrival_time=0.004 * i) for i in range(12)])
+    assert len(results) == 12
+    ttft = sorted((q.first_token_at - q.arrival_time) * 1e3
+                  for q in results)
+    token = []
+    for q in results:
+        n = max(len(q.out_tokens), 1)
+        live = q.live_at if q.live_at is not None else q.admitted_at
+        token += [(q.finished_at - live) * 1e3 / n] * n
+    token.sort()
+    assert trk.hist_ttft_ms.to_event()["count"] == len(ttft)
+    assert trk.hist_token_ms.to_event()["count"] == len(token)
+
+    def pick(vals, p):
+        return vals[min(int(p * len(vals)), len(vals) - 1)]
+
+    for hist, exact in ((trk.hist_ttft_ms, ttft),
+                        (trk.hist_token_ms, token)):
+        for p in (0.50, 0.99):
+            assert 1 / 1.45 <= hist.percentile(p) / pick(exact, p) \
+                <= 1.45, (p, hist.percentile(p), pick(exact, p))
+    engine.monitor.close()
+
+
 def test_serving_slo_jsonl_schema_roundtrip(obs_setup):
     """The new event schema through the real sink: `serving_slo` with
     schema-stable histogram payloads, and the extended timing keys on

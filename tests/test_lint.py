@@ -3,7 +3,7 @@
 Four layers:
   * the SELF-RUN: the analyzer over the whole shipped package must
     report zero non-baselined findings — the analyzer is part of the
-    verify loop, the same trick the bench smoke tests use;
+    verify loop, as any other Tier-1 test is;
   * per-rule fixtures: every rule fires on its true-positive snippet
     (tests/lint_fixtures/tp) and stays silent on its true-negative
     (tests/lint_fixtures/tn);

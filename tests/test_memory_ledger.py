@@ -650,6 +650,42 @@ def test_memory_plan_agrees_with_ledger_and_measured():
     engine.monitor.close()
 
 
+def test_13b_plan_agrees_with_the_closed_form():
+    """GPT-2 13B over a data mesh of 128, from abstract shapes
+    (`eval_shape`: nothing of that size is allocated):
+    `ZeroShardingPolicy.memory_plan` in the master-less bfloat16 mode
+    against the closed form 6 bytes a parameter over dp (padding of
+    the leaves that do not divide makes the plan slightly larger,
+    never smaller), and with float32 masters the state of one device
+    stays under 2 GiB: the feasibility number
+    `docs/tutorials/zero.md` leans on."""
+    from deepspeed_tpu.models.gpt2 import GPT2ForCausalLM, gpt2_config
+    from deepspeed_tpu.runtime.zero.partition import ZeroShardingPolicy
+    cfg = gpt2_config("gpt2-13b", n_positions=1024, dropout=0.0)
+    shapes = jax.eval_shape(
+        lambda: GPT2ForCausalLM(cfg).init(
+            jax.random.PRNGKey(0),
+            {"input_ids": np.zeros((1, 1024), np.int32)}))
+
+    class MeshShim:   # the axis sizes are all the policy's math needs
+        shape = {"pipe": 1, "data": 128, "model": 1}
+
+    policy = ZeroShardingPolicy(MeshShim(), 3)
+    n = sum(int(np.prod(l.shape))
+            for l in jax.tree_util.tree_leaves(shapes))
+    assert n > 12e9
+    plan = policy.memory_plan(shapes, compute_bytes=2, sr_mode=True,
+                              gas=1)
+    closed_form = 6.0 * n / 128
+    planned = plan["params"] + plan["opt_state"]
+    assert 0.0 <= (planned - closed_form) / closed_form < 0.05
+    with_masters = policy.memory_plan(shapes, compute_bytes=2,
+                                      sr_mode=False, gas=1)
+    per_device = with_masters["params"] + with_masters["master"] + \
+        with_masters["opt_state"]
+    assert 14.0 * n / 128 <= per_device < 2 * 2**30
+
+
 # ----------------------------------------------------------------------
 # subprocess OOM-classification flight dump
 # ----------------------------------------------------------------------

@@ -296,6 +296,38 @@ def test_engine_wires_quantized_compute_and_emits_event(tmp_path):
     assert ev["stochastic_rounding"] is True
 
 
+def test_engine_loss_trajectory_tracks_unquantized():
+    """Ten steps of the same tiny GPT-2 on the same data with the
+    `quantized_compute` block on and off: the int8 forward perturbs
+    the trajectory and must not leave it (every step within 0.2 of
+    the unquantized loss, and really another number)."""
+    import deepspeed_tpu
+    ids = np.random.default_rng(1).integers(
+        0, 256, (10, 1, 8, 64)).astype(np.int32)
+
+    def run(quant):
+        model = _tiny()
+        params = model.init(jax.random.PRNGKey(0),
+                            {"input_ids": ids[0, 0]})
+        config = {"train_micro_batch_size_per_gpu": 8,
+                  "gradient_accumulation_steps": 1,
+                  "steps_per_print": 1000,
+                  "optimizer": {"type": "Adam", "params": {"lr": 1e-3}}}
+        if quant:
+            config["quantized_compute"] = {
+                "enabled": True, "mode": "on",
+                "block": qm.DEFAULT_QUANT_BLOCK}
+        engine, _, _, _ = deepspeed_tpu.initialize(
+            model=model, model_parameters=params, config=config)
+        return [float(jax.device_get(
+            engine.train_batch(batch={"input_ids": ids[i]})))
+            for i in range(10)]
+
+    base, quant = run(False), run(True)
+    assert base != quant
+    assert max(abs(a - b) for a, b in zip(base, quant)) <= 0.2
+
+
 def test_engine_warns_when_model_lacks_hook(caplog):
     import deepspeed_tpu
 

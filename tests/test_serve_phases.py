@@ -203,10 +203,27 @@ def test_fence_rows_say_where_the_hosts_time_went(model_and_params,
                [request("late", new=30, at=1.0)])
     rows = sink.rows
     assert len(rows) >= 20
-    for row in rows:
+    # Row by row the phases are compared with the HOST's clock, and a
+    # host that five other workers load takes the processor away for
+    # milliseconds at a time. Inside an iteration that is some phase's
+    # time (a span begins where the one before it ended). It is no
+    # phase's between two iterations (that row's phases fall short of
+    # its window), and between the opening of `fence.bookkeeping` and
+    # the reading of the window's end inside it (the row falls short
+    # and the next, which reports that span, overshoots by as much;
+    # its longest span may then outlast its window). Alone 0 rows of
+    # 40 part; beside 24 busy processes on 8 cores 0 to 3 do, never
+    # the same ones. Work of the loop's that stands outside every
+    # iteration parts every row. So the names, the arithmetic and the
+    # sums over the run are held exactly, and a quarter of the rows
+    # may part.
+    parted = set()
+    for i, row in enumerate(rows):
         assert set(row["host_ms"]) <= set(SERVE_PHASES)
         phase, ms = row["host_longest"]
-        assert phase in SERVE_PHASES and 0 < ms <= row["window_ms"] * 1.02
+        assert phase in SERVE_PHASES and ms > 0
+        if ms > row["window_ms"] * 1.02:
+            parted.add(i)
         assert row["host_iter_ms"] == pytest.approx(sum(
             ms for p, ms in row["host_ms"].items()
             if p not in ("fence.device_get", "idle")), abs=2e-3)
@@ -215,9 +232,12 @@ def test_fence_rows_say_where_the_hosts_time_went(model_and_params,
     # tens of microseconds between two iterations, which are no phase)
     assert sum(sum(r["host_ms"].values()) for r in rows) == pytest.approx(
         sum(r["window_ms"] for r in rows), rel=0.02)
-    for row in rows[1:]:
-        assert sum(row["host_ms"].values()) == pytest.approx(
-            row["window_ms"], rel=0.05, abs=0.25)
+    for i, row in enumerate(rows[1:], 1):
+        if sum(row["host_ms"].values()) != pytest.approx(
+                row["window_ms"], rel=0.05, abs=0.25):
+            parted.add(i)
+    assert len(parted) <= len(rows) // 4, [
+        (i, rows[i]["window_ms"], rows[i]["host_ms"]) for i in sorted(parted)]
     assert any("idle" in r["host_ms"] for r in rows)
 
 

@@ -327,6 +327,11 @@ def test_stage3_hot_loop_has_zero_host_syncs(monkeypatch):
 # ----------------------------------------------------------------------
 # memory-ledger window bound
 # ----------------------------------------------------------------------
+def _full_bytes(tree):
+    return sum(int(np.prod(l.shape)) * l.dtype.itemsize
+               for l in jax.tree_util.tree_leaves(tree))
+
+
 @pytest.mark.parametrize("prefetch", [0, 1, 2])
 def test_ledger_window_bytes_bound(prefetch):
     """zero3_gather in the ledger == gathered embeddings + exactly
@@ -344,13 +349,9 @@ def test_ledger_window_bytes_bound(prefetch):
     window = min(prefetch, n_layer - 1) + 1
     assert info["window_layers"] == window
 
-    def full_bytes(tree):
-        return sum(int(np.prod(l.shape)) * l.dtype.itemsize
-                   for l in jax.tree_util.tree_leaves(tree))
-
     (_, stacked), = engine.state.params["h"].items()
-    per_layer = full_bytes(stacked) // n_layer
-    extras = sum(full_bytes(engine.state.params[k])
+    per_layer = _full_bytes(stacked) // n_layer
+    extras = sum(_full_bytes(engine.state.params[k])
                  for k in ("wte", "wpe", "ln_f"))
     cats = engine.monitor.ledger.totals()["hbm"]
     assert cats["zero3_gather"] == per_layer * window + extras
@@ -367,6 +368,12 @@ def test_ledger_naive_mode_records_whole_stack():
     _run(engine, 1)
     info = engine.zero3_scheduler.stack_info["h"]
     assert info["window_layers"] == n_layer
+    # and the ledger says so in bytes, against the raw param tree:
+    # the embeddings and the final norm beside the WHOLE stack
+    cats = engine.monitor.ledger.totals()["hbm"]
+    assert cats["zero3_gather"] == sum(
+        _full_bytes(engine.state.params[k])
+        for k in ("h", "wte", "wpe", "ln_f"))
 
 
 def test_oom_hints_name_prefetch_layers():
