@@ -30,6 +30,8 @@ from deepspeed_tpu.inference.engine import InferenceEngine
 from deepspeed_tpu.inference import latent_kind  # noqa: F401
 # and so does "state+window+shared"
 from deepspeed_tpu.inference import hybrid_kind  # noqa: F401
+# and "paged|state"
+from deepspeed_tpu.inference import layered_kind  # noqa: F401
 from deepspeed_tpu.inference.kv_cache import (PagedKVCache,
                                               RecurrentStateCache)
 from deepspeed_tpu.inference.scheduler import (Request, ServingLoop,

@@ -25,10 +25,10 @@ The model supplies the block, the engine supplies the cache (the seam;
 docs/inference.md has it at length):
 
   * a MODEL MODULE (`models/gpt2.py`, `brumby.py`, `trinity.py`,
-    `falcon_h1.py`, `sarvam_mla.py`, `phi4flash.py`) holds the
-    model's math as plain functions: `embed(mc, params, tokens,
-    positions)`, ONE `block(mc, lp, hidden, positions, mixer, cache)
-    -> (hidden, cache)`, `head(mc, params, hidden)`, `layers(params)`
+    `falcon_h1.py`, `sarvam_mla.py`, `phi4flash.py`, `nemotron_h.py`)
+    holds the model's math as plain functions: `embed(mc, params,
+    tokens, positions)`, ONE `block(mc, lp, hidden, positions, mixer,
+    cache) -> (hidden, cache)`, `head(mc, params, hidden)`, `layers`
     (the stacked [n_layer, ...] weights `block` takes one layer of),
     `QUANT_KERNEL_MODULES` (the projections an int8 load may quantise;
     () refuses it) and, where `truncate:N` drafts are served,
@@ -43,20 +43,20 @@ docs/inference.md has it at length):
     computes its own projections under SCOPE_ATTN_QKV / SCOPE_ATTN_OUT
     / SCOPE_MLP and calls `mixer` exactly once with what it projected
     (two branches side by side: both in that one call). A model whose
-    layers keep DIFFERENT things (`enter`, `leave`: `layers_with_carry`
-    below) has a block of a PERIOD of layers that calls `mixer(role,
-    ...)` once for each thing a layer keeps or reads, and says which
-    stacks a launch that yields no logits must run. No block knows
+    layers keep DIFFERENT things calls `mixer(role, ...)` once for each
+    thing a layer keeps or reads: through `stacks` where it also COUNTS
+    (Nemotron-H), through `enter` / `leave` (`layers_with_carry` below)
+    where a value rides beside the hidden state. No block knows
     of pages, tables, slots or state arrays. The MODEL CONFIG names the
     kind of cache the layers keep (`cache_kind`), carries the geometry
-    that kind's manager needs and points at the module
-    (`serving_module`). `models/` and this package import each other
-    nowhere;
+    that kind's manager needs and points at the module (`serving_module`).
+    `models/` and this package import each other nowhere;
   * the ENGINE owns the kinds of cache (`PagedKind`, `RecurrentKind`,
     `PagedStateKind`: the paged kind and a state kind side by side in
     every layer, `PagedWindowKind`: pages in two geometries, a window
     or everything; `latent_kind.py` adds one pool of latent rows,
-    `hybrid_kind.py` state, rings and one shared layer of pages) and
+    `hybrid_kind.py` state, rings and one shared layer of pages,
+    `layered_kind.py` pages OR state a layer, counted apart) and
     nothing of any model: per kind the manager
     (inference/kv_cache.py), the fresh device arrays and their keys in
     the engine's state, and the mixers;

@@ -49,7 +49,9 @@ latent attention keeps ONE pool of one compressed row a token
 (`LatentKVCache`, the paged manager counting one pool). A model whose
 layers keep DIFFERENT things (a state in some, a ring in others, one
 layer of pages that several layers read) is served by `HybridKVCache`:
-the state's, the ring's and the paged manager behind one. All six answer the
+the state's, the ring's and the paged manager behind one. A model
+whose every layer keeps pages OR state is `PagedStateCache`'s again,
+its halves over different counts of layers. All of them answer the
 scheduler's one interface: `can_admit`, `admit`, `ensure`, `free`,
 `reserved_tokens`, `never_fits`, `reservation`, `occupancy`,
 `attended`, `slot_operand` (and `rollback`, which recurrent state
@@ -635,12 +637,13 @@ class PagedStateCache:
     is freed; neither half knows of the other, and every fence row
     carries both halves' counters (`kv_pages_*` and `state_slots_*`).
     State cannot be rewound, so `rollback` is refused as for recurrent
-    state alone."""
+    state alone. Each half has its own count of layers: a model whose
+    layers keep pages OR state (`models/nemotron_h.py`, kind
+    "paged|state") is served by the same two managers, each sized by
+    the layers that keep its half."""
 
-    kind = "paged+state"
-
-    def __init__(self, pages, state):
-        self.pages, self.state = pages, state
+    def __init__(self, pages, state, kind="paged+state"):
+        self.pages, self.state, self.kind = pages, state, kind
         self.pool_bytes = pages.pool_bytes + state.pool_bytes
         self.num_pages = pages.num_pages      # the tracker's snapshot
 

@@ -17,7 +17,7 @@ SCOPE_LAYERS alone is a regression.
 
 A model's block (`models/gpt2.py`, `models/brumby.py`,
 `models/falcon_h1.py`, `models/trinity.py`, `models/sarvam_mla.py`,
-`models/phi4flash.py`) and the engine
+`models/phi4flash.py`, `models/nemotron_h.py`) and the engine
 (`inference/engine.py`, which re-exports them) both take the names
 from here: neither the models nor the ops import the serving code.
 """
@@ -131,4 +131,17 @@ SCOPE_GMU = "gmu"                    # the memory unit: its gate inside
 SCOPES_IN_LAYER_HYBRID = SCOPES_IN_LAYER_PAGED_STATE + (SCOPE_SHARED_KV,
                                                         SCOPE_GMU)
 SCOPES_HYBRID = (SCOPE_EMBED, SCOPE_LAYERS) + SCOPES_IN_LAYER_HYBRID + \
+    (SCOPE_HEAD, SCOPE_SAMPLE, SCOPE_BOOKKEEPING)
+
+# a model whose every layer is ONE thing (`models/nemotron_h.py`, kind
+# "paged|state"): a Mamba-2 mixer that keeps a state, attention that
+# keeps K/V pages, or a layer of experts that keeps nothing. No region
+# is new: the state-space regions over the M layers and the paged ones
+# over the `*` layers keep the names and meaning they have where both
+# stand in every layer (SCOPES_PAGED_STATE), each layer's norm and input
+# projection under SCOPE_ATTN_QKV, its output projection and residual
+# under SCOPE_ATTN_OUT; an expert layer IS the feed-forward, its norm
+# and residual under SCOPE_MLP and its parts under their own inside it
+SCOPES_IN_LAYER_LAYERED = SCOPES_IN_LAYER_PAGED_STATE + SCOPES_MOE
+SCOPES_LAYERED = (SCOPE_EMBED, SCOPE_LAYERS) + SCOPES_IN_LAYER_LAYERED + \
     (SCOPE_HEAD, SCOPE_SAMPLE, SCOPE_BOOKKEEPING)

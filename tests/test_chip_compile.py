@@ -760,9 +760,137 @@ def test_state_rings_and_the_shared_pool_ride_the_scans_in_place_on_a_v5e(
         assert not regions & {"state_update", "shared_kv", "gmu"}
 
 
+# ----------------------------------------------------------------------
+# Nemotron-3-Nano's layer scans and head at the serving cell's shapes
+# (the first 16 layers of the pattern, this chip's 64 of 128 experts,
+# 96 slots, 2 layers of 2,305 pages of 128 and 7 of state; ISSUE 45)
+# ----------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def nemotron_scans(one_chip):
+    """program -> (compiled layer scans [+ the head, in decode], the
+    cache arrays), with the backend probes of the paged decode kernel
+    and of the grouped product answering "TPU"."""
+    from deepspeed_tpu.models import nemotron_h
+    from deepspeed_tpu.moe import serving as moe
+    from deepspeed_tpu.ops.transformer import paged_decode_attention as pda
+    slots, chunk, seq = 96, 512, 3072
+    cfg = nemotron_h.NemotronHConfig(
+        num_hidden_layers=16,
+        hybrid_override_pattern=nemotron_h.PUBLISHED_PATTERN[:16],
+        experts_held=64, vocab_size=65536, expert_width_stored=1920)
+    block = InferenceConfig({"inference": {
+        "max_slots": slots, "prefill_chunk": chunk, "sync_every": 4,
+        "max_new_tokens": 1024, "max_seq_len": seq,
+        "kv_cache": {"num_pages": 2305, "page_size": 128}}})
+    family = engine_mod.Serving(cfg, block, seq)
+    cache = family.kind.make_cache(None)
+    place = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip)
+    sds = lambda shape, dtype: place(jax.ShapeDtypeStruct(shape, dtype))
+    params = jax.tree_util.tree_map(place, jax.eval_shape(
+        lambda k: nemotron_h.init_params(cfg, k), jax.random.PRNGKey(0)))
+    fresh = jax.eval_shape(lambda: family.fresh(cache))
+    keys = family.cache_keys
+    arrays = tuple(place(fresh[k]) for k in keys)
+    pages = seq // 128
+
+    def decode_layers(params, hidden, arrays, tables, pos, active):
+        hidden, state, read = family.decode_layers(params, hidden, dict(
+            zip(keys, arrays), tables=tables, pos=pos, active=active),
+            readings=True)
+        return family.head(params, hidden)[:, 0], state, read
+
+    def prefill_layers(params, hidden, arrays, row, slot, start, n_valid):
+        posv = start + jnp.arange(chunk, dtype=jnp.int32)
+        return family.prefill_layers(
+            params, hidden, arrays, (row, slot), posv,
+            jnp.arange(chunk) < n_valid, start, n_valid)
+
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    programs = {
+        "decode": (decode_layers, (
+            params, sds((slots, 1, 2688), cfg.dtype), arrays,
+            i32(slots, pages), i32(slots), sds((slots,), bool))),
+        "prefill": (prefill_layers, (
+            params, sds((1, chunk, 2688), cfg.dtype), arrays, i32(pages),
+            i32(), i32(), i32()))}
+    compiled = {}
+
+    def get(program):
+        if program not in compiled:
+            layers, args = programs[program]
+            probes = (pda._on_tpu, moe._on_tpu)
+            pda._on_tpu = moe._on_tpu = lambda: True
+            try:
+                compiled[program] = jax.jit(
+                    layers, donate_argnums=(2,)).lower(*args).compile()
+            finally:
+                pda._on_tpu, moe._on_tpu = probes
+        return compiled[program], arrays
+    return get
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_pages_and_state_counted_apart_ride_seven_scans_in_place_on_a_v5e(
+        nemotron_scans, program):
+    """2 layers of K/V pages (a row of 2 heads x 128 = 256 lanes) and 7
+    of Mamba-2 state (1.41 GB float32) ride in the carry of the seven
+    layer scans the pattern's first 16 letters make (M, 2 EM, *, 3 EM,
+    *, EM, E): the donated arrays are the outputs and nothing
+    cache-shaped is copied. Mosaic takes the grouped product at the
+    tiling the shapes give (the experts stored at 1920 columns: at the
+    published 1856 = 14.5 lane tiles the compiler re-laid the whole 4.5
+    GB stack of W_up three times a launch), two calls an expert layer's
+    body and NO third; no layer's held experts are sliced out of the stack; the
+    state-space, paged and expert regions all survive the chip's
+    fusions."""
+    from benchmark import nemotron_h_costs, region_join
+    from deepspeed_tpu.monitor import programs
+    compiled, arrays = nemotron_scans(program)
+    k_pool, _, conv, state, counts = arrays
+    assert k_pool.shape == (2, 2305, 128, 256)
+    assert conv.shape == (7, 96, 3, 6144)
+    assert state.shape == (7, 96, 64, 64, 128) and state.dtype == jnp.float32
+    assert counts.shape == (2, 3)
+    cache_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                      for a in arrays)
+    assert 2.0e9 < cache_bytes < 2.1e9
+    memory = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert memory.alias_size_in_bytes >= cache_bytes
+    # (a layer of the state is READ by a dynamic-slice: `ssm_step`'s)
+    for shape, moves in ((k_pool.shape, "copy|transpose|dynamic-slice"),
+                         (state.shape, "copy|transpose|dynamic-slice"),
+                         (state.shape[1:], "copy|transpose"),
+                         ((7, 64, 2688, 1920), "copy|transpose"),
+                         ((64, 2688, 1920), "copy|transpose|dynamic-slice"),
+                         ((64, 1920, 2688), "copy|transpose|dynamic-slice")):
+        whole = ",".join(map(str, shape))
+        moved = re.findall(
+            rf"= \w+\[(?:1,)?{whole}\]\S* ({moves})\(", text)
+        assert moved == [], (shape, moved)
+    calls = re.findall(
+        r'custom-call\(.*custom_call_target="tpu_custom_call"', text)
+    regions = {region_join.region_of(stack, nemotron_h_costs.LAYERED)
+               for stack in programs.parse_op_scopes(text).values()}
+    assert set(nemotron_h_costs.MOE) <= regions
+    assert {"kv_write", "attn", "ssm_conv", "attn_qkv", "attn_out",
+            "mlp"} <= regions
+    # up and down in each of the 4 bodies with an expert layer (2 EM,
+    # 3 EM, EM, E), and the attention kernel of each of the two `*`
+    if program == "decode":
+        assert len(calls) == 2 * 4 + 2
+        assert "state_update" in regions and "ssm_chunk" not in regions
+        assert memory.temp_size_in_bytes < 128 << 20
+    else:
+        assert "ssm_chunk" in regions and "state_reset" in regions
+        assert "state_update" not in regions
+        assert memory.temp_size_in_bytes < 256 << 20
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 @pytest.mark.parametrize("family", ["brumby", "falcon", "trinity", "sarvam",
-                                    "phi4flash"])
+                                    "phi4flash", "nemotron"])
 def test_no_head_projection_is_sliced_out_and_transposed_on_a_v5e(
         request, family, program):
     """W_q, W_k and W_v go through `head_projection`, which pins their
@@ -778,8 +906,15 @@ def test_no_head_projection_is_sliced_out_and_transposed_on_a_v5e(
     compiled = request.getfixturevalue(family + "_scans")(program)[0]
     text = compiled.as_text()
     w_kvb = 512 * 16384 * 2
+    # Nemotron's prefill: `ssd_chunked`'s own scan over the chunks is a
+    # `while/body/dynamic_slice` too, and at 8 groups of 8 heads of 64
+    # the compiler re-lays a chunk's xs, B, C and dt: activations (4 x
+    # 262 KB and 33 KB), once in each of its 4 bodies with a Mamba-2
+    # layer; no weight
+    chunks = 4 * (4 * 128 * 8 * 128 * 2 + 128 * 8 * 8 * 4) \
+        if (family, program) == ("nemotron", "prefill") else 0
     assert programs.parse_relaid(text) == \
-        (2 * w_kvb if family == "sarvam" else 0)
+        (2 * w_kvb if family == "sarvam" else chunks)
     if family == "sarvam":
         assert re.findall(r"= bf16\[1,4096,12288\]\S* copy\(", text) == []
     # what the count is made of is there to be counted: the stacks are
@@ -813,6 +948,34 @@ def test_the_bias_is_balanced_beside_the_weights_on_a_v5e(one_chip):
     assert 8 << 30 > memory.argument_size_in_bytes > 7 << 30
     assert memory.temp_size_in_bytes < 3 << 30
     assert memory.output_size_in_bytes == 4 * 128 * 4
+
+
+def test_nemotrons_bias_is_balanced_beside_the_weights_on_a_v5e(one_chip):
+    """The same for Nemotron-3-Nano's cell, whose weights are 10.9 GB:
+    `weights_nemotron_h.balance_program` takes the 4 x 2,048 rows
+    through the plain reference a SEQUENCE at a time, and its
+    temporaries stay under 1.5 GB (all four at once: 5.55 GB, past the
+    chip beside the weights; ISSUE 45)."""
+    import json
+    from benchmark import weights_nemotron_h as weights
+    from benchmark.reference import nemotron_h as reference
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmark", "configs",
+                           "nemotron-3-nano-30b.json")) as f:
+        sizes = json.load(f)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    flat = {name: sds(spec[1], jnp.float32 if weights.float32_leaf(name)
+                      else jnp.bfloat16)
+            for name, spec in weights.weight_shapes(sizes).items()}
+    total = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                for x in flat.values())
+    assert 10.8e9 < total < 10.9e9
+    ids = sds((weights.BALANCE_SEQUENCES, weights.BALANCE_TOKENS), jnp.int32)
+    memory = weights.balance_program(sizes, reference).lower(
+        flat, ids).compile().memory_analysis()
+    assert memory.temp_size_in_bytes < 1.5 * (1 << 30)
+    assert memory.output_size_in_bytes < 16 << 10     # the biases alone
 
 
 @pytest.mark.parametrize("slots, vocab", [(96, 200192), (16, 261120)])
