@@ -29,7 +29,8 @@ from jax.sharding import PartitionSpec
 
 from deepspeed_tpu.ops import overlap as _overlap
 from deepspeed_tpu.ops.transformer.flash_attention import (
-    dense_attention, flash_attention, flash_attention_rematerializable,
+    dense_attention, flash_attention, flash_attention_qkv,
+    flash_attention_qkv_usable, flash_attention_rematerializable,
     flash_attention_usable)
 from deepspeed_tpu.ops.transformer.quantized_matmul import (KERNEL_SCALE,
                                                             int8_matmul)
@@ -59,7 +60,8 @@ class GPT2Config:
     # d=64 head packing in the flash kernel: "auto" pairs two heads per
     # grid step on real TPU so every score/output matmul contracts over
     # K=128 (the MXU's native width; unpacked d=64 runs half-starved),
-    # "packed"/"off" force it. Odd B*H counts pad one zero row.
+    # "packed"/"off" force it. An odd head count leaves the last pair
+    # one head.
     attention_head_packing: str = "auto"
     # Fused non-attention epilogues ("auto"|"on"|"off"): the block's
     # c_proj-bias + residual + ln_2 chain and the c_fc-bias + GeLU run
@@ -185,7 +187,18 @@ def causal_attention_xla(q, k, v, dropout_rng=None, dropout_rate=0.0,
                            deterministic=deterministic)
 
 
-def _attention(config, q, k, v, dropout_rng, deterministic):
+def _attention(config, qkv, dropout_rng, deterministic):
+    """Attention over the `c_attn` product [B, T, 3·C] -> [B, T, C].
+    Where the flash kernel can read q, k and v out of the product where
+    it lies (C a whole number of its column tiles: seen in the shape),
+    it gets the product whole and no slice of it is copied; everywhere
+    else q, k and v are its three slices."""
+    b, t, c = qkv.shape[0], qkv.shape[1], qkv.shape[2] // 3
+    heads = (b, t, config.n_head, config.head_dim)
+
+    def split():
+        return tuple(x.reshape(heads) for x in jnp.split(qkv, 3, axis=-1))
+
     if config.sequence_parallel:
         # shard_map over the sequence axis composes inside the engine's
         # GSPMD step: activations reshard to [B, T/sp, H, D] on entry
@@ -201,31 +214,36 @@ def _attention(config, q, k, v, dropout_rng, deterministic):
                 f"sequence_parallel={config.sequence_parallel!r}; "
                 f"valid values: {sorted(impls)} or None")
         fn = impls[config.sequence_parallel]
-        return fn(q, k, v, mesh=config.sp_mesh,
+        return fn(*split(), mesh=config.sp_mesh,
                   axis_name=config.sp_axis, causal=True,
-                  head_packing=config.attention_head_packing)
+                  head_packing=config.attention_head_packing
+                  ).reshape(b, t, c)
     if config.attention_impl in ("pallas", "auto"):
-        if flash_attention_usable(q, deterministic or config.dropout == 0.0):
-            if config.remat:
-                # (out, lse) carry checkpoint_names: with a
-                # save_only_these_names:attn_out,attn_lse policy the
-                # backward never re-runs the flash fwd kernel
-                return flash_attention_rematerializable(
-                    q, k, v, causal=True,
-                    head_packing=config.attention_head_packing)
-            return flash_attention(
-                q, k, v, causal=True,
-                head_packing=config.attention_head_packing)
+        no_dropout = deterministic or config.dropout == 0.0
+        packing = config.attention_head_packing
+        # under remat (out, lse) carry checkpoint_names: with a
+        # save_only_these_names:attn_out,attn_lse policy the backward
+        # never re-runs the flash fwd kernel
+        if flash_attention_qkv_usable(qkv, config.n_head, no_dropout):
+            return flash_attention_qkv(
+                qkv, config.n_head, causal=True, head_packing=packing,
+                rematerializable=config.remat).reshape(b, t, c)
+        if flash_attention_usable(jax.ShapeDtypeStruct(heads, qkv.dtype),
+                                  no_dropout):
+            flash = flash_attention_rematerializable if config.remat \
+                else flash_attention
+            return flash(*split(), causal=True,
+                         head_packing=packing).reshape(b, t, c)
         if config.attention_impl == "pallas":
             raise RuntimeError("pallas attention requested but unusable "
                                "for these shapes/settings")
-    out = causal_attention_xla(q, k, v, dropout_rng, config.dropout,
+    out = causal_attention_xla(*split(), dropout_rng, config.dropout,
                                deterministic)
     # keep the named residual on the XLA path too, so
     # save_only_these_names:attn_out policies behave uniformly (no lse
     # here — XLA attention has no separate softmax stats to save)
     from jax.ad_checkpoint import checkpoint_name
-    return checkpoint_name(out, "attn_out")
+    return checkpoint_name(out, "attn_out").reshape(b, t, c)
 
 
 def _quant_dense(features, cfg, name, init_scale=1.0, split=False,
@@ -340,10 +358,6 @@ class GPT2Block(nn.Module):
         else:
             x = ln1(hidden).astype(cfg.dtype)
         qkv = proj(3 * cfg.n_embd, "c_attn")(x)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, t, cfg.n_head, cfg.head_dim)
-        k = k.reshape(b, t, cfg.n_head, cfg.head_dim)
-        v = v.reshape(b, t, cfg.n_head, cfg.head_dim)
         drop_rng = None
         if not deterministic and cfg.dropout > 0.0:
             drop_rng = self.make_rng("dropout")
@@ -353,8 +367,7 @@ class GPT2Block(nn.Module):
         # ~27 MB/layer at 1.5B and the backward pass never re-runs the
         # flash forward kernel — the sweet spot between full remat
         # (+1 fwd of recompute) and dots_saveable (~235 MB/layer, OOM).
-        attn = _attention(cfg, q, k, v, drop_rng, deterministic)
-        attn = attn.reshape(b, t, cfg.n_embd)
+        attn = _attention(cfg, qkv, drop_rng, deterministic)
         if use_fused:
             attn_y, attn_b = proj(
                 cfg.n_embd, "c_proj",
@@ -432,15 +445,10 @@ class MoEGPT2Block(nn.Module):
 
         x = ln1(hidden).astype(cfg.dtype)
         qkv = proj(3 * cfg.n_embd, "c_attn")(x)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, t, cfg.n_head, cfg.head_dim)
-        k = k.reshape(b, t, cfg.n_head, cfg.head_dim)
-        v = v.reshape(b, t, cfg.n_head, cfg.head_dim)
         drop_rng = None
         if not deterministic and cfg.dropout > 0.0:
             drop_rng = self.make_rng("dropout")
-        attn = _attention(cfg, q, k, v, drop_rng, deterministic)
-        attn = attn.reshape(b, t, cfg.n_embd)
+        attn = _attention(cfg, qkv, drop_rng, deterministic)
         attn = proj(cfg.n_embd, "c_proj",
                     init_scale=1.0 / np.sqrt(2 * cfg.n_layer))(attn)
         attn = nn.Dropout(cfg.dropout)(attn, deterministic=deterministic)

@@ -42,6 +42,14 @@ def _mesh_of(operands):
     return None
 
 
+def divides_cols(x):
+    """Whether a launch over `x` would divide COLS: the mesh in its type
+    has a free model axis wider than one device."""
+    mesh = _mesh_of((x,))
+    return mesh is not None and MODEL_AXIS in mesh.axis_names and \
+        MODEL_AXIS not in mesh.manual_axes and mesh.shape[MODEL_AXIS] > 1
+
+
 def _axes_dividing(mesh, free, candidates, sizes):
     """The `candidates` that are free mesh axes wider than one device
     and whose product divides every size in `sizes`."""
@@ -52,7 +60,7 @@ def _axes_dividing(mesh, free, candidates, sizes):
     return tuple(axes)
 
 
-def per_device(fn, in_dims, out_dims, row_summed=()):
+def per_device(fn, in_dims, out_dims, row_summed=(), cols=()):
     """`fn` as a function of the same array operands that launches once
     per device of the operands' mesh.
 
@@ -60,7 +68,10 @@ def per_device(fn, in_dims, out_dims, row_summed=()):
     entry per dimension: ROWS, COLS or None (held whole by every
     device). row_summed: indices of outputs that are sums over ROWS
     (a bias gradient): each device's partial sum is added over the
-    devices that divided the rows.
+    devices that divided the rows. cols: sizes the mesh's columns have
+    to divide besides the COLS dimensions themselves (the head count
+    behind a [B, T, H·D] operand: a shard holds whole heads, or the
+    model axis divides nothing).
     """
     def call(*operands):
         mesh = _mesh_of(operands)
@@ -76,7 +87,8 @@ def per_device(fn, in_dims, out_dims, row_summed=()):
         mesh_axes = {
             ROWS: _axes_dividing(mesh, free, (DATA_AXIS, EXPERT_AXIS),
                                  sizes(ROWS)),
-            COLS: _axes_dividing(mesh, free, (MODEL_AXIS,), sizes(COLS)),
+            COLS: _axes_dividing(mesh, free, (MODEL_AXIS,),
+                                 sizes(COLS) + list(cols)),
         }
 
         def spec(dims):

@@ -6,23 +6,41 @@ TPU-native replacement for the reference's fused CUDA attention chain
 [B, H, T, T] score tensor in HBM, the kernel streams K/V blocks through
 VMEM with an online-softmax running (m, l) pair, so HBM traffic is
 O(T·d) and the MXU sees back-to-back [block_q, d]×[d, block_k] matmuls.
+Backward is the standard two-kernel flash backward (dKV sweep + dQ
+sweep) off saved logsumexp rows — the reference instead checkpoints 17
+intermediate activations (`ops/transformer/transformer.py:155-213`).
 
-Layout: [B, T, H, D] in/out (the model's native layout); the kernel grid
-is (B·H, T/block_q, T/block_k) with K innermost so the (m, l, acc)
-scratch carries across K blocks.  Backward is the standard two-kernel
-flash backward (dKV sweep + dQ sweep) off saved logsumexp rows — the
-reference instead checkpoints 17 intermediate activations
-(`ops/transformer/transformer.py:155-213`).
+Layout.  ONE layout, the projections' own: every operand and result of
+a launch is a `[B, T, H·D]` array (the free reshape of the public
+`[B, T, H, D]`), read and written in COLUMN TILES of 128 lanes (two
+heads of 64 side by side, which is what `[B, T, H, 64]` already is in
+memory) or of D lanes (one head, D a multiple of 128).  The grid walks
+(batch group, T block, column tile, T block); a block is
+`(G, block, tile)` at `(b, qi | ki, j)`, in the tiled HBM layout a run
+of whole tiles.  Nothing is transposed, paired or unpaired outside the
+kernel.  Three things follow from the shape alone:
 
-Head packing (d = 64).  The MXU contracts 128 elements per pass, so a
-d=64 attention runs its QK^T at K=64 (half the systolic rows idle) and
-its PV at N=64 (half the lanes idle; what that costs is not measured
-on the current installation).  With `head_packing` the kernel
-processes TWO heads per grid step in a feature-packed layout
-[rows, T, 128] (adjacent B·H rows pair up; an odd B·H count pads one
-zero row that is sliced off):
+  * where H·D is a whole number of tiles the caller may hand over the
+    `c_attn` product `[B, T, 3·H·D]` whole (`flash_attention_qkv`): q,
+    k and v are then column tiles j, n + j and 2n + j of ONE operand
+    and the split costs no copy, forward or backward;
+  * where it is not (H odd at D = 64: GPT-2 1.5B's 25 heads) the last
+    tile holds one head. Its out-of-range lanes are undefined on the
+    way in, so the kernel zeroes them before any contraction, and
+    dropped by the edge block's write on the way out;
+  * the row statistics (lse, δ) are `[B, T, H]` float32, the layout in
+    which XLA's rowsum(dO ⊙ out) comes out. A launch reads the block
+    `(G, block_q, H)` and picks its tile's one or two columns with a
+    lane select; the forward, whose column tiles of one T block follow
+    each other, writes its columns into the block the same way.
 
-    Qp  = [q0 | q1]                          [bq, 128]   (dense)
+Head packing (d = 64) is a choice INSIDE the kernel, on the same tile.
+The MXU contracts 128 elements per pass, so two d = 64 heads worked in
+turn run QK^T at K=64 (half the systolic rows idle) and PV at N=64
+(half the lanes idle).  With `head_packing` the kernel works the tile
+whole:
+
+    Qp  = [q0 | q1]                          [bq, 128]   (the tile)
     Kbd = [[k0 | 0], [0 | k1]]               [2·bk, 128] (block diagonal)
     S   = Qp · Kbdᵀ = [S0 | S1]              [bq, 2·bk]  K=128 contraction
     O   = P · Vbd   = [O0 | O1]              [bq, 128]   N=128 lanes
@@ -35,13 +53,15 @@ unpacked results agree bit-for-bit under a deterministic backend.  The
 backward's dV/dK contractions come out row-stacked ([2·bk, 128] with
 the useful blocks on the diagonal) and are folded back with a lane
 select.  `head_packing="auto"` packs on real TPU for d=64; the CPU
-interpreter path, d ≠ 64, and `"off"` use the unpacked kernel.
+interpreter path, d ≠ 64, and `"off"` work the tile's heads in turn.
 
 Ring-attention partial merge.  `flash_attention_merge` fuses the ring
 step's (out, lse) softmax-partial merge into the kernel epilogue: the
 previous partial rides in as two extra refs and the merged result is
 written directly, so the per-step partial never round-trips HBM through
 an XLA elementwise merge chain (`ops/sequence/ring_attention.py`).
+(Its interface, and `flash_attention_with_lse`'s, keeps lse as
+`[B, H, T, 1]`: those two re-lay B·H·T floats at their boundary.)
 
 On non-TPU backends the same kernels run in Pallas interpreter mode so
 CPU CI validates kernel logic bit-for-bit against the XLA reference path.
@@ -55,7 +75,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.ops.per_device import COLS, ROWS, per_device
+from deepspeed_tpu.ops.per_device import (COLS, ROWS, divides_cols,
+                                          per_device)
 
 NEG_INF = -1e30
 # The online softmax runs in log2 space: exp2 is the TPU VPU's native
@@ -123,7 +144,8 @@ def _fit_block(block, t):
 def flash_attention_usable(q, no_dropout: bool,
                            block_q=None, block_k=None):
     """The kernel handles [B, T, H, D] with T divisible by the block size
-    and D a lane-friendly multiple of 64; dropout stays on the XLA path."""
+    and D = 64 (two heads a column tile) or a multiple of 128 (one);
+    dropout stays on the XLA path."""
     if not no_dropout:
         return False
     if q.ndim != 4:
@@ -135,16 +157,17 @@ def flash_attention_usable(q, no_dropout: bool,
     # t for 128 <= t < 1024, so without it a T like 136 would "fit" its
     # own single tile — unaligned lanes Mosaic rejects or pads on real
     # TPU (CPU interpret mode hides it).
-    return t % block_q == 0 and t % block_k == 0 and d % 64 == 0 and \
-        t >= 128 and t % 128 == 0
+    return t % block_q == 0 and t % block_k == 0 and \
+        (d == 64 or d % 128 == 0) and t >= 128 and t % 128 == 0
 
 
 def _resolve_head_packing(head_packing, d, interpret):
-    """Head-packing mode -> bool.  "auto" packs d=64 heads pairwise on
-    real TPU (K=128 contractions); the interpreter path stays unpacked
-    so CPU CI timings/VMEM budgets reflect the per-head kernel unless a
-    test forces "packed".  Odd B·H counts are handled by a one-row zero
-    pad, NOT a fallback — the flagship's 11×25 = 275 rows still pack."""
+    """Head-packing mode -> bool.  "auto" works a d=64 column tile's two
+    heads as one K=128 contraction on real TPU; the interpreter path
+    works them in turn so CPU CI timings/VMEM budgets reflect the
+    per-head kernel unless a test forces "packed".  An odd head count
+    leaves one head in the last tile, NOT a fallback — the flagship's
+    25 heads still pack."""
     if head_packing in ("off", False, 0):
         return False
     if head_packing in ("packed", True, 1):
@@ -162,28 +185,54 @@ def _resolve_head_packing(head_packing, d, interpret):
 
 
 # ----------------------------------------------------------------------
-# packed-layout helpers
+# what a kernel does with one column tile
 # ----------------------------------------------------------------------
-def _pack_pairs(x):
-    """[rows, T, d] -> [ceil(rows/2), T, 2·d]: adjacent rows pair up
-    feature-wise (row 2i in lanes [:d], row 2i+1 in lanes [d:]); an odd
-    row count pads one zero row.  Also packs [rows, T, 1] lse/delta
-    columns into [pairs, T, 2]."""
-    rows, t, d = x.shape
-    if rows % 2:
-        x = jnp.concatenate([x, jnp.zeros((1, t, d), x.dtype)], axis=0)
-    pairs = (rows + 1) // 2
-    return x.reshape(pairs, 2, t, d).transpose(0, 2, 1, 3) \
-        .reshape(pairs, t, 2 * d)
+def _tile_width(d):
+    """Lanes of one column tile: two heads of 64, or one of d."""
+    return 128 if d == 64 else d
 
 
-def _unpack_pairs(x, rows):
-    """Inverse of `_pack_pairs`, slicing off the odd-count pad row."""
-    pairs, t, dd = x.shape
-    d = dd // 2
-    x = x.reshape(pairs, t, 2, d).transpose(0, 2, 1, 3) \
-        .reshape(2 * pairs, t, d)
-    return x[:rows]
+def _tile(ref, j, ncol, edge):
+    """The block of `ref`. `edge` (static) is how many lanes of the LAST
+    column tile lie inside the array, 0 where all do: past them an edge
+    block holds whatever was in VMEM, and NaN × 0 in a block-diagonal
+    contraction would reach the real head, so they are zeroed here."""
+    x = ref[...]
+    if not edge:
+        return x
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    keep = jnp.logical_or(j < ncol - 1, lane < edge)
+    return jnp.where(keep, x, jnp.zeros_like(x))
+
+
+def _tile_stats(ref, j, heads):
+    """A [G, bq, H] block of row statistics -> the columns [G, bq, 1] of
+    column tile j's `heads` heads (a lane select; a column past H, the
+    odd head count's phantom, reads 0)."""
+    x = ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    return [jnp.sum(jnp.where(lane == heads * j + i, x, 0.0), axis=-1,
+                    keepdims=True) for i in range(heads)]
+
+
+def _store_tile_stats(ref, j, cols):
+    """Write column tile j's statistics into its columns of the
+    [G, bq, H] block, which stays in VMEM while the grid walks the
+    column tiles of one T block."""
+    x = ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    for i, col in enumerate(cols):
+        x = jnp.where(lane == len(cols) * j + i, col, x)
+    ref[...] = x
+
+
+def _lanes(x, i, d):
+    """Head i's d lanes of a tile."""
+    return x[:, :, i * d:(i + 1) * d]
+
+
+def _join(parts):
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-1)
 
 
 def _block_diag_pack(x, half):
@@ -218,9 +267,9 @@ def _halves(a, b, half):
 
 
 def _two_cols(x, half):
-    """Collapse a half-broadcast [G, bq, 2·half] stat to its two
-    representative columns [G, bq, 2]."""
-    return jnp.concatenate([x[:, :, :1], x[:, :, half:half + 1]], axis=-1)
+    """A half-broadcast [G, bq, 2·half] stat's two representative
+    columns, each [G, bq, 1]."""
+    return [x[:, :, :1], x[:, :, half:half + 1]]
 
 
 def _mask_causal(s, causal, qi, ki, block_q, block_k):
@@ -259,22 +308,27 @@ def _mask_causal_packed(s, causal, qi, ki, block_q, block_k):
 # ----------------------------------------------------------------------
 # forward
 # ----------------------------------------------------------------------
+# Grid (batch group, q block, column tile, k block): K innermost so the
+# (m, l, acc) scratch carries across K blocks, the column tile inside
+# the q block so the block of lse stays put while its columns are
+# written.
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale, causal,
-                block_q, block_k, merge):
+                block_q, block_k, merge, d, edge):
+    """The tile's heads in turn, each at its own d lanes."""
     if merge:
         (po_ref, plse_ref, o_ref, lse_ref, lse_n_ref,
          m_scr, l_scr, acc_scr) = rest
     else:
         o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    qi, j, ki = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    ncol, nk = pl.num_programs(2), pl.num_programs(3)
+    heads = q_ref.shape[-1] // d
 
     @pl.when(ki == 0)
     def _():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
     # Causal: a K block strictly above the diagonal contributes nothing —
     # skip its matmuls entirely (the grid still visits it).
@@ -284,78 +338,87 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale, causal,
 
     @pl.when(visible)
     def _():
-        q = q_ref[...]                            # [G, bq, d] native dtype
-        k = k_ref[...]                            # [G, bk, d]
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * (sm_scale * LOG2E)
-        s = _mask_causal(s, causal, qi, ki, block_q, block_k)
+        qt = _tile(q_ref, j, ncol, edge)          # [G, bq, tile] native
+        kt = _tile(k_ref, j, ncol, edge)          # [G, bk, tile]
+        vt = _tile(v_ref, j, ncol, edge)
+        for i in range(heads):
+            q, k, v = (_lanes(x, i, d) for x in (qt, kt, vt))
+            s = jax.lax.dot_general(
+                q, k, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32) * (sm_scale * LOG2E)
+            s = _mask_causal(s, causal, qi, ki, block_q, block_k)
 
-        m_prev = m_scr[:, :, :1]                   # [G, bq, 1]
-        l_prev = l_scr[:, :, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp2(s - m_new)                    # [G, bq, bk]
-        alpha = jnp.exp2(m_prev - m_new)           # [G, bq, 1]
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            m_prev = m_scr[i, :, :, :1]            # [G, bq, 1]
+            l_prev = l_scr[i, :, :, :1]
+            m_cur = jnp.max(s, axis=-1, keepdims=True)
+            m_new = jnp.maximum(m_prev, m_cur)
+            p = jnp.exp2(s - m_new)                # [G, bq, bk]
+            alpha = jnp.exp2(m_prev - m_new)       # [G, bq, 1]
+            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
 
-        v = v_ref[...]                             # [G, bk, d]
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)    # [G, bq, d]
-        acc_scr[...] = acc_scr[...] * alpha + pv
-        m_scr[:, :, :1] = m_new
-        l_scr[:, :, :1] = l_new
+            pv = jax.lax.dot_general(
+                p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)  # [G, bq, d]
+            acc_scr[i] = acc_scr[i] * alpha + pv
+            m_scr[i, :, :, :1] = m_new
+            l_scr[i, :, :, :1] = l_new
 
     @pl.when(ki == nk - 1)
     def _():
-        m = m_scr[:, :, :1]
-        l = l_scr[:, :, :1]
-        # log2-space LSE (= natural lse · log2e); consumed only by the
-        # backward kernels, which stay in the same space
-        lse_n = m + jnp.log2(l)
         if merge:
-            # in-kernel softmax-partial merge: fold the previous ring
-            # partial into this pass's (m, l, acc) before the single
-            # HBM write (ops/sequence/ring_attention.py)
-            plse = plse_ref[...]                   # [G, bq, 1]
-            mm = jnp.maximum(lse_n, plse)
-            w_p = jnp.exp2(plse - mm)
-            # w_n/ l == exp2(m - mm): acc is unnormalized, so its merge
-            # weight folds the 1/l normalization in
-            wsum = w_p + jnp.exp2(lse_n - mm)
-            out = (po_ref[...] * w_p +
-                   acc_scr[...] * jnp.exp2(m - mm)) / wsum
-            o_ref[...] = out.astype(o_ref.dtype)
-            lse_ref[...] = mm + jnp.log2(wsum)
-            lse_n_ref[...] = lse_n
-        else:
-            o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
-            lse_ref[...] = lse_n
+            plses = _tile_stats(plse_ref, j, heads)
+            po = po_ref[...]
+        outs, lses, lse_ns = [], [], []
+        for i in range(heads):
+            m = m_scr[i, :, :, :1]
+            l = l_scr[i, :, :, :1]
+            # log2-space LSE (= natural lse · log2e); consumed only by
+            # the backward kernels, which stay in the same space
+            lse_n = m + jnp.log2(l)
+            if merge:
+                # in-kernel softmax-partial merge: fold the previous
+                # ring partial into this pass's (m, l, acc) before the
+                # single HBM write (ops/sequence/ring_attention.py)
+                plse = plses[i]                    # [G, bq, 1]
+                mm = jnp.maximum(lse_n, plse)
+                w_p = jnp.exp2(plse - mm)
+                # w_n / l == exp2(m - mm): acc is unnormalized, so its
+                # merge weight folds the 1/l normalization in
+                wsum = w_p + jnp.exp2(lse_n - mm)
+                outs.append((_lanes(po, i, d) * w_p +
+                             acc_scr[i] * jnp.exp2(m - mm)) / wsum)
+                lses.append(mm + jnp.log2(wsum))
+                lse_ns.append(lse_n)
+            else:
+                outs.append(acc_scr[i] / l)
+                lses.append(lse_n)
+        o_ref[...] = _join(outs).astype(o_ref.dtype)
+        _store_tile_stats(lse_ref, j, lses)
+        if merge:
+            _store_tile_stats(lse_n_ref, j, lse_ns)
 
 
 def _fwd_kernel_packed(q_ref, k_ref, v_ref, *rest, sm_scale, causal,
-                       block_q, block_k, merge):
-    """Two heads per grid step in the feature-packed layout: the QK^T
-    contraction runs at K=128 and PV at N=128 (see module docstring).
-    m/l scratch is half-broadcast-stored ([G, bq, 128] with each head's
-    stat replicated across its 64 lanes) so alpha/l apply to the packed
-    acc with plain elementwise ops."""
+                       block_q, block_k, merge, edge):
+    """The tile's two heads at once: the QK^T contraction runs at K=128
+    and PV at N=128 (see module docstring).  m/l scratch is
+    half-broadcast-stored ([G, bq, 128] with each head's stat replicated
+    across its 64 lanes) so alpha/l apply to the packed acc with plain
+    elementwise ops."""
     if merge:
         (po_ref, plse_ref, o_ref, lse_ref, lse_n_ref,
          m_scr, l_scr, acc_scr) = rest
     else:
         o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    qi, j, ki = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    ncol, nk = pl.num_programs(2), pl.num_programs(3)
     half = q_ref.shape[-1] // 2
 
     @pl.when(ki == 0)
     def _():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
 
     visible = True
     if causal:
@@ -363,8 +426,8 @@ def _fwd_kernel_packed(q_ref, k_ref, v_ref, *rest, sm_scale, causal,
 
     @pl.when(visible)
     def _():
-        q = q_ref[...]                             # [G, bq, 128]
-        k = k_ref[...]                             # [G, bk, 128]
+        q = _tile(q_ref, j, ncol, edge)            # [G, bq, 128]
+        k = _tile(k_ref, j, ncol, edge)            # [G, bk, 128]
         kbd = _block_diag_pack(k, half)            # [G, 2bk, 128]
         s = jax.lax.dot_general(
             q, kbd, (((2,), (2,)), ((0,), (0,))),
@@ -385,7 +448,7 @@ def _fwd_kernel_packed(q_ref, k_ref, v_ref, *rest, sm_scale, causal,
             jnp.sum(p0, axis=-1, keepdims=True),
             jnp.sum(p1, axis=-1, keepdims=True), half)
 
-        v = v_ref[...]
+        v = _tile(v_ref, j, ncol, edge)
         vbd = _block_diag_pack(v, half)            # [G, 2bk, 128]
         p = jnp.concatenate([p0, p1], axis=-1)     # [G, bq, 2bk]
         pv = jax.lax.dot_general(
@@ -401,117 +464,148 @@ def _fwd_kernel_packed(q_ref, k_ref, v_ref, *rest, sm_scale, causal,
         l = l_scr[...]
         lse_n = m + jnp.log2(l)                    # half-broadcast
         if merge:
-            plse = plse_ref[...]                   # [G, bq, 2]
-            plse_b = _halves(plse[:, :, :1], plse[:, :, 1:2], half)
+            plse_b = _halves(*_tile_stats(plse_ref, j, 2), half)
             mm = jnp.maximum(lse_n, plse_b)
             w_p = jnp.exp2(plse_b - mm)
             wsum = w_p + jnp.exp2(lse_n - mm)
             out = (po_ref[...] * w_p +
                    acc_scr[...] * jnp.exp2(m - mm)) / wsum
             o_ref[...] = out.astype(o_ref.dtype)
-            lse_ref[...] = _two_cols(mm + jnp.log2(wsum), half)
-            lse_n_ref[...] = _two_cols(lse_n, half)
+            _store_tile_stats(lse_ref, j,
+                              _two_cols(mm + jnp.log2(wsum), half))
+            _store_tile_stats(lse_n_ref, j, _two_cols(lse_n, half))
         else:
             o_ref[...] = (acc_scr[...] / l).astype(o_ref.dtype)
-            lse_ref[...] = _two_cols(lse_n, half)
+            _store_tile_stats(lse_ref, j, _two_cols(lse_n, half))
 
 
-def _head_group(bh, block_q, block_k, d, tile_budget=8 * 1024 * 1024):
-    """Largest head-group G (≤ default) dividing B·H, with the fp32 score
-    tile capped to `tile_budget` bytes of VMEM (the backward kernels keep
-    ~4 score-sized tiles live, so they pass a smaller budget)."""
+def _head_group(b, block_q, score_k, tile_budget=8 * 1024 * 1024):
+    """Largest group G (≤ default) of batch rows worked in one grid step
+    on one column tile, dividing B, with the fp32 score tile
+    [G, block_q, score_k] capped to `tile_budget` bytes of VMEM (the
+    backward kernels keep ~4 score-sized tiles live, so they pass a
+    smaller budget)."""
     g = _DEFAULT_HEAD_GROUP
-    cap = max(1, tile_budget // (block_q * block_k * 4))
+    cap = max(1, tile_budget // (block_q * score_k * 4))
     g = min(g, cap)
-    while bh % g:
+    while b % g:
         g -= 1
     return max(g, 1)
 
 
 # operand layouts, as per_device dims: the batch divides with the rows
-# of the mesh, the heads with its columns
-_BTHD = (ROWS, None, COLS, None)
-_BHTX = (ROWS, COLS, None, None)
+# of the mesh, the heads (columns of [B, T, H·D], of [B, T, H]) with its
+# columns: WHOLE heads, so every launcher hands per_device H beside the
+# operands (`cols=`; m | H·D alone would cut a head in two). A shard
+# with an odd count of d=64 heads has its own edge tile.
+_BTC = (ROWS, None, COLS)
+_WHOLE = (ROWS, None, None)
 
 
-def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, pack,
+def _qkv_dims(qkv):
+    return (_WHOLE,) if len(qkv) == 1 else (_BTC,) * 3
+
+
+def _n_heads(qkv, d):
+    """H of a launch's q, k, v (as `_columns` takes them)."""
+    c = qkv[0].shape[-1]
+    return (c if len(qkv) == 3 else c // 3) // d
+
+
+def _columns(qkv, d):
+    """Of a launch's q, k, v (three [B, T, C] arrays, or the one
+    [B, T, 3·C] product): B, T, C, the tile width, the column tiles of
+    C, and each of q, k, v as (array, its first column tile)."""
+    b, t, c = qkv[0].shape
+    w = _tile_width(d)
+    if len(qkv) == 3:
+        assert c % d == 0, (c, d)
+        return b, t, c, w, pl.cdiv(c, w), [(x, 0) for x in qkv]
+    c //= 3
+    assert c % w == 0, (c, w)
+    return b, t, c, w, c // w, [(qkv[0], i * (c // w)) for i in range(3)]
+
+
+def _specs(g, block_q, block_k, w, h, q_axis, k_axis, col_axis):
+    """BlockSpecs of a grid whose axis 0 walks the batch groups,
+    `col_axis` the column tiles and `q_axis` / `k_axis` the T blocks of
+    q and of k (None: the one block there is). Returns (at_q, at_k,
+    stat): at_q(first) / at_k(first) the spec of a [B, T, ·] operand
+    read from its column tile `first` on, stat that of a [B, T, H] row
+    statistic."""
+    def t_block(ids, axis):
+        return 0 if axis is None else ids[axis]
+
+    def tiles(block, axis):
+        return lambda first=0: pl.BlockSpec(
+            (g, block, w),
+            lambda *ids: (ids[0], t_block(ids, axis), first + ids[col_axis]))
+
+    stat = pl.BlockSpec((g, block_q, h),
+                        lambda *ids: (ids[0], t_block(ids, q_axis), 0))
+    return tiles(block_q, q_axis), tiles(block_k, k_axis), stat
+
+
+def _fwd(qkv, d, sm_scale, causal, block_q, block_k, interpret, pack,
          prev=None):
-    """Forward launcher.  Returns (out [bh, t, d], lse [bh, t, 1]); with
-    `prev = (prev_out [B,T,H,D], prev_lse [B,H,T,1])` the kernel merges
-    the prior softmax partial in its epilogue and additionally returns
-    the CURRENT partial's lse_n [bh, t, 1] (the backward residual)."""
-    b, t, h, d = q.shape
+    """Forward launcher over `qkv` (a tuple: q, k, v [B, T, C], or the
+    [B, T, 3·C] product alone).  Returns (out [B, T, C], lse [B, T, H]);
+    with `prev = (prev_out [B, T, C], prev_lse [B, T, H])` the kernel
+    merges the prior softmax partial in its epilogue and additionally
+    returns the CURRENT partial's lse_n [B, T, H] (the backward
+    residual)."""
     merge = prev is not None
     local = functools.partial(
-        _fwd_local, sm_scale=sm_scale, causal=causal, block_q=block_q,
-        block_k=block_k, interpret=interpret, pack=pack)
-    outs = per_device(
+        _fwd_local, n_qkv=len(qkv), d=d, sm_scale=sm_scale, causal=causal,
+        block_q=block_q, block_k=block_k, interpret=interpret, pack=pack)
+    return per_device(
         local,
-        in_dims=(_BTHD,) * 3 + ((_BTHD, _BHTX) if merge else ()),
-        out_dims=(_BHTX,) * (3 if merge else 2))(
-            q, k, v, *(prev if merge else ()))
-    return tuple(o.reshape(b * h, t, o.shape[-1]) for o in outs)
+        in_dims=_qkv_dims(qkv) + ((_BTC, _BTC) if merge else ()),
+        out_dims=(_BTC,) * (3 if merge else 2), cols=(_n_heads(qkv, d),))(
+            *qkv, *(prev if merge else ()))
 
 
-def _fwd_local(q, k, v, *prev, sm_scale, causal, block_q, block_k,
+def _fwd_local(*operands, n_qkv, d, sm_scale, causal, block_q, block_k,
                interpret, pack):
-    """One device's launch: [B, T, H, D] blocks in, (out [B, H, T, D],
-    lse [B, H, T, 1][, lse_n]) out."""
-    b, t, h, d = q.shape
-    bh = b * h
+    """One device's launch."""
+    qkv, prev = operands[:n_qkv], operands[n_qkv:]
+    b, t, c, w, ncol, (q, k, v) = _columns(qkv, d)
+    h = c // d
+    heads = w // d
     merge = bool(prev)
-
-    # [B, T, H, D] -> [B*H, T, D]
-    def to_bht(x):
-        return x.transpose(0, 2, 1, 3).reshape(bh, t, d)
-    qt, kt, vt = to_bht(q), to_bht(k), to_bht(v)
-    if merge:
-        prev_out, prev_lse = prev
-        pot = to_bht(prev_out.astype(jnp.float32))
-        plse = prev_lse.astype(jnp.float32).reshape(bh, t, 1)
-
-    if pack:
-        qt, kt, vt = _pack_pairs(qt), _pack_pairs(kt), _pack_pairs(vt)
-        if merge:
-            pot, plse = _pack_pairs(pot), _pack_pairs(plse)
-    rows = qt.shape[0]                    # bh, or padded pair count
-    dl = qt.shape[-1]                     # d, or 2·d packed
-    lanes = 2 if pack else 1              # lse columns per row
 
     # 8 MB score-tile budget. A 24 MB budget (g=5 at the flagship
     # shape) measures ~20% faster on the ISOLATED kernel chain but ~1%
     # slower inside the full train step (VMEM pressure against the
-    # surrounding fusions) — keep the in-model winner.  The packed tile
-    # is [bq, 2·bk], so the same budget halves G there.
-    g = _head_group(rows, block_q, (2 if pack else 1) * block_k, dl)
+    # surrounding fusions) — keep the in-model winner.  A d=64 tile
+    # scores two heads, [bq, 2·bk] packed or twice [bq, bk] in turn.
+    g = _head_group(b, block_q, heads * block_k)
     nq, nk = t // block_q, t // block_k
-    grid = (rows // g, nq, nk)
-    kernel_fn = _fwd_kernel_packed if pack else _fwd_kernel
-    kernel = functools.partial(kernel_fn, sm_scale=sm_scale,
-                               causal=causal, block_q=block_q,
-                               block_k=block_k, merge=merge)
+    grid = (b // g, nq, ncol, nk)
+    static = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
+                  block_k=block_k, merge=merge, edge=c % w)
+    if pack:
+        kernel = functools.partial(_fwd_kernel_packed, **static)
+    else:
+        kernel = functools.partial(_fwd_kernel, d=d, **static)
 
-    def q_spec(width):
-        return pl.BlockSpec((g, block_q, width),
-                            lambda bhi, qi, ki: (bhi, qi, 0))
-
-    kv_spec = pl.BlockSpec((g, block_k, dl),
-                           lambda bhi, qi, ki: (bhi, ki, 0))
-    in_specs = [q_spec(dl), kv_spec, kv_spec]
-    operands = [qt, kt, vt]
-    out_specs = [q_spec(dl), q_spec(lanes)]
+    q_spec, kv_spec, stat_spec = _specs(g, block_q, block_k, w, h, 1, 3, 2)
+    stat_shape = jax.ShapeDtypeStruct((b, t, h), jnp.float32)
+    in_specs = [q_spec(q[1]), kv_spec(k[1]), kv_spec(v[1])]
+    operands = [q[0], k[0], v[0]]
+    out_specs = [q_spec(), stat_spec]
     out_shape = [
-        jax.ShapeDtypeStruct((rows, t, dl),
-                             jnp.float32 if merge else q.dtype),
-        jax.ShapeDtypeStruct((rows, t, lanes), jnp.float32),
+        jax.ShapeDtypeStruct((b, t, c),
+                             jnp.float32 if merge else q[0].dtype),
+        stat_shape,
     ]
     if merge:
-        in_specs += [q_spec(dl), q_spec(lanes)]
-        operands += [pot, plse]
-        out_specs.append(q_spec(lanes))
-        out_shape.append(
-            jax.ShapeDtypeStruct((rows, t, lanes), jnp.float32))
-    outs = pl.pallas_call(
+        in_specs += [q_spec(), stat_spec]
+        operands += list(prev)
+        out_specs.append(stat_spec)
+        out_shape.append(stat_shape)
+    lead = () if pack else (heads,)
+    return tuple(pl.pallas_call(
         kernel,
         name="flash_fwd" + ("_packed" if pack else ""),
         grid=grid,
@@ -520,26 +614,80 @@ def _fwd_local(q, k, v, *prev, sm_scale, causal, block_q, block_k,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((g, block_q, max(dl, 128)), jnp.float32),
-            pltpu.VMEM((g, block_q, max(dl, 128)), jnp.float32),
-            pltpu.VMEM((g, block_q, dl), jnp.float32),
+            pltpu.VMEM(lead + (g, block_q, 128), jnp.float32),
+            pltpu.VMEM(lead + (g, block_q, 128), jnp.float32),
+            pltpu.VMEM(lead + (g, block_q, w if pack else d),
+                       jnp.float32),
         ],
         interpret=interpret,
-    )(*operands)
-    if pack:
-        outs = [_unpack_pairs(o, bh) for o in outs]
-    return tuple(o.reshape(b, h, t, o.shape[-1]) for o in outs)
+    )(*operands))
 
 
 # ----------------------------------------------------------------------
 # backward
 # ----------------------------------------------------------------------
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale, causal,
-                    block_q, block_k):
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+def _p_ds(q, k, v, do, lse, delta, sm_scale, causal, qi, ki, block_q,
+          block_k):
+    """One head's recomputed P and dS for a [G, bq, bk] tile."""
+    s = jax.lax.dot_general(
+        q, k, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32) * (sm_scale * LOG2E)
+    s = _mask_causal(s, causal, qi, ki, block_q, block_k)
+    p = jnp.exp2(s - lse)                          # [G, bq, bk]
+    # dP = dO Vᵀ ; dS = P ⊙ (dP − δ) · scale
+    dp = jax.lax.dot_general(
+        do, v, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+    return p, p * (dp - delta) * sm_scale
+
+
+def _bwd_refs(refs, has_out, has_delta):
+    """A backward kernel's refs -> ((q, k, v, dO, out | None, lse,
+    delta | None), the results and scratch that follow)."""
+    refs = list(refs)
+    out = refs.pop(4) if has_out else None
+    delta = refs.pop(5) if has_delta else None
+    return (*refs[:4], out, refs[4], delta), refs[5:]
+
+
+def _deltas(do, out, delta_ref, j, heads, d):
+    """δ of the tile's heads, each [G, bq, 1]: rowsum(dO ⊙ out) over the
+    head's lanes where `out` is given, plus the head's column of the
+    `delta` operand where that is given."""
+    cols = [] if delta_ref is None else _tile_stats(delta_ref, j, heads)
+    if out is None:
+        return cols
+    prod = do.astype(jnp.float32) * out.astype(jnp.float32)
+    sums = [jnp.sum(_lanes(prod, i, d), axis=-1, keepdims=True)
+            for i in range(heads)]
+    return [a + b for a, b in zip(sums, cols)] if cols else sums
+
+
+def _bwd_tiles(operands, j, ncol, edge, heads, d):
+    """A backward kernel's operands (`_bwd_refs`) at column tile j: the
+    tiles of q, k, v and dO, and its heads' columns of lse and of δ."""
+    *tiles, out_ref, lse_ref, delta_ref = operands
+    q, k, v, do = (_tile(r, j, ncol, edge) for r in tiles)
+    out = None if out_ref is None else _tile(out_ref, j, ncol, edge)
+    return (q, k, v, do, _tile_stats(lse_ref, j, heads),
+            _deltas(do, out, delta_ref, j, heads, d))
+
+
+def _heads_of(operands, j, ncol, edge, d):
+    """`_bwd_tiles` head by head: a list of (q, k, v, dO: the head's
+    lanes of each tile; lse, δ: its columns)."""
+    heads = operands[0].shape[-1] // d
+    *tiles, lse, delta = _bwd_tiles(operands, j, ncol, edge, heads, d)
+    return [tuple(_lanes(x, i, d) for x in tiles) + (lse[i], delta[i])
+            for i in range(heads)]
+
+
+def _bwd_dkv_kernel(*refs, sm_scale, causal, block_q, block_k, d, edge,
+                    has_out, has_delta):
+    operands, (dk_ref, dv_ref, dk_scr, dv_scr) = _bwd_refs(
+        refs, has_out, has_delta)
+    ki, j, qi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    ncol, nq = pl.num_programs(2), pl.num_programs(3)
 
     @pl.when(qi == 0)
     def _():
@@ -552,44 +700,31 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(visible)
     def _():
-        q = q_ref[...]                             # [G, bq, d] native dtype
-        k = k_ref[...]                             # [G, bk, d]
-        v = v_ref[...]
-        do = do_ref[...]                           # [G, bq, d]
-        lse = lse_ref[...]                         # [G, bq, 1]
-        delta = delta_ref[...]                     # [G, bq, 1]
-
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * (sm_scale * LOG2E)
-        s = _mask_causal(s, causal, qi, ki, block_q, block_k)
-        p = jnp.exp2(s - lse)                      # [G, bq, bk]
-
-        # dV += Pᵀ dO
-        dv_scr[...] += jax.lax.dot_general(
-            p.astype(do.dtype), do, (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        # dP = dO Vᵀ ; dS = P ⊙ (dP − δ) · scale
-        dp = jax.lax.dot_general(
-            do, v, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale
-        # dK += dSᵀ Q
-        dk_scr[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
+        for i, (q, k, v, do, lse, delta) in enumerate(
+                _heads_of(operands, j, ncol, edge, d)):
+            p, ds = _p_ds(q, k, v, do, lse, delta, sm_scale, causal,
+                          qi, ki, block_q, block_k)
+            # dV += Pᵀ dO
+            dv_scr[i] += jax.lax.dot_general(
+                p.astype(do.dtype), do, (((1,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+            # dK += dSᵀ Q
+            dk_scr[i] += jax.lax.dot_general(
+                ds.astype(q.dtype), q, (((1,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
 
     @pl.when(qi == nq - 1)
     def _():
-        dk_ref[...] = dk_scr[...].astype(dk_ref.dtype)
-        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+        heads = range(dk_scr.shape[0])
+        dk_ref[...] = _join([dk_scr[i] for i in heads]).astype(dk_ref.dtype)
+        dv_ref[...] = _join([dv_scr[i] for i in heads]).astype(dv_ref.dtype)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, dq_scr, *, sm_scale, causal, block_q, block_k):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+def _bwd_dq_kernel(*refs, sm_scale, causal, block_q, block_k, d, edge,
+                   has_out, has_delta):
+    operands, (dq_ref, dq_scr) = _bwd_refs(refs, has_out, has_delta)
+    qi, j, ki = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    ncol, nk = pl.num_programs(2), pl.num_programs(3)
 
     @pl.when(ki == 0)
     def _():
@@ -601,95 +736,86 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(visible)
     def _():
-        q = q_ref[...]                             # [G, bq, d]
-        k = k_ref[...]                             # [G, bk, d]
-        v = v_ref[...]
-        do = do_ref[...]
-        lse = lse_ref[...]
-        delta = delta_ref[...]
-
-        s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * (sm_scale * LOG2E)
-        s = _mask_causal(s, causal, qi, ki, block_q, block_k)
-        p = jnp.exp2(s - lse)
-        dp = jax.lax.dot_general(
-            do, v, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * sm_scale
-        # dQ += dS K
-        dq_scr[...] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
+        for i, (q, k, v, do, lse, delta) in enumerate(
+                _heads_of(operands, j, ncol, edge, d)):
+            _, ds = _p_ds(q, k, v, do, lse, delta, sm_scale, causal,
+                          qi, ki, block_q, block_k)
+            # dQ += dS K
+            dq_scr[i] += jax.lax.dot_general(
+                ds.astype(k.dtype), k, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
 
     @pl.when(ki == nk - 1)
     def _():
-        dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
+        dq_ref[...] = _join([dq_scr[i] for i in range(dq_scr.shape[0])]) \
+            .astype(dq_ref.dtype)
 
 
-def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dk_ref, dv_ref, *, sm_scale, causal,
-                      block_q, block_k):
+def _bwd_fused_kernel(*refs, sm_scale, causal, block_q, block_k, d, edge,
+                      has_out, has_delta):
     """Single-tile backward (T == block): s, p and dP exist once, so
     dQ, dK and dV all come out of ONE pass — the two-kernel flash
     backward recomputes s/p (and dP) in each sweep, paying ~2x the
     matmul+exp work at tiles the VMEM can hold whole."""
-    q = q_ref[...]
-    k = k_ref[...]
-    v = v_ref[...]
-    do = do_ref[...]
-    lse = lse_ref[...]
-    delta = delta_ref[...]
-
-    s = jax.lax.dot_general(
-        q, k, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32) * (sm_scale * LOG2E)
-    s = _mask_causal(s, causal, 0, 0, block_q, block_k)
-    p = jnp.exp2(s - lse)
-    dv_ref[...] = jax.lax.dot_general(
-        p.astype(do.dtype), do, (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32).astype(dv_ref.dtype)
-    dp = jax.lax.dot_general(
-        do, v, (((2,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)
-    ds = p * (dp - delta) * sm_scale
-    dk_ref[...] = jax.lax.dot_general(
-        ds.astype(q.dtype), q, (((1,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32).astype(dk_ref.dtype)
-    dq_ref[...] = jax.lax.dot_general(
-        ds.astype(k.dtype), k, (((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32).astype(dq_ref.dtype)
+    operands, (dq_ref, dk_ref, dv_ref) = _bwd_refs(refs, has_out, has_delta)
+    j, ncol = pl.program_id(1), pl.num_programs(1)
+    dqs, dks, dvs = [], [], []
+    for q, k, v, do, lse, delta in _heads_of(operands, j, ncol, edge, d):
+        p, ds = _p_ds(q, k, v, do, lse, delta, sm_scale, causal, 0, 0,
+                      block_q, block_k)
+        dvs.append(jax.lax.dot_general(
+            p.astype(do.dtype), do, (((1,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32))
+        dks.append(jax.lax.dot_general(
+            ds.astype(q.dtype), q, (((1,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32))
+        dqs.append(jax.lax.dot_general(
+            ds.astype(k.dtype), k, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32))
+    dq_ref[...] = _join(dqs).astype(dq_ref.dtype)
+    dk_ref[...] = _join(dks).astype(dk_ref.dtype)
+    dv_ref[...] = _join(dvs).astype(dv_ref.dtype)
 
 
 def _packed_p_ds(q, k, v, do, lse, delta, half, sm_scale, causal, qi, ki,
                  block_q, block_k):
     """Shared packed-backward front half: recompute P and dS for a
-    [G, bq, 2·bk] tile at K=128 contractions.  Returns (p, ds, kbd)."""
+    [G, bq, 2·bk] tile at K=128 contractions.  `lse`, `delta`: the two
+    heads' columns.  Returns (p, ds, kbd)."""
     kbd = _block_diag_pack(k, half)                # [G, 2bk, 128]
     s = jax.lax.dot_general(
         q, kbd, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32) * (sm_scale * LOG2E)
     s = _mask_causal_packed(s, causal, qi, ki, block_q, block_k)
-    p0 = jnp.exp2(s[:, :, :block_k] - lse[:, :, :1])
-    p1 = jnp.exp2(s[:, :, block_k:] - lse[:, :, 1:2])
+    p0 = jnp.exp2(s[:, :, :block_k] - lse[0])
+    p1 = jnp.exp2(s[:, :, block_k:] - lse[1])
     p = jnp.concatenate([p0, p1], axis=-1)         # [G, bq, 2bk]
     vbd = _block_diag_pack(v, half)
     dp = jax.lax.dot_general(
         do, vbd, (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)        # [G, bq, 2bk]
-    ds0 = p0 * (dp[:, :, :block_k] - delta[:, :, :1]) * sm_scale
-    ds1 = p1 * (dp[:, :, block_k:] - delta[:, :, 1:2]) * sm_scale
+    ds0 = p0 * (dp[:, :, :block_k] - delta[0]) * sm_scale
+    ds1 = p1 * (dp[:, :, block_k:] - delta[1]) * sm_scale
     ds = jnp.concatenate([ds0, ds1], axis=-1)
     return p, ds, kbd
 
 
-def _bwd_dkv_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                           dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale,
-                           causal, block_q, block_k):
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
-    half = q_ref.shape[-1] // 2
+def _packed_tile(operands, j, ncol, edge, **where):
+    """The packed backward's tiles (`_bwd_tiles`) and what
+    `_packed_p_ds` makes of them: (q, k, do, p, ds, kbd)."""
+    half = operands[0].shape[-1] // 2
+    q, k, v, do, lse, delta = _bwd_tiles(operands, j, ncol, edge, 2, half)
+    p, ds, kbd = _packed_p_ds(q, k, v, do, lse, delta, half, **where)
+    return q, k, do, p, ds, kbd
+
+
+def _bwd_dkv_kernel_packed(*refs, sm_scale, causal, block_q, block_k,
+                           edge, has_out, has_delta):
+    operands, (dk_ref, dv_ref, dk_scr, dv_scr) = _bwd_refs(
+        refs, has_out, has_delta)
+    ki, j, qi = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    ncol, nq = pl.num_programs(2), pl.num_programs(3)
+    half = dk_ref.shape[-1] // 2
 
     @pl.when(qi == 0)
     def _():
@@ -702,12 +828,9 @@ def _bwd_dkv_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(visible)
     def _():
-        q = q_ref[...]
-        do = do_ref[...]
-        p, ds, _ = _packed_p_ds(q, k_ref[...], v_ref[...], do,
-                                lse_ref[...], delta_ref[...], half,
-                                sm_scale, causal, qi, ki, block_q,
-                                block_k)
+        q, _, do, p, ds, _ = _packed_tile(
+            operands, j, ncol, edge, sm_scale=sm_scale, causal=causal, qi=qi, ki=ki,
+            block_q=block_q, block_k=block_k)
         # dV/dK come out row-stacked [G, 2bk, 128] with the useful
         # blocks on the block diagonal (K=bq, N=128 contractions)
         dv_stack = jax.lax.dot_general(
@@ -725,13 +848,11 @@ def _bwd_dkv_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _bwd_dq_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dq_ref, dq_scr, *, sm_scale, causal, block_q,
-                          block_k):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
-    half = q_ref.shape[-1] // 2
+def _bwd_dq_kernel_packed(*refs, sm_scale, causal, block_q, block_k,
+                          edge, has_out, has_delta):
+    operands, (dq_ref, dq_scr) = _bwd_refs(refs, has_out, has_delta)
+    qi, j, ki = pl.program_id(1), pl.program_id(2), pl.program_id(3)
+    ncol, nk = pl.num_programs(2), pl.num_programs(3)
 
     @pl.when(ki == 0)
     def _():
@@ -743,11 +864,9 @@ def _bwd_dq_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(visible)
     def _():
-        k = k_ref[...]
-        _, ds, kbd = _packed_p_ds(q_ref[...], k, v_ref[...], do_ref[...],
-                                  lse_ref[...], delta_ref[...], half,
-                                  sm_scale, causal, qi, ki, block_q,
-                                  block_k)
+        _, k, _, _, ds, kbd = _packed_tile(
+            operands, j, ncol, edge, sm_scale=sm_scale, causal=causal, qi=qi, ki=ki,
+            block_q=block_q, block_k=block_k)
         # dQ += dS Kbd: [G, bq, 2bk] x [G, 2bk, 128] (K=2bk, N=128); the
         # block-diagonal zeros route each half's keys to its own lanes
         dq_scr[...] += jax.lax.dot_general(
@@ -759,18 +878,15 @@ def _bwd_dq_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_ref[...] = dq_scr[...].astype(dq_ref.dtype)
 
 
-def _bwd_fused_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref,
-                             delta_ref, dq_ref, dk_ref, dv_ref, *,
-                             sm_scale, causal, block_q, block_k):
+def _bwd_fused_kernel_packed(*refs, sm_scale, causal, block_q, block_k,
+                             edge, has_out, has_delta):
     """Packed single-tile backward: one pass for dQ/dK/dV at K=128
     contractions (see `_bwd_fused_kernel`)."""
-    half = q_ref.shape[-1] // 2
-    q = q_ref[...]
-    k = k_ref[...]
-    do = do_ref[...]
-    p, ds, kbd = _packed_p_ds(q, k, v_ref[...], do, lse_ref[...],
-                              delta_ref[...], half, sm_scale, causal,
-                              0, 0, block_q, block_k)
+    operands, (dq_ref, dk_ref, dv_ref) = _bwd_refs(refs, has_out, has_delta)
+    half = dq_ref.shape[-1] // 2
+    q, k, do, p, ds, kbd = _packed_tile(
+        operands, pl.program_id(1), pl.num_programs(1), edge, sm_scale=sm_scale,
+        causal=causal, qi=0, ki=0, block_q=block_q, block_k=block_k)
     dv_stack = jax.lax.dot_general(
         p.astype(do.dtype), do, (((1,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)
@@ -786,198 +902,139 @@ def _bwd_fused_kernel_packed(q_ref, k_ref, v_ref, do_ref, lse_ref,
         preferred_element_type=jnp.float32).astype(dq_ref.dtype)
 
 
-def _bwd(sm_scale, causal, block_q, block_k, interpret, res, g,
-         dlse=None, pack=False, delta=None):
-    """dlse: optional [bh, t, 1] cotangent of the (log2-space) LSE
-    output. ∂lse/∂s_scaled = p·log2e, so the lse path contributes
-    ds += p·log2e·dlse — algebraically a shift of δ:
-    ds = p·(dp − (δ − log2e·dlse))·scale. The kernels stay unchanged;
-    only the δ row vector moves.
+def _bwd(d, sm_scale, causal, block_q, block_k, interpret, pack, qkv, out,
+         lse, g, delta=None):
+    """Backward launcher over `qkv` as `_fwd` took it, `out` and dO `g`
+    [B, T, C] and lse [B, T, H]; returns the cotangent of `qkv` in its
+    own form (three [B, T, C], or the one [B, T, 3·C] joined).
 
-    delta: optional precomputed δ = rowsum(dO ⊙ O) [bh, t, 1] — the
-    merged ring backward derives it from merge weights without ever
-    materializing the per-step partial out (res[3] may then be None)."""
-    q, k, v, out, lse = res
-    b, t, h, d = q.shape
-    if delta is None:
-        # δ = rowsum(dO ⊙ O) — computed by XLA (one fused
-        # elementwise+reduce)
-        delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                        axis=-1, keepdims=True) \
-            .transpose(0, 2, 1, 3)                  # [b, h, t, 1]
-    delta = delta.reshape(b, h, t, 1)
-    if dlse is not None:
-        delta = delta - LOG2E * dlse.astype(jnp.float32) \
-            .reshape(b, h, t, 1)
+    The kernels' δ is rowsum(dO ⊙ out), taken in the kernel from the
+    tiles it holds anyway, plus `delta` [B, T, H] where given. That is
+    where a cotangent of the (log2-space) LSE output enters:
+    ∂lse/∂s_scaled = p·log2e, so the lse path contributes
+    ds += p·log2e·dlse, algebraically a shift of δ by −log2e·dlse:
+    ds = p·(dp − (δ − log2e·dlse))·scale. And the merged ring backward
+    hands over its whole δ, derived from merge weights without ever
+    materializing the per-step partial out (`out` is then None)."""
+    operands = [*qkv, g, *(x for x in (out, lse, delta) if x is not None)]
     local = functools.partial(
-        _bwd_local, sm_scale=sm_scale, causal=causal, block_q=block_q,
-        block_k=block_k, interpret=interpret, pack=pack)
-    return per_device(
-        local, in_dims=(_BTHD,) * 4 + (_BHTX,) * 2,
-        out_dims=(_BTHD,) * 3)(
-            q, k, v, g, lse.reshape(b, h, t, 1), delta)
+        _bwd_local, n_qkv=len(qkv), d=d, sm_scale=sm_scale, causal=causal,
+        block_q=block_q, block_k=block_k, interpret=interpret, pack=pack,
+        has_out=out is not None, has_delta=delta is not None)
+    grads = per_device(
+        local,
+        in_dims=_qkv_dims(qkv) + (_BTC,) * (len(operands) - len(qkv)),
+        out_dims=(_BTC,) * 3, cols=(_n_heads(qkv, d),))(*operands)
+    if len(qkv) == 1:
+        return (jnp.concatenate(grads, axis=-1),)
+    return tuple(grads)
 
 
-def _bwd_local(q, k, v, g, lse, delta, *, sm_scale, causal, block_q,
-               block_k, interpret, pack):
-    """One device's backward launch: [B, T, H, D] blocks of q, k, v, dO
-    and [B, H, T, 1] row statistics in, (dq, dk, dv) [B, T, H, D] out."""
-    b, t, h, d = q.shape
-    bh = b * h
-
-    def to_bht(x):
-        return x.transpose(0, 2, 1, 3).reshape(bh, t, d)
-
-    def from_bht(x):
-        return x.reshape(b, h, t, d).transpose(0, 2, 1, 3)
-
-    qt, kt, vt, dot_ = to_bht(q), to_bht(k), to_bht(v), to_bht(g)
-    lse = lse.reshape(bh, t, 1)
-    delta = delta.reshape(bh, t, 1)
-
-    if pack:
-        qt, kt, vt, dot_ = map(_pack_pairs, (qt, kt, vt, dot_))
-        lse_in = _pack_pairs(lse)
-        delta_in = _pack_pairs(delta)
-    else:
-        lse_in, delta_in = lse, delta
-    rows = qt.shape[0]
-    dl = qt.shape[-1]
-    lanes = 2 if pack else 1
-    score_k = (2 if pack else 1) * block_k
-
-    def unpack(x):
-        return from_bht(_unpack_pairs(x, bh) if pack else x)
-
+def _bwd_local(*operands, n_qkv, d, sm_scale, causal, block_q, block_k,
+               interpret, pack, has_out, has_delta):
+    """One device's backward launch: (dq, dk, dv), each [B, T, C]."""
+    b, t, c, w, ncol, (q, k, v) = _columns(operands[:n_qkv], d)
+    h = c // d
+    score_k = (w // d) * block_k
     nq, nk = t // block_q, t // block_k
+    static = dict(sm_scale=sm_scale, causal=causal, block_q=block_q,
+                  block_k=block_k, edge=c % w, has_out=has_out,
+                  has_delta=has_delta)
+    if not pack:
+        static["d"] = d
+    suffix = "_packed" if pack else ""
+    operands = (q[0], k[0], v[0]) + operands[n_qkv:]
+    grad_shape = jax.ShapeDtypeStruct((b, t, c), q[0].dtype)
+    # the unpacked kernels keep one accumulator a head
+    lead = () if pack else (w // d,)
+    acc_w = w if pack else d
+
+    def in_specs(at_q, at_k, stat):
+        """q, k, v, dO[, out], lse[, delta]"""
+        return [at_q(q[1]), at_k(k[1]), at_k(v[1])] + \
+            [at_q()] * (1 + has_out) + [stat] * (1 + has_delta)
 
     if nq == 1 and nk == 1:
         # whole sequence in one tile: fused one-pass backward (~4
         # score-sized fp32 tiles live: s, p, dp, ds). Bigger budgets
         # win on the isolated kernel but lose inside the full step —
         # see the forward's budget note.
-        gf = _head_group(rows, block_q, score_k, dl,
-                         tile_budget=4 * 1024 * 1024)
-        fused = functools.partial(
-            _bwd_fused_kernel_packed if pack else _bwd_fused_kernel,
-            sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k)
-        specs = pl.BlockSpec((gf, t, dl), lambda i: (i, 0, 0))
-        row_spec = pl.BlockSpec((gf, t, lanes), lambda i: (i, 0, 0))
-        dq, dk, dv = pl.pallas_call(
-            fused,
-            name="flash_bwd_fused" + ("_packed" if pack else ""),
-            grid=(rows // gf,),
+        gf = _head_group(b, block_q, score_k, tile_budget=4 * 1024 * 1024)
+        spec, _, stat = _specs(gf, t, t, w, h, None, None, 1)
+        return tuple(pl.pallas_call(
+            functools.partial(
+                _bwd_fused_kernel_packed if pack else _bwd_fused_kernel,
+                **static),
+            name="flash_bwd_fused" + suffix,
+            grid=(b // gf, ncol),
             compiler_params=_COMPILER_PARAMS,
-            in_specs=[specs, specs, specs, specs, row_spec, row_spec],
-            out_specs=[specs, specs, specs],
-            out_shape=[jax.ShapeDtypeStruct((rows, t, dl), q.dtype),
-                       jax.ShapeDtypeStruct((rows, t, dl), k.dtype),
-                       jax.ShapeDtypeStruct((rows, t, dl), v.dtype)],
+            in_specs=in_specs(spec, spec, stat),
+            out_specs=[spec()] * 3,
+            out_shape=[grad_shape] * 3,
             interpret=interpret,
-        )(qt, kt, vt, dot_, lse_in, delta_in)
-        return unpack(dq), unpack(dk), unpack(dv)
+        )(*operands))
 
-    gg = _head_group(rows, block_q, score_k, dl,
-                     tile_budget=2 * 1024 * 1024)
+    gg = _head_group(b, block_q, score_k, tile_budget=2 * 1024 * 1024)
 
-    dkv_kernel = functools.partial(
-        _bwd_dkv_kernel_packed if pack else _bwd_dkv_kernel,
-        sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k)
+    # dKV sweep: grid (batch group, k block, column tile, q block)
+    at_q, at_k, stat = _specs(gg, block_q, block_k, w, h, 3, 1, 2)
     dk, dv = pl.pallas_call(
-        dkv_kernel,
-        name="flash_bwd_dkv" + ("_packed" if pack else ""),
-        grid=(rows // gg, nk, nq),
+        functools.partial(
+            _bwd_dkv_kernel_packed if pack else _bwd_dkv_kernel, **static),
+        name="flash_bwd_dkv" + suffix,
+        grid=(b // gg, nk, ncol, nq),
         compiler_params=_COMPILER_PARAMS,
-        in_specs=[
-            pl.BlockSpec((gg, block_q, dl),
-                         lambda bhi, ki, qi: (bhi, qi, 0)),
-            pl.BlockSpec((gg, block_k, dl),
-                         lambda bhi, ki, qi: (bhi, ki, 0)),
-            pl.BlockSpec((gg, block_k, dl),
-                         lambda bhi, ki, qi: (bhi, ki, 0)),
-            pl.BlockSpec((gg, block_q, dl),
-                         lambda bhi, ki, qi: (bhi, qi, 0)),
-            pl.BlockSpec((gg, block_q, lanes),
-                         lambda bhi, ki, qi: (bhi, qi, 0)),
-            pl.BlockSpec((gg, block_q, lanes),
-                         lambda bhi, ki, qi: (bhi, qi, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((gg, block_k, dl),
-                         lambda bhi, ki, qi: (bhi, ki, 0)),
-            pl.BlockSpec((gg, block_k, dl),
-                         lambda bhi, ki, qi: (bhi, ki, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((rows, t, dl), k.dtype),
-            jax.ShapeDtypeStruct((rows, t, dl), v.dtype),
-        ],
+        in_specs=in_specs(at_q, at_k, stat),
+        out_specs=[at_k(), at_k()],
+        out_shape=[grad_shape] * 2,
         scratch_shapes=[
-            pltpu.VMEM((gg, block_k, dl), jnp.float32),
-            pltpu.VMEM((gg, block_k, dl), jnp.float32),
+            pltpu.VMEM(lead + (gg, block_k, acc_w), jnp.float32),
+            pltpu.VMEM(lead + (gg, block_k, acc_w), jnp.float32),
         ],
         interpret=interpret,
-    )(qt, kt, vt, dot_, lse_in, delta_in)
+    )(*operands)
 
-    dq_kernel = functools.partial(
-        _bwd_dq_kernel_packed if pack else _bwd_dq_kernel,
-        sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k)
+    # dQ sweep: grid (batch group, q block, column tile, k block)
+    at_q, at_k, stat = _specs(gg, block_q, block_k, w, h, 1, 3, 2)
     dq = pl.pallas_call(
-        dq_kernel,
-        name="flash_bwd_dq" + ("_packed" if pack else ""),
-        grid=(rows // gg, nq, nk),
+        functools.partial(
+            _bwd_dq_kernel_packed if pack else _bwd_dq_kernel, **static),
+        name="flash_bwd_dq" + suffix,
+        grid=(b // gg, nq, ncol, nk),
         compiler_params=_COMPILER_PARAMS,
-        in_specs=[
-            pl.BlockSpec((gg, block_q, dl),
-                         lambda bhi, qi, ki: (bhi, qi, 0)),
-            pl.BlockSpec((gg, block_k, dl),
-                         lambda bhi, qi, ki: (bhi, ki, 0)),
-            pl.BlockSpec((gg, block_k, dl),
-                         lambda bhi, qi, ki: (bhi, ki, 0)),
-            pl.BlockSpec((gg, block_q, dl),
-                         lambda bhi, qi, ki: (bhi, qi, 0)),
-            pl.BlockSpec((gg, block_q, lanes),
-                         lambda bhi, qi, ki: (bhi, qi, 0)),
-            pl.BlockSpec((gg, block_q, lanes),
-                         lambda bhi, qi, ki: (bhi, qi, 0)),
-        ],
-        out_specs=pl.BlockSpec((gg, block_q, dl),
-                               lambda bhi, qi, ki: (bhi, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, t, dl), q.dtype),
-        scratch_shapes=[pltpu.VMEM((gg, block_q, dl), jnp.float32)],
+        in_specs=in_specs(at_q, at_k, stat),
+        out_specs=at_q(),
+        out_shape=grad_shape,
+        scratch_shapes=[pltpu.VMEM(lead + (gg, block_q, acc_w),
+                                   jnp.float32)],
         interpret=interpret,
-    )(qt, kt, vt, dot_, lse_in, delta_in)
-
-    return unpack(dq), unpack(dk), unpack(dv)
+    )(*operands)
+    return dq, dk, dv
 
 
 # ----------------------------------------------------------------------
 # public API
 # ----------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash(q, k, v, sm_scale, causal, block_q, block_k, interpret, pack):
-    out, _ = _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
-                  pack)
-    b, t, h, d = q.shape
-    return out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
+# Inside, `qkv` is a tuple (q, k, v [B, T, C], or the [B, T, 3·C]
+# product alone), every other tensor [B, T, C] and every row statistic
+# [B, T, H]; the public functions reshape (free) at their boundary.
+_STATIC = tuple(range(1, 8))   # d, sm_scale, causal, blocks, interpret, pack
 
 
-def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
-               pack):
-    out, lse = _fwd(q, k, v, sm_scale, causal, block_q, block_k,
-                    interpret, pack)
-    b, t, h, d = q.shape
-    out_bthd = out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
-    return out_bthd, (q, k, v, out_bthd, lse)
+@functools.partial(jax.custom_vjp, nondiff_argnums=_STATIC)
+def _flash(qkv, d, sm_scale, causal, block_q, block_k, interpret, pack):
+    return _fwd(qkv, d, sm_scale, causal, block_q, block_k, interpret,
+                pack)[0]
 
 
-def _flash_bwd(sm_scale, causal, block_q, block_k, interpret, pack, res,
-               g):
-    return _bwd(sm_scale, causal, block_q, block_k, interpret, res, g,
-                pack=pack)
+def _flash_fwd(qkv, *static):
+    out, lse = _fwd(qkv, *static)
+    return out, (qkv, out, lse)
+
+
+def _flash_bwd(*args):
+    *static, (qkv, out, lse), g = args
+    return (_bwd(*static, qkv, out, lse, g),)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -986,36 +1043,39 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 # ----------------------------------------------------------------------
 # (out, lse) form: differentiable partials for ring attention
 # ----------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash_lse(q, k, v, sm_scale, causal, block_q, block_k, interpret,
+@functools.partial(jax.custom_vjp, nondiff_argnums=_STATIC)
+def _flash_lse(qkv, d, sm_scale, causal, block_q, block_k, interpret,
                pack):
-    out, lse = _fwd(q, k, v, sm_scale, causal, block_q, block_k,
-                    interpret, pack)
-    b, t, h, d = q.shape
-    return (out.reshape(b, h, t, d).transpose(0, 2, 1, 3),
-            lse.reshape(b, h, t, 1))
+    return _fwd(qkv, d, sm_scale, causal, block_q, block_k, interpret,
+                pack)
 
 
-def _flash_lse_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret,
-                   pack):
-    out, lse = _fwd(q, k, v, sm_scale, causal, block_q, block_k,
-                    interpret, pack)
-    b, t, h, d = q.shape
-    out_bthd = out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
-    return (out_bthd, lse.reshape(b, h, t, 1)), (q, k, v, out_bthd, lse)
+def _flash_lse_fwd(qkv, *static):
+    out, lse = _fwd(qkv, *static)
+    return (out, lse), (qkv, out, lse)
 
 
-def _flash_lse_bwd(sm_scale, causal, block_q, block_k, interpret, pack,
-                   res, g):
-    g_out, g_lse = g
-    b = res[0].shape[0]
-    h = res[0].shape[2]
-    t = res[0].shape[1]
-    return _bwd(sm_scale, causal, block_q, block_k, interpret, res, g_out,
-                dlse=g_lse.reshape(b * h, t, 1), pack=pack)
+def _flash_lse_bwd(*args):
+    *static, (qkv, out, lse), (g_out, g_lse) = args
+    return (_bwd(*static, qkv, out, lse, g_out, delta=-LOG2E * g_lse),)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
+
+
+def _columns_of(*xs):
+    """[B, T, H, D] -> [B, T, H·D], a free reshape."""
+    return tuple(x.reshape(*x.shape[:2], -1) for x in xs)
+
+
+def _stat_in(x):
+    """A row statistic as the ring carries it, [B, H, T, 1], in the
+    kernels' [B, T, H]."""
+    return jnp.swapaxes(x[..., 0], 1, 2)
+
+
+def _stat_out(x):
+    return jnp.swapaxes(x, 1, 2)[..., None]
 
 
 def flash_attention_with_lse(q, k, v, causal=True, sm_scale=None,
@@ -1030,38 +1090,31 @@ def flash_attention_with_lse(q, k, v, causal=True, sm_scale=None,
     ring-attention per-step merge (ops/sequence/ring_attention.py,
     which fuses that merge into the kernel epilogue via
     `flash_attention_merge`). Fully differentiable: the lse cotangent
-    enters the backward kernels as a δ shift (see _bwd)."""
+    enters the backward kernels as a shift of δ (see _bwd)."""
     args = _normalize_flash_args(q, k, v, causal, sm_scale, block_q,
                                  block_k, interpret, head_packing)
-    return _flash_lse(q, k, v, *args)
+    out, lse = _flash_lse(_columns_of(q, k, v), q.shape[-1], *args)
+    return out.reshape(q.shape), _stat_out(lse)
 
 
 # ----------------------------------------------------------------------
 # in-kernel merge with a prior partial: the ring-attention step body
 # ----------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
-def _flash_merge(q, k, v, prev_out, prev_lse, sm_scale, causal, block_q,
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=tuple(range(3, 10)))
+def _flash_merge(qkv, prev_out, prev_lse, d, sm_scale, causal, block_q,
                  block_k, interpret, pack):
-    out, lse, _ = _fwd(q, k, v, sm_scale, causal, block_q, block_k,
+    out, lse, _ = _fwd(qkv, d, sm_scale, causal, block_q, block_k,
                        interpret, pack, prev=(prev_out, prev_lse))
-    b, t, h, d = q.shape
-    return (out.reshape(b, h, t, d).transpose(0, 2, 1, 3),
-            lse.reshape(b, h, t, 1))
+    return out, lse
 
 
-def _flash_merge_fwd(q, k, v, prev_out, prev_lse, sm_scale, causal,
-                     block_q, block_k, interpret, pack):
-    out, lse, lse_n = _fwd(q, k, v, sm_scale, causal, block_q, block_k,
-                           interpret, pack, prev=(prev_out, prev_lse))
-    b, t, h, d = q.shape
-    out_bthd = out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
-    lse_m = lse.reshape(b, h, t, 1)
-    return (out_bthd, lse_m), (q, k, v, prev_out, prev_lse, out_bthd,
-                               lse_m, lse_n)
+def _flash_merge_fwd(qkv, prev_out, prev_lse, *static):
+    out_m, lse_m, lse_n = _fwd(qkv, *static, prev=(prev_out, prev_lse))
+    return (out_m, lse_m), (qkv, prev_out, prev_lse, out_m, lse_m, lse_n)
 
 
-def _flash_merge_bwd(sm_scale, causal, block_q, block_k, interpret, pack,
-                     res, g):
+def _flash_merge_bwd(*args):
     """VJP of merge(flash(q,k,v), prev).  With a_p = w_p/W =
     2^(lse_p − lse_m) and a_n = w_n/W = 2^(lse_n − lse_m) (a_p+a_n = 1):
 
@@ -1073,38 +1126,37 @@ def _flash_merge_bwd(sm_scale, causal, block_q, block_k, interpret, pack,
     where R_x = Σ_d(ḡ_o ⊙ o_x).  Every quantity uses only the SAVED
     o_p/o_m/lses — the current partial o_n is never reconstructed (a
     naive o_n = (o_m·W − w_p·o_p)/w_n divides by a possibly-underflowed
-    w_n).  δ_n and d lse_n then drive the standard flash backward
-    kernels directly (res out=None, delta= precomputed)."""
-    q, k, v, prev_out, prev_lse, out_m, lse_m, lse_n = res
-    g_out, g_lse = g
-    b, t, h, d = q.shape
-    bh = b * h
+    w_n).  δ_n, shifted by d lse_n, then drives the standard flash
+    backward kernels directly (out=None).  Everything here is
+    [B, T, C] beside [B, T, H]: a statistic meets a tensor through the
+    free [B, T, H, D] view."""
+    *static, res, (g_out, g_lse) = args
+    qkv, prev_out, prev_lse, out_m, lse_m, lse_n = res
+    d = static[0]
+    b, t, c = g_out.shape
 
-    def bhq1_to_bqh1(x):
-        return x.transpose(0, 2, 1, 3)
+    def heads(x):                # [B, T, C] -> [B, T, H, D]
+        return x.reshape(b, t, c // d, d)
 
     go = g_out.astype(jnp.float32)
-    a_p = jnp.exp2(prev_lse.astype(jnp.float32) - lse_m)   # [B,H,T,1]
-    a_n = jnp.exp2(lse_n.reshape(b, h, t, 1) - lse_m)
+    a_p = jnp.exp2(prev_lse.astype(jnp.float32) - lse_m)   # [B, T, H]
+    a_n = jnp.exp2(lse_n - lse_m)
 
-    def rowsum(x, y):            # [B,T,H,D] ⊙ [B,T,H,D] -> [B,H,T,1]
-        return jnp.sum(x * y.astype(jnp.float32), axis=-1,
-                       keepdims=True).transpose(0, 2, 1, 3)
+    def rowsum(x, y):            # [B, T, C] ⊙ [B, T, C] -> [B, T, H]
+        return jnp.sum(heads(x * y.astype(jnp.float32)), axis=-1)
 
     r_m = rowsum(go, out_m)
     r_p = rowsum(go, prev_out)
-    d_prev_out = go * bhq1_to_bqh1(a_p)
-    d_o_n = g_out * bhq1_to_bqh1(a_n).astype(g_out.dtype)
+    d_prev_out = (heads(go) * a_p[..., None]).reshape(b, t, c)
+    d_o_n = (heads(g_out) * a_n[..., None].astype(g_out.dtype)) \
+        .reshape(b, t, c)
     d_prev_lse = _LN2 * a_p * (r_p - r_m) + g_lse * a_p
     d_lse_n = _LN2 * a_p * (r_m - r_p) + g_lse * a_n
     delta_n = r_m - a_p * r_p
 
-    dq, dk, dv = _bwd(
-        sm_scale, causal, block_q, block_k, interpret,
-        (q, k, v, None, lse_n), d_o_n,
-        dlse=d_lse_n.reshape(bh, t, 1), pack=pack,
-        delta=delta_n.reshape(bh, t, 1))
-    return dq, dk, dv, d_prev_out, d_prev_lse
+    d_qkv = _bwd(*static, qkv, None, lse_n, d_o_n,
+                 delta=delta_n - LOG2E * d_lse_n)
+    return d_qkv, d_prev_out, d_prev_lse
 
 
 _flash_merge.defvjp(_flash_merge_fwd, _flash_merge_bwd)
@@ -1128,8 +1180,11 @@ def flash_attention_merge(q, k, v, prev_out, prev_lse, causal=True,
     in q, k, v, prev_out and prev_lse."""
     args = _normalize_flash_args(q, k, v, causal, sm_scale, block_q,
                                  block_k, interpret, head_packing)
-    return _flash_merge(q, k, v, prev_out.astype(jnp.float32),
-                        prev_lse, *args)
+    out, lse = _flash_merge(
+        _columns_of(q, k, v),
+        *_columns_of(prev_out.astype(jnp.float32)), _stat_in(prev_lse),
+        q.shape[-1], *args)
+    return out.reshape(q.shape), _stat_out(lse)
 
 
 # ----------------------------------------------------------------------
@@ -1140,35 +1195,33 @@ def flash_attention_merge(q, k, v, prev_out, prev_lse, causal=True,
 # blocks pay the (expensive) flash forward kernel twice.
 # The split below routes the residuals AROUND the remat boundary:
 #
-#     out, lse = _flash_outlse(q, k, v)      # fwd kernel, NOT differentiable
+#     out, lse = _fwd(qkv)                   # fwd kernel, NOT differentiable
 #     out = checkpoint_name(out, "attn_out") # 2 B/elem per layer
 #     lse = checkpoint_name(lse, "attn_lse") # 4 B/token per layer
-#     out = _flash_apply(q, k, v, out, lse)  # identity fwd; custom bwd
+#     out = _flash_apply(qkv, out, lse)      # identity fwd; custom bwd
 #
 # With a `save_only_these_names:attn_out,attn_lse` policy the named
-# values are saved, `_flash_outlse` is dead in the recompute (its only
-# outputs are saved) and never re-runs, while `_flash_apply`'s VJP runs
-# the dq/dkv kernels directly from the saved residuals — q, k, v are
-# recomputed by the (cheap) qkv-matmul chain remat. Without such a
+# values are saved, the forward launch is dead in the recompute (its
+# only outputs are saved) and never re-runs, while `_flash_apply`'s VJP
+# runs the dq/dkv kernels directly from the saved residuals — q, k, v
+# are recomputed by the (cheap) qkv-matmul chain remat. Without such a
 # policy the behavior degrades gracefully to plain full remat.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
-def _flash_apply(q, k, v, out, lse, sm_scale, causal, block_q, block_k,
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(3, 10)))
+def _flash_apply(qkv, out, lse, d, sm_scale, causal, block_q, block_k,
                  interpret, pack):
     return out
 
 
-def _flash_apply_fwd(q, k, v, out, lse, sm_scale, causal, block_q,
-                     block_k, interpret, pack):
-    return out, (q, k, v, out, lse)
+def _flash_apply_fwd(qkv, out, lse, *static):
+    return out, (qkv, out, lse)
 
 
-def _flash_apply_bwd(sm_scale, causal, block_q, block_k, interpret, pack,
-                     res, g):
-    dq, dk, dv = _bwd(sm_scale, causal, block_q, block_k, interpret,
-                      res, g, pack=pack)
+def _flash_apply_bwd(*args):
+    *static, (qkv, out, lse), g = args
     # out/lse enter via the non-differentiable forward kernel (gradient
     # flows exclusively through q, k, v — mathematically out = f(q,k,v))
-    return dq, dk, dv, jnp.zeros_like(res[3]), jnp.zeros_like(res[4])
+    return (_bwd(*static, qkv, out, lse, g), jnp.zeros_like(out),
+            jnp.zeros_like(lse))
 
 
 _flash_apply.defvjp(_flash_apply_fwd, _flash_apply_bwd)
@@ -1178,7 +1231,8 @@ def _normalize_flash_args(q, k, v, causal, sm_scale, block_q, block_k,
                           interpret, head_packing="auto"):
     """Shared argument validation/defaulting for all flash entry
     points — they must never diverge (the rematerializable form
-    guarantees identical numerics)."""
+    guarantees identical numerics).  q, k, v: anything with the
+    [B, T, H, D] shape and a dtype."""
     assert q.shape == k.shape == v.shape, (q.shape, k.shape, v.shape)
     t = q.shape[1]
     if block_q is None and block_k is None:
@@ -1215,6 +1269,17 @@ def _normalize_flash_args(q, k, v, causal, sm_scale, block_q, block_k,
             bool(interpret), pack)
 
 
+def _attend(qkv, static, rematerializable):
+    """`qkv` (the tuple the launchers take) -> out [B, T, C]."""
+    if not rematerializable:
+        return _flash(qkv, *static)
+    from jax.ad_checkpoint import checkpoint_name
+    out, lse = _fwd(tuple(jax.lax.stop_gradient(x) for x in qkv), *static)
+    out = checkpoint_name(out, "attn_out")
+    lse = checkpoint_name(lse, "attn_lse")
+    return _flash_apply(qkv, out, lse, *static)
+
+
 def flash_attention_rematerializable(q, k, v, causal=True, sm_scale=None,
                                      block_q=None,
                                      block_k=None,
@@ -1223,17 +1288,10 @@ def flash_attention_rematerializable(q, k, v, causal=True, sm_scale=None,
     annotations ("attn_out"/"attn_lse") so a names-saving remat policy
     skips the forward-kernel re-run in backward. Numerics identical to
     `flash_attention`."""
-    from jax.ad_checkpoint import checkpoint_name
-    b, t, h, d = q.shape
     args = _normalize_flash_args(q, k, v, causal, sm_scale, block_q,
                                  block_k, interpret, head_packing)
-
-    out, lse = _fwd(jax.lax.stop_gradient(q), jax.lax.stop_gradient(k),
-                    jax.lax.stop_gradient(v), *args)
-    out = out.reshape(b, h, t, d).transpose(0, 2, 1, 3)
-    out = checkpoint_name(out, "attn_out")
-    lse = checkpoint_name(lse, "attn_lse")
-    return _flash_apply(q, k, v, out, lse, *args)
+    return _attend(_columns_of(q, k, v), (q.shape[-1],) + args,
+                   True).reshape(q.shape)
 
 
 def flash_attention(q, k, v, causal=True, sm_scale=None,
@@ -1243,10 +1301,55 @@ def flash_attention(q, k, v, causal=True, sm_scale=None,
 
     interpret=None auto-selects Pallas interpreter mode off-TPU so the
     same kernel code is exercised by CPU tests.  head_packing
-    ("auto"|"packed"|"off") selects the two-heads-per-step K=128 kernel
-    for d=64 (auto: on real TPU only; packed/off force it on/off; see
-    module docstring).
+    ("auto"|"packed"|"off") selects the K=128 contraction over a d=64
+    column tile's two heads (auto: on real TPU only; packed/off force it
+    on/off; see module docstring).
     """
-    return _flash(q, k, v, *_normalize_flash_args(
-        q, k, v, causal, sm_scale, block_q, block_k, interpret,
-        head_packing))
+    args = _normalize_flash_args(q, k, v, causal, sm_scale, block_q,
+                                 block_k, interpret, head_packing)
+    return _attend(_columns_of(q, k, v), (q.shape[-1],) + args,
+                   False).reshape(q.shape)
+
+
+def _heads_of_product(qkv, n_head):
+    """What q, k and v each are inside the product [B, T, 3·H·D]: the
+    [B, T, H, D] shape and the dtype."""
+    b, t, c = qkv.shape[0], qkv.shape[1], qkv.shape[2] // 3
+    return jax.ShapeDtypeStruct((b, t, n_head, c // n_head), qkv.dtype)
+
+
+def flash_attention_qkv_usable(qkv, n_head, no_dropout: bool):
+    """Whether `flash_attention_qkv` can read q, k and v out of the
+    `c_attn` product where it lies: the kernel's own conditions, H·D a
+    whole number of column tiles (k and v then start on a tile), and no
+    tensor parallelism over the product's columns (a shard of
+    [q | k | v] is not a [q | k | v])."""
+    if qkv.ndim != 3 or qkv.shape[-1] % (3 * n_head):
+        return False
+    q = _heads_of_product(qkv, n_head)
+    return flash_attention_usable(q, no_dropout) and \
+        (n_head * q.shape[-1]) % _tile_width(q.shape[-1]) == 0 and \
+        not divides_cols(qkv)
+
+
+def flash_attention_qkv(qkv, n_head, causal=True, sm_scale=None,
+                        block_q=None, block_k=None, interpret=None,
+                        head_packing="auto", rematerializable=False):
+    """`flash_attention` (or, with `rematerializable`, its
+    rematerializable form) over the projection's product
+    [B, T, 3·H·D] = [q | k | v] whole; returns [B, T, H, D]. Bit-for-bit
+    what the split product gives: the kernels read the same tiles
+    through three index maps on one operand, and no slice of it is
+    copied, forward or backward (`flash_attention_qkv_usable` says
+    where)."""
+    q = _heads_of_product(qkv, n_head)
+    if not flash_attention_qkv_usable(qkv, n_head, True):
+        raise ValueError(
+            f"flash_attention_qkv cannot read q, k and v out of a "
+            f"{qkv.shape} product of {n_head} heads where it lies (see "
+            "flash_attention_qkv_usable): split it and call "
+            "flash_attention")
+    args = _normalize_flash_args(q, q, q, causal, sm_scale, block_q,
+                                 block_k, interpret, head_packing)
+    return _attend((qkv,), (q.shape[-1],) + args,
+                   rematerializable).reshape(q.shape)

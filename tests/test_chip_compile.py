@@ -1044,3 +1044,168 @@ def test_the_samplers_top_k_stays_in_the_conditional_on_a_v5e(
     assert launches == [branches[1]], (launches, branches)
     assert compiled.memory_analysis().temp_size_in_bytes < \
         slots * vocab * 2
+
+
+# ----------------------------------------------------------------------
+# The two training cells' layer scans, forward and backward: no array is
+# re-laid around a flash launch (ISSUE 47)
+# ----------------------------------------------------------------------
+TRAIN_CELLS = {
+    # preset, rows a step, remat policy, parameter dtype, layers compiled
+    "gpt2-350m.train-seq1024": (
+        "gpt2-350m", 16, "dots_with_no_batch_dims_saveable", jnp.float32, 4),
+    "gpt2-1.5b.train-zero2": ("gpt2-1.5b", 10, None, jnp.bfloat16, 2),
+}
+_HLO_LINE = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = (\([^=]*?\)|\S+) ([\w\-]+)\((.*)$")
+# what passes an array on as it is, or re-laid: followed from a launch
+# to whatever copies feed it or take its results
+_PASSES_ON = {"bitcast", "get-tuple-element", "copy", "transpose",
+              "reshape"}
+
+
+def flash_bodies(text):
+    """[(the flash launches of one HLO computation, the re-layings tied
+    to them)], for each computation that holds a launch. A re-laying is
+    a `copy` or `transpose` instruction reached from a launch's operand
+    or result through nothing but bitcasts, tuple elements and other
+    re-layings, given as (name, shape, op_name's tail). Other copies of
+    such a body (the loop's own copy of a LayerNorm result into its
+    carry) are not a launch's."""
+    out = []
+    for body in re.split(r"\n\}\n", text):
+        rows = {}
+        for line in body.splitlines():
+            m = _HLO_LINE.match(line)
+            if m:
+                name, shape, op, rest = m.groups()
+                operands = re.findall(r"%([\w.\-]+)",
+                                      rest.split(")", 1)[0])
+                rows[name] = (shape, op, operands, line)
+        launches = [n for n, (_, op, _, line) in rows.items()
+                    if op == "custom-call" and
+                    re.search(r"flash_(fwd|bwd)", line)]
+        if not launches:
+            continue
+        users = {}
+        for name, (_, _, operands, _) in rows.items():
+            for o in operands:
+                users.setdefault(o, []).append(name)
+        tied, seen = [], set()
+
+        def walk(name, step):
+            for nxt in step(name):
+                if nxt in rows and nxt not in seen and \
+                        rows[nxt][1] in _PASSES_ON:
+                    seen.add(nxt)
+                    walk(nxt, step)
+
+        for launch in launches:
+            walk(launch, lambda n: rows[n][2])           # towards operands
+            walk(launch, lambda n: users.get(n, []))     # towards users
+        for name in seen:
+            shape, op, _, line = rows[name]
+            if op in ("copy", "transpose"):
+                scope = re.search(r'op_name="([^"]+)"', line)
+                tied.append((name, shape.split("{")[0],
+                             scope.group(1)[-40:] if scope else ""))
+        out.append((sorted(re.sub(r"[.\d]+$", "", n) for n in launches),
+                    sorted(tied)))
+    return out
+
+
+@pytest.fixture(scope="module")
+def train_scans(one_chip):
+    """cell -> the HLO of value-and-grad of the cell's loss (a few
+    layers of it, the scan's bodies being what is read), compiled once
+    a module with the kernels' own backend probes answering "TPU": on
+    the MODULES (`ops.transformer.flash_attention` the attribute is the
+    function, and a patch on it would compile the interpreter's `while`
+    loops)."""
+    import importlib
+    place = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip)
+    cache = {}
+
+    def build(cell):
+        if cell in cache:
+            return cache[cell]
+        preset, rows, policy, param_dtype, layers = TRAIN_CELLS[cell]
+        cfg = gpt2_config(preset, n_positions=SEQ, dropout=0.0, remat=True,
+                          remat_policy=policy, param_dtype=param_dtype,
+                          n_layer=layers)
+        model = GPT2ForCausalLM(cfg)
+        params = jax.tree_util.tree_map(place, jax.eval_shape(
+            lambda k: model.init(
+                k, {"input_ids": np.zeros((1, SEQ), np.int32)}),
+            jax.random.PRNGKey(0)))
+        ids = place(jax.ShapeDtypeStruct((rows, SEQ), jnp.int32))
+        with pytest.MonkeyPatch.context() as patch:
+            for name in ("flash_attention", "fused_ops"):
+                patch.setattr(importlib.import_module(
+                    "deepspeed_tpu.ops.transformer." + name),
+                    "_on_tpu", lambda: True)
+            text = jax.jit(jax.value_and_grad(
+                lambda p, i: model.loss_fn(p, {"input_ids": i},
+                                           deterministic=True))) \
+                .lower(params, ids).compile().as_text()
+        cache[cell] = (cfg, rows, text)
+        return cache[cell]
+    return build
+
+
+@pytest.mark.parametrize("cell", sorted(TRAIN_CELLS))
+def test_no_array_is_re_laid_around_a_flash_launch_on_a_v5e(train_scans,
+                                                            cell):
+    """The flash kernels read q, k and v where `c_attn` wrote them and
+    write where `c_proj` reads: in the scan's forward and backward
+    bodies the chip's compiler ties no `copy` or `transpose` of a
+    [B, T, H·D]-sized array to a launch. Before ISSUE 47 this count
+    read 7 and 13 a layer at 350M and 6 and 13 at 1.5B (`split`,
+    `reshape`, `transpose` and the kernels' own results; the walk stops
+    at the first fusion, and counted by hand with what lay behind those
+    they were 28 and 25 arrays a layer: the ledger's 43 and 103 ms of
+    `copy` a step). At 1.5B, where C = 1,600 is no whole number of
+    lane tiles, q, k and v may be three slices of the product a launch
+    (this compiler makes them three results of the bias add's fusion
+    and copies nothing)."""
+    cfg, rows, text = train_scans(cell)
+    bodies = flash_bodies(text)
+    assert [launches for launches, _ in bodies] == [
+        ["flash_fwd_packed"],
+        ["flash_bwd_fused_packed", "flash_fwd_packed"]], bodies
+    tensor = rows * SEQ * cfg.n_embd
+    whole = cfg.n_embd % 128 == 0
+    for launches, tied in bodies:
+        big = [t for t in tied
+               if int(np.prod([int(d) for d in re.findall(
+                   r"\d+", t[1].split("[", 1)[1])])) >= tensor]
+        assert len(big) <= (0 if whole else 3 * len(launches)), big
+
+
+@pytest.mark.parametrize("cell", sorted(TRAIN_CELLS))
+def test_flash_reads_the_projection_where_it_lies_on_a_v5e(train_scans,
+                                                           cell):
+    """Where H·D is a whole number of lane tiles (350M: 1,024) every
+    launch takes the `c_attn` product [B, T, 3·H·D] itself, three times
+    over (three index maps on one operand); where it is not (1.5B) it
+    takes three [B, T, H·D] arrays. Either way its result is
+    [B, T, H·D] and its row statistics [B, T, H]."""
+    cfg, rows, text = train_scans(cell)
+    c = cfg.n_embd
+    product = f"bf16[{rows},{SEQ},{3 * c}]"
+    tensor = f"bf16[{rows},{SEQ},{c}]"
+    stats = f"f32[{rows},{SEQ},{cfg.n_head}]"
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line
+             and re.search(r"= \S+ flash_|%flash_(fwd|bwd)", line)]
+    assert len(calls) == 3
+    for line in calls:
+        operands = re.findall(
+            r"(\w+\[[\d,]+\])\{", re.search(
+                r"operand_layout_constraints=\{(.*?)\}, \w+=", line).group(1))
+        assert operands[:3] == [product if c % 128 == 0 else tensor] * 3, \
+            operands
+        result = line.split(" = ", 1)[1].split(" custom-call(")[0]
+        assert result.count(tensor) in (1, 3) and product not in result
+        assert stats in result or stats in operands

@@ -107,11 +107,15 @@ def test_the_states_decode_step_lowers_for_tpu(monkeypatch, slots, heads, p,
     assert "output_operand_aliases" in call and f"tensor<{whole}xf32>" in call
 
 
-def test_sharded_train_step_lowers_for_tpu(on_tpu):
+@pytest.mark.parametrize("n_head", [2, 3], ids=[
+    "a-head-a-column", "three-heads-whole-on-both-columns"])
+def test_sharded_train_step_lowers_for_tpu(on_tpu, n_head):
     """GSPMD cannot partition a Mosaic call: a loss+grad whose operands
     live on a multi-device mesh lowers only because every launch runs
     per device (ops/per_device.py). The virtual CPU mesh never shows
-    this — there the kernels are interpreted, ordinary XLA."""
+    this — there the kernels are interpreted, ordinary XLA. (Three
+    heads: the mesh's two columns divide H·D and not H, so the flash
+    launches hold the heads whole.)"""
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -121,7 +125,8 @@ def test_sharded_train_step_lowers_for_tpu(on_tpu):
 
     mesh = build_mesh({"pipe": 1, "data": 4, "model": 2})
     model = GPT2ForCausalLM(gpt2_config(
-        "gpt2-tiny", n_embd=128, n_head=2, n_positions=128, dropout=0.0,
+        "gpt2-tiny", n_embd=64 * n_head, n_head=n_head, n_positions=128,
+        dropout=0.0,
         remat=True, dtype=jnp.bfloat16))
     params = jax.eval_shape(lambda: model.init(
         jax.random.PRNGKey(0), {"input_ids": np.zeros((1, 128), np.int32)}))

@@ -9,7 +9,9 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.ops.transformer.flash_attention import (
-    dense_attention, flash_attention, flash_attention_usable)
+    dense_attention, flash_attention, flash_attention_qkv,
+    flash_attention_qkv_usable, flash_attention_rematerializable,
+    flash_attention_usable)
 from deepspeed_tpu.models.gpt2 import causal_attention_xla
 
 
@@ -28,7 +30,12 @@ def dense_reference(q, k, v, causal):
 
 
 @pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.parametrize("b,t,h,d", [(2, 256, 4, 64), (1, 384, 2, 128)])
+@pytest.mark.parametrize("b,t,h,d", [
+    (2, 256, 4, 64), (1, 384, 2, 128),
+    (1, 128, 25, 64),   # GPT-2 1.5B's heads: C = 1,600 is 12.5 column
+                        # tiles, the last one holds one head
+    (2, 256, 3, 64),    # H·D = 192, no multiple of 128, two T blocks
+])
 def test_forward_matches_dense(b, t, h, d, causal):
     q, k, v = qkv(b, t, h, d)
     ref = dense_reference(q, k, v, causal)
@@ -38,12 +45,22 @@ def test_forward_matches_dense(b, t, h, d, causal):
 
 
 @pytest.mark.parametrize("causal", [True, False])
-def test_grads_match_dense(causal):
-    q, k, v = qkv(1, 256, 2, 64, seed=3)
+@pytest.mark.parametrize("b,t,h,d,blocks,packing", [
+    (1, 256, 2, 64, 128, "off"),
+    (1, 256, 5, 64, 128, "packed"),   # odd heads, the two sweep kernels
+    (1, 256, 5, 64, 128, "off"),
+    (2, 128, 25, 64, None, "packed"),  # C = 1,600, the one-pass kernel
+    (2, 128, 25, 64, None, "off"),
+    (1, 256, 2, 128, 128, "off"),     # D = 128: a head a column tile
+    (2, 128, 2, 128, None, "off"),
+])
+def test_grads_match_dense(b, t, h, d, blocks, packing, causal):
+    q, k, v = qkv(b, t, h, d, seed=3)
 
     def loss_flash(q, k, v):
         return jnp.sum(flash_attention(q, k, v, causal=causal,
-                                       block_q=128, block_k=128) ** 2)
+                                       block_q=blocks, block_k=blocks,
+                                       head_packing=packing) ** 2)
 
     def loss_ref(q, k, v):
         return jnp.sum(dense_reference(q, k, v, causal) ** 2)
@@ -75,6 +92,11 @@ def test_usability_gate():
     # (advisor r4) — the gate must refuse it
     assert not flash_attention_usable(jnp.zeros((2, 136, 4, 64)), True)
     assert flash_attention_usable(jnp.zeros((2, 640, 4, 64)), True)
+    # a head is half a column tile (two of 64 side by side) or whole
+    # tiles (a multiple of 128): 192 is neither
+    assert flash_attention_usable(jnp.zeros((2, 256, 4, 128)), True)
+    assert flash_attention_usable(jnp.zeros((2, 256, 4, 256)), True)
+    assert not flash_attention_usable(jnp.zeros((2, 256, 4, 192)), True)
 
 
 def test_jit_and_dtype_preserved():
@@ -180,3 +202,103 @@ def test_with_lse_grads_flow_through_lse(causal):
     for a, b_ in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    atol=5e-4, rtol=5e-4)
+
+
+# ----------------------------------------------------------------------
+# the `c_attn` product whole: q, k, v read where the projection wrote them
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+@pytest.mark.parametrize("b,t,h,d,blocks,packing", [
+    (2, 128, 4, 64, None, "packed"),   # one T tile: the one-pass backward
+    (2, 128, 4, 64, None, "off"),
+    (1, 256, 2, 64, 128, "packed"),    # the two sweep kernels
+    (1, 256, 2, 64, 128, "off"),
+    (2, 128, 2, 128, None, "off"),     # D = 128
+])
+def test_the_product_entry_is_the_split_entry_bit_for_bit(
+        b, t, h, d, blocks, packing, remat):
+    rng = np.random.RandomState(23)
+    product = jnp.asarray(rng.randn(b, t, 3 * h * d), jnp.float32)
+    assert flash_attention_qkv_usable(product, h, True)
+    split_entry = flash_attention_rematerializable if remat \
+        else flash_attention
+
+    def whole(x):
+        out = flash_attention_qkv(x, h, block_q=blocks, block_k=blocks,
+                                  head_packing=packing,
+                                  rematerializable=remat)
+        return jnp.sum(jnp.sin(out)), out
+
+    def split(x):
+        q, k, v = (y.reshape(b, t, h, d) for y in jnp.split(x, 3, axis=-1))
+        out = split_entry(q, k, v, block_q=blocks, block_k=blocks,
+                          head_packing=packing)
+        return jnp.sum(jnp.sin(out)), out
+
+    (_, out_w), grad_w = jax.value_and_grad(whole, has_aux=True)(product)
+    (_, out_s), grad_s = jax.value_and_grad(split, has_aux=True)(product)
+    assert out_w.shape == (b, t, h, d)
+    np.testing.assert_array_equal(np.asarray(out_w), np.asarray(out_s))
+    np.testing.assert_array_equal(np.asarray(grad_w), np.asarray(grad_s))
+
+
+def test_the_product_entry_is_offered_where_the_shape_allows():
+    # 4 heads of 64: two whole column tiles, k and v start on a tile
+    assert flash_attention_qkv_usable(jnp.zeros((2, 256, 3 * 256)), 4, True)
+    assert not flash_attention_qkv_usable(
+        jnp.zeros((2, 256, 3 * 256)), 4, False)          # dropout active
+    # 25 heads of 64: C = 1,600 is 12.5 tiles, k starts half a tile off
+    assert not flash_attention_qkv_usable(
+        jnp.zeros((2, 256, 3 * 1600)), 25, True)
+    assert not flash_attention_qkv_usable(
+        jnp.zeros((2, 100, 3 * 256)), 4, True)           # the kernel's own
+    assert flash_attention_qkv_usable(jnp.zeros((2, 256, 3 * 256)), 2, True)
+    with pytest.raises(ValueError, match="where it lies"):
+        flash_attention_qkv(jnp.zeros((2, 256, 3 * 1600)), 25)
+
+
+@pytest.mark.parametrize("h", [4, 2, 5, 25], ids=[
+    "whole-tiles", "a-head-a-shard", "odd-heads-stay-whole",
+    "25-heads-stay-whole"])
+def test_heads_divide_over_the_mesh_columns(h):
+    """A 2 x 2 mesh: the batch over its rows, the heads over its columns
+    (`per_device`). With 2 heads each shard holds ONE, half a column
+    tile: its launch runs the edge tile, not a fallback. With 5 or 25
+    the columns divide H·D and not H: a shard of half of it would hold
+    2.5 or 12.5 heads, so the columns divide nothing and every device
+    of a row works all the heads. The product entry steps aside, since
+    a shard of [q | k | v] is no [q | k | v]."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.runtime.mesh import build_mesh
+
+    mesh = build_mesh({"pipe": 1, "data": 2, "model": 2},
+                      devices=jax.devices()[:4])
+    q, k, v = qkv(4, 128, h, 64, seed=29)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, head_packing="packed")
+        return jnp.sum(jnp.sin(out))
+
+    grad = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+    want = grad(q, k, v)
+    heads = NamedSharding(
+        mesh, P("data", None, "model" if h % 2 == 0 else None, None))
+    got = grad(*(jax.device_put(x, heads) for x in (q, k, v)))
+    # the loss is a float32 sum of B·T·H·D terms, added in another order
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-4)
+    for a, w in zip(got[1], want[1]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w),
+                                   atol=1e-5, rtol=1e-5)
+    assert got[1][0].sharding.is_equivalent_to(heads, 4)
+
+    product = jax.device_put(
+        jnp.zeros((4, 128, 3 * h * 64)),
+        NamedSharding(mesh, P("data", None, "model")))
+    text = str(jax.make_jaxpr(loss)(
+        *(jax.device_put(x, heads) for x in (q, k, v))))
+    assert "shard_map" in text and "flash_fwd_packed" in text
+    seen = []
+    jax.jit(lambda x: seen.append(
+        flash_attention_qkv_usable(x, h, True)) or x)(product)
+    assert seen == [False]

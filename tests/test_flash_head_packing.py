@@ -1,12 +1,13 @@
 """Head-packed vs unpacked flash-kernel parity (ISSUE 4 satellite).
 
-The packed kernel processes two d=64 heads per grid step in a
-feature-packed [rows, T, 128] layout with block-diagonal K/V so every
-score/output contraction runs at the MXU's native K=128
+Both kernels read the same 128-lane column tile of [B, T, H·D], two
+d=64 heads side by side: the packed one works it whole, with
+block-diagonal K/V so every score/output contraction runs at the MXU's
+native K=128, the unpacked one its two halves in turn
 (flash_attention.py module docstring). The zero lanes contribute exact
 +0 to every fp32 partial sum, so packed and unpacked must agree to
 fp32 roundoff — forward AND backward — across head counts (even, and
-odd B·H exercising the one-row zero pad), seq lengths that are and are
+odd, whose last tile holds one head), seq lengths that are and are
 not multiples of the default block, causal/bidirectional, and
 bf16/fp32. Everything runs the real Pallas kernels in interpreter mode
 on CPU (head_packing="packed" forces the packed body; "auto" stays
@@ -43,10 +44,11 @@ TOL = {jnp.float32: dict(atol=2e-6, rtol=2e-6),
 @pytest.mark.parametrize("causal", [True, False],
                          ids=["causal", "bidir"])
 @pytest.mark.parametrize("b,t,h", [
-    (2, 128, 2),    # even B*H, single 128 tile
-    (1, 256, 3),    # ODD B*H -> one-row zero pad, multi-tile
+    (2, 128, 2),    # even H, single 128 tile
+    (1, 256, 3),    # ODD H -> the last column tile holds one head
     (1, 384, 2),    # T=384: NOT a multiple of the 1024 default block
                     # (_fit_block shrinks to 128-wide tiles)
+    (2, 128, 25),   # GPT-2 1.5B's heads: C = 1,600, 12.5 column tiles
 ])
 def test_forward_parity(b, t, h, causal, dtype):
     q, k, v = qkv(b, t, h, 64, dtype)
@@ -64,7 +66,8 @@ def test_forward_parity(b, t, h, causal, dtype):
                          ids=["causal", "bidir"])
 @pytest.mark.parametrize("b,t,h", [
     (2, 128, 2),    # single-tile -> fused one-pass backward kernel
-    (1, 256, 3),    # odd B*H + multi-tile -> dkv+dq sweep kernels
+    (1, 256, 3),    # odd H + multi-tile -> dkv+dq sweep kernels
+    (2, 128, 25),   # C = 1,600 through the one-pass kernel
 ])
 def test_backward_parity(b, t, h, causal, dtype):
     q, k, v = qkv(b, t, h, 64, dtype, seed=3)
@@ -81,11 +84,12 @@ def test_backward_parity(b, t, h, causal, dtype):
         np.testing.assert_allclose(ab(g_p), ab(g_u), **TOL[dtype])
 
 
-def test_lse_parity():
+@pytest.mark.parametrize("h", [3, 4])
+def test_lse_parity(h):
     """The saved logsumexp rows (log2 space) drive both backward
     kernels and the ring merge — they must match too, including on the
-    odd pad row's real neighbors."""
-    q, k, v = qkv(1, 256, 3, 64, seed=5)
+    odd head's neighbors in the [B, T, H] block."""
+    q, k, v = qkv(1, 256, h, 64, seed=5)
     out_p, lse_p = flash_attention_with_lse(
         q, k, v, causal=True, interpret=True, head_packing="packed")
     out_u, lse_u = flash_attention_with_lse(
@@ -94,13 +98,14 @@ def test_lse_parity():
     np.testing.assert_allclose(ab(lse_p), ab(lse_u), atol=2e-6, rtol=2e-6)
 
 
+@pytest.mark.parametrize("h", [2, 3], ids=["even", "odd"])
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "bidir"])
-def test_merge_parity(causal):
+def test_merge_parity(causal, h):
     """Ring-step epilogue merge: packed vs unpacked kernels folding the
     same prior (out, lse) partial must agree in the merged result AND
     in the gradients flowing to the prior partial (the ring backward
     differentiates through every step's carry)."""
-    b, t, h = 1, 256, 2
+    b, t = 1, 256
     q, k, v = qkv(b, t, h, 64, seed=7)
     k2, v2 = qkv(b, t, h, 64, seed=11)[:2]
     prev_out, prev_lse = flash_attention_with_lse(
@@ -124,6 +129,27 @@ def test_merge_parity(causal):
     np.testing.assert_allclose(ab(l_p), ab(l_u), atol=2e-6, rtol=2e-6)
     for a, b_ in zip(g_p, g_u):
         np.testing.assert_allclose(ab(a), ab(b_), atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("b,t,h", [(2, 128, 4), (2, 128, 5)],
+                         ids=["even", "odd"])
+def test_one_tile_packed_is_unpacked_bit_for_bit(b, t, h):
+    """At one T tile (what the training cells run) the two kernels do
+    not merely agree: the packed contraction's zero blocks add exact
+    +0, so outputs, lse and all three gradients are the same bits."""
+    q, k, v = qkv(b, t, h, 64, seed=19)
+
+    def run(hp):
+        def f(q, k, v):
+            out, lse = flash_attention_with_lse(
+                q, k, v, causal=True, interpret=True, head_packing=hp)
+            return jnp.sum(jnp.sin(out)) + jnp.sum(jnp.cos(lse)), (out, lse)
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)(
+            q, k, v)
+
+    for a, b_ in zip(jax.tree_util.tree_leaves(run("packed")),
+                     jax.tree_util.tree_leaves(run("off"))):
+        np.testing.assert_array_equal(ab(a), ab(b_))
 
 
 def test_packed_matches_dense_reference():
@@ -157,3 +183,32 @@ def test_resolution_rules():
     out = flash_attention(q, k, v, causal=True, interpret=True,
                           head_packing="auto")
     assert out.shape == (1, 128, 2, 128)
+
+
+def test_the_chip_probe_compares_what_it_says_at_toy_size():
+    """`tests/perf/flash_kernel_ab.py` is how packed against unpacked
+    (and this launcher against a parent checkout's) is re-measured on
+    the chip; here its comparisons run in the interpreter, timing
+    nothing, with this tree standing in for the parent's checkout."""
+    import importlib.util
+    import os
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "flash_kernel_ab", os.path.join(repo, "tests/perf/flash_kernel_ab.py"))
+    probe = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(probe)
+
+    shapes = {name: probe.TOY[name] for name in ("gpt2-350m", "gpt2-1.5b")}
+    assert [s[2] * s[3] % 128 for s in shapes.values()] == [0, 64]
+    rows = probe.compare(shapes, parent=probe.load_parent(repo),
+                         interpret=True)
+    assert set(rows["gpt2-350m"]) == {"packed", "off", "product",
+                                      "parent_packed", "parent_off"}
+    assert "product" not in rows["gpt2-1.5b"]       # 2.5 column tiles
+    for variants in rows.values():
+        for row in variants.values():
+            assert row["finite"] and "fwd_ms" not in row
+            assert max(row["grad_rel_err_vs_dense"]) < 1e-2
+        assert variants["packed"]["grad_rel_err_vs_parent"] == [0.0] * 3
+    assert rows["gpt2-350m"]["product"]["same_bits_as_packed"]
